@@ -24,8 +24,6 @@ arrivals at a fixed offered rate do not.
     python bench.py --loadtest --smoke     # CPU smoke; updates
                                            # benchmarks/LOADTEST_cpu.json
     python bench.py --loadtest             # longer run, same artifact shape
-
-Wired into benchmarks/tpu_battery.py as phase 6 (subprocess, CPU-forced).
 """
 
 from __future__ import annotations

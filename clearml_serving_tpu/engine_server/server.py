@@ -207,6 +207,7 @@ async def serve(service_id: Optional[str] = None) -> None:
 
     from ..serving.model_request_processor import ModelRequestProcessor
     from ..statistics.metrics import StatisticsController
+    from ..utils.tpu import device_memory_stats
 
     from ..serving.main import maybe_start_profiler
 
@@ -257,7 +258,7 @@ async def serve(service_id: Optional[str] = None) -> None:
                     for name, info in repo.list_models().items():
                         requests_g.labels(model=name).set(info["requests_served"])
                         batches_g.labels(model=name).set(info["batches_executed"])
-                    hbm.update_device_gauges()
+                    hbm.update_device_gauges(device_memory_stats())
             except Exception as ex:
                 print("engine server reconcile error: {}".format(ex))
 

@@ -37,25 +37,26 @@ from ..utils.files import atomic_write_json, read_json
 # initialization (or contend for the TPU device lock) just to mutate config.
 
 _DEFAULT_BUCKETS = [1, 2, 4, 8, 16, 32, 64, 128]
-_compilation_cache_ready = False
+# the one in-code location of the persistent compilation cache: a fixed
+# directory inside the checkout (the path is part of the cache key, so a
+# directory that moves never hits)
+_CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def enable_persistent_compilation_cache() -> None:
-    global _compilation_cache_ready
-    if _compilation_cache_ready:
+    """Place JAX's persistent compilation cache. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from outside
+    — JAX reads the variable itself and this function sets nothing;
+    otherwise the cache lives in ``<checkout>/.jax_cache``. Every entry
+    point that compiles goes through here (LLM and jax endpoints, bench,
+    loadtests), so no other code names a cache directory."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax
 
-    cache_dir = os.environ.get("TPUSERVE_COMPILE_CACHE") or str(
-        Path.home() / ".tpu-serving" / "xla-cache"
-    )
-    try:
-        Path(cache_dir).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        _compilation_cache_ready = True
-    except Exception:  # tpuserve: ignore[TPU401] cache dir may be read-only/unsupported; compile-per-process still works
-        pass
+    _CHECKOUT_CACHE_DIR.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 # -- bundle IO ----------------------------------------------------------------

@@ -6,51 +6,38 @@ keys the serve loop presents to XLA is finite and fully compiled before
 serving starts. The static analyzer proves the bucketizer discipline the
 invariant rests on; this sentry proves the INVARIANT ITSELF at runtime:
 armed with ``TPUSERVE_COMPILE_SENTRY=1`` (count) or ``=strict`` (raise),
-it hooks JAX's compile path, splits compilations at the warmup fence
+it listens to JAX's compile events, splits compilations at the warmup fence
 (``llm/warmup.py`` sets it after the sweep), attributes each post-fence
 compilation to the in-flight launch (phase, dispatch seq, pipeline depth —
 the engine tags its dispatch workers through a thread-local context), and
 feeds ``engine_xla_compiles_total{phase}`` / ``engine_xla_compile_ms``
 (statistics/metrics.py). In strict mode a post-fence compilation records a
-violation naming the jitted function and its argument avals; the engine
+violation naming the jitted function; the engine
 raises :class:`CompileSentryError` for it at the next loop boundary (the
 same check-at-the-boundary shape as the KV sanitizer).
 
-Hook mechanics (jax 0.4.x): the primary listener is a ``logging.Handler``
-on the two loggers ``jax_log_compiles`` writes through —
-``jax._src.interpreters.pxla`` emits ``Compiling <fn> with global shapes
-and types [<avals>]`` at compile start and ``jax._src.dispatch`` emits
-``Finished XLA compilation of jit(<fn>) in <s> sec`` — captured at DEBUG
-without flipping the (stderr-spamming) ``jax_log_compiles`` flag;
-``propagate`` is disabled on those loggers while installed so armed runs
-stay quiet, and restored on uninstall. ``install()`` PROBES the hook with
-a guaranteed-fresh jit compile; if the log records never arrive (jax
-moved its internals), the sentry falls back to a
-``jax.monitoring`` duration listener on the backend-compile event —
-counts and durations survive, function/aval attribution degrades to the
-thread context, and ``stats()["mode"]`` says which net is live.
+Hook mechanics: one listener on the public ``jax.monitoring`` duration
+event ``/jax/core/compile/backend_compile_duration``, which JAX records
+once per executable it builds (a persistent-compilation-cache hit
+included: the event wraps the build-or-fetch), on the thread that
+triggered it, with the jitted function's name as ``fun_name`` and the
+wall time as the duration. Argument avals are not part of the event; the
+thread context carries what the engine knows about the launch (phase,
+prompt length, dispatch seq) instead.
 """
 
 from __future__ import annotations
 
 import contextlib
-import logging
 import os
-import re
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax.monitoring
+
 ENV = "TPUSERVE_COMPILE_SENTRY"
 
-_LOGGER_NAMES = ("jax._src.interpreters.pxla", "jax._src.dispatch")
-_COMPILING_RE = re.compile(
-    r"Compiling (\S+) with global shapes and types (\[.*\])\. "
-    r"Argument mapping"
-)
-_FINISHED_RE = re.compile(
-    r"Finished XLA compilation of (?:jit\()?([^)]+)\)? in ([0-9.eE+-]+) sec"
-)
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # scrape-time histogram edges (ms): compile stalls live in the 10 ms (tiny
@@ -72,20 +59,7 @@ def strict_enabled() -> bool:
 
 class CompileSentryError(RuntimeError):
     """A post-warmup-fence XLA compilation under strict mode: names the
-    jitted function, its argument avals, and the launch context it was
-    attributed to."""
-
-
-class _SentryHandler(logging.Handler):
-    def __init__(self, sentry: "CompileSentry"):
-        super().__init__(level=logging.DEBUG)
-        self._sentry = sentry
-
-    def emit(self, record: logging.LogRecord) -> None:
-        try:
-            self._sentry._on_log(record.getMessage())
-        except Exception:  # never let bookkeeping break a compile
-            pass
+    jitted function and the launch context it was attributed to."""
 
 
 class CompileSentry:
@@ -99,11 +73,6 @@ class CompileSentry:
         self._tls = threading.local()
         self._fence = False
         self._installed = False
-        self._probing = False
-        self._mode = "off"            # "log" | "monitoring" | "off"
-        self._log_seen = False
-        self._handler: Optional[_SentryHandler] = None
-        self._saved: Dict[str, tuple] = {}
         self.counts = {"warmup": 0, "serve": 0}
         self._hist_counts = [0] * (len(_BUCKETS_MS) + 1)
         self._hist_sum_ms = 0.0
@@ -113,76 +82,17 @@ class CompileSentry:
     # -- install / uninstall ----------------------------------------------
 
     def install(self) -> "CompileSentry":
-        if self._installed:
-            return self
-        for name in _LOGGER_NAMES:
-            logger = logging.getLogger(name)
-            self._saved[name] = (logger.level, logger.propagate)
-        self._handler = _SentryHandler(self)
-        for name in _LOGGER_NAMES:
-            logger = logging.getLogger(name)
-            logger.addHandler(self._handler)
-            logger.setLevel(logging.DEBUG)
-            logger.propagate = False
-        self._installed = True
-        if self._probe():
-            self._mode = "log"
-        else:
-            self._mode = "monitoring"
-            self._install_monitoring()
+        if not self._installed:
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_event
+            )
+            self._installed = True
         return self
 
-    def _probe(self) -> bool:
-        """Force a guaranteed-fresh jit compile and report whether the log
-        listener saw it (a fresh lambda object is a fresh jit cache, so
-        this compiles no matter what ran before). Probe compiles are not
-        counted."""
-        self._probing = True
-        try:
-            import jax
-            import jax.numpy as jnp
-
-            jax.jit(lambda x: x + jnp.float32(1))(jnp.zeros((3,), jnp.float32))
-        except Exception:
-            return False
-        finally:
-            self._probing = False
-        return self._log_seen
-
-    def _install_monitoring(self) -> None:
-        try:
-            import jax.monitoring as monitoring
-
-            def _on_event(event: str, duration: float, **_kw) -> None:
-                # jax.monitoring has no per-listener unregister: gate on
-                # the installed flag so an uninstalled sentry goes inert
-                # instead of mutating counters forever
-                if (
-                    event == _BACKEND_COMPILE_EVENT
-                    and self._installed
-                    and not self._log_seen
-                ):
-                    self._record(
-                        fn="<unknown>", avals="<unavailable>",
-                        duration_ms=duration * 1e3,
-                    )
-
-            monitoring.register_event_duration_secs_listener(_on_event)
-        except Exception:
-            pass
-
     def uninstall(self) -> None:
-        if not self._installed:
-            return
-        for name in _LOGGER_NAMES:
-            logger = logging.getLogger(name)
-            if self._handler is not None:
-                logger.removeHandler(self._handler)
-            level, propagate = self._saved.get(name, (logging.NOTSET, True))
-            logger.setLevel(level)
-            logger.propagate = propagate
-        self._installed = False
-        self._mode = "off"
+        if self._installed:
+            jax.monitoring.unregister_event_duration_listener(self._on_event)
+            self._installed = False
 
     # -- attribution context ----------------------------------------------
 
@@ -199,34 +109,17 @@ class CompileSentry:
 
     # -- event intake ------------------------------------------------------
 
-    def _on_log(self, message: str) -> None:
-        m = _COMPILING_RE.search(message)
-        if m is not None:
-            self._log_seen = True
-            if self._probing:
-                return
-            self._record(fn=m.group(1), avals=m.group(2), duration_ms=None)
+    def _on_event(self, event: str, duration: float, **kwargs) -> None:
+        if event != _BACKEND_COMPILE_EVENT:
             return
-        m = _FINISHED_RE.search(message)
-        if m is not None:
-            self._log_seen = True
-            if self._probing:
-                return
-            try:
-                duration_ms = float(m.group(2)) * 1e3
-            except ValueError:
-                return
-            self._attach_duration(m.group(1), duration_ms)
-
-    def _record(self, fn: str, avals: str,
-                duration_ms: Optional[float]) -> None:
+        fn = str(kwargs.get("fun_name") or "<unknown>")
+        duration_ms = duration * 1e3
         ctx = dict(getattr(self._tls, "ctx", None) or {})
         with self._lock:
             phase = "serve" if self._fence else "warmup"
             self.counts[phase] += 1
             event = {
                 "fn": fn,
-                "avals": avals,
                 "phase": phase,
                 "context": ctx,
                 "t": time.time(),
@@ -234,23 +127,12 @@ class CompileSentry:
             }
             self.events.append(event)
             del self.events[:-_MAX_EVENTS]
-            if duration_ms is not None:
-                self._observe_locked(duration_ms)
+            self._observe_locked(duration_ms)
             # a `lazy=True` context marks a __compile_keys__ "lazy"-role
             # entry (one bounded compile per variant on first use, by
             # declared design): counted and attributed, never a violation
             if phase == "serve" and self.strict and not ctx.get("lazy"):
                 self.violations.append(event)
-
-    def _attach_duration(self, fn: str, duration_ms: float) -> None:
-        with self._lock:
-            for event in reversed(self.events):
-                if event["duration_ms"] is None and event["fn"] == fn:
-                    event["duration_ms"] = duration_ms
-                    break
-            else:
-                return
-            self._observe_locked(duration_ms)
 
     def _observe_locked(self, ms: float) -> None:
         for i, edge in enumerate(_BUCKETS_MS):
@@ -290,11 +172,11 @@ class CompileSentry:
                 return
             v = self.violations[0]
         raise CompileSentryError(
-            "XLA compiled {} with avals {} AFTER the warmup fence{}{} — "
+            "XLA compiled {} AFTER the warmup fence{}{} — "
             "a serve-time compile stall; extend llm/warmup.py's sweep or "
             "bucketize the shape source (docs/static_analysis.md TPU6xx)"
             .format(
-                v["fn"], v["avals"],
+                v["fn"],
                 " at {}".format(where) if where else "",
                 " (context: {})".format(v["context"]) if v["context"] else "",
             )
@@ -318,7 +200,6 @@ class CompileSentry:
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             return {
-                "mode": self._mode,
                 "strict": self.strict,
                 "fenced": self._fence,
                 "compiles": dict(self.counts),
@@ -331,7 +212,6 @@ class CompileSentry:
         metrics collector reads): counters + histogram, no event list."""
         with self._lock:
             return {
-                "mode": self._mode,
                 "strict": self.strict,
                 "fenced": self._fence,
                 "warmup": self.counts["warmup"],

@@ -66,23 +66,54 @@ class LLMEngineRequest(BaseEngineRequest):
         self._warmup_task = None
         super().__init__(*args, **kwargs)
 
-    async def _ensure_warm(self) -> None:
-        """First arrivals share one warmup task (llm/warmup.py) and wait
-        for it; afterwards this is one attribute read. A failed warmup is
-        logged and disabled rather than bricking the endpoint — serving
-        then compiles lazily, exactly the pre-knob behavior."""
-        if not self._warmup_needed or self.engine is None:
-            return
-        if self._warmup_task is None:
+    def start_warmup(self) -> None:
+        """Start the shared warmup task (llm/warmup.py) on the running
+        event loop. The server calls this at startup for every endpoint
+        loaded with aux engine.warmup, so the sweep compiles before the
+        first request and /ready holds 503 until it finished; a lazily
+        loaded endpoint starts it from its first request instead."""
+        if (
+            self._warmup_needed
+            and self.engine is not None
+            and self._warmup_task is None
+        ):
             self._warmup_task = asyncio.create_task(
                 self.engine.warmup(full=self._warmup_full)
             )
+
+    @property
+    def warmup_state(self) -> Optional[str]:
+        """None without aux engine.warmup; else pending / running / done /
+        "failed: <error>" — /ready reports it and is 503 until "done"."""
+        task = self._warmup_task
+        if task is None:
+            return "pending" if self._warmup_needed else None
+        if not task.done():
+            return "running"
+        if task.cancelled():
+            return "failed: cancelled"
+        if task.exception() is not None:
+            return "failed: {}".format(task.exception())
+        return "done"
+
+    async def _ensure_warm(self) -> None:
+        """Arrivals share one warmup task and wait for it; afterwards this
+        is one attribute read. A failed warmup FAILS THE ENDPOINT: a
+        program that did not compile in the sweep will not compile for a
+        user either, so every request gets the load error (and /ready
+        stays 503) instead of the first user meeting it as a 5xx."""
+        if not self._warmup_needed or self.engine is None:
+            return
+        self.start_warmup()
         try:
             await asyncio.shield(self._warmup_task)
-        except Exception as ex:  # tpuserve: ignore[TPU401] warmup is best-effort by contract; failure falls back to lazy compiles and is logged
-            logging.getLogger(__name__).warning(
-                "engine warmup failed (serving will compile lazily): %s", ex
-            )
+        except asyncio.CancelledError:
+            raise
+        except Exception as ex:
+            raise EndpointModelError(
+                "llm endpoint {!r} failed its engine.warmup sweep: {}: {}"
+                .format(self.endpoint.serving_url, type(ex).__name__, ex)
+            ) from ex
         self._warmup_needed = False
 
     # -- loading --------------------------------------------------------------
@@ -163,7 +194,16 @@ class LLMEngineRequest(BaseEngineRequest):
                     **cfg_overrides,
                 },
             )
-            params = bundle.init(jax.random.PRNGKey(int(engine_cfg.get("seed", 0))))
+            key = jax.random.PRNGKey(int(engine_cfg.get("seed", 0)))
+            # with engine.weight_quant the packed tree is generated
+            # directly (models/llama init): llama3-8b is 16 GB in bf16 —
+            # a full-precision tree for the engine to quantize afterwards
+            # does not fit the 16 GB chip the int8 one serves from
+            params = (
+                bundle.init(key, weight_quant=str(weight_quant))
+                if weight_quant
+                else bundle.init(key)
+            )
         else:
             raise EndpointModelError(
                 "llm endpoint {!r} needs a model bundle or aux_config engine.preset".format(
@@ -175,8 +215,17 @@ class LLMEngineRequest(BaseEngineRequest):
         if aux.get("mesh"):
             from ..parallel import mesh_from_aux_cfg
 
-            if len(jax.devices()) > 1:
+            try:
                 mesh = mesh_from_aux_cfg(aux)
+            except ValueError as ex:
+                # an endpoint that asks for a mesh this host cannot build
+                # must not silently serve from one device
+                raise ValueError(
+                    "aux mesh={!r} cannot be built on this host's {} "
+                    "device(s): {}".format(
+                        aux.get("mesh"), len(jax.devices()), ex
+                    )
+                ) from ex
 
         self.tokenizer = load_tokenizer(
             self._model_local_path, int(bundle.config.get("vocab_size", 0))

@@ -263,7 +263,7 @@ def warm_ragged_variants(engine) -> int:
         blocks = (
             jnp.asarray(np.full(nb, -1, np.int32)),
             jnp.asarray(np.zeros(nb, np.int32)),
-        ) if engine._ragged_on_tpu else (None, None)
+        ) if engine._ragged_kernel else (None, None)
         for steps in windows:
             for spec_on in spec_opts:
                 chain = None

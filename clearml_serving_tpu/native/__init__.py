@@ -1,8 +1,11 @@
 """ctypes bindings for the native runtime library (libtpuserve_native.so).
 
-Builds lazily with the in-image toolchain (`make` + g++) on first use; every
-consumer must degrade gracefully to its pure-Python path when the library is
-unavailable (no compiler, read-only filesystem, exotic platform).
+The library is never committed: every load runs ``make``, which builds it
+from the committed ``queue.cpp`` when the binary is missing or older than
+its source and is a no-op otherwise — so what runs is always what the
+checkout holds. Consumers fall back to their pure-Python path when it
+cannot be built (no compiler, read-only filesystem) and say which one
+serves (``FastSimpleQueue.backend``).
 """
 
 from __future__ import annotations
@@ -19,16 +22,16 @@ _lib_failed = False
 
 
 def load_native() -> Optional[ctypes.CDLL]:
-    """The shared library, building it if needed; None if unavailable."""
+    """The shared library, (re)built from source if stale; None if
+    unavailable."""
     global _lib, _lib_failed
     if _lib is not None or _lib_failed:
         return _lib
     try:
-        if not _LIB_PATH.exists():
-            subprocess.run(
-                ["make", "-s", "libtpuserve_native.so"],
-                cwd=str(_NATIVE_DIR), check=True, capture_output=True, timeout=120,
-            )
+        subprocess.run(
+            ["make", "-s", "libtpuserve_native.so"],
+            cwd=str(_NATIVE_DIR), check=True, capture_output=True, timeout=120,
+        )
         lib = ctypes.CDLL(str(_LIB_PATH))
         lib.tpuserve_queue_create.restype = ctypes.c_void_p
         lib.tpuserve_queue_create.argtypes = [ctypes.c_uint64, ctypes.c_uint64]

@@ -161,62 +161,21 @@ def quantize_llama_params(
 
 
 def random_quantized_llama(config: dict, seed: int = 0, bits: int = 8):
-    """(bundle, params) with the int8/int4 tree built DIRECTLY — full-precision
-    weights are never materialized, so an 8B model initializes inside a single
-    chip's HBM. For benchmarks and weightless demo endpoints (throughput is
-    weight-value-independent); real checkpoints go through
-    quantize_llama_params instead."""
+    """(bundle, params) for a scan-layers build with the int8/int4 tree
+    generated DIRECTLY (``bundle.init(..., weight_quant=...)``) —
+    full-precision weights are never materialized, so an 8B model
+    initializes inside a single chip's HBM. For benchmarks; real
+    checkpoints go through quantize_llama_params instead."""
     import jax
 
     from ..models import llama
 
-    cfg = llama.resolve_config(dict(config, scan_layers=True))
+    if bits not in (4, 8):
+        raise ValueError("bits must be 4 or 8, got {}".format(bits))
     bundle = llama.build(dict(config, scan_layers=True))
-    dim = int(cfg["dim"])
-    n_layers = int(cfg["n_layers"])
-    heads_dim = dim  # wq output
-    n_kv_dim = int(cfg["n_kv_heads"]) * (dim // int(cfg["n_heads"]))
-    ffn = int(cfg["ffn_dim"])
-    vocab = int(cfg["vocab_size"])
-    dtype = jnp.dtype(cfg["dtype"])
-
-    def _qleaf(key, shape):  # shape = (K, N), possibly under a leading stack
-        k_in = shape[-2]
-        if bits == 4:
-            groups = int4_groups(k_in)
-            return {
-                "_q4": jax.random.randint(
-                    key, shape[:-2] + (k_in // 2, shape[-1]), 0, 256, jnp.uint8
-                ),
-                "_scale4": jnp.full(
-                    shape[:-2] + (groups, shape[-1]), 0.01, jnp.float32
-                ),
-            }
-        return {
-            "_q8": jax.random.randint(key, shape, -127, 128, jnp.int8),
-            "_scale": jnp.full(shape[:-2] + (1, shape[-1]), 0.01, jnp.float32),
-        }
-
-    def qstack(key, shape):
-        return _qleaf(key, (n_layers,) + shape)
-
-    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
-    params = {
-        "embed": (jax.random.normal(ks[0], (vocab, dim)) * 0.02).astype(dtype),
-        "lm_head": _qleaf(ks[1], (dim, vocab)),
-        "final_norm": jnp.ones((dim,), dtype),
-        "layers": {
-            "attn_norm": jnp.ones((n_layers, dim), dtype),
-            "wq": qstack(ks[2], (dim, heads_dim)),
-            "wk": qstack(ks[3], (dim, n_kv_dim)),
-            "wv": qstack(ks[4], (dim, n_kv_dim)),
-            "wo": qstack(ks[5], (heads_dim, dim)),
-            "ffn_norm": jnp.ones((n_layers, dim), dtype),
-            "w_gate": qstack(ks[6], (dim, ffn)),
-            "w_up": qstack(ks[7], (dim, ffn)),
-            "w_down": qstack(ks[8], (ffn, dim)),
-        },
-    }
+    params = bundle.init(
+        jax.random.PRNGKey(seed), weight_quant="int4" if bits == 4 else "int8"
+    )
     return bundle, params
 
 

@@ -219,18 +219,7 @@ def configure_process_devices(devices: Optional[dict]) -> None:
     block = devices or {}
     n = int(block.get("cpu_devices") or 0)
     if n > 0:
-        # env first: it works even on jax builds without the explicit
-        # config knob (same fallback ladder as tests/conftest.py), and the
-        # worker main calls this before jax is ever imported
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count={}".format(n)
-            ).strip()
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
 
-        try:
-            jax.config.update("jax_num_cpu_devices", n)
-        except AttributeError:
-            pass
+        jax.config.update("jax_num_cpu_devices", n)

@@ -23,13 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# jax moved shard_map out of experimental in newer releases and removed the
-# experimental alias; older jaxlibs (this image: 0.4.x) only have the
-# experimental one. Resolve once, newest spelling first.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _stream_block(q, k, v, o, m, l, mask):
     """One flash-style accumulation step.
@@ -63,11 +56,13 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
 
     # accumulators start as constants; mark them device-varying over the ring
     # axis so the fori_loop carry type matches the body outputs (JAX vma
-    # rules). Older jax has no pvary (and no vma typing either) — identity.
-    pvary = getattr(lax, "pvary", lambda x, _axis: x)
-    o = pvary(jnp.zeros((b, s_q, h, d), jnp.float32), axis_name)
-    m = pvary(jnp.full((b, s_q, h), -jnp.inf, jnp.float32), axis_name)
-    l = pvary(jnp.zeros((b, s_q, h), jnp.float32), axis_name)
+    # rules)
+    def varying(x):
+        return lax.pcast(x, axis_name, to="varying")
+
+    o = varying(jnp.zeros((b, s_q, h, d), jnp.float32))
+    m = varying(jnp.full((b, s_q, h), -jnp.inf, jnp.float32))
+    l = varying(jnp.zeros((b, s_q, h), jnp.float32))
 
     causal_mask = jnp.where(
         jnp.tril(jnp.ones((s_q, s_q), dtype=bool)), 0.0, -jnp.inf
@@ -119,7 +114,7 @@ def ring_attention(
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis_name, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -113,6 +113,13 @@ def _engine_health(processor: ModelRequestProcessor) -> dict:
                 out[url] = health()
             except Exception as ex:
                 out[url] = {"ready": False, "error": str(ex)}
+            # aux engine.warmup endpoints are not ready until the sweep
+            # finished, and never if it failed (llm/openai_api.py)
+            warmup = getattr(proc, "warmup_state", None)
+            if warmup is not None:
+                out[url]["warmup"] = warmup
+                if warmup != "done":
+                    out[url]["ready"] = False
     return out
 
 
@@ -383,6 +390,7 @@ def build_app(processor: ModelRequestProcessor) -> web.Application:
                     "brownout": brownout,
                     "fleet": fleet,
                     "engines": engines,
+                    "stats_queue": processor.stats_queue_backend,
                 },
                 status=503,
                 headers={"Retry-After": "5"},
@@ -394,8 +402,21 @@ def build_app(processor: ModelRequestProcessor) -> web.Application:
                 "brownout": brownout,
                 "fleet": fleet,
                 "engines": engines,
+                "stats_queue": processor.stats_queue_backend,
             }
         )
+
+    async def start_warmups(app: web.Application) -> None:
+        # endpoints prefetched at launch run their aux engine.warmup sweep
+        # now, on the serving loop, so /ready answers for compiled programs
+        for proc in list(
+            getattr(processor, "_engine_processor_lookup", {}).values()
+        ):
+            start = getattr(proc, "start_warmup", None)
+            if callable(start):
+                start()
+
+    app.on_startup.append(start_warmups)
 
     app.router.add_post("/{}/{{tail:.+}}".format(serve_suffix), serve_model)
     app.router.add_get("/{}/{{tail:openai/.+}}".format(serve_suffix), serve_model)

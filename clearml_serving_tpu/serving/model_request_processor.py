@@ -110,13 +110,17 @@ class FastSimpleQueue:
 
         self._json = _json
         self._native = None
+        # which queue serves — both are legitimate, silence is not: /ready
+        # reports this string
+        self.backend = "python"
         if os.environ.get("TPUSERVE_NATIVE_QUEUE"):
             try:
                 from ..native import NativeQueue
 
                 self._native = NativeQueue(capacity=1024, cell_bytes=4096)
-            except Exception:  # tpuserve: ignore[TPU401] optional native accel; deque fallback below
-                pass
+                self.backend = "native"
+            except Exception as ex:  # tpuserve: ignore[TPU401] optional native accel; deque fallback below, named in .backend
+                self.backend = "python (native requested, unavailable: {})".format(ex)
         self._q = deque()
         self._event = threading.Event()
         self._last_notify = time.time()
@@ -875,6 +879,11 @@ class ModelRequestProcessor:
                 time.sleep(5.0)
 
     # -- observability ---------------------------------------------------------
+
+    @property
+    def stats_queue_backend(self) -> str:
+        """"native" or "python": which queue carries the stats packets."""
+        return self._stats_queue.backend
 
     def get_serving_layout(self) -> Dict[str, Any]:
         """Endpoint table + routing graph — the reference's endpoint-table /

@@ -14,9 +14,11 @@ Capability parity with the reference's StatisticsController
   throttled config re-sync;
 - a sync daemon polls the control plane for metric-spec updates.
 
-TPU addition (SURVEY.md §5.1/§5.5): per-chip HBM gauges sourced from
-``jax.local_devices()[i].memory_stats()`` — the bytes-in-use / bytes-limit
-pair is the serving fleet's north-star memory signal.
+TPU addition (SURVEY.md §5.1/§5.5): per-chip HBM gauges — the bytes-in-use /
+bytes-limit pair is the serving fleet's north-star memory signal. The
+numbers come from the process that owns the chip (``update_device_gauges``
+takes ``utils.tpu.device_memory_stats()`` rows); this package itself never
+imports the accelerator runtime.
 """
 
 from __future__ import annotations
@@ -1208,14 +1210,12 @@ class StatisticsController:
             n += 1
         return n
 
-    def update_device_gauges(self) -> None:
-        """Per-chip HBM gauges (no-op on backends without memory_stats)."""
-        try:
-            import jax
-
-            devices = jax.local_devices()
-        except Exception:
-            return
+    def update_device_gauges(self, devices) -> None:
+        """Per-chip HBM gauges from ``utils.tpu.device_memory_stats()``
+        rows, handed in by the process that OWNS the chip (the engine
+        server). This package never imports jax: a chip belongs to one
+        process, and a statistics service that enumerated devices would
+        take it from the router or fail trying."""
         if not self._device_gauges_ready:
             self._hbm_used = Gauge(
                 "tpu_hbm_bytes_in_use", "HBM bytes in use", labelnames=("device",),
@@ -1226,16 +1226,11 @@ class StatisticsController:
                 registry=self._registry,
             )
             self._device_gauges_ready = True
-        for d in devices:
-            try:
-                stats = d.memory_stats() or {}
-            except Exception:
-                continue
-            if "bytes_in_use" in stats:
-                self._hbm_used.labels(device=str(d.id)).set(stats["bytes_in_use"])
-            limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if limit:
-                self._hbm_limit.labels(device=str(d.id)).set(limit)
+        for row in devices:
+            if "bytes_in_use" in row:
+                self._hbm_used.labels(device=str(row["id"])).set(row["bytes_in_use"])
+            if "bytes_limit" in row:
+                self._hbm_limit.labels(device=str(row["id"])).set(row["bytes_limit"])
 
     def start(self) -> None:
         """Blocking consume loop (run in the statistics container main)."""
@@ -1245,7 +1240,6 @@ class StatisticsController:
             batch = self._consumer.poll() if self._consumer else []
             if batch:
                 self.process_batch(batch)
-            self.update_device_gauges()
             if time.time() - last_spec_sync > self._poll_frequency_sec:
                 self.sync_specs()
                 last_spec_sync = time.time()

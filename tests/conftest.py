@@ -13,23 +13,12 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# The serving image preloads jax via sitecustomize, so the env vars above can
-# arrive after import. The config knobs below still apply as long as the
-# backend itself has not been initialized yet.
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-try:
-    # jax >= 0.4.x with the explicit knob; older/other versions rely on the
-    # XLA_FLAGS fallback set above
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass
-
 assert jax.device_count() == 8, (
-    "tests need 8 virtual CPU devices (got {}); the XLA_FLAGS "
-    "--xla_force_host_platform_device_count=8 fallback did not take — jax "
-    "was initialized before conftest ran".format(jax.device_count())
+    "tests need 8 virtual CPU devices (got {}): XLA_FLAGS "
+    "--xla_force_host_platform_device_count=8 did not take — jax was "
+    "initialized before conftest ran".format(jax.device_count())
 )
 
 import pytest  # noqa: E402
@@ -46,6 +35,16 @@ def pytest_configure(config):
         "chaos: fault-injection robustness test (CPU-fast, runs in tier-1; "
         "select with -m chaos)",
     )
+
+
+def pytest_collection_finish(session):
+    # chip_smoke's tiny walk is a minute of child processes: start it now
+    # so it overlaps the first test files (tests/test_chip_smoke.py joins it)
+    if session.config.option.collectonly:
+        return
+    for item in session.items:
+        if item.name.startswith("test_tiny_size_walks_every_phase"):
+            item.module.start_tiny_walk()
 
 
 @pytest.fixture()

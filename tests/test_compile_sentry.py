@@ -65,18 +65,26 @@ async def _collect(engine, req):
 def test_sentry_counts_fence_and_strict_raise():
     sentry = CompileSentry(strict=True).install()
     try:
-        assert sentry.stats()["mode"] == "log"
         jax.jit(lambda x: x * 2)(jnp.ones((3,)))  # fresh lambda: compiles
         assert sentry.counts["warmup"] >= 1
         assert sentry.counts["serve"] == 0
         sentry.check()  # pre-fence: nothing to raise
+        x5 = jnp.ones((5,))  # its eager broadcast compiles pre-fence
         sentry.fence()
-        jax.jit(lambda x: x * 3)(jnp.ones((5,)))
+
+        def times_three(x):
+            return x * 3
+
+        jax.jit(times_three)(x5)
         assert sentry.post_fence_compiles >= 1
+        # a compile after the fence is counted with its fun_name (the
+        # public jax.monitoring event carries it)
+        served = [e for e in sentry.stats()["events"] if e["phase"] == "serve"]
+        assert any(e["fn"] == "jit(times_three)" for e in served), served
         with pytest.raises(CompileSentryError) as exc:
             sentry.check(where="unit")
         assert "AFTER the warmup fence" in str(exc.value)
-        assert "ShapedArray" in str(exc.value)
+        assert "times_three" in str(exc.value)
     finally:
         sentry.uninstall()
     # uninstalled: further compiles are invisible

@@ -67,7 +67,7 @@ def test_kernel_interpret_parity(m, k, n, group):
     q, s = quantize_int4(w, group=group)
     assert int4_kernel_unsupported_reason(x, q, s, interpret=True) is None
     ref = int4_matmul_xla(x, q, s, jnp.float32)
-    out = fused_int4_matmul(x, q, s, dtype=jnp.float32, interpret=True)
+    out = fused_int4_matmul(x, q, s, interpret=True)
     assert out.shape == ref.shape and out.dtype == ref.dtype
     assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
 
@@ -79,7 +79,7 @@ def test_kernel_interpret_parity_3d_activations():
     x3 = x.reshape(2, 3, 256)
     q, s = quantize_int4(w, group=128)
     ref = int4_matmul_xla(x3, q, s, jnp.float32)
-    out = fused_int4_matmul(x3, q, s, dtype=jnp.float32, interpret=True)
+    out = fused_int4_matmul(x3, q, s, interpret=True)
     assert out.shape == ref.shape
     assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
 
@@ -89,7 +89,7 @@ def test_kernel_interpret_parity_bf16():
     x = x.astype(jnp.bfloat16)
     q, s = quantize_int4(w, group=128)
     ref = int4_matmul_xla(x, q, s, jnp.bfloat16)
-    out = fused_int4_matmul(x, q, s, dtype=jnp.bfloat16, interpret=True)
+    out = fused_int4_matmul(x, q, s, interpret=True)
     assert out.dtype == jnp.bfloat16
     # bf16 epsilon-scale agreement (both paths accumulate in f32; the
     # operand rounding differs)
@@ -111,9 +111,7 @@ def test_kernel_parity_stacked_tree_slices():
 
     dense = dequantize_int4(q, s, jnp.float32)          # [L, K, N]
     for layer in range(L):
-        out = fused_int4_matmul(
-            x, q[layer], s[layer], dtype=jnp.float32, interpret=True
-        )
+        out = fused_int4_matmul(x, q[layer], s[layer], interpret=True)
         ref = x @ dense[layer]
         assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
 
@@ -121,12 +119,20 @@ def test_kernel_parity_stacked_tree_slices():
 # -- routing matrix ----------------------------------------------------------
 
 def test_unsupported_reason_matrix():
+    """The ONE routing decision (models/llama _mm and the engine's health
+    block both call it): shapes, then — without interpret — platform and
+    the hardware tiling gates."""
     x, w = _rand_wx(2, 256, 256)
     q, s = quantize_int4(w, group=128)
     ok = lambda *a, **kw: int4_kernel_unsupported_reason(*a, **kw)
 
     assert ok(x, q, s, interpret=True) is None
-    assert ok(x, q, s) is None  # hardware-aligned: 2 groups of 128, N=256
+    # hardware-aligned: 2 groups of 128, N=256 — the kernel, on a TPU
+    assert ok(x, q, s, platform="tpu") is None
+    # and the reference everywhere else; the default platform is the
+    # backend the program compiles for (tier-1 runs on CPU)
+    assert "platform cpu" in ok(x, q, s, platform="cpu")
+    assert "platform cpu" in ok(x, q, s)
 
     # stacked (3-D) weights route per layer, never whole
     q3, s3 = quantize_int4(jnp.stack([w, w]), group=128)
@@ -140,37 +146,42 @@ def test_unsupported_reason_matrix():
     x6 = jnp.ones((2, 6), jnp.float32)
     assert "odd group" in ok(x6, q_odd, s_odd, interpret=True)
 
-    # prefill-shaped M falls back to the XLA path
+    # M > cap: prefill-shaped matmuls take the XLA path
     big = jnp.ones((MAX_FUSED_ROWS + 1, 256), jnp.float32)
     assert "rows exceed" in ok(big, q, s, interpret=True)
+    at_cap = jnp.ones((MAX_FUSED_ROWS, 256), jnp.float32)
+    assert ok(at_cap, q, s, platform="tpu") is None
 
-    # hardware-only gates: lane/sublane misalignment (fine in interpret)
+    # hardware-only gates: lane misalignment (fine in interpret)
     xs, ws = _rand_wx(2, 256, 130)
     qs, ss = quantize_int4(ws, group=128)
     assert ok(xs, qs, ss, interpret=True) is None
-    assert "lane-tileable" in ok(xs, qs, ss)
-    xg, wg = _rand_wx(2, 96, 128)   # single 96-row group -> 48 packed rows
+    assert "lane-tileable" in ok(xs, qs, ss, platform="tpu")
+    xg, wg = _rand_wx(2, 96, 128)   # one 96-row group: not a 128-lane window
     qg, sg = quantize_int4(wg, group=96)
     assert ok(xg, qg, sg, interpret=True) is None
-    assert "sublane" in ok(xg, qg, sg)
+    assert "lane-aligned" in ok(xg, qg, sg, platform="tpu")
 
     # int-typed activations are rejected outright
     assert "floating" in ok(x.astype(jnp.int32), q, s, interpret=True)
 
 
-def test_fallback_shapes_match_reference_exactly():
-    """Ineligible shapes must return the byte-identical historical XLA
-    expression — routing through the wrapper is a no-op for them."""
+def test_kernel_never_returns_the_reference():
+    """fused_int4_matmul only ever runs the kernel: operands the routing
+    function rejects raise with its reason — picking int4_matmul_xla is
+    the caller's decision (models/llama _mm), never a silent one."""
     x, w = _rand_wx(2, 6, 10)
-    q, s = quantize_int4(w, group=3)  # odd group -> fallback even in interpret
-    out = fused_int4_matmul(x, q, s, dtype=jnp.float32, interpret=True)
-    ref = int4_matmul_xla(x, q, s, jnp.float32)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-    big = jnp.ones((MAX_FUSED_ROWS + 8, 6), jnp.float32)
-    out = fused_int4_matmul(big, q, s, dtype=jnp.float32, interpret=True)
-    ref = int4_matmul_xla(big, q, s, jnp.float32)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    q, s = quantize_int4(w, group=3)  # odd group: rejected even in interpret
+    with pytest.raises(ValueError, match="odd group"):
+        fused_int4_matmul(x, q, s, interpret=True)
+    # misaligned for the hardware, and not interpreted: refused, not rerouted
+    xs, ws = _rand_wx(2, 256, 130)
+    qs, ss = quantize_int4(ws, group=128)
+    with pytest.raises(ValueError, match="lane-tileable"):
+        fused_int4_matmul(xs, qs, ss)
+    big = jnp.ones((MAX_FUSED_ROWS + 8, 256), jnp.float32)
+    with pytest.raises(ValueError, match="rows exceed"):
+        fused_int4_matmul(big, qs, ss, interpret=True)
 
 
 # -- model-level routing -----------------------------------------------------

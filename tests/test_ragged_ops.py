@@ -9,7 +9,11 @@ import numpy as np
 import pytest
 
 from clearml_serving_tpu.ops.paged_attention import (
+    SMEM_BYTES,
+    paged_attention,
     paged_attention_xla,
+    paged_kernel_smem_bytes,
+    paged_kernel_unsupported_reason,
     ragged_layout,
     ragged_paged_attention,
     ragged_paged_attention_xla,
@@ -188,17 +192,55 @@ def test_ragged_int8_requires_scales():
         )
 
 
-def test_ragged_without_block_map_falls_back_to_xla():
-    """No block metadata -> the XLA reference (identical output), never a
-    kernel crash: jitted callers may omit the host-only layout."""
+def test_ragged_kernel_never_returns_the_reference():
+    """The kernel entry points run the kernel or raise: no block metadata
+    and shapes Mosaic cannot take (here D=64 pools, not interpreted) are
+    errors naming the reason, never a quiet XLA reference."""
     args = _setup(jax.random.PRNGKey(5), row_lens=(1, 4, 1, 1))
     (q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
-     _br, _bq) = args
-    a = ragged_paged_attention(
-        q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
-        interpret=True,
-    )
-    b = ragged_paged_attention_xla(
-        q, k_pool, v_pool, page_table, kv_lens, starts, row_lens
-    )
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b))
+     br, bq) = args
+    with pytest.raises(ValueError, match="block_rows"):
+        ragged_paged_attention(
+            q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
+            interpret=True,
+        )
+    assert q.shape[-1] % 128  # the fixture's head_dim is off the lane tile
+    with pytest.raises(ValueError, match="head_dim"):
+        ragged_paged_attention(
+            q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
+            block_rows=br, block_q0=bq,
+        )
+    with pytest.raises(ValueError, match="head_dim"):
+        paged_attention(q[:4], k_pool, v_pool, page_table, kv_lens)
+
+
+def test_paged_kernel_routing_reasons():
+    """paged_kernel_unsupported_reason is the one routing decision for
+    both paged kernels (models/llama at trace time, the engine's health
+    block at construction): platform, lane tile, dtype-dependent sublane
+    tile — and None for the aligned Llama-3-8B shapes."""
+    why = paged_kernel_unsupported_reason
+    assert why(128, 16, jnp.bfloat16, platform="tpu") is None
+    assert why(128, 32, jnp.int8, platform="tpu") is None
+    assert "head_dim 64" in why(64, 16, jnp.bfloat16, platform="tpu")
+    # int8 pools on the default 16-token pages miss the (32, 128) tile
+    assert "page_size 16" in why(128, 16, jnp.int8, platform="tpu")
+    assert "32-row" in why(128, 16, jnp.int8, platform="tpu")
+    assert "page_size 8" in why(128, 8, jnp.bfloat16, platform="tpu")
+    assert "platform cpu" in why(128, 16, jnp.bfloat16, platform="cpu")
+    assert "platform cpu" in why(128, 16, jnp.bfloat16)  # tier-1 backend
+
+
+def test_paged_kernel_smem_accounting():
+    """The SMEM estimate the engine checks at construction reproduces what
+    the v5e compiler allocated (docs/ragged_attention.md hardware notes):
+    the page table pads to (8, 128) int32 tiles, the ancestor table rides
+    flat, and 1 MiB is the chip's scalar memory."""
+    # decode: s32[256, 896] fit, s32[250, 1024] did not
+    assert paged_kernel_smem_bytes(256, 896) <= SMEM_BYTES
+    assert paged_kernel_smem_bytes(250, 1024) > SMEM_BYTES
+    # ragged + tree: T=8192 R=200 PP=768 compiled at width 12, not at 13
+    assert paged_kernel_smem_bytes(200, 768, 8192, 12) <= SMEM_BYTES
+    assert paged_kernel_smem_bytes(200, 768, 8192, 13) > SMEM_BYTES
+    # the smoke's own configuration is three orders of magnitude inside
+    assert paged_kernel_smem_bytes(8, 129, 184) < SMEM_BYTES // 50

@@ -126,10 +126,31 @@ def test_unknown_endpoint_reserved_only(tmp_path, state_root):
     assert _get_sample(registry, "mystery_custom") is None
 
 
-def test_device_gauges_no_crash(tmp_path):
+def test_device_gauges_take_rows_from_the_chip_owner(tmp_path):
+    """The controller exports what the chip-owning process hands it
+    (utils.tpu.device_memory_stats rows); a CPU row carries only its id."""
     registry = CollectorRegistry()
     ctl = StatisticsController("", registry=registry)
-    ctl.update_device_gauges()  # CPU backend: must not raise
+    ctl.update_device_gauges(
+        [{"id": 0, "bytes_in_use": 5, "bytes_limit": 9}, {"id": 1}]
+    )
+    assert registry.get_sample_value("tpu_hbm_bytes_in_use", {"device": "0"}) == 5
+    assert registry.get_sample_value("tpu_hbm_bytes_limit", {"device": "0"}) == 9
+    assert registry.get_sample_value("tpu_hbm_bytes_in_use", {"device": "1"}) is None
+
+
+def test_statistics_package_never_imports_jax():
+    """A chip belongs to one process: the statistics service runs beside
+    the process that owns it, so nothing in this package may import jax —
+    not at module level and not lazily."""
+    import pathlib
+    import re
+
+    import clearml_serving_tpu.statistics as pkg
+
+    pattern = re.compile(r"^\s*(import|from)\s+jax\b", re.M)
+    for path in pathlib.Path(pkg.__file__).parent.glob("*.py"):
+        assert not pattern.search(path.read_text()), path
 
 
 def test_prefix_cache_collector_exports_live_counters():
@@ -818,7 +839,7 @@ def test_engine_compile_metrics_exported(monkeypatch):
     stats = {
         "queue_depth": 0,
         "compile": {
-            "mode": "log", "strict": False, "fenced": True,
+            "strict": False, "fenced": True,
             "warmup": 7, "serve": 2, "violations": 0,
             "compile_ms": {
                 "buckets": [10.0, 50.0],
