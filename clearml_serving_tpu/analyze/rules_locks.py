@@ -49,6 +49,7 @@ PROJECT_REGISTRY: Dict[str, Tuple[str, Optional[Tuple[str, ...]]]] = {
     "_refs": ("_lock", None),
     "_pending_cow": ("_lock", None),
     "_pins": ("_lock", None),
+    "_used_peak": ("_lock", None),
     # RadixPrefixCache tree state (prefix_cache.py)
     "_roots": ("_lock", None),
     "_leaf_nodes": ("_lock", None),

@@ -171,6 +171,17 @@ class GenRequest:
     # the shipped prefix as a ship hit or a recompute)
     _ship_to: Optional[str] = None
     _shipped: bool = False
+    # engine-internal, monotonic seconds: the request's way to its first
+    # token (lifecycle_stats()["requests"]). _queued is the submission, or
+    # the re-queue of a preempted request (a new wait, not a new TTFT);
+    # _slot_at the pop from the queue with a slot reserved; _job_at the
+    # ragged job's opening (legacy path: the admission task's start);
+    # _prefill_launches the launches that carried one of its prompt chunks
+    _submitted: float = 0.0
+    _queued: float = 0.0
+    _slot_at: float = 0.0
+    _job_at: float = 0.0
+    _prefill_launches: int = 0
 
     def cancel(self) -> None:
         self.cancelled = True
@@ -250,6 +261,85 @@ class _MsHistogram:
             "sum_ms": self.total_ms,
             "count": self.n,
         }
+
+
+# request-phase grid: the default grid ends at 1 s, and a long prompt's
+# first token can take tens of seconds behind other prefills
+_REQUEST_MS_BUCKETS = (
+    1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
+    1000.0, 2500.0, 5000.0, 10000.0, 30000.0,
+)
+_PREFILL_LAUNCH_BUCKETS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64, 128)
+
+
+class _CycleClock:
+    """The loop thread's time through one scheduling cycle, cut into phases
+    that add up (docs/pipelined_decode.md "Observability"): ``admin`` (loop
+    top until the step is entered), ``plan`` (_prepare_ragged /
+    _prepare_dispatch), ``launch`` (waiting for the dispatch worker),
+    ``wait`` (retire entered until every host copy is in hand), ``emit``
+    (the rest of the retire) and ``yield`` (sanitizer + the sleep(0) that
+    hands the event loop to the HTTP handlers). A cycle is a loop iteration
+    that dispatched or retired; it runs from its loop top to the next one.
+    ``mark`` closes the running phase and opens the next on ONE clock read,
+    so the phases partition the cycle (``cycle_ms`` is their sum), and each
+    phase is an ``engine.<phase>`` annotation on the profiler's host plane
+    while a profiler session is open (a flag test otherwise). Loop-thread
+    only."""
+
+    PHASES = ("admin", "plan", "launch", "wait", "emit", "yield")
+
+    def __init__(self):
+        self.phases = {p: _MsHistogram() for p in self.PHASES}
+        self.cycle = _MsHistogram()
+        self._acc = dict.fromkeys(self.PHASES, 0.0)
+        self._phase = None   # None = between cycles (parked or stopped)
+        self._span = None
+        self._t = 0.0
+        self._worked = False
+
+    def _open(self, phase: str, seq: int, now: float) -> None:
+        self._phase, self._t = phase, now
+        self._span = jax.profiler.TraceAnnotation("engine." + phase, seq=seq)
+        self._span.__enter__()
+
+    def _close(self, now: float) -> None:
+        if self._phase is not None:
+            self._acc[self._phase] += now - self._t
+            self._span.__exit__(None, None, None)
+            self._phase = self._span = None
+
+    def top(self, seq: int) -> None:
+        """Loop top: close the iteration that just ran (observed only when
+        it dispatched or retired) and open the next one's ``admin``."""
+        now = time.perf_counter()
+        self._close(now)
+        if self._worked:
+            for phase, acc in self._acc.items():
+                self.phases[phase].observe(acc * 1e3)
+            self.cycle.observe(sum(self._acc.values()) * 1e3)
+        self._acc = dict.fromkeys(self.PHASES, 0.0)
+        self._worked = False
+        self._open("admin", seq, now)
+
+    def mark(self, phase: str, seq: int) -> float:
+        """Enter ``phase`` (no-op when already in it or between cycles);
+        returns the boundary's clock read for callers that share it."""
+        now = time.perf_counter()
+        if self._phase is not None and phase != self._phase:
+            self._close(now)
+            self._open(phase, seq, now)
+            if phase in ("launch", "wait"):
+                self._worked = True
+        return now
+
+    def park(self) -> None:
+        """The loop waits for work or exits: not a cycle, drop the stretch."""
+        self._close(time.perf_counter())
+        self._worked = False
+
+    def snapshot(self) -> dict:
+        return {p + "_ms": h.snapshot() for p, h in self.phases.items()}
 
 
 @dataclass
@@ -1333,9 +1423,20 @@ class LLMEngineCore:
         # they only change at commit (invalidated there)
         self._sampling_dev = None
         self._extras_dev = None
-        # dispatch/retire stage timing for the lifecycle collector
+        # dispatch/retire stage timing for the lifecycle collector.
+        # retire_ms = wait_ms + emit_ms of the cycle clock: it INCLUDES the
+        # blocking device->host sync, so it is not host time
         self._hist_dispatch = _MsHistogram()
         self._hist_retire = _MsHistogram()
+        self._cycle = _CycleClock()
+        # a request's way to its first token, observed at the first _emit
+        # (queue_wait_ms at every slot reservation: a preempted request's
+        # re-admission is a new wait, not a new TTFT)
+        self._hist_request = {
+            name: _MsHistogram(_REQUEST_MS_BUCKETS)
+            for name in ("queue_wait_ms", "admit_ms", "prefill_ms", "ttft_ms")
+        }
+        self._hist_prefill_launches = _MsHistogram(_PREFILL_LAUNCH_BUCKETS)
         # host-tier promotion reaping (docs/kv_tiering.md): loop-affine —
         # completed promotion DMAs are observed at retire boundaries
         self._tier_counters = {"reaps": 0}
@@ -1613,6 +1714,7 @@ class LLMEngineCore:
 
         self._lp_k = lp_k = max(1, int(logprobs_k))
 
+        @jax.named_scope("logprobs")
         def _lp_of(logits, sampled, nb):
             """(chosen logprob [B], top ids [B,K], top logprobs [B,K]).
             Callers pass the PENALIZED logits when bias/penalties are active
@@ -3530,6 +3632,7 @@ class LLMEngineCore:
         self._slot_req[slot] = None
         self._release_guided(slot, request)  # no-op for victims; kept for symmetry
         self._free_slot_pages(slot)
+        request._queued = time.monotonic()  # the resume leg's own wait
         self._pending.put_nowait(request)
         self._wake_loop()
         return True
@@ -3566,6 +3669,7 @@ class LLMEngineCore:
         self._resolve_deadlines(request)
         request.prompt_len = len(request.prompt_ids)
         request.out_queue = asyncio.Queue()
+        request._submitted = request._queued = time.monotonic()
         self._pending.put_nowait(request)
         self._ensure_loop()
         self._wake_loop()
@@ -3632,6 +3736,10 @@ class LLMEngineCore:
             dtype=self.paged_cache.pool_dtype,
             num_pages=self.paged_cache.pool.num_pages,
             page_size=self.paged_cache.pool.page_size,
+            # raw high-water mark, prefix-cache pages included (the brownout
+            # "pool" signal leaves out what the cache could give back; the
+            # current occupancy is the kv_pool_free_pages gauge)
+            used_pages_peak=self.paged_cache.pool.used_pages_peak,
         )
 
     def _reap_promotions(self, force: bool = False) -> None:
@@ -4172,8 +4280,17 @@ class LLMEngineCore:
                 "depth": self.pipeline_depth,
                 "inflight": len(self._inflight),
                 "dispatch_ms": self._hist_dispatch.snapshot(),
+                # wait_ms + emit_ms: the device->host sync is inside it
                 "retire_ms": self._hist_retire.snapshot(),
+                # the loop thread's time per cycle; the six phases add up
+                # to cycle_ms (_CycleClock)
+                "phases": self._cycle.snapshot(),
+                "cycle_ms": self._cycle.cycle.snapshot(),
             },
+            "requests": dict(
+                {k: h.snapshot() for k, h in self._hist_request.items()},
+                prefill_launches=self._hist_prefill_launches.snapshot(),
+            ),
             # ragged token-budget scheduler (docs/ragged_attention.md):
             # per-step budget utilization + per-phase row counters backing
             # engine_step_token_budget_utilization / engine_step_rows
@@ -4652,7 +4769,8 @@ class LLMEngineCore:
         touches no slot state, so decode throughput does not stall while a
         long prompt prefills. The cheap commit happens on the loop thread at
         the next chunk boundary (_commit_admission)."""
-        with self._sentry_scope("prefill", prompt_len=len(request.prompt_ids)):
+        with self._sentry_scope("prefill", prompt_len=len(request.prompt_ids)), \
+                jax.profiler.TraceAnnotation("engine.admit"):
             return self._prefill_device_inner(request)
 
     def _prefill_device_inner(self, request: GenRequest):
@@ -4723,7 +4841,7 @@ class LLMEngineCore:
                 )
             else:
                 prefix_result = self._prefix_admission(
-                    ids, lora_arr, lora_i, gate_bypass
+                    ids, lora_arr, lora_i, gate_bypass, request
                 )
         c = self._chunked
         # the chunked mini cache must be a multiple of C: a final chunk
@@ -4770,6 +4888,7 @@ class LLMEngineCore:
                     # admissions are multi-segment by construction: no
                     # single-dispatch bypass here)
                     self._prefill_gate.acquire()
+                request._prefill_launches += 1
                 last_logits, cache = fn(
                     self.params,
                     jnp.asarray(seg_tokens),
@@ -4789,6 +4908,7 @@ class LLMEngineCore:
                 prefill_fn = self._prefill_jit
             if self._prefill_gate is not None:
                 self._prefill_gate.acquire(bypass=gate_bypass)
+            request._prefill_launches += 1
             last_logits, mini_cache = prefill_fn(
                 self.params, jnp.asarray(tokens), seq_lens, template, lora_arr
             )
@@ -4886,7 +5006,7 @@ class LLMEngineCore:
         return bucket
 
     def _prefill_tail(self, cache, ids, prefix_len: int, lora_arr,
-                      gate_bypass: bool = False):
+                      gate_bypass: bool = False, request=None):
         """Prefill only the non-shared tail of ``ids`` through the donating
         prefill_chunk, attending over the prefix KV already in ``cache``.
         The cache is owned by this admission, so every segment may donate it
@@ -4898,6 +5018,8 @@ class LLMEngineCore:
         # the single-dispatch bypass only applies to a one-segment tail: a
         # longer train is paced exactly like a chunked cold prefill
         gate_bypass = gate_bypass and len(starts) == 1
+        if request is not None:
+            request._prefill_launches += len(starts)
         for si, s in enumerate(starts):
             seg = ids[s : s + c2]
             seg_tokens = np.zeros((1, c2), np.int32)
@@ -4916,7 +5038,7 @@ class LLMEngineCore:
         return last_logits, cache
 
     def _prefix_admission(self, ids, lora_arr, lora_i,
-                          gate_bypass: bool = False):
+                          gate_bypass: bool = False, request=None):
         """Dense prefix-cache hit path: assemble the tree's block run into a
         mini cache and prefill only the remainder through prefill_chunk.
         Returns (last_logits, mini_cache) or None (miss / doesn't fit)."""
@@ -4937,7 +5059,7 @@ class LLMEngineCore:
             template, hit["bufs"], jnp.asarray(prefix_len, jnp.int32)
         )
         return self._prefill_tail(cache, ids, prefix_len, lora_arr,
-                                  gate_bypass)
+                                  gate_bypass, request)
 
     def _prefix_admission_paged(self, ids, lora_arr, lora_i, request):
         """Paged prefix-cache hit path. The shared pages are PINNED by the
@@ -4983,6 +5105,7 @@ class LLMEngineCore:
             last_logits, cache = self._prefill_tail(
                 cache, ids, prefix_len, lora_arr,
                 gate_bypass=request.priority == "interactive",
+                request=request,
             )
         except BaseException:
             # release() is pop-idempotent by construction: re-entering here
@@ -5108,6 +5231,7 @@ class LLMEngineCore:
     async def _admission_task(self, request: GenRequest, slot: int) -> None:
         """Background prefill for one request; reserves `slot` via
         self._admitting until committed or failed."""
+        request._job_at = time.monotonic()
         try:
             first_id, mini_cache, first_lp = await asyncio.to_thread(
                 self._prefill_device, request
@@ -5265,6 +5389,7 @@ class LLMEngineCore:
             request._gen_ids.append(int(token_id))
         if request.first_token_at is None:
             request.first_token_at = time.time()  # client-observable TTFT
+            self._observe_first_token(request)
         request.out_queue.put_nowait(token_id)
         stop_ids = request.stop_token_ids or (
             [self.eos_token_id] if self.eos_token_id is not None else []
@@ -5289,6 +5414,19 @@ class LLMEngineCore:
             except faults.InjectedFault:
                 pass
             self._ledger_audit_request(request, "emit-finish")
+
+    def _observe_first_token(self, request: GenRequest) -> None:
+        """The request's stamps into lifecycle_stats()["requests"]: with
+        queue_wait_ms (observed at the slot reservation) the three stretches
+        add up to ttft_ms for a request that was never preempted."""
+        if not request._job_at:
+            return  # activated without an admission (a test's direct commit)
+        now = time.monotonic()
+        h = self._hist_request
+        h["admit_ms"].observe((request._job_at - request._slot_at) * 1e3)
+        h["prefill_ms"].observe((now - request._job_at) * 1e3)
+        h["ttft_ms"].observe((now - request._submitted) * 1e3)
+        self._hist_prefill_launches.observe(request._prefill_launches)
 
     def _drain_ready(self, err: BaseException) -> None:
         """Fail every completed-but-uncommitted admission (loop is exiting)."""
@@ -5569,12 +5707,13 @@ class LLMEngineCore:
         until the final chunk's commit or a failure path releases it."""
 
         def prep():
-            if faults.active():
-                # the same chaos seam the legacy admission worker fires
-                # (delay = slow admission, raise = failed admission)
-                faults.fire("engine.prefill", request=request)
-            if request.guided is not None:
-                self._ensure_grammar(request)
+            with jax.profiler.TraceAnnotation("engine.admit"):
+                if faults.active():
+                    # the same chaos seam the legacy admission worker fires
+                    # (delay = slow admission, raise = failed admission)
+                    faults.fire("engine.prefill", request=request)
+                if request.guided is not None:
+                    self._ensure_grammar(request)
 
         try:
             await asyncio.to_thread(prep)
@@ -5681,6 +5820,7 @@ class LLMEngineCore:
         # the prefix lookup ran (hit or miss): the preemption-era eviction
         # pin on the stored history has done its job (legacy parity)
         self._release_resume_pin(request)
+        request._job_at = time.monotonic()
         return _RaggedJob(request=request, slot=slot, pos=pos)
 
     def _free_ragged_slot(self, slot: int) -> None:
@@ -6184,7 +6324,8 @@ class LLMEngineCore:
         row's chunk plus the ONE device launch (donated pools/cache,
         rebound under the dispatch lock — same discipline as the legacy
         dispatch workers)."""
-        with self._sentry_scope("ragged", seq=plan["seq"]):
+        with self._sentry_scope("ragged", seq=plan["seq"]), \
+                jax.profiler.TraceAnnotation("engine.dispatch", seq=plan["seq"]):
             return self._dispatch_ragged_device_inner(plan)
 
     def _dispatch_ragged_device_inner(self, plan: dict) -> dict:
@@ -6386,10 +6527,12 @@ class LLMEngineCore:
         the moment the admission backlog drains."""
         # post-ragged decode must re-upload the host mirrors: the device
         # chains were built by the (drained) pipelined path
+        self._cycle.mark("plan", self._dispatch_seq + 1)
         self._reset_device_chains()
         plan = self._prepare_ragged(active_mask, epoch)
         if plan is None:
             return
+        self._cycle.mark("launch", plan["seq"])
         self._dispatching = (plan["seq"], plan["decode_mask"], time.monotonic())
         try:
             result = await asyncio.to_thread(self._dispatch_ragged_device, plan)
@@ -6454,7 +6597,8 @@ class LLMEngineCore:
         pool rolls its over-allocation back to what the verify kept, and
         finishing prefill jobs sample their first token (the legacy
         admission code path) and activate their slot."""
-        t0 = time.perf_counter()
+        seq = plan["seq"]
+        t0 = self._cycle.mark("wait", seq)
         sampled = np.asarray(result["sampled"])
         if sampled.ndim == 1:
             sampled = sampled[None]               # step-major [S, B]
@@ -6473,6 +6617,7 @@ class LLMEngineCore:
             if result["spec_acc"] is not None
             else None
         )
+        self._cycle.mark("emit", seq)
         spec_g = (
             np.asarray(result["spec_g"])
             if result["spec_g"] is not None
@@ -6643,6 +6788,7 @@ class LLMEngineCore:
             if job not in self._prefill_jobs:  # failed since planning
                 continue
             job.pos += take
+            job.request._prefill_launches += 1
             if job.pos < len(job.request.prompt_ids):
                 # draft-ahead KV shipping: the chunk boundary just made
                 # whole storable pages final — overlap the transport with
@@ -6690,7 +6836,8 @@ class LLMEngineCore:
         # retire-stage promotion reap, same rule as the pipelined retire
         self._reap_promotions()
         self._last_progress = time.monotonic()
-        self._hist_retire.observe((time.perf_counter() - t0) * 1e3)
+        t1 = self._cycle.mark("yield", seq)
+        self._hist_retire.observe((t1 - t0) * 1e3)
 
     async def _run_loop(self) -> None:
         try:
@@ -6700,6 +6847,7 @@ class LLMEngineCore:
             self._drain_ready(ex)
             raise
         finally:
+            self._cycle.park()
             if self._prefill_gate is not None:
                 # no decode loop -> nothing to pace against; unblock waiters
                 self._prefill_gate.set_active(False)
@@ -6757,6 +6905,7 @@ class LLMEngineCore:
         decode throughput does not stall during admission (VERDICT r1 #6)."""
         self._wake = asyncio.Event()
         while not self._stopped:
+            self._cycle.top(self._dispatch_seq + 1)
             # deadline sweep: queued requests expire where they wait
             self._expire_pending()
             # host-tier promotions that completed since the last boundary
@@ -6786,6 +6935,10 @@ class LLMEngineCore:
                     continue
                 slot = free.pop(0)
                 self._admitting.add(slot)
+                request._slot_at = time.monotonic()
+                self._hist_request["queue_wait_ms"].observe(
+                    (request._slot_at - request._queued) * 1e3
+                )
                 # hold a strong ref: the loop keeps only weak refs to tasks,
                 # so an unreferenced admission could be GC'd mid-flight,
                 # leaving the slot stuck in _admitting forever. Ragged mode
@@ -6869,6 +7022,7 @@ class LLMEngineCore:
                     return  # drained; a new generate() restarts the loop
                 # idle but admissions in flight: sleep until a prefill lands
                 # or a new request arrives (no busy-spin)
+                self._cycle.park()
                 await self._wake.wait()
                 self._wake.clear()
                 continue
@@ -6897,6 +7051,7 @@ class LLMEngineCore:
                 raise
             except Exception as ex:
                 await self._handle_step_failure(ex, step_epoch)
+            self._cycle.mark("yield", self._dispatch_seq)
             # armed sanitizer: audit page accounting after every step —
             # including steps that just went through failure recovery, which
             # is exactly where reclamation bugs hide. A violation raises out
@@ -6970,7 +7125,7 @@ class LLMEngineCore:
             # hides the per-chunk host work behind device compute
             dispatch_res, retire_res = await asyncio.gather(
                 self._dispatch_async(dispatch_mask.copy(), epoch),
-                self._retire_chunk(entry),
+                self._retire_beside_dispatch(entry),
                 return_exceptions=True,
             )
             if self._inflight and self._inflight[0] is entry:
@@ -7051,7 +7206,9 @@ class LLMEngineCore:
         thread (_prepare_dispatch), then the device call runs in a worker
         thread, possibly concurrently with the previous chunk's retirement.
         Appends the in-flight entry and fails pool-exhausted slots."""
+        self._cycle.mark("plan", self._dispatch_seq + 1)
         prep = self._prepare_dispatch(active_mask, epoch)
+        self._cycle.mark("launch", prep["seq"])
         # barrier visibility: a slot freed by the concurrent retire stage
         # must see this chunk before its entry lands in the queue. The
         # timestamp bounds the watchdog's compile-tolerance grace.
@@ -7132,7 +7289,8 @@ class LLMEngineCore:
         on the paged backend, the host page allocation it needs). Only
         touches state the retire stage never reads: the cache/pool handles,
         the device-resident chains, and the dispatch histogram."""
-        with self._sentry_scope("decode", seq=prep["seq"]):
+        with self._sentry_scope("decode", seq=prep["seq"]), \
+                jax.profiler.TraceAnnotation("engine.dispatch", seq=prep["seq"]):
             return self._dispatch_device_inner(prep)
 
     def _dispatch_device_inner(self, prep: dict) -> "_InFlightChunk":
@@ -7312,7 +7470,7 @@ class LLMEngineCore:
             )
             return chunk_np, gstate_np, lp_np
 
-        t0 = time.perf_counter()
+        t0 = self._cycle.mark("wait", entry.seq)
         ready = getattr(entry.chunk, "is_ready", None)
         if not faults.active() and ready is not None and ready():
             # chunk already landed (device ran ahead): the copies are
@@ -7320,6 +7478,7 @@ class LLMEngineCore:
             chunk_np, gstate_np, lp_np = _sync()
         else:
             chunk_np, gstate_np, lp_np = await asyncio.to_thread(_sync)
+        self._cycle.mark("emit", entry.seq)
         if entry.epoch != self._recover_epoch:
             # the watchdog failed this batch while the pipeline was in
             # flight: every queued chunk is stale — discard them all and
@@ -7380,7 +7539,15 @@ class LLMEngineCore:
         # a DMA that finished while this chunk computed cost the loop nothing
         self._reap_promotions()
         self._last_progress = time.monotonic()
-        self._hist_retire.observe((time.perf_counter() - t0) * 1e3)
+        t1 = self._cycle.mark("yield", entry.seq)
+        self._hist_retire.observe((t1 - t0) * 1e3)
+
+    async def _retire_beside_dispatch(self, entry: "_InFlightChunk") -> None:
+        """The gather leg of the steady pipelined step: once the retire is
+        through, what is left of the concurrent dispatch to wait for is
+        the cycle's ``launch`` time."""
+        await self._retire_chunk(entry)
+        self._cycle.mark("launch", self._dispatch_seq)
 
     async def _spec_step(self, active_mask: np.ndarray, spec_masks,
                          epoch: int) -> None:

@@ -78,7 +78,7 @@ class PagePool:
     # with the lock already held annotate their def line
     __guarded_by__ = {
         "_lock": ("_free", "_slot_pages", "_slot_len", "_refs",
-                  "_pending_cow", "_pins"),
+                  "_pending_cow", "_pins", "_used_peak"),
     }
 
     # ownership-discipline registry (tpuserve-analyze TPU7xx,
@@ -127,11 +127,21 @@ class PagePool:
         # from _refs so the KV sanitizer (llm/kv_sanitizer.py) can prove
         # refcount CONSERVATION: refs == slot-table + cache-node + pin refs.
         self._pins: Dict[int, int] = {}
+        # high-water mark of pages off the free list since construction
+        self._used_peak = 0
 
     @property
     def free_pages(self) -> int:
         with self._lock:
             return len(self._free)
+
+    @property
+    def used_pages_peak(self) -> int:
+        """High-water mark of pages off the free list: held by live
+        requests AND by the prefix cache (the null page is neither free nor
+        used)."""
+        with self._lock:
+            return self._used_peak
 
     def pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)
@@ -143,6 +153,9 @@ class PagePool:
     def _pop_free(self) -> int:  # tpuserve: ignore[TPU301] lock held by caller
         page = self._free.pop()
         self._refs[page] = 1
+        self._used_peak = max(
+            self._used_peak, self.num_pages - 1 - len(self._free)
+        )
         return page
 
     def _unref(self, page: int) -> bool:  # tpuserve: ignore[TPU301] lock held by caller

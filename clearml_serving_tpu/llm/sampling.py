@@ -157,6 +157,7 @@ def warp_logits(
 
 
 @partial(jax.jit, donate_argnums=())
+@jax.named_scope("sample")
 def sample_tokens(
     logits: jnp.ndarray,
     params: SamplingParams,

@@ -569,6 +569,11 @@ def build(config: dict) -> SimpleNamespace:
             return y
         return y + _lora_delta(layer, name, x, lora_idx)
 
+    # jax.named_scope on the sections of a step (qkv, attn, kv_write, oproj,
+    # ffn / moe, logits): a traced operation's op_name metadata then says
+    # which section it belongs to, not only XLA's `fusion.225`
+
+    @jax.named_scope("qkv")
     def _qkv(layer, x, cos, sin, lora_idx=None):
         b, s, _ = x.shape
         q = _with_lora(layer, "wq", x, _mm(layer, "wq", x), lora_idx)
@@ -583,9 +588,11 @@ def build(config: dict) -> SimpleNamespace:
         v = v.reshape(b, s, n_kv, head_dim)
         return _apply_rope(q, cos, sin), _apply_rope(k, cos, sin), v
 
+    @jax.named_scope("oproj")
     def _oproj(layer, attn, lora_idx=None):
         return _with_lora(layer, "wo", attn, _mm(layer, "wo", attn), lora_idx)
 
+    @jax.named_scope("attn")
     def _attend(q, k, v, mask):
         """q: [B,S,Hq,D]; k,v: [B,T,Hkv,D]; mask: [B,1,S,T] additive."""
         b, s, _, _ = q.shape
@@ -685,6 +692,7 @@ def build(config: dict) -> SimpleNamespace:
         out = jnp.einsum("te,etd->td", weights.astype(x.dtype), expert_out)
         return out.reshape(b, s, d_).astype(x.dtype)
 
+    @jax.named_scope("moe" if moe else "ffn")
     def _ffn(layer, x, valid=None, dropless=False, lora_idx=None):
         if moe:
             # decode and speculative verification must be dropless: capacity
@@ -695,6 +703,7 @@ def build(config: dict) -> SimpleNamespace:
             return _ffn_moe(layer, x, valid)
         return _ffn_dense(layer, x, lora_idx)
 
+    @jax.named_scope("logits")
     def _logits(params, x):
         x = _rms_norm(x, params["final_norm"], eps, norm_offset)
         if "lm_head" in params:
@@ -1329,35 +1338,38 @@ def build(config: dict) -> SimpleNamespace:
 
             def attn_fn(layer_, h):
                 q, k, v = _qkv(layer_, h, cos, sin, lora_idx)      # q [B,1,H,D]
-                k_q, k_s = _kv_store(k)                            # [B,1,Hkv(,D)]
-                v_q, v_s = _kv_store(v)
-                # index tuple (:, wp, wo): the advanced indices are
-                # CONTIGUOUS, so the broadcast dim [B] lands after the sliced
-                # head dim -> set() takes [Hkv, B, D].
-                k_hm = k_q[:, 0].transpose(1, 0, 2).astype(k_pool_l.dtype)
-                v_hm = v_q[:, 0].transpose(1, 0, 2).astype(v_pool_l.dtype)
-                k_p = k_pool_l.at[:, write_page, write_offset].set(k_hm)
-                v_p = v_pool_l.at[:, write_page, write_offset].set(v_hm)
-                scale_kw = {}
-                if kv_quant:
-                    # scale rows scatter at the same (page, offset) the int8
-                    # values took — one lifecycle per page id
-                    k_sp = k_sc_l.at[:, write_page, write_offset].set(
-                        k_s[:, 0].transpose(1, 0)
-                    )
-                    v_sp = v_sc_l.at[:, write_page, write_offset].set(
-                        v_s[:, 0].transpose(1, 0)
-                    )
-                    stash.append((k_p, v_p, k_sp, v_sp))
-                    scale_kw = {"k_scale": k_sp, "v_scale": v_sp}
-                else:
-                    stash.append((k_p, v_p))
+                with jax.named_scope("kv_write"):
+                    k_q, k_s = _kv_store(k)                    # [B,1,Hkv(,D)]
+                    v_q, v_s = _kv_store(v)
+                    # index tuple (:, wp, wo): the advanced indices are
+                    # CONTIGUOUS, so the broadcast dim [B] lands after the
+                    # sliced head dim -> set() takes [Hkv, B, D].
+                    k_hm = k_q[:, 0].transpose(1, 0, 2).astype(k_pool_l.dtype)
+                    v_hm = v_q[:, 0].transpose(1, 0, 2).astype(v_pool_l.dtype)
+                    k_p = k_pool_l.at[:, write_page, write_offset].set(k_hm)
+                    v_p = v_pool_l.at[:, write_page, write_offset].set(v_hm)
+                    scale_kw = {}
+                    if kv_quant:
+                        # scale rows scatter at the same (page, offset) the
+                        # int8 values took — one lifecycle per page id
+                        k_sp = k_sc_l.at[:, write_page, write_offset].set(
+                            k_s[:, 0].transpose(1, 0)
+                        )
+                        v_sp = v_sc_l.at[:, write_page, write_offset].set(
+                            v_s[:, 0].transpose(1, 0)
+                        )
+                        stash.append((k_p, v_p, k_sp, v_sp))
+                        scale_kw = {"k_scale": k_sp, "v_scale": v_sp}
+                    else:
+                        stash.append((k_p, v_p))
                 q_grouped = q[:, 0].reshape(b, n_kv, group, head_dim)
                 if q_prescale != 1.0:
                     q_grouped = q_grouped * jnp.asarray(q_prescale, q_grouped.dtype)
-                attn = attend(
-                    q_grouped, k_p, v_p, page_table, lengths + 1, **scale_kw
-                )                                                  # [B,Hkv,G,D]
+                with jax.named_scope("attn"):
+                    attn = attend(
+                        q_grouped, k_p, v_p, page_table, lengths + 1,
+                        **scale_kw
+                    )                                              # [B,Hkv,G,D]
                 return attn.reshape(b, 1, n_heads * head_dim).astype(x.dtype)
 
             x = _block(layer, x, attn_fn, lora_idx)
@@ -1582,41 +1594,44 @@ def build(config: dict) -> SimpleNamespace:
 
             def attn_fn(layer_, h):
                 q, k, v = _qkv(layer_, h, cos, sin, tok_lora)  # [T,1,H,D]
-                k_q, k_s = _kv_store(k)
-                v_q, v_s = _kv_store(v)
-                k_hm = k_q[:, 0].transpose(1, 0, 2).astype(k_pool_l.dtype)
-                v_hm = v_q[:, 0].transpose(1, 0, 2).astype(v_pool_l.dtype)
-                k_p = k_pool_l.at[:, write_page, write_offset].set(k_hm)
-                v_p = v_pool_l.at[:, write_page, write_offset].set(v_hm)
-                scale_kw = {}
-                if kv_quant:
-                    k_sp = k_sc_l.at[:, write_page, write_offset].set(
-                        k_s[:, 0].transpose(1, 0)
-                    )
-                    v_sp = v_sc_l.at[:, write_page, write_offset].set(
-                        v_s[:, 0].transpose(1, 0)
-                    )
-                    stash.append((k_p, v_p, k_sp, v_sp))
-                    scale_kw = {"k_scale": k_sp, "v_scale": v_sp}
-                else:
-                    stash.append((k_p, v_p))
+                with jax.named_scope("kv_write"):
+                    k_q, k_s = _kv_store(k)
+                    v_q, v_s = _kv_store(v)
+                    k_hm = k_q[:, 0].transpose(1, 0, 2).astype(k_pool_l.dtype)
+                    v_hm = v_q[:, 0].transpose(1, 0, 2).astype(v_pool_l.dtype)
+                    k_p = k_pool_l.at[:, write_page, write_offset].set(k_hm)
+                    v_p = v_pool_l.at[:, write_page, write_offset].set(v_hm)
+                    scale_kw = {}
+                    if kv_quant:
+                        k_sp = k_sc_l.at[:, write_page, write_offset].set(
+                            k_s[:, 0].transpose(1, 0)
+                        )
+                        v_sp = v_sc_l.at[:, write_page, write_offset].set(
+                            v_s[:, 0].transpose(1, 0)
+                        )
+                        stash.append((k_p, v_p, k_sp, v_sp))
+                        scale_kw = {"k_scale": k_sp, "v_scale": v_sp}
+                    else:
+                        stash.append((k_p, v_p))
                 q_grouped = q[:, 0].reshape(t, n_kv, group, head_dim)
                 if q_prescale != 1.0:
                     q_grouped = q_grouped * jnp.asarray(
                         q_prescale, q_grouped.dtype
                     )
-                if use_kernel:
-                    attn = ragged_paged_attention(
-                        q_grouped, k_p, v_p, page_table, kv_lens,
-                        row_starts, row_lens,
-                        block_rows=block_rows, block_q0=block_q0,
-                        tree_anc=tree_anc, **scale_kw,
-                    )                                              # [T,Hkv,G,D]
-                else:
-                    attn = ragged_paged_attention_xla(
-                        q_grouped, k_p, v_p, page_table, kv_lens,
-                        row_starts, row_lens, tree_anc=tree_anc, **scale_kw,
-                    )
+                with jax.named_scope("attn"):
+                    if use_kernel:
+                        attn = ragged_paged_attention(
+                            q_grouped, k_p, v_p, page_table, kv_lens,
+                            row_starts, row_lens,
+                            block_rows=block_rows, block_q0=block_q0,
+                            tree_anc=tree_anc, **scale_kw,
+                        )                                          # [T,Hkv,G,D]
+                    else:
+                        attn = ragged_paged_attention_xla(
+                            q_grouped, k_p, v_p, page_table, kv_lens,
+                            row_starts, row_lens, tree_anc=tree_anc,
+                            **scale_kw,
+                        )
                 return attn.reshape(t, 1, n_heads * head_dim).astype(x.dtype)
 
             # dropless MoE: capacity dropping would make a row's tokens

@@ -442,6 +442,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",  # the kernel's name in a trace
     )(page_table, lengths, *inputs)
 
 
@@ -921,4 +922,5 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(*prefetch, *inputs)

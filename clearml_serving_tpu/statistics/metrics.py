@@ -391,7 +391,28 @@ class EngineLifecycleCollector(_KeyedCollector):
         )
         retire_ms = HistogramMetricFamily(
             p + "_step_retire_ms",
-            "host time to sync + emit one retired chunk (ms)",
+            "device wait + emission of one retired launch (ms): the "
+            "blocking device->host sync is inside it (= phase wait + emit)",
+        )
+        # the loop thread's time per scheduling cycle, cut into phases that
+        # add up (llm/engine.py _CycleClock); phase="cycle" is their total
+        step_phase_ms = HistogramMetricFamily(
+            p + "_step_phase_ms",
+            "loop-thread time per scheduling cycle by phase (ms): admin, "
+            "plan, launch, wait, emit, yield; cycle = their sum",
+        )
+        # a request's way to its first token (vLLM request_queue_time /
+        # request_prefill_time / time_to_first_token)
+        request_phase_ms = HistogramMetricFamily(
+            p + "_request_phase_ms",
+            "a request's way to its first token by phase (ms): queue_wait "
+            "(submit -> slot), admit (slot -> job open), prefill (job open "
+            "-> first token), ttft (submit -> first token)",
+        )
+        request_prefill_launches = HistogramMetricFamily(
+            p + "_request_prefill_launches",
+            "launches that carried a chunk of one request's prompt before "
+            "its first token",
         )
         # ragged token-budget scheduler (docs/ragged_attention.md): how full
         # each mixed launch ran against its token budget, and how many rows
@@ -591,6 +612,7 @@ class EngineLifecycleCollector(_KeyedCollector):
 
         any_grpc = False
         any_pipeline = False
+        any_requests = False
         any_kv_pool = False
         any_kv_tier = False
         any_kv_ship = False
@@ -718,6 +740,19 @@ class EngineLifecycleCollector(_KeyedCollector):
                     snap = pipe.get(field)
                     if snap:
                         hist(fam, key, s, snap)
+                for field, snap in (pipe.get("phases") or {}).items():
+                    hist(step_phase_ms, key, s, snap,
+                         phase=field.removesuffix("_ms"))
+                if pipe.get("cycle_ms"):
+                    hist(step_phase_ms, key, s, pipe["cycle_ms"],
+                         phase="cycle")
+            for field, snap in (s.get("requests") or {}).items():
+                any_requests = True
+                if field == "prefill_launches":
+                    hist(request_prefill_launches, key, s, snap)
+                else:
+                    hist(request_phase_ms, key, s, snap,
+                         phase=field.removesuffix("_ms"))
             qd_classes = s.get("queue_depths")
             if isinstance(qd_classes, dict):
                 for cls_name, v in qd_classes.items():
@@ -770,6 +805,10 @@ class EngineLifecycleCollector(_KeyedCollector):
             yield pipe_depth
             yield dispatch_ms
             yield retire_ms
+            yield step_phase_ms
+        if any_requests:
+            yield request_phase_ms
+            yield request_prefill_launches
         if any_ragged:
             yield budget_util
             yield step_rows

@@ -348,6 +348,65 @@ def test_engine_pipeline_metrics_exported():
     ) is None
 
 
+def _phase_snap(i):
+    return {"buckets": [1.0, 10.0], "counts": [i, 1, 2],
+            "sum_ms": 10.0 * i + 3.0, "count": i + 3}
+
+
+@pytest.mark.parametrize("family,block,phase,index", [
+    ("engine_step_phase_ms", "phases", p, i) for i, p in enumerate(
+        ("admin", "plan", "launch", "wait", "emit", "yield", "cycle"))
+] + [
+    ("engine_request_phase_ms", "requests", p, i) for i, p in enumerate(
+        ("queue_wait", "admit", "prefill", "ttft"))
+] + [("engine_request_prefill_launches", "requests", None, 5)])
+def test_engine_phase_families_exported(family, block, phase, index):
+    """The cycle clock's phases and a request's way to its first token
+    (docs/pipelined_decode.md "Observability"): one histogram family each,
+    labelled by phase, built from the snapshots like
+    engine_step_dispatch_ms; a fleet row carries its replica label, and a
+    provider without the blocks keeps the historical families only."""
+    from clearml_serving_tpu.statistics.metrics import register_engine_lifecycle
+
+    steps = ("admin", "plan", "launch", "wait", "emit", "yield")
+    reqs = ("queue_wait", "admit", "prefill", "ttft")
+    stats = {
+        "queue_depth": 0,
+        "replica": "r1",
+        "pipeline": {
+            "depth": 2, "inflight": 0,
+            "phases": {p + "_ms": _phase_snap(i) for i, p in enumerate(steps)},
+            "cycle_ms": _phase_snap(6),
+        },
+        "requests": dict(
+            {p + "_ms": _phase_snap(i) for i, p in enumerate(reqs)},
+            prefill_launches=_phase_snap(5),
+        ),
+    }
+    registry = CollectorRegistry()
+    register_engine_lifecycle(lambda: stats, registry=registry, key="m1")
+    labels = {"model": "m1", "replica": "r1"}
+    if phase is not None:
+        labels["phase"] = phase
+
+    def val(suffix, **extra):
+        return registry.get_sample_value(family + suffix, {**labels, **extra})
+
+    assert val("_bucket", le="1.0") == index
+    assert val("_bucket", le="10.0") == index + 1
+    assert val("_bucket", le="+Inf") == val("_count") == index + 3
+    assert val("_sum") == 10.0 * index + 3.0
+    registry2 = CollectorRegistry()
+    register_engine_lifecycle(
+        lambda: {"queue_depth": 1, "pipeline": {"depth": 2}},
+        registry=registry2, key="m2",
+    )
+    assert registry2.get_sample_value(
+        family + "_count", {k: v for k, v in labels.items() if k != "replica"}
+        | {"model": "m2"}
+    ) is None
+
+
 def test_engine_sharding_metrics_exported():
     """Sharding-discipline observability (docs/static_analysis.md TPU8xx):
     the lifecycle collector exports the sentry's audit counter and the two
