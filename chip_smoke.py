@@ -601,6 +601,12 @@ def kernel_child(size):
             return k, v, table, {"k_scale": ks, "v_scale": vs}
         return k, v, table, {}
 
+    def stacked(pool):
+        """[3, ...]: the pool as layer 2 under two layers of other data."""
+        other = jax.random.normal(jax.random.PRNGKey(9), (2,) + pool.shape,
+                                  jnp.float32).astype(pool.dtype)
+        return jnp.concatenate([other, pool[None]])
+
     # -- decode: ragged lengths incl. an empty row and a one-token row
     for quant, page in ((False, 16), (True, 32)):
         rows = 8
@@ -617,6 +623,12 @@ def kernel_child(size):
             lengths, scales.get("k_scale"), scales.get("v_scale"))
         compare("paged_attention {}/P{}".format(
             "int8" if quant else "bf16", page), out, ref)
+        if not quant:
+            # the form the model step calls: the stack of all layers and a
+            # traced layer index (the page DMAs start at [layer, h, page])
+            out = jax.jit(functools.partial(pa.paged_attention, interpret=interpret))(
+                q, stacked(k), stacked(v), table, lengths, layer=jnp.int32(2))
+            compare("paged_attention bf16/P16 stacked", out, ref)
 
     # -- ragged: decode rows + a multi-block prefill row + a verify row (on
     # a 3-token history, so masking its siblings moves its output), an idle
@@ -669,6 +681,41 @@ def kernel_child(size):
             compare("ragged_paged_attention {}/P{}{}".format(
                 "int8" if quant else "bf16", page,
                 " tree_anc" if tree else ""), out, ref)
+            if not quant and not tree:
+                out = jax.jit(functools.partial(
+                    pa.ragged_paged_attention, interpret=interpret))(
+                    q, stacked(k), stacked(v), *args[2:],
+                    block_rows=jnp.asarray(block_rows),
+                    block_q0=jnp.asarray(block_q0), layer=jnp.int32(2))
+                compare("ragged_paged_attention bf16/P16 stacked", out, ref)
+
+    # -- the write of a launch's new K/V into the stacked pools: a prefill
+    # run that crosses pages, decode rows between pads on the null page, a
+    # page left and come back to
+    for quant, page in ((False, 16), (True, 32)):
+        k, v, _table, _scales = pools(jax.random.PRNGKey(7), page, 8, quant)
+        coords = ([(3, o) for o in range(page - 6, page)]
+                  + [(4, o) for o in range(10)] + [(7, 2)] + [(0, 0)] * 7
+                  + [(8, page - 1)] + [(0, 0)] * 7
+                  + [(5, 1), (6, 3), (5, 2), (5, 9)] + [(0, 0)] * 4)
+        wp, wo = (jnp.asarray(c, jnp.int32) for c in zip(*coords))
+        new = [jax.random.normal(jax.random.PRNGKey(8 + i),
+                                 (len(coords), hkv, d), jnp.float32)
+               for i in range(2)]
+        if quant:
+            new = [jnp.round(x * 40).astype(jnp.int8) for x in new]
+        else:
+            new = [x.astype(dtype) for x in new]
+        out = jax.jit(functools.partial(pa.paged_kv_write, interpret=interpret))(
+            stacked(k), stacked(v), *new, wp, wo, layer=jnp.int32(2))
+        ref = pa.paged_kv_write_xla(stacked(k), stacked(v), *new, wp, wo,
+                                    layer=2)
+        # page 0 takes every pad: its winner is open
+        compare("paged_kv_write {}/P{} stacked".format(
+            "int8" if quant else "bf16", page),
+            jnp.stack(out)[:, :, :, 1:], jnp.stack(ref)[:, :, :, 1:])
+        check(results[-1]["max_abs_diff"] == 0.0,
+              "paged_kv_write is a copy: it must equal the scatter exactly")
 
     # -- w4a16: the 8B projections at decode / verify / cap row counts
     for name, kdim, ndim in int4_shapes:

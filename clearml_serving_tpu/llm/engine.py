@@ -2472,19 +2472,24 @@ class LLMEngineCore:
                             sp, so = write_page[src], write_offset[src]
                             dp = jnp.where(move, write_page[dst], 0)
                             do = jnp.where(move, write_offset[dst], 0)
-                            k_pools = k_pools.at[:, :, dp, do].set(
-                                k_pools[:, :, sp, so]
-                            )
-                            v_pools = v_pools.at[:, :, dp, do].set(
-                                v_pools[:, :, sp, so]
-                            )
+
+                            def compact(pool):
+                                # layers and heads as indices, not slices:
+                                # the scatter's window stays one row, so
+                                # the stack keeps the kernels' layout (a
+                                # [L, Hkv, D] window makes the v5e compiler
+                                # convert the whole stack there and back;
+                                # ops.paged_attention.paged_kv_write_xla)
+                                ls = jnp.arange(pool.shape[0])[:, None, None]
+                                hs = jnp.arange(pool.shape[1])[None, :, None]
+                                return pool.at[ls, hs, dp, do].set(
+                                    pool[ls, hs, sp, so]
+                                )
+
+                            k_pools, v_pools = compact(k_pools), compact(v_pools)
                             if paged_quant:
-                                k_scales = k_scales.at[:, :, dp, do].set(
-                                    k_scales[:, :, sp, so]
-                                )
-                                v_scales = v_scales.at[:, :, dp, do].set(
-                                    v_scales[:, :, sp, so]
-                                )
+                                k_scales = compact(k_scales)
+                                v_scales = compact(v_scales)
                     raw = logits.astype(jnp.float32)
                     sampled, counts, lp, gstate = _sample_rows(
                         raw, plain_mask, sampling, rng, extras, counts,

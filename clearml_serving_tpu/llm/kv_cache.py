@@ -19,7 +19,14 @@ copy-on-write: the pool swaps the page id host-side and records a
 before the next write lands.
 
 Device layout per layer:   k_pool/v_pool [Hkv, num_pages, page_size, D]
-(head-major — the layout ops/paged_attention.py's kernel tiles over)
+(head-major — the layout ops/paged_attention.py's kernel tiles over); the
+cache holds the layers stacked, ``k`` / ``v`` ``[L, Hkv, num_pages, P, D]``.
+The model step takes the donated stacks whole, updates them in place (the
+touched pages are patched at [layer, head, page, offset]) and hands them
+back: the kernels read a layer of the stack by its index, so a launch never
+copies a pool
+(models/llama.py "paged KV serving path"). Everything here — allocation, CoW
+copies, prefix cache, tiering, shipping — reads and writes the same stacks.
 Host bookkeeping:          free-page stack + per-slot page lists + refcounts
 
 int8 paged KV (``kv_quant="int8"``, docs/paged_kv_quant.md): the K/V pools

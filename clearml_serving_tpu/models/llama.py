@@ -1282,6 +1282,78 @@ def build(config: dict) -> SimpleNamespace:
         return logits, cache
 
     # -- paged KV serving path (pools from llm/kv_cache.PagedKVCache) --------
+    #
+    # The stacked pools [L, Hkv, N, P, D] (+ [L, Hkv, N, P] scale pools
+    # under kv_quant) stay ONE buffer for a whole launch. The three paged
+    # passes carry them through the loop over the layers next to ``x`` and
+    # name a layer by its index: the write goes into the stack at
+    # [l, head, page, offset] (in place: the engine donates the pools), and
+    # the attention entry points read layer ``l`` of the stack (ops/
+    # paged_attention.py). Handing each layer its own [Hkv, N, P, D] slice
+    # instead makes XLA copy the whole layer out of the stack and back in
+    # every layer of every pass (PERF.md, PR 25).
+
+    def _paged_pools(k_pools, v_pools, k_scales, v_scales, who):
+        if not kv_quant:
+            return (k_pools, v_pools)
+        if k_scales is None:
+            raise ValueError("kv_quant {} needs k_scales/v_scales".format(who))
+        return (k_pools, v_pools, k_scales, v_scales)
+
+    def _paged_write(pools, li, k, v, write_page, write_offset):
+        """The stacked pools with layer ``li``'s new K/V ([*I, Hkv, D], I
+        the shape of ``write_page`` / ``write_offset``) stored at
+        [li, head, page, offset], through ops.paged_attention's write (the
+        page-patching kernel where the attention kernels run, a row scatter
+        elsewhere; both in place). Under kv_quant the values quantize
+        through the dense path's _kv_store and the per-(token, head) scales
+        land at the same (page, offset) of the scale pools — one lifecycle
+        per page id."""
+        from ..ops.paged_attention import (
+            paged_kernel_unsupported_reason,
+            paged_kv_write,
+            paged_kv_write_xla,
+        )
+
+        write = (
+            paged_kv_write
+            if paged_kernel_unsupported_reason(
+                head_dim, pools[0].shape[3], pools[0].dtype
+            ) is None
+            else paged_kv_write_xla
+        )
+        with jax.named_scope("kv_write"):
+            wp, wo = write_page.reshape(-1), write_offset.reshape(-1)
+            k_q, k_s = _kv_store(k)
+            v_q, v_s = _kv_store(v)
+            new = write(
+                pools[0], pools[1], k_q.reshape(-1, n_kv, head_dim),
+                v_q.reshape(-1, n_kv, head_dim), wp, wo, layer=li,
+            )
+            if kv_quant:
+                new += paged_kv_write_xla(
+                    pools[2], pools[3], k_s.reshape(-1, n_kv),
+                    v_s.reshape(-1, n_kv), wp, wo, layer=li,
+                )
+            return new
+
+    def _paged_layers(params, x, pools, layer_fn):
+        """``layer_fn(x, layer, li, pools) -> (x, pools)`` over the layers,
+        the pools in the loop's CARRY (never its scanned inputs or outputs);
+        the unrolled loop is the same body under a static index."""
+        if scan_layers:
+            def body(carry, xs):
+                return layer_fn(carry[0], xs[0], xs[1], carry[1]), None
+
+            n = pools[0].shape[0]
+            (x, pools), _ = jax.lax.scan(
+                body, (x, pools),
+                (params["layers"], jnp.arange(n, dtype=jnp.int32)),
+            )
+        else:
+            for li, layer in enumerate(params["layers"]):
+                x, pools = layer_fn(x, layer, li, pools)
+        return x, pools
 
     def decode_paged(
         params,
@@ -1298,8 +1370,9 @@ def build(config: dict) -> SimpleNamespace:
         v_scales=None,
     ):
         """One decode step over paged KV: writes the new token's K/V into the
-        pools (scatter by (page, offset)), then attends via
-        ops.paged_attention. Returns (logits [B, vocab], k_pools, v_pools) —
+        stacked pools (at (layer, page, offset)), then attends via
+        ops.paged_attention on that layer of the stack. Returns
+        (logits [B, vocab], k_pools, v_pools) —
         plus the updated scale pools when ``kv_quant`` is on: the new
         token's K/V quantize through the dense path's _kv_store and the
         per-(token, head) scales scatter beside the int8 pages; dequant
@@ -1310,8 +1383,8 @@ def build(config: dict) -> SimpleNamespace:
             paged_kernel_unsupported_reason,
         )
 
-        if kv_quant and k_scales is None:
-            raise ValueError("kv_quant decode_paged needs k_scales/v_scales")
+        pools = _paged_pools(k_pools, v_pools, k_scales, v_scales,
+                             "decode_paged")
         # kernel or XLA gather: one pure decision over (head_dim, page size,
         # pool dtype, backend) — the engine's health block evaluates the
         # same function with the same arguments
@@ -1330,78 +1403,31 @@ def build(config: dict) -> SimpleNamespace:
         # family query_scale override folds into q before the kernel
         q_prescale = query_scale * (head_dim ** 0.5)
 
-        def layer_body(x, layer, k_pool_l, v_pool_l, k_sc_l, v_sc_l):
-            """One layer on its own pool slice [Hkv, N, P, D] (+ [Hkv, N, P]
-            scale slices under kv_quant); returns the updated slices
-            (scatter of the new token's K/V and scales)."""
+        def layer_fn(x, layer, li, pools):
             stash = []
 
             def attn_fn(layer_, h):
                 q, k, v = _qkv(layer_, h, cos, sin, lora_idx)      # q [B,1,H,D]
-                with jax.named_scope("kv_write"):
-                    k_q, k_s = _kv_store(k)                    # [B,1,Hkv(,D)]
-                    v_q, v_s = _kv_store(v)
-                    # index tuple (:, wp, wo): the advanced indices are
-                    # CONTIGUOUS, so the broadcast dim [B] lands after the
-                    # sliced head dim -> set() takes [Hkv, B, D].
-                    k_hm = k_q[:, 0].transpose(1, 0, 2).astype(k_pool_l.dtype)
-                    v_hm = v_q[:, 0].transpose(1, 0, 2).astype(v_pool_l.dtype)
-                    k_p = k_pool_l.at[:, write_page, write_offset].set(k_hm)
-                    v_p = v_pool_l.at[:, write_page, write_offset].set(v_hm)
-                    scale_kw = {}
-                    if kv_quant:
-                        # scale rows scatter at the same (page, offset) the
-                        # int8 values took — one lifecycle per page id
-                        k_sp = k_sc_l.at[:, write_page, write_offset].set(
-                            k_s[:, 0].transpose(1, 0)
-                        )
-                        v_sp = v_sc_l.at[:, write_page, write_offset].set(
-                            v_s[:, 0].transpose(1, 0)
-                        )
-                        stash.append((k_p, v_p, k_sp, v_sp))
-                        scale_kw = {"k_scale": k_sp, "v_scale": v_sp}
-                    else:
-                        stash.append((k_p, v_p))
+                new = _paged_write(
+                    pools, li, k[:, 0], v[:, 0], write_page, write_offset
+                )
+                stash.append(new)
                 q_grouped = q[:, 0].reshape(b, n_kv, group, head_dim)
                 if q_prescale != 1.0:
                     q_grouped = q_grouped * jnp.asarray(q_prescale, q_grouped.dtype)
                 with jax.named_scope("attn"):
                     attn = attend(
-                        q_grouped, k_p, v_p, page_table, lengths + 1,
-                        **scale_kw
+                        q_grouped, new[0], new[1], page_table, lengths + 1,
+                        layer=li, **dict(zip(("k_scale", "v_scale"), new[2:]))
                     )                                              # [B,Hkv,G,D]
                 return attn.reshape(b, 1, n_heads * head_dim).astype(x.dtype)
 
             x = _block(layer, x, attn_fn, lora_idx)
-            return (x,) + stash[0]
+            return x, stash[0]
 
-        if kv_quant:
-            xs_all = (params["layers"], k_pools, v_pools, k_scales, v_scales)
-        else:
-            xs_all = (params["layers"], k_pools, v_pools)
-        if scan_layers:
-            def scan_body(x, xs):
-                layer = xs[0]
-                pools = xs[1:] if kv_quant else xs[1:] + (None, None)
-                out = layer_body(x, layer, *pools)
-                return out[0], out[1:]
-
-            x, new_pools = jax.lax.scan(scan_body, x, xs_all)
-        else:
-            per_layer = []
-            for li, layer in enumerate(params["layers"]):
-                tup = tuple(a[li] for a in xs_all[1:])
-                if not kv_quant:
-                    tup = tup + (None, None)
-                out = layer_body(x, layer, *tup)
-                x = out[0]
-                per_layer.append(out[1:])
-            new_pools = tuple(
-                jnp.stack([bufs[j] for bufs in per_layer])
-                for j in range(len(per_layer[0]))
-            )
+        x, pools = _paged_layers(params, x, pools, layer_fn)
         logits = _logits(params, x)[:, 0]
-        return (logits,) + tuple(new_pools)
+        return (logits,) + pools
 
     def verify_paged(
         params,
@@ -1432,8 +1458,10 @@ def build(config: dict) -> SimpleNamespace:
         verify exactly like they decode. Under ``kv_quant`` the chunk's K/V
         quantize before the scatter and the gather dequantizes with the
         scale pools (returned updated, like decode_paged)."""
-        if kv_quant and k_scales is None:
-            raise ValueError("kv_quant verify_paged needs k_scales/v_scales")
+        from ..ops.paged_attention import gather_pages
+
+        pools = _paged_pools(k_pools, v_pools, k_scales, v_scales,
+                             "verify_paged")
         b, s = tokens.shape
         pp = page_table.shape[1]
         page = k_pools.shape[3]
@@ -1450,39 +1478,27 @@ def build(config: dict) -> SimpleNamespace:
             t_idx < (positions[:, :, None] + 1), 0.0, -jnp.inf
         ).astype(jnp.float32)[:, None]                             # [B,1,S,cap]
 
-        def layer_body(x, layer, k_pool_l, v_pool_l, k_sc_l, v_sc_l):
+        def layer_fn(x, layer, li, pools):
             stash = []
 
             def attn_fn(layer_, h):
                 q, k, v = _qkv(layer_, h, cos, sin, lora_idx)      # k,v [B,S,Hkv,D]
-                k_q, k_s = _kv_store(k)
-                v_q, v_s = _kv_store(v)
-                k_hm = k_q.transpose(2, 0, 1, 3).astype(k_pool_l.dtype)
-                v_hm = v_q.transpose(2, 0, 1, 3).astype(v_pool_l.dtype)
-                k_p = k_pool_l.at[:, wp, wo].set(k_hm)
-                v_p = v_pool_l.at[:, wp, wo].set(v_hm)
-                if kv_quant:
-                    k_sp = k_sc_l.at[:, wp, wo].set(k_s.transpose(2, 0, 1))
-                    v_sp = v_sc_l.at[:, wp, wo].set(v_s.transpose(2, 0, 1))
-                    stash.append((k_p, v_p, k_sp, v_sp))
-                else:
-                    stash.append((k_p, v_p))
-                # [Hkv, B, PP, P, D] -> [B, cap, Hkv, D] (table order IS
-                # sequence-position order)
-                kg = k_p[:, page_table].transpose(1, 2, 3, 0, 4).reshape(
-                    b, cap, n_kv, head_dim
-                )
-                vg = v_p[:, page_table].transpose(1, 2, 3, 0, 4).reshape(
-                    b, cap, n_kv, head_dim
+                new = _paged_write(pools, li, k, v, wp, wo)
+                stash.append(new)
+                # this layer's pages of every row, [Hkv, B, PP, P(, D)] ->
+                # [B, cap, Hkv(, D)] (table order IS sequence-position order)
+                kg, vg = (
+                    gather_pages(pool, page_table, li)
+                    .transpose(1, 2, 3, 0, 4).reshape(b, cap, n_kv, head_dim)
+                    for pool in new[:2]
                 )
                 if kv_quant:
                     # dequant the gathered run with its scale rows ([B, cap,
                     # Hkv]), f32 math like the dense path's _kv_load
-                    ksg = k_sp[:, page_table].transpose(1, 2, 3, 0).reshape(
-                        b, cap, n_kv
-                    )
-                    vsg = v_sp[:, page_table].transpose(1, 2, 3, 0).reshape(
-                        b, cap, n_kv
+                    ksg, vsg = (
+                        gather_pages(pool, page_table, li)
+                        .transpose(1, 2, 3, 0).reshape(b, cap, n_kv)
+                        for pool in new[2:]
                     )
                     kg = kg.astype(jnp.float32) * ksg[..., None]
                     vg = vg.astype(jnp.float32) * vsg[..., None]
@@ -1492,34 +1508,10 @@ def build(config: dict) -> SimpleNamespace:
             # accept chain depend on batch occupancy
             x = _block(layer, x, attn_fn, lora_idx,
                        ffn_kwargs={"dropless": True})
-            return (x,) + stash[0]
+            return x, stash[0]
 
-        if kv_quant:
-            xs_all = (params["layers"], k_pools, v_pools, k_scales, v_scales)
-        else:
-            xs_all = (params["layers"], k_pools, v_pools)
-        if scan_layers:
-            def scan_body(x, xs):
-                layer = xs[0]
-                pools = xs[1:] if kv_quant else xs[1:] + (None, None)
-                out = layer_body(x, layer, *pools)
-                return out[0], out[1:]
-
-            x, new_pools = jax.lax.scan(scan_body, x, xs_all)
-        else:
-            per_layer = []
-            for li, layer in enumerate(params["layers"]):
-                tup = tuple(a[li] for a in xs_all[1:])
-                if not kv_quant:
-                    tup = tup + (None, None)
-                out = layer_body(x, layer, *tup)
-                x = out[0]
-                per_layer.append(out[1:])
-            new_pools = tuple(
-                jnp.stack([bufs[j] for bufs in per_layer])
-                for j in range(len(per_layer[0]))
-            )
-        return (_logits(params, x),) + tuple(new_pools)
+        x, pools = _paged_layers(params, x, pools, layer_fn)
+        return (_logits(params, x),) + pools
 
     # -- ragged mixed prefill+decode step (docs/ragged_attention.md) ---------
 
@@ -1556,8 +1548,8 @@ def build(config: dict) -> SimpleNamespace:
         draft chain of q=k+1 candidate tokens, prefill rows a prompt
         chunk — flattened into a token-major operand (PAPERS.md "Ragged
         Paged Attention"). Every token embeds at its own absolute
-        position, writes its K/V into the paged pools at host-precomputed
-        (page, offset) coords — the same scatter as decode_paged, with
+        position, writes its K/V into the stacked paged pools at
+        host-precomputed (page, offset) coords — decode_paged's scatter, with
         the chunk's quantized scales beside int8 pages — and attends
         through ops.ragged_paged_attention with per-row causal bounds.
         Returns (row logits [R, vocab] at each row's last real token,
@@ -1575,8 +1567,8 @@ def build(config: dict) -> SimpleNamespace:
             ragged_paged_attention_xla,
         )
 
-        if kv_quant and k_scales is None:
-            raise ValueError("kv_quant forward_ragged needs k_scales/v_scales")
+        pools = _paged_pools(k_pools, v_pools, k_scales, v_scales,
+                             "forward_ragged")
         # same pure decision as decode_paged; the kernel needs the caller's
         # q-block-aligned layout (block_rows/block_q0) and raises without it
         use_kernel = paged_kernel_unsupported_reason(
@@ -1589,48 +1581,33 @@ def build(config: dict) -> SimpleNamespace:
         tok_lora = lora_idx[tok_row] if lora_idx is not None else None
         q_prescale = query_scale * (head_dim ** 0.5)
 
-        def layer_body(x, layer, k_pool_l, v_pool_l, k_sc_l, v_sc_l):
+        def layer_fn(x, layer, li, pools):
             stash = []
 
             def attn_fn(layer_, h):
                 q, k, v = _qkv(layer_, h, cos, sin, tok_lora)  # [T,1,H,D]
-                with jax.named_scope("kv_write"):
-                    k_q, k_s = _kv_store(k)
-                    v_q, v_s = _kv_store(v)
-                    k_hm = k_q[:, 0].transpose(1, 0, 2).astype(k_pool_l.dtype)
-                    v_hm = v_q[:, 0].transpose(1, 0, 2).astype(v_pool_l.dtype)
-                    k_p = k_pool_l.at[:, write_page, write_offset].set(k_hm)
-                    v_p = v_pool_l.at[:, write_page, write_offset].set(v_hm)
-                    scale_kw = {}
-                    if kv_quant:
-                        k_sp = k_sc_l.at[:, write_page, write_offset].set(
-                            k_s[:, 0].transpose(1, 0)
-                        )
-                        v_sp = v_sc_l.at[:, write_page, write_offset].set(
-                            v_s[:, 0].transpose(1, 0)
-                        )
-                        stash.append((k_p, v_p, k_sp, v_sp))
-                        scale_kw = {"k_scale": k_sp, "v_scale": v_sp}
-                    else:
-                        stash.append((k_p, v_p))
+                new = _paged_write(
+                    pools, li, k[:, 0], v[:, 0], write_page, write_offset
+                )
+                stash.append(new)
                 q_grouped = q[:, 0].reshape(t, n_kv, group, head_dim)
                 if q_prescale != 1.0:
                     q_grouped = q_grouped * jnp.asarray(
                         q_prescale, q_grouped.dtype
                     )
+                kw = dict(zip(("k_scale", "v_scale"), new[2:]),
+                          tree_anc=tree_anc, layer=li)
                 with jax.named_scope("attn"):
                     if use_kernel:
                         attn = ragged_paged_attention(
-                            q_grouped, k_p, v_p, page_table, kv_lens,
+                            q_grouped, new[0], new[1], page_table, kv_lens,
                             row_starts, row_lens,
-                            block_rows=block_rows, block_q0=block_q0,
-                            tree_anc=tree_anc, **scale_kw,
+                            block_rows=block_rows, block_q0=block_q0, **kw,
                         )                                          # [T,Hkv,G,D]
                     else:
                         attn = ragged_paged_attention_xla(
-                            q_grouped, k_p, v_p, page_table, kv_lens,
-                            row_starts, row_lens, tree_anc=tree_anc,
-                            **scale_kw,
+                            q_grouped, new[0], new[1], page_table, kv_lens,
+                            row_starts, row_lens, **kw,
                         )
                 return attn.reshape(t, 1, n_heads * head_dim).astype(x.dtype)
 
@@ -1640,33 +1617,9 @@ def build(config: dict) -> SimpleNamespace:
             x = _block(layer, x, attn_fn, tok_lora,
                        ffn_kwargs={"valid": tok_valid[:, None],
                                    "dropless": True})
-            return (x,) + stash[0]
+            return x, stash[0]
 
-        if kv_quant:
-            xs_all = (params["layers"], k_pools, v_pools, k_scales, v_scales)
-        else:
-            xs_all = (params["layers"], k_pools, v_pools)
-        if scan_layers:
-            def scan_body(x, xs):
-                layer = xs[0]
-                pools = xs[1:] if kv_quant else xs[1:] + (None, None)
-                out = layer_body(x, layer, *pools)
-                return out[0], out[1:]
-
-            x, new_pools = jax.lax.scan(scan_body, x, xs_all)
-        else:
-            per_layer = []
-            for li, layer in enumerate(params["layers"]):
-                tup = tuple(a[li] for a in xs_all[1:])
-                if not kv_quant:
-                    tup = tup + (None, None)
-                out = layer_body(x, layer, *tup)
-                x = out[0]
-                per_layer.append(out[1:])
-            new_pools = tuple(
-                jnp.stack([bufs[j] for bufs in per_layer])
-                for j in range(len(per_layer[0]))
-            )
+        x, pools = _paged_layers(params, x, pools, layer_fn)
         last_x = x[:, 0][row_last][:, None]                    # [R, 1, dim]
         logits = _logits(params, last_x)[:, 0]                 # [R, vocab]
         if row_logit_idx is not None:
@@ -1677,8 +1630,8 @@ def build(config: dict) -> SimpleNamespace:
             # consumer stays bitwise identical across spec/no-spec launches.
             sel_x = x[:, 0][row_logit_idx]                     # [R, W, dim]
             gathered = _logits(params, sel_x)                  # [R, W, vocab]
-            return ((logits, gathered),) + tuple(new_pools)
-        return (logits,) + tuple(new_pools)
+            return ((logits, gathered),) + pools
+        return (logits,) + pools
 
     def forward_ragged_dense(params, tokens, start, last_rel, row_active,
                              cache, lora_idx=None, *, logit_rel=None):

@@ -11,9 +11,22 @@ Canonical layout (head-major pools — the TPU tiling wants the page's
 [page_size, head_dim] plane to be the trailing block):
     q            [B, Hkv, G, D]    one new token per sequence, query heads
                                    grouped under their shared KV head (GQA)
-    k/v pools    [Hkv, N_pages, P, D]
+    k/v pools    [Hkv, N_pages, P, D], or the model's whole stack
+                 [L, Hkv, N_pages, P, D] with ``layer`` (an int32 scalar,
+                 traced or static) naming the layer to attend
     page_table   [B, pages_per_seq] int32 page ids into the pool
     lengths      [B] int32         tokens currently in each sequence
+
+The stacked form is what the model step passes (models/llama.py): the pools
+of all layers stay ONE donated buffer for the whole launch, carried through
+the layer scan, and every entry point here reads layer ``layer`` of it in
+place — the kernels' page DMAs start at ``k_hbm.at[layer, h, page]`` (the
+scalar rides in the scalar prefetch), the XLA references gather
+``pool[layer, :, page_table]``, and the step's new K/V go in through
+:func:`paged_kv_write` (below, "KV write"), which patches the touched pages
+of the donated stack in place. Nothing slices a layer's pool out of the
+stack, which on the chip is a copy of the whole layer (PERF.md, PR 25). A
+4-D pool is the one-layer stack: same kernel, ``layer`` 0.
 
 Pallas design (decode, r2 rewrite): grid (B, Hkv); the kernel owns the whole
 sequence. K/V pools stay in HBM (memory_space=ANY); the kernel issues manual
@@ -128,7 +141,7 @@ def _check_kernel_operands(name, q, k_pool, quantized, interpret):
         )
     if not interpret:
         reason = paged_kernel_unsupported_reason(
-            q.shape[-1], k_pool.shape[2], k_pool.dtype, platform="tpu"
+            q.shape[-1], k_pool.shape[-2], k_pool.dtype, platform="tpu"
         )
         if reason is not None:
             raise ValueError("{}: {}".format(name, reason))
@@ -158,23 +171,46 @@ def paged_kernel_smem_bytes(
 
     table = (-(-rows // 8) * 8) * (-(-pages_per_seq // 128) * 128) * 4
     if not tokens:
-        return 2048 + table + vec(rows)                # + lengths
+        return 2048 + table + vec(rows) + vec(1)       # + lengths, layer
     nb = -(-tokens // _RAGGED_QB)
     return (
-        2048 + table + 2 * vec(rows)                   # kv_lens, row_lens
+        2048 + table + 2 * vec(rows) + vec(1)          # kv_lens, row_lens, layer
         + 2 * vec(nb)                                  # block_rows, block_q0
         + vec(tokens * tree_width)                     # flat ancestor table
     )
 
 
+def _stacked(layer, *pools):
+    """(layer [1] int32, pools as [L, Hkv, N, ...] stacks): a pool of one
+    layer becomes the one-layer stack (a bitcast under jit), so that the
+    kernels have one formulation. A stack needs its ``layer``."""
+    if pools[0].ndim == 4:
+        if layer is not None:
+            raise ValueError("layer indexes a stacked [L, Hkv, N, P, D] pool")
+        layer, pools = 0, tuple(p[None] for p in pools)
+    elif layer is None:
+        raise ValueError("a stacked [L, Hkv, N, P, D] pool needs its layer")
+    return (jnp.asarray(layer, jnp.int32).reshape(1),) + tuple(pools)
+
+
+def gather_pages(pool, page_table, layer=None):
+    """``pool[:, page_table]`` of one layer -> [Hkv, R, PP, P(, D)], from a
+    layer's pool or (``layer`` given) in ONE gather from the stack: slicing
+    the layer out first would materialise it."""
+    if layer is None:
+        return pool[:, page_table]
+    heads = jnp.arange(pool.shape[1], dtype=jnp.int32)[:, None, None]
+    return pool[layer, heads, page_table[None]]
+
+
 # ----------------------------------------------------------------- reference
 
 def paged_attention_xla(q, k_pool, v_pool, page_table, lengths,
-                        k_scale=None, v_scale=None):
+                        k_scale=None, v_scale=None, layer=None):
     """Reference implementation in plain XLA ops (also the CPU fallback).
 
-    q: [B, Hkv, G, D]; pools: [Hkv, N, P, D]; page_table: [B, PP];
-    lengths: [B] -> out [B, Hkv, G, D].
+    q: [B, Hkv, G, D]; pools: [Hkv, N, P, D], or stacked [L, Hkv, N, P, D]
+    with ``layer``; page_table: [B, PP]; lengths: [B] -> out [B, Hkv, G, D].
 
     ``k_scale``/``v_scale`` ([Hkv, N, P] f32) dequantize int8 pools: the
     per-(token, head) symmetric scales of models/llama._kv_store. Dequant
@@ -182,14 +218,14 @@ def paged_attention_xla(q, k_pool, v_pool, page_table, lengths,
     mirroring the dense path's _kv_load, so XLA fuses it into the gather.
     """
     b, hkv, g, d = q.shape
-    _, n, p, _ = k_pool.shape
+    p = k_pool.shape[-2]
     pp = page_table.shape[1]
     # gather pages -> [Hkv, B, PP, P, D] -> [B, T, Hkv, D]-equivalent einsum order
-    k = k_pool[:, page_table].reshape(hkv, b, pp * p, d)
-    v = v_pool[:, page_table].reshape(hkv, b, pp * p, d)
+    k = gather_pages(k_pool, page_table, layer).reshape(hkv, b, pp * p, d)
+    v = gather_pages(v_pool, page_table, layer).reshape(hkv, b, pp * p, d)
     if k_scale is not None:
-        ks = k_scale[:, page_table].reshape(hkv, b, pp * p, 1)
-        vs = v_scale[:, page_table].reshape(hkv, b, pp * p, 1)
+        ks = gather_pages(k_scale, page_table, layer).reshape(hkv, b, pp * p, 1)
+        vs = gather_pages(v_scale, page_table, layer).reshape(hkv, b, pp * p, 1)
         k = (k.astype(jnp.float32) * ks).astype(q.dtype)
         v = (v.astype(jnp.float32) * vs).astype(q.dtype)
     t_idx = jnp.arange(pp * p, dtype=jnp.int32)[None]
@@ -215,10 +251,11 @@ def _paged_attention_kernel(
     # scalar prefetch
     page_table_ref,    # [B, PP] int32 (SMEM)
     lengths_ref,       # [B] int32 (SMEM)
+    layer_ref,         # [1] int32 (SMEM): the layer of the stack to attend
     # then, positionally (in_specs order):
     #   q_ref            [1, 1, G, D] VMEM
-    #   k_hbm            [Hkv, N, P, D] ANY (stays in HBM)
-    #   v_hbm            [Hkv, N, P, D] ANY
+    #   k_hbm            [L, Hkv, N, P, D] ANY (stays in HBM)
+    #   v_hbm            [L, Hkv, N, P, D] ANY
     #   k_scale_ref      [1, 1, 1, PP*P] f32 VMEM   (quantized=True only:
     #   v_scale_ref      [1, 1, 1, PP*P] f32 VMEM    pre-gathered per-token
     #                    scales in sequence order — module docstring)
@@ -244,6 +281,7 @@ def _paged_attention_kernel(
     p = page_size
     pb = pages_per_block
     length = lengths_ref[b]
+    layer = layer_ref[0]
     block_tokens = pb * p
     # blocks that contain live tokens; DMA never touches pages past length
     n_blocks = (length + block_tokens - 1) // block_tokens
@@ -254,10 +292,12 @@ def _paged_attention_kernel(
         dst = pl.ds(j * p, p)
         return (
             pltpu.make_async_copy(
-                k_hbm.at[h, page], k_buf.at[slot, dst], sems.at[slot, j, 0]
+                k_hbm.at[layer, h, page], k_buf.at[slot, dst],
+                sems.at[slot, j, 0]
             ),
             pltpu.make_async_copy(
-                v_hbm.at[h, page], v_buf.at[slot, dst], sems.at[slot, j, 1]
+                v_hbm.at[layer, h, page], v_buf.at[slot, dst],
+                sems.at[slot, j, 1]
             ),
         )
 
@@ -365,7 +405,7 @@ def _paged_attention_kernel(
 
 def paged_attention(
     q, k_pool, v_pool, page_table, lengths, *,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, layer=None,
     pages_per_block: int = 32, interpret: bool = False,
 ):
     """Pallas paged decode attention — compiled by Mosaic, or interpreted
@@ -374,18 +414,20 @@ def paged_attention(
     :func:`paged_kernel_unsupported_reason`'s reason; choosing
     :func:`paged_attention_xla` instead is the caller's decision.
 
-    Shapes as in :func:`paged_attention_xla` (head-major pools).
+    Shapes as in :func:`paged_attention_xla` (head-major pools; the stack
+    of all layers with ``layer``, which the page DMAs index in place).
     ``pages_per_block``: pages flash-processed per MXU block (DMA'd together,
     double-buffered against the previous block's compute).
-    ``k_scale``/``v_scale`` ([Hkv, N, P] f32): per-(token, head) dequant
-    scales for int8 pools (required when the pools are int8); dequant fuses
-    into the in-kernel flash update (module docstring).
+    ``k_scale``/``v_scale`` ([Hkv, N, P] f32, stacked like the pools):
+    per-(token, head) dequant scales for int8 pools (required when the
+    pools are int8); dequant fuses into the in-kernel flash update (module
+    docstring).
     """
     quantized = k_scale is not None
     _check_kernel_operands("paged_attention", q, k_pool, quantized, interpret)
 
     b, hkv, g, d = q.shape
-    _, n, page_size, _ = k_pool.shape
+    page_size = k_pool.shape[-2]
     pages_per_seq = page_table.shape[1]
     pb = max(1, min(pages_per_block, pages_per_seq))
     cap = pages_per_seq * page_size
@@ -397,11 +439,11 @@ def paged_attention(
         quantized=quantized,
     )
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b, h, pt, ln: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, g, d), lambda b, h, *_: (b, h, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
     ]
-    inputs = [q, k_pool, v_pool]
+    scales = []
     if quantized:
         # pre-gather the tiny scale vectors into sequence order (XLA-side:
         # scale rows are not tile-aligned for the per-page DMA plan — see
@@ -417,20 +459,23 @@ def paged_attention(
 
         def gather(scale):
             seq = jnp.moveaxis(
-                scale[:, page_table].reshape(hkv, b, cap), 0, 1
+                gather_pages(scale, page_table, layer).reshape(hkv, b, cap),
+                0, 1,
             ).reshape(b, hkv, 1, cap)
             return jnp.pad(seq, pad)
 
         in_specs += [
-            pl.BlockSpec((1, 1, 1, cap_pad), lambda b, h, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, cap_pad), lambda b, h, pt, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, cap_pad), lambda b, h, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, cap_pad), lambda b, h, *_: (b, h, 0, 0)),
         ]
-        inputs += [gather(k_scale), gather(v_scale)]
+        scales = [gather(k_scale), gather(v_scale)]
+    layer, k_pool, v_pool = _stacked(layer, k_pool, v_pool)
+    inputs = [q, k_pool, v_pool] + scales
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, lengths
+        num_scalar_prefetch=3,  # page_table, lengths, layer
         grid=(b, hkv),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b, h, pt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, d), lambda b, h, *_: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, pb * page_size, d), k_pool.dtype),
             pltpu.VMEM((2, pb * page_size, d), v_pool.dtype),
@@ -443,7 +488,180 @@ def paged_attention(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
         name="paged_attention_decode",  # the kernel's name in a trace
-    )(page_table, lengths, *inputs)
+    )(page_table, lengths, layer, *inputs)
+
+
+# ------------------------------------------------------------------ KV write
+#
+# The write of a launch's new K/V into the stacked pools. As XLA ops it is a
+# ROW scatter (every index given; see paged_kv_write_xla), which the v5e
+# executes at ~70 ns a row: 0.4 ms a layer for a 352-token ragged pass, 10% of
+# a Mistral-7B launch (PERF.md, PR 25). The kernel works on PAGES instead: a
+# launch's tokens arrive in runs that share a page (a prefill chunk fills
+# page after page; a decode row is a run of one), a [P, D] page plane is the
+# smallest block of a bf16 pool a DMA can move (a row is half a packed
+# sublane), and so each run is one fetch of its page for all heads, a patch
+# of its rows in VMEM, and one store.
+
+def paged_kv_write_xla(k_pool, v_pool, k_new, v_new, write_page,
+                       write_offset, layer=None):
+    """Reference (and the CPU / gated-shape path): ``k_new`` / ``v_new``
+    ``[T, Hkv, D]`` stored at ``pool[(layer,) h, write_page[t],
+    write_offset[t]]``; returns the pools. EVERY index is given, heads too,
+    so that the scatter's update window is one [D] row: with the heads left
+    as a slice the window is [Hkv, D], XLA then keeps the whole stack in a
+    layout with the heads next to D, and converts ALL of it to the kernels'
+    row-major layout in front of every attention call (the v5e compiler's
+    output; tests/test_tpu_compile.py). Also writes the scale pools
+    (``[T, Hkv]`` into ``[(L,) Hkv, N, P]``)."""
+    heads = jnp.arange(k_new.shape[1], dtype=jnp.int32)
+    at = (heads, write_page[:, None], write_offset[:, None])
+    if layer is not None:
+        at = (layer,) + at
+    return (k_pool.at[at].set(k_new.astype(k_pool.dtype)),
+            v_pool.at[at].set(v_new.astype(v_pool.dtype)))
+
+
+def _kv_write_kernel(
+    # scalar prefetch (SMEM)
+    page_ref,      # [T] int32 page id per token
+    off_ref,       # [T] int32 offset within the page
+    layer_ref,     # [1] int32
+    # inputs: k_new_ref / v_new_ref [TB, Hkv, D] VMEM (this step's tokens);
+    # k_in / v_in [L, Hkv, N, P, D] ANY, aliased to the outputs and unused
+    k_new_ref, v_new_ref, k_in, v_in,
+    # outputs (the same buffers as k_in / v_in)
+    k_hbm, v_hbm,
+    # scratch: k_buf / v_buf [2, Hkv, P, D]; sems [2 slots, k/v, fetch/store]
+    k_buf, v_buf, sems,
+    *, block: int,
+):
+    del k_in, v_in
+    base = pl.program_id(0) * block
+    layer = layer_ref[0]
+    hkv, p = k_buf.shape[1], k_buf.shape[2]
+
+    def copies(page, slot, store):
+        """The K and the V copy of one page's planes, all heads: HBM -> slot
+        (``store`` 0) or slot -> HBM (``store`` 1)."""
+        out = []
+        for side, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            ends = (hbm.at[layer, pl.ds(0, hkv), page], buf.at[slot])
+            out.append(pltpu.make_async_copy(
+                *(ends[::-1] if store else ends), sems.at[slot, side, store]
+            ))
+        return out
+
+    def start(page, slot, store):
+        for c in copies(page, slot, store):
+            c.start()
+
+    def wait(page, slot, store):
+        for c in copies(page, slot, store):
+            c.wait()
+
+    def run_end(t0, page):
+        """First token of the block past ``t0`` that writes another page."""
+        return jax.lax.while_loop(
+            lambda t: jnp.logical_and(
+                t < block, page_ref[base + jnp.minimum(t, block - 1)] == page
+            ),
+            lambda t: t + 1, t0 + 1,
+        )
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, (p, k_buf.shape[3]), 0)
+
+    def patch(t, slot):
+        here = rows == off_ref[base + t]
+        for buf, new in ((k_buf, k_new_ref), (v_buf, v_new_ref)):
+            for h in range(hkv):   # one [P, D] tile per head
+                row = new[pl.ds(t, 1), h, :]                     # [1, D]
+                buf[slot, h] = jnp.where(here, row, buf[slot, h])
+
+    # Runs of tokens that share a page, two page buffers. Run j patches slot
+    # j % 2 while the fetch of run j+1 flies into the other slot, which is
+    # issued only once the store of run j-1 (that slot's last use) has
+    # landed; consecutive runs differ in their page by construction. So a
+    # page is never read while a store to it is in flight, whatever the
+    # coordinates, and tokens apply in order (the last of a duplicate wins).
+    page0 = page_ref[base]
+    start(page0, 0, store=0)
+
+    def run(carry):
+        t0, j, page, prev = carry
+        slot = jax.lax.rem(j, 2)
+        t1 = run_end(t0, page)
+        nxt = page_ref[base + jnp.minimum(t1, block - 1)]
+        wait(page, slot, store=0)
+
+        @pl.when(t1 < block)
+        def _prefetch():
+            @pl.when(j > 0)
+            def _landed():
+                wait(prev, 1 - slot, store=1)
+
+            start(nxt, 1 - slot, store=0)
+
+        jax.lax.fori_loop(t0, t1, lambda t, c: (patch(t, slot), c)[1], 0)
+        start(page, slot, store=1)
+        return t1, j + 1, nxt, page
+
+    _, n_runs, _, last = jax.lax.while_loop(
+        lambda c: c[0] < block, run, (0, 0, page0, page0)
+    )
+    # in flight still: the last run's store, and the one before it (a run
+    # waits for its predecessor's store only when it prefetches a successor).
+    # A wait needs the semaphore and the size alone, so any page stands in.
+
+    @pl.when(n_runs > 1)
+    def _before_last():
+        wait(last, jax.lax.rem(n_runs, 2), store=1)
+
+    wait(last, jax.lax.rem(n_runs - 1, 2), store=1)
+
+
+def paged_kv_write(k_pool, v_pool, k_new, v_new, write_page, write_offset, *,
+                   layer=None, interpret: bool = False):
+    """Pallas write of a launch's new K/V into the pools, IN PLACE
+    (``input_output_aliases``; the pools stay in HBM): :func:
+    `paged_kv_write_xla`'s result wherever coordinates are not duplicated
+    (of duplicates the last token wins; XLA leaves the winner open — the
+    engine's only duplicates are its pads on the null page). Same gates as
+    the attention kernels (:func:`paged_kernel_unsupported_reason`); never
+    the reference."""
+    _check_kernel_operands("paged_kv_write", k_new, k_pool, True, interpret)
+    t, hkv, d = k_new.shape
+    one_layer = k_pool.ndim == 4
+    layer, k_pool, v_pool = _stacked(layer, k_pool, v_pool)
+    page_size = k_pool.shape[3]
+    # tokens per grid step: a step's new K/V sit in VMEM whole, 2 MB a side
+    # at most (the pipeline holds two steps of both)
+    fit = (2 << 20) // (max(hkv, 16) * d * k_pool.dtype.itemsize)
+    block = max(b for b in range(1, min(t, max(fit, 1)) + 1) if t % b == 0)
+    new_spec = pl.BlockSpec((block, hkv, d), lambda i, *_: (i, 0, 0))
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    pools = pl.pallas_call(
+        functools.partial(_kv_write_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # write_page, write_offset, layer
+            grid=(t // block,),
+            in_specs=[new_spec, new_spec, anywhere, anywhere],
+            out_specs=[anywhere, anywhere],
+            scratch_shapes=[
+                pltpu.VMEM((2, hkv, page_size, d), k_pool.dtype),
+                pltpu.VMEM((2, hkv, page_size, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, 2)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # operands count the scalar prefetch: 3 + (k_new, v_new, k, v)
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+        name="paged_kv_write",
+    )(write_page, write_offset, layer,
+      k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype), k_pool, v_pool)
+    return tuple(pool[0] for pool in pools) if one_layer else tuple(pools)
 
 
 # ----------------------------------------------------------- ragged (mixed)
@@ -560,11 +778,12 @@ def tree_ancestors(parents, n_nodes=None, *, width=None):
 def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
                                row_starts, row_lens,
                                k_scale=None, v_scale=None,
-                               tree_anc=None):
+                               tree_anc=None, layer=None):
     """Reference ragged paged attention in plain XLA ops (CPU fallback).
 
-    Shapes per the module's ragged section; returns [T, Hkv, G, D] with
-    zeros at tokens no row owns. Per-token math mirrors
+    Shapes per the module's ragged section (pools of one layer, or the
+    stack with ``layer``); returns [T, Hkv, G, D] with zeros at tokens no
+    row owns. Per-token math mirrors
     :func:`paged_attention_xla` exactly (same contraction order, f32
     softmax, probs cast to the value dtype before the PV product) so a
     decode row's output is the decode reference's output — the engine's
@@ -576,7 +795,7 @@ def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
     test/smoke scale; the Pallas kernel is the capacity-scale path), but
     HBM gather traffic stays R*cap, not T*cap."""
     t, hkv, g, d = q.shape
-    _, n, p, _ = k_pool.shape
+    p = k_pool.shape[-2]
     pp = page_table.shape[1]
     cap = pp * p
     t_idx = jnp.arange(t, dtype=jnp.int32)
@@ -591,11 +810,13 @@ def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
     bound = jnp.where(
         tok_valid, jnp.minimum(base + qi + 1, kv_lens[tok_row]), 0
     )                                                       # [T]
-    k_rows = k_pool[:, page_table].reshape(hkv, -1, cap, d)  # [Hkv, R, cap, D]
-    v_rows = v_pool[:, page_table].reshape(hkv, -1, cap, d)
+    k_rows = gather_pages(k_pool, page_table, layer).reshape(
+        hkv, -1, cap, d
+    )                                                       # [Hkv, R, cap, D]
+    v_rows = gather_pages(v_pool, page_table, layer).reshape(hkv, -1, cap, d)
     if k_scale is not None:
-        ks = k_scale[:, page_table].reshape(hkv, -1, cap, 1)
-        vs = v_scale[:, page_table].reshape(hkv, -1, cap, 1)
+        ks = gather_pages(k_scale, page_table, layer).reshape(hkv, -1, cap, 1)
+        vs = gather_pages(v_scale, page_table, layer).reshape(hkv, -1, cap, 1)
         k_rows = (k_rows.astype(jnp.float32) * ks).astype(q.dtype)
         v_rows = (v_rows.astype(jnp.float32) * vs).astype(q.dtype)
     k = k_rows[:, tok_row]                                  # [Hkv, T, cap, D]
@@ -628,7 +849,7 @@ def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
 
 def _ragged_attention_kernel(
     # scalar prefetch (SMEM): block_rows [NB], block_q0 [NB],
-    # page_table [R, PP], kv_lens [R], row_lens [R],
+    # page_table [R, PP], kv_lens [R], row_lens [R], layer [1],
     # tree only: tree_anc [T * tree_width] (flat; per token: in-row
     # ancestor indices incl. self, -1 padded; first entry -2 => plain causal)
     *refs,
@@ -638,13 +859,13 @@ def _ragged_attention_kernel(
     quantized: bool = False,
     tree_width: int = 0,
 ):
-    # then positionally: q_ref [QB,1,G,D]; k_hbm/v_hbm [Hkv,N,P,D] (ANY);
+    # then positionally: q_ref [QB,1,G,D]; k_hbm/v_hbm [L,Hkv,N,P,D] (ANY);
     # quantized only: k_scale_ref/v_scale_ref [1,1,1,cap_pad] (per-ROW
     # pre-gathered scales, pipelined by the block_rows index map);
     # out_ref [QB,1,G,D]; scratch k_buf/v_buf [2, PB*P, D], sems [2, PB, 2]
     (block_rows_ref, block_q0_ref, page_table_ref, kv_lens_ref,
-     row_lens_ref) = refs[:5]
-    refs = refs[5:]
+     row_lens_ref, layer_ref) = refs[:6]
+    refs = refs[6:]
     tree = tree_width > 0
     if tree:
         tree_anc_ref, refs = refs[0], refs[1:]
@@ -666,6 +887,7 @@ def _ragged_attention_kernel(
     q0 = block_q0_ref[bi]
     kv_len = kv_lens_ref[row]
     row_len = row_lens_ref[row]
+    layer = layer_ref[0]
     base = kv_len - row_len          # absolute position of the row's query 0
     # causal bound of this block's LAST query — pages past it never DMA
     bound = jnp.where(live, jnp.minimum(kv_len, base + q0 + qb), 0)
@@ -678,10 +900,12 @@ def _ragged_attention_kernel(
         dst = pl.ds(j * p, p)
         return (
             pltpu.make_async_copy(
-                k_hbm.at[h, page], k_buf.at[slot, dst], sems.at[slot, j, 0]
+                k_hbm.at[layer, h, page], k_buf.at[slot, dst],
+                sems.at[slot, j, 0]
             ),
             pltpu.make_async_copy(
-                v_hbm.at[h, page], v_buf.at[slot, dst], sems.at[slot, j, 1]
+                v_hbm.at[layer, h, page], v_buf.at[slot, dst],
+                sems.at[slot, j, 1]
             ),
         )
 
@@ -811,7 +1035,7 @@ def _ragged_attention_kernel(
 def ragged_paged_attention(
     q, k_pool, v_pool, page_table, kv_lens, row_starts, row_lens, *,
     block_rows=None, block_q0=None,
-    k_scale=None, v_scale=None, tree_anc=None,
+    k_scale=None, v_scale=None, tree_anc=None, layer=None,
     pages_per_block: int = 32, q_block: int = _RAGGED_QB,
     interpret: bool = False,
 ):
@@ -820,7 +1044,9 @@ def ragged_paged_attention(
     reference: like :func:`paged_attention`, operands the compiler cannot
     take (the SAME gates as the decode kernel: D % 128, dtype-dependent
     page sublane tile) raise ``ValueError``;
-    :func:`ragged_paged_attention_xla` is the caller's choice.
+    :func:`ragged_paged_attention_xla` is the caller's choice. Pools and
+    scale pools are one layer's, or the stack with ``layer``, as in
+    :func:`paged_attention`.
 
     ``block_rows``/``block_q0`` ([T/q_block] int32) are the host-computed
     q-block -> row map (:func:`ragged_layout`); the kernel REQUIRES them
@@ -843,7 +1069,7 @@ def ragged_paged_attention(
     )
 
     t, hkv, g, d = q.shape
-    _, n, page_size, _ = k_pool.shape
+    page_size = k_pool.shape[-2]
     pages_per_seq = page_table.shape[1]
     if t % q_block:
         raise ValueError(
@@ -863,8 +1089,8 @@ def ragged_paged_attention(
         tree_width=0 if tree_anc is None else tree_anc.shape[1],
     )
     nb = t // q_block
-    # index maps take *_ for the scalar-prefetch refs: their count is 5
-    # or 6 (tree_anc) and the maps never read beyond block_rows
+    # index maps take *_ for the scalar-prefetch refs: their count is 6
+    # or 7 (tree_anc) and the maps never read beyond block_rows
     in_specs = [
         pl.BlockSpec(
             (q_block, 1, g, d), lambda b, h, *_: (b, h, 0, 0)
@@ -872,7 +1098,7 @@ def ragged_paged_attention(
         pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
     ]
-    inputs = [q, k_pool, v_pool]
+    scales = []
     if quantized:
         # per-ROW pre-gathered scales (same rationale/padding as the decode
         # kernel's: f32 scale rows are not tile-alignable for the page DMA
@@ -884,7 +1110,8 @@ def ragged_paged_attention(
 
         def gather(scale):
             seq = jnp.moveaxis(
-                scale[:, page_table].reshape(hkv, r, cap), 0, 1
+                gather_pages(scale, page_table, layer).reshape(hkv, r, cap),
+                0, 1,
             ).reshape(r, hkv, 1, cap)
             return jnp.pad(seq, pad)
 
@@ -895,8 +1122,10 @@ def ragged_paged_attention(
             pl.BlockSpec((1, 1, 1, cap_pad), scale_idx),
             pl.BlockSpec((1, 1, 1, cap_pad), scale_idx),
         ]
-        inputs += [gather(k_scale), gather(v_scale)]
-    prefetch = [block_rows, block_q0, page_table, kv_lens, row_lens]
+        scales = [gather(k_scale), gather(v_scale)]
+    layer, k_pool, v_pool = _stacked(layer, k_pool, v_pool)
+    inputs = [q, k_pool, v_pool] + scales
+    prefetch = [block_rows, block_q0, page_table, kv_lens, row_lens, layer]
     if tree_anc is not None:
         if tree_anc.shape[0] != t:
             raise ValueError(
@@ -905,7 +1134,7 @@ def ragged_paged_attention(
         # flat: a 2-D SMEM operand pads its minor dim to 128 lanes
         prefetch.append(tree_anc.astype(jnp.int32).reshape(-1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # block/row map + tables (+ tree)
+        num_scalar_prefetch=len(prefetch),  # maps, tables, layer (+ tree)
         grid=(nb, hkv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(

@@ -159,6 +159,10 @@ def test_kernel_layout_serves_and_matches_the_xla_path(parts, monkeypatch):
         pa, "ragged_paged_attention",
         functools.partial(pa.ragged_paged_attention, interpret=True),
     )
+    monkeypatch.setattr(
+        pa, "paged_kv_write",
+        functools.partial(pa.paged_kv_write, interpret=True),
+    )
     engine = LLMEngineCore(bundle, params, **kw)
     assert engine._ragged_kernel and engine._ragged_qb == pa._RAGGED_QB
     # budget 16 + one q block of alignment waste per row, q-block aligned

@@ -1,3 +1,5 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -402,3 +404,63 @@ def test_paged_attention_block_sizes_and_bf16():
         np.asarray(outb, np.float32), np.asarray(refb, np.float32),
         rtol=2e-2, atol=2e-2,
     )
+
+
+# -- the stacked pool [L, Hkv, N, P, D] with a layer index (ISSUE 25) ---------
+
+
+def _stack_with(layer, pool, layers=3):
+    """A stack whose layer ``layer`` is ``pool`` and whose other layers are
+    other data: reading the wrong layer cannot go unseen."""
+    others = jax.random.normal(
+        jax.random.PRNGKey(100 + layer), (layers,) + pool.shape, jnp.float32
+    )
+    if jnp.issubdtype(pool.dtype, jnp.integer):
+        others = jnp.round(others * 40)
+    return others.astype(pool.dtype).at[layer].set(pool)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_paged_attention_reads_its_layer_of_the_stack(kind, layer):
+    """Decode kernel (interpret) and XLA reference on the stack of L = 3 with
+    ``layer`` equal, bit for bit, the same entry point on ``pool[layer]`` —
+    under jit with a TRACED layer, as the layer scan calls them — and agree
+    with each other as before."""
+    q, k_pool, v_pool, page_table, lengths = _random_paged_setup(
+        jax.random.PRNGKey(7), d=128, page_size=16
+    )
+    lengths = jnp.asarray([int(lengths[0]), 13, 0], jnp.int32)
+    scales, stacked_scales = {}, {}
+    if kind == "int8":
+        (k_pool, ks), (v_pool, vs) = _quantize_pool(k_pool), _quantize_pool(v_pool)
+        scales = {"k_scale": ks, "v_scale": vs}
+        stacked_scales = {n: _stack_with(layer, s) for n, s in scales.items()}
+    else:
+        q, k_pool, v_pool = (a.astype(jnp.bfloat16) for a in (q, k_pool, v_pool))
+    k_stack, v_stack = _stack_with(layer, k_pool), _stack_with(layer, v_pool)
+    assert k_stack.shape == (3,) + k_pool.shape
+
+    kernel = functools.partial(paged_attention, pages_per_block=2, interpret=True)
+    for fn in (kernel, paged_attention_xla):
+        want = fn(q, k_pool, v_pool, page_table, lengths, **scales)
+        got = jax.jit(
+            lambda li, fn=fn: fn(q, k_stack, v_stack, page_table, lengths,
+                                 layer=li, **stacked_scales)
+        )(jnp.int32(layer))
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    np.testing.assert_allclose(
+        np.asarray(kernel(q, k_stack, v_stack, page_table, lengths,
+                          layer=layer, **stacked_scales), np.float32),
+        np.asarray(want, np.float32), rtol=2e-2, atol=2e-2,
+    )
+
+
+def test_paged_attention_stack_and_layer_go_together():
+    q, k_pool, v_pool, page_table, lengths = _random_paged_setup(jax.random.PRNGKey(8))
+    with pytest.raises(ValueError, match="needs its layer"):
+        paged_attention(q, k_pool[None], v_pool[None], page_table, lengths,
+                        interpret=True)
+    with pytest.raises(ValueError, match="layer indexes a stacked"):
+        paged_attention(q, k_pool, v_pool, page_table, lengths, layer=0,
+                        interpret=True)

@@ -497,18 +497,26 @@ def test_chaos_retire_fault_mid_multistep_window(parts, monkeypatch):
     engine = _engine(bundle, params, cache_mode="paged", scheduler="ragged",
                      step_token_budget=64, decode_steps=4,
                      ragged_decode_steps=4, max_seq_len=160)
-    # deterministic window accounting: record the poisoned row's produced
-    # count and window size at the retire the fault fires in
+    # deterministic window accounting: the poison is armed INSIDE the first
+    # retire whose plan carries the decoding request as a q>1 row (arming it
+    # from the test's coroutine raced the loop: the fault could land on a
+    # launch already in flight with a q=1 row, on the parent commit as often
+    # as not), and the row's produced count and window size are recorded there
     seen = {}
     real_retire = engine._retire_ragged
 
     def spy(plan, result):
-        if faults.active() and not seen:
+        if not seen:
             for slot, request in enumerate(engine._slot_req):
                 if request is not None and marker in request.prompt_ids:
                     if plan["row_steps"][slot] > 1:
                         seen["produced"] = request.produced
                         seen["steps"] = int(plan["row_steps"][slot])
+                        faults.configure([
+                            {"point": "engine.decode.retire",
+                             "action": "raise", "match_token": marker,
+                             "times": 1},
+                        ])
         return real_retire(plan, result)
 
     engine._retire_ragged = spy
@@ -529,19 +537,12 @@ def test_chaos_retire_fault_mid_multistep_window(parts, monkeypatch):
             while a.produced < 2:
                 await asyncio.sleep(0.005)
             # the admission makes the loop take ragged steps; with this
-            # much budget the decode row rides them as a q=4 window —
-            # arm the poison only now, so it lands on a q>1 retire
+            # much budget the decode row rides them as a q=4 window, and a
+            # outlives the admission (48 tokens): a retire that carries its
+            # decode row as a window is guaranteed, and the spy poisons it
             b_task = asyncio.create_task(tolerant(
                 GenRequest(prompt_ids=list(LONG), max_new_tokens=12)
             ))
-            # a outlives the admission (48 tokens): the poisoned retire is
-            # guaranteed to carry its decode row
-            while not engine._prefill_jobs:
-                await asyncio.sleep(0.002)
-            faults.configure([
-                {"point": "engine.decode.retire", "action": "raise",
-                 "match_token": marker, "times": 1},
-            ])
             out_a, a_err = await asyncio.wait_for(a_task, 60)
             out_b, b_err = await asyncio.wait_for(b_task, 60)
             await engine.wait_drained()

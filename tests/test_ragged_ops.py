@@ -3,6 +3,8 @@ mixed prefill+decode Pallas kernel (interpret mode) against the ragged XLA
 reference, the ragged reference against the per-row decode/dense references,
 and the layout helper's q-block contract."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,8 @@ from clearml_serving_tpu.ops.paged_attention import (
     paged_attention_xla,
     paged_kernel_smem_bytes,
     paged_kernel_unsupported_reason,
+    paged_kv_write,
+    paged_kv_write_xla,
     ragged_layout,
     ragged_paged_attention,
     ragged_paged_attention_xla,
@@ -244,3 +248,144 @@ def test_paged_kernel_smem_accounting():
     assert paged_kernel_smem_bytes(200, 768, 8192, 13) > SMEM_BYTES
     # the smoke's own configuration is three orders of magnitude inside
     assert paged_kernel_smem_bytes(8, 129, 184) < SMEM_BYTES // 50
+
+
+# -- the stacked pool [L, Hkv, N, P, D] with a layer index (ISSUE 25) ---------
+
+
+def _stack_with(layer, pool, layers=3):
+    """A stack whose layer ``layer`` is ``pool`` and whose other layers are
+    other data: reading the wrong layer cannot go unseen."""
+    others = jax.random.normal(
+        jax.random.PRNGKey(100 + layer), (layers,) + pool.shape, jnp.float32
+    )
+    if jnp.issubdtype(pool.dtype, jnp.integer):
+        others = jnp.round(others * 40)
+    return others.astype(pool.dtype).at[layer].set(pool)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kind", ["bf16", "int8", "tree"])
+def test_ragged_attention_reads_its_layer_of_the_stack(kind, layer):
+    """Ragged kernel (interpret) and XLA reference on the stack of L = 3
+    with ``layer`` equal, bit for bit, the same entry point on
+    ``pool[layer]`` — under jit with a TRACED layer, as the layer scan calls
+    them — for bf16 pools, int8 pools with their scale stacks, and a batch
+    with a draft-tree row; kernel and reference agree as before."""
+    from clearml_serving_tpu.ops.paged_attention import tree_ancestors
+
+    (q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
+     block_rows, block_q0) = _setup(
+        jax.random.PRNGKey(6), rows=4, hkv=2, g=2, d=128, page=16,
+        pages_per_seq=4, row_lens=(1, 7, 1, 10), kv_extra=(17, 3, 32, 0),
+    )
+    kw, stacked_kw = {}, {}
+    if kind == "int8":
+        (k_pool, ks), (v_pool, vs) = _quantize_pool(k_pool), _quantize_pool(v_pool)
+        kw = {"k_scale": ks, "v_scale": vs}
+        stacked_kw = {n: _stack_with(layer, s) for n, s in kw.items()}
+    else:
+        q, k_pool, v_pool = (a.astype(jnp.bfloat16) for a in (q, k_pool, v_pool))
+    if kind == "tree":
+        # row 1 (7 tokens) is a binary draft tree; every other token plain
+        anc = np.full((q.shape[0], 7), -1, np.int32)
+        anc[:, 0] = -2
+        s = int(starts[1])
+        anc[s: s + 7] = tree_ancestors([-1, 0, 0, 1, 1, 2, 2], width=7)
+        kw = stacked_kw = {"tree_anc": jnp.asarray(anc)}
+    k_stack, v_stack = _stack_with(layer, k_pool), _stack_with(layer, v_pool)
+
+    kernel = functools.partial(
+        ragged_paged_attention, block_rows=block_rows, block_q0=block_q0,
+        pages_per_block=2, interpret=True,
+    )
+    for fn in (kernel, ragged_paged_attention_xla):
+        want = fn(q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
+                  **kw)
+        got = jax.jit(
+            lambda li, fn=fn: fn(q, k_stack, v_stack, page_table, kv_lens,
+                                 starts, row_lens, layer=li, **stacked_kw)
+        )(jnp.int32(layer))
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    np.testing.assert_allclose(
+        np.asarray(kernel(q, k_stack, v_stack, page_table, kv_lens, starts,
+                          row_lens, layer=layer, **stacked_kw), np.float32),
+        np.asarray(want, np.float32), rtol=2e-2, atol=2e-2,
+    )
+
+
+# -- the write of new K/V into the pools: page-patching kernel vs row scatter --
+
+
+def _write_case(dtype):
+    """Coordinates of one launch: a prefill run that crosses from page 3
+    into page 4, two decode rows between their pads on the null page, and a
+    page left and come back to (5, 6, 5, 5)."""
+    layers, hkv, pages, page, d = 3, 2, 12, 16, 128
+    coords = ([(3, o) for o in range(10, 16)] + [(4, o) for o in range(10)]
+              + [(7, 2)] + [(0, 0)] * 7 + [(8, 15)] + [(0, 0)] * 7
+              + [(5, 1), (6, 3), (5, 2), (5, 9)] + [(0, 0)] * 4)
+    wp, wo = (jnp.asarray(c, jnp.int32) for c in zip(*coords))
+
+    def rnd(seed, shape):
+        x = jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
+        return jnp.round(x * 20).astype(dtype)
+
+    stack = (layers, hkv, pages, page, d)
+    new = (len(coords), hkv, d)
+    return rnd(0, stack), rnd(1, stack), rnd(2, new), rnd(3, new), wp, wo
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_kv_write_kernel_equals_the_row_scatter(dtype, layer):
+    """Bit for bit on every page but the null page (page 0 takes every pad,
+    and of duplicates XLA leaves the winner open), under jit with a traced
+    layer; the other layers of the stack are untouched."""
+    k, v, k_new, v_new, wp, wo = _write_case(dtype)
+    want = paged_kv_write_xla(k, v, k_new, v_new, wp, wo, layer=layer)
+    got = jax.jit(functools.partial(paged_kv_write, interpret=True))(
+        k, v, k_new, v_new, wp, wo, layer=jnp.int32(layer))
+    for old, w, g in zip((k, v), want, got):
+        w, g = np.asarray(w), np.asarray(g)
+        assert g[:, :, 1:].tobytes() == w[:, :, 1:].tobytes()
+        assert not np.array_equal(g[layer], np.asarray(old)[layer])
+        others = [i for i in range(3) if i != layer]
+        assert np.array_equal(g[others], np.asarray(old)[others])
+    # a written row holds its token
+    assert np.array_equal(np.asarray(got[0])[layer, :, 8, 15],
+                          np.asarray(k_new)[24])
+
+
+def test_kv_write_kernel_on_one_layers_pool_and_duplicates():
+    """The 4-D form, and two tokens on one coordinate: the later one wins."""
+    k, v, k_new, v_new, wp, wo = _write_case(jnp.bfloat16)
+    wo = wo.at[-5].set(1)                  # (5, 9) -> (5, 1), written before
+    got = paged_kv_write(k[1], v[1], k_new, v_new, wp, wo, interpret=True)
+    assert got[0].shape == k[1].shape
+    assert np.array_equal(np.asarray(got[1])[:, 5, 1], np.asarray(v_new)[-5])
+    want = paged_kv_write_xla(k[1], v[1], k_new, v_new, wp, wo)
+    keep = np.ones(k.shape[2:4], bool)
+    keep[0], keep[5, 1] = False, False
+    for w, g in zip(want, got):
+        assert np.array_equal(np.asarray(g)[:, keep], np.asarray(w)[:, keep])
+
+
+def test_kv_write_kernel_over_several_grid_steps():
+    """More tokens than one grid step holds (512 at Hkv <= 16, D = 128,
+    bf16): a 1024-token prefill over 64 pages whose run crosses the steps'
+    boundary mid-page, so the page is flushed and fetched again there."""
+    hkv, d, page = 2, 128, 16
+    t = jnp.arange(1024, dtype=jnp.int32) + 8        # starts mid-page
+    wp, wo = 1 + t // page, t % page
+
+    def rnd(seed, shape):
+        x = jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
+        return x.astype(jnp.bfloat16)
+
+    k, v = rnd(0, (hkv, 70, page, d)), rnd(1, (hkv, 70, page, d))
+    k_new, v_new = rnd(2, (1024, hkv, d)), rnd(3, (1024, hkv, d))
+    want = paged_kv_write_xla(k, v, k_new, v_new, wp, wo)
+    got = paged_kv_write(k, v, k_new, v_new, wp, wo, interpret=True)
+    for w, g in zip(want, got):
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()

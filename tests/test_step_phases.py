@@ -278,3 +278,155 @@ def test_kv_pool_reports_raw_high_water_mark(parts):
     assert "used_pages" not in pool
     assert engine.health()["kv_pool"]["used_pages_peak"] == pool["used_pages_peak"]
     engine.stop()
+
+
+# -- the stacked pools stay one buffer through a launch (ISSUE 25) ------------
+
+# sizes no other dimension of llama-tiny shares: 2 layers, 2 KV heads x 16,
+# 11 pages of 8 tokens
+_POOL = dict(layers=2, hkv=2, pages=11, page=8, d=16)
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in _sub_jaxprs(eqn):
+            yield from _walk(inner)
+
+
+def _assert_pools_ride_the_carry(jaxpr, quant):
+    """No equation yields one layer's pool (a slice of the stack, or a stack
+    rebuilt from slices, is a copy of gigabytes on the chip), and every scan
+    that touches the stacks has them in its carry, never among the inputs it
+    scans over or the outputs it stacks. Returns the scans seen."""
+    p = _POOL
+    layer_pool = (p["hkv"], p["pages"], p["page"], p["d"])
+    stacks = {(p["layers"],) + layer_pool}
+    if quant:
+        stacks.add((p["layers"],) + layer_pool[:-1])
+    banned = {s[1:] for s in stacks}
+    scans = 0
+    for eqn in _walk(jaxpr):
+        for var in eqn.outvars:
+            shape = tuple(var.aval.shape)
+            while shape[:1] == (1,):
+                shape = shape[1:]
+            assert shape not in banned, (eqn.primitive.name, var.aval)
+        if eqn.primitive.name != "scan":
+            continue
+        n_consts, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        shapes = [tuple(v.aval.shape) for v in eqn.invars]
+        carry = shapes[n_consts:n_consts + n_carry]
+        scanned = shapes[n_consts + n_carry:]
+        stacked = [tuple(v.aval.shape) for v in eqn.outvars[n_carry:]]
+        if not stacks & set(shapes):
+            continue
+        scans += 1
+        assert stacks <= set(carry), carry
+        assert not stacks & set(shapes[:n_consts]), "a pool closed over"
+        # a scanned input or stacked output has the scan's length in front
+        for shape in scanned + stacked:
+            assert shape not in stacks and shape[1:] not in stacks, shape
+    return scans
+
+
+def _pass_jaxpr(which, quant, scan_layers):
+    """The jaxpr of one paged pass of llama-tiny on pools of _POOL's sizes."""
+    import jax.numpy as jnp
+
+    cfg = {"preset": "llama-tiny", "dtype": "bfloat16",
+           "scan_layers": scan_layers}
+    if quant:
+        cfg["kv_quant"] = "int8"
+    bundle = models.build_model("llama", cfg)
+    params = jax.eval_shape(lambda: bundle.init(jax.random.PRNGKey(0)))
+    p = _POOL
+    stack = (p["layers"], p["hkv"], p["pages"], p["page"], p["d"])
+    pool = jax.ShapeDtypeStruct(stack, jnp.int8 if quant else jnp.bfloat16)
+    scales = (
+        {n: jax.ShapeDtypeStruct(stack[:-1], jnp.float32)
+         for n in ("k_scales", "v_scales")} if quant else {}
+    )
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    rows, t = 3, 16
+    table, per_row, per_tok = i32(rows, 4), i32(rows), i32(t)
+    # the operands in front of and behind (k_pools, v_pools), per signature
+    front, back = {
+        "decode_paged": ([per_row], [table] + [per_row] * 3),
+        "verify_paged": ([i32(rows, 3)], [table, per_row]),
+        "forward_ragged": (
+            [per_tok] * 3 + [jax.ShapeDtypeStruct((t,), jnp.bool_), per_row],
+            [table] + [per_row] * 3 + [per_tok] * 2,
+        ),
+    }[which]
+
+    def run(params, front, k, v, back, scales):
+        return getattr(bundle, which)(params, *front, k, v, *back, **scales)
+
+    return jax.make_jaxpr(run)(params, front, pool, pool, back, scales).jaxpr
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["unrolled", "scan"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("which", ["decode_paged", "forward_ragged",
+                                   "verify_paged"])
+def test_a_paged_pass_carries_the_stacked_pools(which, quant, scan_layers):
+    jaxpr = _pass_jaxpr(which, quant, scan_layers)
+    scans = _assert_pools_ride_the_carry(jaxpr, quant)
+    assert scans == (1 if scan_layers else 0)
+    # the pass hands the stacks back whole, behind the logits
+    p = _POOL
+    stack = (p["layers"], p["hkv"], p["pages"], p["page"], p["d"])
+    outs = [tuple(v.aval.shape) for v in jaxpr.outvars]
+    assert outs.count(stack) == 2
+    assert outs.count(stack[:-1]) == (2 if quant else 0)
+
+
+@pytest.mark.parametrize("scan_layers", [False, True], ids=["unrolled", "scan"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_the_ragged_step_carries_the_stacked_pools(quant, scan_layers):
+    """The same on the program the engine launches (the spy of
+    test_the_steps_operations_carry_their_named_scope): the ragged pass and
+    the decode passes chained behind it, whose scan over steps carries the
+    pools too."""
+    cfg = {"preset": "llama-tiny", "dtype": "bfloat16",
+           "scan_layers": scan_layers}
+    if quant:
+        cfg["kv_quant"] = "int8"
+    bundle = models.build_model("llama", cfg)
+    p = _POOL
+    engine = _engine((bundle, bundle.init(jax.random.PRNGKey(0))),
+                     **dict(RAGGED, page_size=p["page"], num_pages=p["pages"]))
+    step, seen = engine._ragged_paged_jit, []
+
+    def spy(*args, **kw):
+        if kw.get("chain") is not None and not seen:
+            seen.append(jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+                if hasattr(x, "shape") else x, (args, kw)))
+        return step(*args, **kw)
+
+    engine._ragged_paged_jit = spy
+    # two prompts at once: the first to finish its prefill decodes (a window
+    # of 4 steps, so a chain) beside the other's chunks
+    _run(engine, [p[:20] for p in PROMPTS[:2]], n=8)
+    engine.stop()
+    args, kw = seen[0]
+    jaxpr = jax.make_jaxpr(lambda a, k: step.__wrapped__(*a, **k))(
+        args, {k: v for k, v in kw.items() if k != "want_lp"}).jaxpr
+    scans = _assert_pools_ride_the_carry(jaxpr, quant)
+    # the chain's scan over steps, and under scan_layers the layer scan of
+    # the ragged pass and the one inside the chain's body
+    assert scans == (3 if scan_layers else 1)

@@ -1,0 +1,126 @@
+"""What only the chip's compiler shows, without a chip: the paged step
+compiled for a described TPU v5e (the `on-chip-measurement` guide, section
+2). ISSUE 25's fault was of this kind: with the stacked KV pools in the layer
+scan's carry, a scatter whose update window is ``[Hkv, D]`` makes XLA keep
+the stack in the scatter's layout and convert ALL of it to the kernels'
+layout in front of every attention call; CPU tests and the Pallas interpreter
+cannot see a layout. The topology is described inside a fixture, never at
+import: every xdist worker collects the same tests and one loads libtpu."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from clearml_serving_tpu import models
+from clearml_serving_tpu.ops import paged_attention as pa
+
+# kernel-aligned widths at a small depth: head_dim 128, 16-token bf16 pages (32
+# for int8). The pools are of a deployment's size (147 MB each; only shapes
+# are compiled): a stack that fits the chip's fast memory is prefetched into
+# it whole, which no real pool is.
+LAYERS, HKV, PAGES, D = 3, 2, 6000, 128
+ROWS, TOKENS, PAGES_PER_SEQ = 4, 16, 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip("no v5e:2x2 topology can be described here: {}".format(e))
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_step(one_chip, monkeypatch, quant):
+    """One ragged pass and two chained decode passes over donated stacked
+    pools, as the engine's ``_ragged_paged_step`` chains them, compiled for
+    the described chip with the Pallas kernels routed in."""
+    monkeypatch.setattr(pa, "paged_kernel_unsupported_reason",
+                        lambda *a, **k: None)
+    cfg = dict(vocab_size=512, dim=512, n_layers=LAYERS, n_heads=4,
+               n_kv_heads=HKV, head_dim=D, ffn_dim=512, scan_layers=True,
+               dtype="bfloat16")
+    page = 16
+    if quant:
+        cfg["kv_quant"], page = "int8", 32
+    bundle = models.build_model("llama", cfg)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return on_chip(shape, jnp.int32)
+
+    params = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: bundle.init(jax.random.PRNGKey(0))))
+    stack = (LAYERS, HKV, PAGES, page, D)
+    pools = [on_chip(stack, jnp.int8 if quant else jnp.bfloat16)] * 2
+    if quant:
+        pools += [on_chip(stack[:-1], jnp.float32)] * 2
+
+    def step(params, pools, tok, valid, row_last, table, per_row, per_tok,
+             blocks, chain_coords):
+        names = ("k_scales", "v_scales")
+        out = bundle.forward_ragged(
+            params, tok, tok, tok, valid, row_last, pools[0], pools[1],
+            table, per_row, per_row, per_row, per_tok, per_tok, blocks,
+            blocks, **dict(zip(names, pools[2:])))
+        nxt = jnp.argmax(out[0], -1).astype(jnp.int32)
+
+        def body(carry, coords):
+            nxt, pools, i = carry
+            out = bundle.decode_paged(
+                params, nxt, pools[0], pools[1], table, per_row + i,
+                coords, coords, **dict(zip(names, pools[2:])))
+            nxt = jnp.argmax(out[0], -1).astype(jnp.int32)
+            return (nxt, tuple(out[1:]), i + 1), nxt
+
+        (_, pools, _), toks = jax.lax.scan(
+            body, (nxt, tuple(out[1:]), jnp.int32(0)), chain_coords)
+        return toks, pools
+
+    lowered = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, i32(TOKENS), on_chip((TOKENS,), jnp.bool_), i32(ROWS),
+        i32(ROWS, PAGES_PER_SEQ), i32(ROWS), i32(TOKENS),
+        i32(TOKENS // 8), i32(2, ROWS))
+    # this compile could be written to the persistent cache and never read
+    # back without a chip (the next run would warn): keep it out
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return lowered.compile().as_text(), page
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_the_v5e_step_updates_the_stacked_pools_in_place(
+        one_chip, monkeypatch, quant):
+    hlo, page = _compiled_step(one_chip, monkeypatch, quant)
+    assert hlo.count("tpu_custom_call") >= 2      # both attention kernels
+    layer = "{},{},{},{}".format(HKV, PAGES, page, D)
+    shape = re.compile(
+        r"^\s*(?:ROOT )?%?(\S+) = \w+\[(?:\d+,)?" + layer + r"\]\S* ([\w\-]+)\(")
+    movers = []
+    for line in hlo.splitlines():
+        m = shape.match(line)
+        if not m:
+            continue
+        name, op = m.groups()
+        # what may have a pool's shape: the buffer itself on its way through
+        # the loops, and the write into it
+        writes = op in ("scatter", "fusion") and "kv_write" in line
+        if op not in ("parameter", "get-tuple-element", "bitcast") and not writes:
+            movers.append((name, op))
+    assert not movers, movers
+    # and the stack keeps the kernels' row-major layout from end to end
+    stack_shapes = set(re.findall(
+        r"\w+\[{},{}\](\{{[\d,]+)".format(LAYERS, layer), hlo))
+    assert stack_shapes == {"{4,3,2,1,0"}, stack_shapes
