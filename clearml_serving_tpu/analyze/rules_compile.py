@@ -127,6 +127,7 @@ WARMUP_COVERED: FrozenSet[str] = frozenset({
     "_spec_paged_jit",
     "_ragged_paged_jit",
     "_ragged_dense_jit",
+    "_ragged_state_jit",
     "_gather_finish_jit",
 })
 

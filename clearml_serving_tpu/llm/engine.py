@@ -368,6 +368,70 @@ class _InFlightChunk:
 _RAGGED_BROWNOUT_CHUNK = 16
 
 
+def _state_cache_refusal(bundle, *, mesh, prefix_cache,
+                         prefix_cache_host_pages, prefix_cache_host_bytes,
+                         speculation, spec_tree, lora_adapters,
+                         scheduler) -> Optional[str]:
+    """Why this engine cannot be built on ``engine.cache=state``, or None.
+    Every feature that assumes K/V pages is refused by name with its reason
+    (docs/state_cache.md lists what each would need)."""
+    if getattr(bundle, "forward_ragged_state", None) is None:
+        return (
+            "engine.cache=state needs a model whose layers keep a recurrent "
+            "state (forward_ragged_state / decode_state / init_state "
+            "surfaces, e.g. config attention='power_retention'); this "
+            "model attends over keys and values: use engine.cache=paged or "
+            "dense"
+        )
+    if scheduler != "ragged":
+        return (
+            "engine.cache=state is served by scheduler='ragged' only: a "
+            "prompt enters its slot in chunks of the ragged step and decode "
+            "rows chain through the same launch (got scheduler={!r})"
+            .format(scheduler)
+        )
+    if prefix_cache:
+        return (
+            "prefix_cache cannot serve engine.cache=state: a cached prefix "
+            "is shared K/V pages, and a recurrent state has none; reuse "
+            "would need snapshots of the state at block boundaries "
+            "(RadixPrefixCache holds pages only). Set prefix_cache to 0"
+        )
+    if prefix_cache_host_pages or prefix_cache_host_bytes:
+        return (
+            "prefix_cache_host_pages / prefix_cache_host_mb (HostKVTier) "
+            "cannot serve engine.cache=state: the host tier spills the "
+            "prefix cache's K/V pages, and a state cache has neither"
+        )
+    if bundle.config.get("kv_quant"):
+        return (
+            "kv_quant cannot serve engine.cache=state: there are no keys "
+            "and values to quantise, and the state is float32 by the "
+            "configuration (a bfloat16 or int8 state is another model)"
+        )
+    if speculation or spec_tree:
+        return (
+            "speculation cannot serve engine.cache=state: a rejected draft "
+            "cannot be rolled back out of a recurrent state without a "
+            "snapshot of it (verify rows overwrite K/V positions; a state "
+            "has no positions)"
+        )
+    if lora_adapters:
+        return (
+            "lora_adapters are not served from engine.cache=state yet (the "
+            "state step takes no per-row adapter index)"
+        )
+    if mesh is not None and mesh.size > 1:
+        return (
+            "engine.cache=state cannot serve under a {}-device mesh: the "
+            "retention kernels have no partitioning rule and the sequence-"
+            "parallel prefill (prefill_ring) passes K/V blocks round a ring, "
+            "which a recurrent state does not have. Serve one engine per "
+            "chip".format(mesh.size)
+        )
+    return None
+
+
 @dataclass(eq=False)  # identity semantics: jobs live in (and leave) lists
 class _RaggedJob:
     """One admission riding the ragged scheduler (docs/ragged_attention.md):
@@ -709,7 +773,8 @@ class LLMEngineCore:
             "_merge_rows_jit", "_decode_chunk_jit",
             "_decode_paged_chunk_jit", "_sample_jit", "_first_lp_jit",
             "_set_sampling_row_jit", "_spec_chunk_jit", "_spec_paged_jit",
-            "_ragged_paged_jit", "_ragged_dense_jit", "_gather_finish_jit",
+            "_ragged_paged_jit", "_ragged_dense_jit", "_ragged_state_jit",
+            "_gather_finish_jit",
         ),
         # prompt scoring runs only for completions echo+logprobs requests:
         # one compile per prefill bucket on first use, sentry-attributed
@@ -866,8 +931,35 @@ class LLMEngineCore:
             bundle, "paged_unsupported_reason", None
         ):
             raise ValueError(bundle.paged_unsupported_reason)
-        if cache_mode not in ("dense", "paged"):
-            raise ValueError("cache_mode must be 'dense' or 'paged'")
+        if cache_mode not in ("dense", "paged", "state"):
+            raise ValueError(
+                "cache_mode must be 'dense', 'paged' or 'state'"
+            )
+        # the state cache (docs/state_cache.md): models whose layers keep a
+        # recurrent state in place of keys and values own one fixed-size
+        # SLOT per sequence. Everything that assumes K/V pages says no here,
+        # by name, until it is taught otherwise — never a silent fallback.
+        recurrent = getattr(bundle, "attention", "softmax") == "power_retention"
+        if recurrent and cache_mode != "state":
+            raise ValueError(
+                "this model mixes tokens through power retention (a recurrent "
+                "state, no keys and values): serve it with engine.cache=state "
+                "(got engine.cache={})".format(cache_mode)
+            )
+        if cache_mode == "state":
+            refused = _state_cache_refusal(
+                bundle, mesh=mesh, prefix_cache=prefix_cache,
+                prefix_cache_host_pages=prefix_cache_host_pages,
+                prefix_cache_host_bytes=prefix_cache_host_bytes,
+                speculation=speculation, spec_tree=spec_tree,
+                lora_adapters=lora_adapters, scheduler=(
+                    scheduler if scheduler is not None
+                    else os.environ.get("TPUSERVE_SCHEDULER", "")
+                    or "two_dispatch"
+                ),
+            )
+            if refused:
+                raise ValueError(refused)
         self.cache_mode = cache_mode
         # kernel or XLA gather for paged pools of this model's shape
         # (ops.paged_attention): the same pure function models/llama.py
@@ -884,6 +976,16 @@ class LLMEngineCore:
         )
         if cache_mode == "paged":
             self._paged_kernel_reason = paged_reason
+        elif cache_mode == "state":
+            # the state pools' two launches (ops/power_retention.py): the
+            # same pure function models/llama.py evaluates at trace time
+            from ..ops.power_retention import (
+                retention_kernel_unsupported_reason,
+            )
+
+            self._paged_kernel_reason = retention_kernel_unsupported_reason(
+                bundle.head_dim
+            )
         else:
             # the dense cache never reaches the paged kernels; say so, and
             # say whether this model's paged pools would reach them
@@ -976,10 +1078,17 @@ class LLMEngineCore:
         if step_token_budget is None:
             raw = os.environ.get("TPUSERVE_STEP_TOKEN_BUDGET", "")
             step_token_budget = int(raw) if raw else None
+        # default: 128 tokens a launch (4 per row at large batches). A state
+        # cache's decode pass streams every row's whole state whatever the
+        # launch carries, so a prompt chunk rides it for little: 256 there
+        # (measured on the v5e, PERF.md PR 26: at 128 a third of the time
+        # went to the prompts' launches and the cell's TPOT swung with the
+        # prompts a window happened to hold)
         self._step_token_budget = (
             int(step_token_budget)
             if step_token_budget is not None
-            else max(128, 4 * self.max_batch)
+            else max(256 if cache_mode == "state" else 128,
+                     4 * self.max_batch)
         )
         if self._ragged and self._step_token_budget <= self.max_batch:
             # every decode row costs one budget token; a budget at or below
@@ -1216,8 +1325,19 @@ class LLMEngineCore:
             self.cache = None
             if self._paged_kernel_reason is None:
                 self._check_kernel_smem()
+            self.state_cache = None
+        elif self.cache_mode == "state":
+            from .kv_cache import StateCache
+
+            # one slot per batch row: admission is bounded by free rows, so
+            # the pool cannot run out under a row (no num_pages, no page
+            # table, no oversubscription)
+            self.paged_cache = None
+            self.cache = None
+            self.state_cache = StateCache(bundle.init_state, self.max_batch)
         else:
             self.paged_cache = None
+            self.state_cache = None
             # dense: the slack keeps verify's dynamic_update_slice writes
             # from clamping at the buffer edge (a clamp would overwrite
             # live K/V)
@@ -1290,6 +1410,11 @@ class LLMEngineCore:
             # windows + accepted spec tokens): ragged_steps / this ratio is
             # dispatches-per-decode-token, the bubble-amortization headline
             "ragged_decode_tokens": 0,
+            # prompt tokens that rode ragged launches as chunk rows, and
+            # model passes (a launch is one mixed pass plus its chained
+            # decode steps): what a roofline of the step needs to count
+            "ragged_prefill_tokens": 0,
+            "ragged_passes": 0,
             # rows the engine.spec.tree chaos seam demoted from spec-verify
             # back to plain decode (docs/spec_decode_trees.md fallback row)
             "spec_tree_fallbacks": 0,
@@ -2565,6 +2690,81 @@ class LLMEngineCore:
                     static_argnames=("want_lp",),
                 )
                 self._ragged_dense_jit = None
+                self._ragged_state_jit = None
+            elif cache_mode == "state":
+
+                def _ragged_state_step(params, tokens, tok_pos, tok_row,
+                                       tok_valid, row_last, s_pool, z_pool,
+                                       positions, row_starts, row_lens,
+                                       row_reset, decode_mask, sampling, rng,
+                                       extras=None, counts=None, pmask=None,
+                                       guided=None, gstate=None,
+                                       want_lp=False, chain=None):
+                    """The ragged step over the state cache: one mixed pass
+                    (decode rows one token, prefill rows a chunk) and the
+                    decode rows' chained window, the pools in the carry of
+                    both. ``positions`` [B]: tokens of each row's sequence
+                    AFTER the mixed pass = the position of the window's
+                    next token. A chained step advances only the rows whose
+                    window is still open (``chain_mask``): a closed window's
+                    pad positions and the rows in prefill leave their slots
+                    untouched (docs/ragged_attention.md)."""
+                    logits, s_pool, z_pool = bundle.forward_ragged_state(
+                        params, tokens, tok_pos, tok_row, tok_valid,
+                        row_last, s_pool, z_pool, row_starts, row_lens,
+                        row_reset,
+                    )
+                    raw = logits.astype(jnp.float32)
+                    sampled, counts, lp, gstate = _sample_rows(
+                        raw, decode_mask, sampling, rng, extras, counts,
+                        pmask, guided, gstate, want_lp,
+                    )
+                    if chain is not None:
+                        step_rngs, chain_mask = chain
+                        nb = sampled.shape[0]
+
+                        def body(carry, xs):
+                            tok_c, s_p, z_p, counts_c, gstate_c, step = carry
+                            s_rng, m = xs
+                            l, s_p, z_p = bundle.decode_state(
+                                params, tok_c, s_p, z_p, positions + step, m,
+                            )
+                            s_tok, counts_c, gstate_c, lp_s = _chain_sample(
+                                l, m, step, s_rng, sampling, extras,
+                                counts_c, pmask, guided, gstate_c, want_lp,
+                                nb,
+                            )
+                            tok_next = jnp.where(m, s_tok, tok_c)
+                            out_s = (
+                                (tok_next, lp_s) if want_lp else tok_next
+                            )
+                            return (
+                                (tok_next, s_p, z_p, counts_c, gstate_c,
+                                 step + 1),
+                                out_s,
+                            )
+
+                        (
+                            (_, s_pool, z_pool, counts, gstate, _),
+                            chain_out,
+                        ) = jax.lax.scan(
+                            body,
+                            (sampled, s_pool, z_pool, counts, gstate,
+                             jnp.int32(0)),
+                            (step_rngs, chain_mask),
+                        )
+                        sampled, lp = _stack_chain(
+                            sampled, lp, chain_out, want_lp
+                        )
+                    return (sampled, raw, s_pool, z_pool, counts, lp, gstate)
+
+                self._ragged_state_jit = jax.jit(
+                    _ragged_state_step,
+                    donate_argnums=(6, 7),
+                    static_argnames=("want_lp",),
+                )
+                self._ragged_paged_jit = None
+                self._ragged_dense_jit = None
             else:
 
                 def _ragged_dense_step(params, tokens, start, last_rel,
@@ -2666,6 +2866,7 @@ class LLMEngineCore:
                     static_argnames=("want_lp",),
                 )
                 self._ragged_paged_jit = None
+                self._ragged_state_jit = None
             # static flat-token capacity per launch: ONE trace per
             # (extras/guided/lp variant). When the Pallas kernel serves the
             # launch each row's segment aligns to the kernel's q block
@@ -2678,11 +2879,15 @@ class LLMEngineCore:
 
             self._ragged_kernel = self._paged_kernel_reason is None
             qb = _RAGGED_QB if self._ragged_kernel else 1
+            if cache_mode == "state":
+                # the chunk kernel slices a row's tokens at its start:
+                # whole 8-row float32 tiles, with the kernel or its twin
+                qb = 8
             self._ragged_qb = qb
             budget = self._step_token_budget
             waste = self.max_batch * (qb - 1) if qb > 1 else 0
             self._ragged_tpad = -(-(budget + waste) // qb) * qb
-            if self._ragged_kernel:
+            if self._ragged_kernel and cache_mode == "paged":
                 self._check_kernel_smem(
                     self._ragged_tpad,
                     self._spec_k + 1 if self._spec_tree else 0,
@@ -3747,6 +3952,15 @@ class LLMEngineCore:
             used_pages_peak=self.paged_cache.pool.used_pages_peak,
         )
 
+    def _state_pool_snapshot(self):
+        """State-cache block shared by health() and lifecycle_stats()
+        (docs/state_cache.md): slots, how many are owned now and at most
+        since the engine started, bytes, and the launches that zeroed a slot
+        for a new owner. None on the K/V backends."""
+        if self.state_cache is None:
+            return None
+        return self.state_cache.snapshot()
+
     def _reap_promotions(self, force: bool = False) -> None:
         """Loop-thread: retire-stage observation of completed host-tier
         promotion DMAs (docs/kv_tiering.md). A no-op without a host tier;
@@ -3810,6 +4024,13 @@ class LLMEngineCore:
             raise ValueError(
                 "replica role must be prefill/decode/hybrid: got {!r}"
                 .format(role)
+            )
+        if endpoint is not None and self.cache_mode == "state":
+            raise ValueError(
+                "KV transport (KVShipment) cannot serve engine.cache=state: "
+                "a shipment is the prefix cache's K/V pages, and a "
+                "recurrent state would have to travel as a snapshot of the "
+                "slot (docs/state_cache.md)"
             )
         if endpoint is not None and (
             self.cache_mode != "paged" or self._prefix is None
@@ -4205,6 +4426,7 @@ class LLMEngineCore:
                 else None
             ),
             "kv_pool": self._kv_pool_snapshot(),
+            "state_pool": self._state_pool_snapshot(),
             "kv_tier": self._kv_tier_snapshot(),
             "kv_ship": self._kv_ship_snapshot(),
             "weights": {
@@ -4314,6 +4536,8 @@ class LLMEngineCore:
                     # launches/decode_tokens is dispatches-per-decode-token
                     "decode_steps": self._ragged_decode_steps,
                     "decode_tokens": self.counters["ragged_decode_tokens"],
+                    "prefill_tokens": self.counters["ragged_prefill_tokens"],
+                    "passes": self.counters["ragged_passes"],
                     "tokens_per_launch": self._hist_launch_tokens.snapshot(),
                     "spec_acceptance": self._hist_spec_accept.snapshot(),
                     # draft-tree verify rows (docs/spec_decode_trees.md):
@@ -4342,6 +4566,7 @@ class LLMEngineCore:
                 else None
             ),
             "kv_pool": self._kv_pool_snapshot(),
+            "state_pool": self._state_pool_snapshot(),
             "kv_tier": self._kv_tier_snapshot(),
             "kv_ship": self._kv_ship_snapshot(),
             "weights": {
@@ -4457,10 +4682,10 @@ class LLMEngineCore:
         if self._dispatching is not None:
             return
         await self._discard_pipeline()
-        if self.paged_cache is not None:
+        if self.paged_cache is not None or self.state_cache is not None:
             for slot in range(self.max_batch):
                 if self._slot_req[slot] is None and slot not in self._admitting:
-                    self.paged_cache.pool.free(slot)
+                    self._release_cache_slot(slot)
         self._recovering = False
         self._last_progress = time.monotonic()
 
@@ -4502,8 +4727,17 @@ class LLMEngineCore:
         if barrier is not None:
             self._quarantine_slot(slot, barrier)
             return
+        self._release_cache_slot(slot)
+
+    def _release_cache_slot(self, slot: int) -> None:
+        """Give a batch row's sequence state back to its cache: the slot's
+        pages to the page pool, or the state slot (zeroed when its next
+        owner's first launch names it). The dense cache keeps nothing per
+        row."""
         if self.paged_cache is not None:
             self.paged_cache.pool.free(slot)
+        elif self.state_cache is not None:
+            self.state_cache.free(slot)
 
     def _quarantine_slot(self, slot: int, barrier: int) -> None:
         """Defer a freed slot's page release to the retire of in-flight
@@ -4641,6 +4875,15 @@ class LLMEngineCore:
                         k: jax.device_put(v, self._cache_sharding[k])
                         for k, v in self.cache.items()
                     }
+            if self.state_cache is not None and any(
+                getattr(a, "is_deleted", lambda: False)()
+                for a in (self.state_cache.s, self.state_cache.z)
+            ):
+                # every live sequence's state went with the donated pools:
+                # their requests were failed by the step-failure path
+                self.state_cache.s, self.state_cache.z = (
+                    self.bundle.init_state(self.max_batch)
+                )
         except Exception:
             pass  # recovery is best-effort; the next dispatch surfaces it
 
@@ -5825,6 +6068,11 @@ class LLMEngineCore:
         # the prefix lookup ran (hit or miss): the preemption-era eviction
         # pin on the stored history has done its job (legacy parity)
         self._release_resume_pin(request)
+        if self.state_cache is not None:
+            # the row's state slot changes hands here; it is zeroed by the
+            # launch that carries the job's first chunk (row_reset)
+            self.state_cache.free(slot)
+            self.state_cache.allocate(slot)
         request._job_at = time.monotonic()
         return _RaggedJob(request=request, slot=slot, pos=pos)
 
@@ -5834,8 +6082,7 @@ class LLMEngineCore:
         # a failed/cancelled job never seals its draft-ahead stream: the
         # receiver's unsealed assembly stays unconsumable and ages out
         self._kv_draft_ahead.pop(slot, None)
-        if self.paged_cache is not None:
-            self.paged_cache.pool.free(slot)
+        self._release_cache_slot(slot)
 
     def _fail_ragged_job(self, job: "_RaggedJob",
                          err: Optional[BaseException]) -> None:
@@ -6117,10 +6364,16 @@ class LLMEngineCore:
         }
         job_of = {job.slot: job for job, _ in shares}
         take_of = {job.slot: take for job, take in shares}
-        if self.cache_mode == "paged":
+        if self.cache_mode in ("paged", "state"):
             from ..ops.paged_attention import ragged_layout
 
-            pool = self.paged_cache.pool
+            # tokens each row's cache holds before this launch: the page
+            # pool's slot length, or the state slot's
+            pool = (
+                self.paged_cache.pool
+                if self.paged_cache is not None
+                else None
+            )
             # layout lens reserve each row's WHOLE window in the flat token
             # axis (a q=N decode row owns N positions: position 0 rides the
             # mixed pass, positions 1.. are written by the in-launch chain);
@@ -6154,7 +6407,11 @@ class LLMEngineCore:
                     continue
                 s = int(starts[slot])
                 v = int(row_lens[slot])
-                pre = pool.slot_length(slot)
+                pre = (
+                    pool.slot_length(slot)
+                    if pool is not None
+                    else self.state_cache.length(slot)
+                )
                 pre_lens[slot] = pre
                 if slot in job_of:
                     job = job_of[slot]
@@ -6215,6 +6472,9 @@ class LLMEngineCore:
                 tok_valid=tok_valid, row_last=row_last, kv_lens=kv_lens,
                 pre_lens=pre_lens, row_starts=starts, row_lens=row_lens,
                 span_lens=span_lens, spans=spans,
+                # state cache: a row whose tokens start its sequence finds
+                # its slot as the last owner left it — the launch zeroes it
+                row_reset=(pre_lens == 0) & (row_lens > 0),
                 row_logit_idx=row_logit_idx,
                 write_page=np.zeros(tpad, np.int32),
                 write_offset=np.zeros(tpad, np.int32),
@@ -6465,6 +6725,51 @@ class LLMEngineCore:
                 if self._paged_quant:
                     self.paged_cache.k_scale = new_ks
                     self.paged_cache.v_scale = new_vs
+        elif self.cache_mode == "state":
+            # a launch's plan carries (slot = row, reset) per row in place
+            # of page tables and write coordinates; nothing is allocated
+            # here (admission gave the row its slot) and nothing can run out
+            chain_arrays = None
+            if launch_steps > 1:
+                chain_arrays = (
+                    plan["step_rngs"],
+                    jnp.asarray(plan["chain_mask"].copy()),
+                )
+            cache = self.state_cache
+            with cache.dispatch_lock:
+                (
+                    sampled, logits, cache.s, cache.z, new_counts, lp,
+                    gstate_out,
+                ) = self._ragged_state_jit(
+                    self.params,
+                    jnp.asarray(plan["tokens"]),
+                    jnp.asarray(plan["tok_pos"]),
+                    jnp.asarray(plan["tok_row"]),
+                    jnp.asarray(plan["tok_valid"]),
+                    jnp.asarray(plan["row_last"]),
+                    cache.s,
+                    cache.z,
+                    jnp.asarray(plan["kv_lens"]),
+                    jnp.asarray(plan["row_starts"]),
+                    jnp.asarray(plan["row_lens"]),
+                    jnp.asarray(plan["row_reset"]),
+                    jnp.asarray(plan["decode_mask"].copy()),
+                    plan["sampling"],
+                    plan["rng"],
+                    plan["extras"],
+                    self._counts_dev if use_extras else None,
+                    self._pmask_dev if use_extras else None,
+                    gtables,
+                    plan["gstate"],
+                    want_lp=want_lp,
+                    chain=chain_arrays,
+                )
+            spec_g = spec_acc = None
+            # the state took every token of every row's span (a decode
+            # row's whole window: a row that stops inside it is freed at
+            # retire, its slot zeroed for the next owner)
+            for slot, (_s, n) in plan["spans"].items():
+                cache.advance(slot, n)
         else:
             chain_arrays = None
             if launch_steps > 1:
@@ -6502,7 +6807,7 @@ class LLMEngineCore:
         # — the [R, vocab] matrix never crosses the device boundary
         finish = [
             s for s in plan["finish_slots"]
-            if self.cache_mode != "paged" or s in plan["spans"]
+            if self.cache_mode == "dense" or s in plan["spans"]
         ]
         if finish:
             pad = 1 << (len(finish) - 1).bit_length()
@@ -6590,6 +6895,14 @@ class LLMEngineCore:
             for job, _take in plan["shares"]:
                 if job in self._prefill_jobs:  # identity compare
                     pool.truncate(job.slot, int(plan["pre_lens"][job.slot]))
+        elif self.state_cache is not None:
+            # a state cannot be rolled back to before the chunk: the
+            # surviving jobs start their prompts again (recompute; the
+            # launch that carries their first chunk zeroes the slot)
+            for job, _take in plan["shares"]:
+                if job in self._prefill_jobs:
+                    self.state_cache.rewind(job.slot)
+                    job.pos = 0
         await self._finish_recovery()
 
     def _retire_ragged(self, plan: dict, result: dict) -> None:
@@ -6771,6 +7084,10 @@ class LLMEngineCore:
         ]
         self.counters["ragged_steps"] += 1
         self.counters["ragged_decode_tokens"] += emitted_decode
+        self.counters["ragged_prefill_tokens"] += sum(
+            t for _, t in live_shares
+        )
+        self.counters["ragged_passes"] += int(plan["launch_steps"])
         self._step_rows["decode"] += len(plain_slots)
         self._step_rows["spec_verify"] += len(spec_slots)
         self._step_rows["prefill"] += len(live_shares)
@@ -6887,12 +7204,12 @@ class LLMEngineCore:
                     await asyncio.to_thread(self._wait_chunks, dropped)
                 except BaseException:
                     pass
-            if self.paged_cache is not None:
+            if self.paged_cache is not None or self.state_cache is not None:
                 # loop exit = no worker thread alive -> safe to reclaim every
                 # slot whose request was failed out without freeing its pages
                 for slot in range(self.max_batch):
                     if self._slot_req[slot] is None:
-                        self.paged_cache.pool.free(slot)
+                        self._release_cache_slot(slot)
             self._recovering = False
             if self._stopped and self._watchdog_task is not None:
                 # engine shut down for good: stop the supervisor too (a
@@ -7037,7 +7354,13 @@ class LLMEngineCore:
             # queue — the loop itself survives both and keeps serving
             step_epoch = self._recover_epoch
             try:
-                if self._prefill_jobs or self._ragged_spec_wanted(active_mask):
+                if (
+                    self._prefill_jobs
+                    or self._ragged_spec_wanted(active_mask)
+                    # the state cache has ONE step: decode-only phases run
+                    # it too (rows of one token and their chained windows)
+                    or self.state_cache is not None
+                ):
                     # ragged scheduling phase (docs/ragged_attention.md):
                     # drain the pipelined queue first (host mirrors must be
                     # current — same rule the legacy spec step used), then

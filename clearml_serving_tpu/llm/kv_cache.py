@@ -1147,3 +1147,103 @@ class PagedKVCache:
                 self.v_scale = self._write_token(
                     self.v_scale, jnp.asarray(v_scale), page, offset
                 )
+
+
+class StateCache:
+    """The second kind of sequence state (docs/state_cache.md): one SLOT of
+    fixed size per sequence, whatever its length, where ``PagedKVCache``
+    holds pages that grow. For models whose layers keep a recurrent state in
+    place of keys and values (``attention="power_retention"``:
+    ops/power_retention.py): per layer and slot ``S`` [Hkv, D, rows] and ``z``
+    [Hkv, zrows, D], float32, stacked as ``s`` [L, slots, ...] and ``z``
+    [L, slots, ...] and carried through the model's layer loop like the paged
+    K/V stacks.
+
+    The contract the engine keeps with it:
+
+    - ``allocate(slot)`` when a request is given the batch row ``slot`` (slot
+      index = batch row: admission is bounded by free rows, so the pool has
+      ``max_batch`` slots and never runs out under a row), ``free(slot)``
+      when the row's request ends, fails or is preempted;
+    - a slot is ZEROED when it changes hands, lazily: the first launch that
+      carries tokens of the new owner starts at position 0 (``length(slot)
+      == 0``) and names the slot in the launch plan's ``reset`` flags; the
+      kernels then count whatever the last owner left as zero. ``resets``
+      counts those launches;
+    - ``advance(slot, n)`` after a launch took ``n`` tokens of the row into
+      the state. A state cannot be rolled back: ``rewind(slot)`` forgets the
+      whole sequence (the next launch recomputes it from position 0), which
+      is also how a preempted request comes back.
+
+    ``dispatch_lock`` and the rebinding of ``s`` / ``z`` under it follow
+    ``PagedKVCache``: the step donates the pools and returns them."""
+
+    __guarded_by__ = {"dispatch_lock": ("s", "z")}
+
+    def __init__(self, init_state, n_slots: int):
+        self.n_slots = int(n_slots)
+        self.s, self.z = init_state(self.n_slots)
+        self.dispatch_lock = threading.Lock()
+        self._lengths = np.zeros(self.n_slots, np.int64)
+        self._in_use = np.zeros(self.n_slots, bool)
+        self.in_use_peak = 0
+        self.resets = 0
+        self.rewinds = 0
+
+    # -- slots ---------------------------------------------------------------
+
+    def allocate(self, slot: int) -> None:
+        if self._in_use[slot]:
+            raise RuntimeError("state slot {} is already owned".format(slot))
+        self._in_use[slot] = True
+        self._lengths[slot] = 0
+        self.in_use_peak = max(self.in_use_peak, int(self._in_use.sum()))
+
+    def free(self, slot: int) -> None:
+        """Idempotent: teardown paths free a row without knowing whether its
+        request ever reached a launch."""
+        self._in_use[slot] = False
+        self._lengths[slot] = 0
+
+    def owned(self, slot: int) -> bool:
+        return bool(self._in_use[slot])
+
+    def length(self, slot: int) -> int:
+        """Tokens of the slot's sequence that the state has taken in."""
+        return int(self._lengths[slot])
+
+    def advance(self, slot: int, tokens: int) -> None:
+        if self._lengths[slot] == 0 and tokens > 0:
+            self.resets += 1     # that launch named the slot in ``reset``
+        self._lengths[slot] += int(tokens)
+
+    def rewind(self, slot: int) -> None:
+        self._lengths[slot] = 0
+        self.rewinds += 1
+
+    # -- accounting ----------------------------------------------------------
+
+    @property
+    def in_use(self) -> int:
+        return int(self._in_use.sum())
+
+    @property
+    def bytes_per_slot(self) -> int:
+        return int((self.s.nbytes + self.z.nbytes) // self.n_slots)
+
+    def pool_bytes(self) -> int:
+        return int(self.s.nbytes + self.z.nbytes)
+
+    def snapshot(self) -> Dict[str, object]:
+        return {
+            "slots": self.n_slots,
+            "in_use": self.in_use,
+            "in_use_peak": int(self.in_use_peak),
+            "bytes_per_slot": self.bytes_per_slot,
+            "bytes": self.pool_bytes(),
+            "resets": int(self.resets),
+            "rewinds": int(self.rewinds),
+            "dtype": str(self.s.dtype),
+            "s_shape": list(self.s.shape),
+            "z_shape": list(self.z.shape),
+        }

@@ -63,6 +63,7 @@ WARMUP_COVERED = frozenset({
     "_spec_paged_jit",
     "_ragged_paged_jit",
     "_ragged_dense_jit",
+    "_ragged_state_jit",
     "_gather_finish_jit",
 })
 
@@ -306,6 +307,43 @@ def warm_ragged_variants(engine) -> int:
                         cache.v_scale = new_vs
                 jax.block_until_ready(sampled)
                 ran += 1
+    elif getattr(engine, "state_cache", None) is not None:
+        # state cache (docs/state_cache.md): the flat token axis is one
+        # static size and there are no spec rows, so the decode window is
+        # the only compile key. Null rows (row_lens 0, chain masks False)
+        # touch no slot: the donated pools come back value-unchanged
+        cache = engine.state_cache
+        tpad = engine._ragged_tpad
+        for steps in windows:
+            chain = None
+            if steps > 1:
+                chain = (
+                    jnp.stack([key() for _ in range(steps - 1)]),
+                    jnp.asarray(np.zeros((steps - 1, b), bool)),
+                )
+            with cache.dispatch_lock:
+                (
+                    sampled, _logits, cache.s, cache.z, _counts, _lp, _gs,
+                ) = engine._ragged_state_jit(
+                    engine.params,
+                    jnp.asarray(np.zeros(tpad, np.int32)),
+                    jnp.asarray(np.zeros(tpad, np.int32)),
+                    jnp.asarray(np.zeros(tpad, np.int32)),
+                    jnp.asarray(np.zeros(tpad, bool)),
+                    jnp.asarray(np.zeros(b, np.int32)),
+                    cache.s, cache.z,
+                    jnp.asarray(np.zeros(b, np.int32)),
+                    jnp.asarray(np.zeros(b, np.int32)),
+                    jnp.asarray(np.zeros(b, np.int32)),
+                    jnp.asarray(np.zeros(b, bool)),
+                    jnp.asarray(np.zeros(b, bool)),
+                    sampling, key(),
+                    None, None, None, None, None,
+                    want_lp=False,
+                    chain=chain,
+                )
+            jax.block_until_ready(sampled)
+            ran += 1
     else:
         # dense ragged: the rectangular chunk width C is its own compile
         # key (pow2 of the widest row — admission takes up to the budget),

@@ -329,6 +329,40 @@ def build(config: dict) -> SimpleNamespace:
     #   query position p iff p - W < t <= p (0 disables)
     attn_bias = bool(cfg.get("attn_bias", False))
     sliding_window = int(cfg.get("sliding_window", 0) or 0)
+    # - qk_norm: RMSNorm over head_dim of every query and key head, before
+    #   the rotary embedding (Qwen3 lineage)
+    # - attention="power_retention": no softmax attention at all; every layer
+    #   mixes tokens through power retention of degree 2 with one learned
+    #   forget gate per key-value head (ops/power_retention.py). Served from
+    #   the engine's state cache (engine.cache=state, docs/state_cache.md):
+    #   a sequence owns a fixed-size recurrent state, not K/V pages
+    qk_norm = bool(cfg.get("qk_norm", False))
+    attention = str(cfg.get("attention") or "softmax")
+    if attention not in ("softmax", "power_retention"):
+        raise ValueError(
+            "attention must be 'softmax' or 'power_retention' (got {!r})"
+            .format(attention)
+        )
+    retention = attention == "power_retention"
+    if retention and int(cfg.get("retention_degree", 2)) != 2:
+        raise ValueError(
+            "power retention is implemented for degree 2 only (got "
+            "retention_degree={!r})".format(cfg.get("retention_degree"))
+        )
+    # state_round="bfloat16": the recurrent state rounded to bfloat16 after
+    # every update. A measuring device, never a deployment: the precision
+    # one step below the float32 the configuration states, which the plain
+    # reference has to tell apart (benchmark probes.tolerance)
+    state_round = str(cfg.get("state_round") or "")
+    if state_round not in ("", "bfloat16"):
+        raise ValueError("state_round must be 'bfloat16' or unset")
+    if retention and (sliding_window or cfg.get("attn_logit_softcap")
+                      or cfg.get("kv_quant")):
+        raise ValueError(
+            "attention='power_retention' has no scores to window or softcap "
+            "and no K/V to quantise: drop sliding_window / "
+            "attn_logit_softcap / kv_quant"
+        )
 
     # multi-LoRA serving (models/lora.py): stacked [A+1, in, r]/[A+1, r, out]
     # factors per targeted projection, gathered per batch slot by lora_idx
@@ -375,6 +409,14 @@ def build(config: dict) -> SimpleNamespace:
             )
         if alt_window:
             out["attn_global"] = jnp.zeros((), jnp.float32)  # set by init()
+        if qk_norm:
+            out.update(q_norm=norm_init((head_dim,), dtype),
+                       k_norm=norm_init((head_dim,), dtype))
+        if retention:
+            # the one weight the layer adds to a GQA block: a forget gate
+            # per key-value head (its key folds in, so the other leaves of
+            # a seed are what a softmax model of these sizes gets)
+            out["wg"] = dense(jax.random.fold_in(key, 101), (dim, n_kv), dim)
         if attn_bias:
             out.update(
                 bq=jnp.zeros((n_heads * head_dim,), dtype),
@@ -586,7 +628,18 @@ def build(config: dict) -> SimpleNamespace:
         q = q.reshape(b, s, n_heads, head_dim)
         k = k.reshape(b, s, n_kv, head_dim)
         v = v.reshape(b, s, n_kv, head_dim)
+        if qk_norm:
+            q = _rms_norm(q, layer["q_norm"], eps, norm_offset)
+            k = _rms_norm(k, layer["k_norm"], eps, norm_offset)
         return _apply_rope(q, cos, sin), _apply_rope(k, cos, sin), v
+
+    @jax.named_scope("qkv")
+    def _log_gate(layer, x):
+        """log g of power retention, [..., n_kv] float32: log sigmoid of a
+        projection of the block's normed input, one gate per kv head."""
+        return jax.nn.log_sigmoid(
+            x.astype(jnp.float32) @ layer["wg"].astype(jnp.float32)
+        )
 
     @jax.named_scope("oproj")
     def _oproj(layer, attn, lora_idx=None):
@@ -608,6 +661,19 @@ def build(config: dict) -> SimpleNamespace:
         probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
         out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
         return out.reshape(b, s, n_heads * head_dim)
+
+    @jax.named_scope("retention")
+    def _retain_full(q, k, v, log_g):
+        """Power retention in its attention form over whole sequences (the
+        full causal ``apply``; no state). q [B,S,Hq,D]; k, v [B,S,Hkv,D];
+        log_g [B,S,Hkv]."""
+        from ..ops.power_retention import power_retention_attention
+
+        b, s = q.shape[:2]
+        y = power_retention_attention(
+            q.reshape(b, s, n_kv, group, head_dim), k, v, log_g
+        )
+        return y.reshape(b, s, n_heads * head_dim).astype(q.dtype)
 
     def _ffn_dense(layer, x, lora_idx=None):
         gate = _with_lora(layer, "w_gate", x, _mm(layer, "w_gate", x), lora_idx)
@@ -763,6 +829,8 @@ def build(config: dict) -> SimpleNamespace:
         def layer_body(x, layer):
             def attn(layer_, h):
                 q, k, v = _qkv(layer_, h, cos, sin, lora_idx)
+                if retention:
+                    return _retain_full(q, k, v, _log_gate(layer_, h))
                 return _attend(q, k, v, _layer_mask(layer_, masks))
 
             return _block(layer, x, attn, lora_idx)
@@ -1674,6 +1742,141 @@ def build(config: dict) -> SimpleNamespace:
             return (last, _logits(params, sel_x)), cache
         return last, cache
 
+    # -- power retention over the state cache (docs/state_cache.md) ----------
+    #
+    # The second implementer of the engine's cache contract: a sequence owns
+    # one SLOT of the stacked float32 pools S [L, slots, Hkv, D, rows] and
+    # z [L, slots, Hkv, zrows, D] (slot = batch row), whatever its length.
+    # The pools ride the layer loop's carry like the paged K/V stacks
+    # (_paged_layers) and both kernels update layer ``li`` of the stack in
+    # place (ops/power_retention.py). Everything around the token mixing —
+    # embedding, norms, QKV / O / FFN matmuls, logits — is the code the
+    # softmax models run (_block, _qkv, _ffn, _logits).
+
+    def init_state(slots: int):
+        from ..ops.power_retention import state_shapes
+
+        s_shape, z_shape = state_shapes(n_layers, slots, n_kv, head_dim)
+        return (jnp.zeros(s_shape, jnp.float32),
+                jnp.zeros(z_shape, jnp.float32))
+
+    def _retention_ops():
+        """(update, chunk): the Pallas kernels where they compile, their XLA
+        twins elsewhere: one pure decision over (head_dim, backend), which
+        the engine's health block evaluates with the same arguments."""
+        from ..ops import power_retention as pr
+
+        if pr.retention_kernel_unsupported_reason(head_dim) is None:
+            ops = pr.power_retention_update, pr.power_retention_chunk
+        else:
+            ops = pr.power_retention_update_xla, pr.power_retention_chunk_xla
+        if state_round:
+            ops = tuple(partial(f, round_state=True) for f in ops)
+        return ops
+
+    def decode_state(
+        params,
+        tokens,        # [B] int32
+        s_pool,        # [L, slots, Hkv, D, rows] float32
+        z_pool,        # [L, slots, Hkv, zrows, D] float32
+        positions,     # [B] int32 absolute position of this token
+        active,        # [B] bool rows that really advance
+    ):
+        """One decode step through the state pools: every ``active`` row's
+        slot takes its token (S <- g S + v phi(k)^T) and the row's query
+        heads read the new state. A row that is not active (an idle slot, a
+        row in prefill, the pad positions of a multi-step window that closed
+        early) leaves its slot bit for bit as it was: a recurrent state has
+        no length to hide a stray write behind. Returns (logits [B, vocab],
+        s_pool, z_pool)."""
+        update, _ = _retention_ops()
+        b = tokens.shape[0]
+        cos, sin = _rope(positions[:, None], head_dim, theta, rope_scaling)
+        x = _embed(params, tokens)[:, None]                        # [B, 1, dim]
+        no_reset = jnp.zeros((b,), bool)
+
+        def layer_fn(x, layer, li, pools):
+            stash = []
+
+            def attn_fn(layer_, h):
+                q, k, v = _qkv(layer_, h, cos, sin)                # q [B,1,H,D]
+                log_g = _log_gate(layer_, h)[:, 0]                 # [B, Hkv]
+                with jax.named_scope("retention"), \
+                        jax.named_scope("state_update"):
+                    y, s_new, z_new = update(
+                        q[:, 0].reshape(b, n_kv, group, head_dim),
+                        k[:, 0], v[:, 0], log_g, active, no_reset,
+                        pools[0], pools[1], layer=li,
+                    )
+                stash.append((s_new, z_new))
+                return y.reshape(b, 1, n_heads * head_dim).astype(x.dtype)
+
+            x = _block(layer, x, attn_fn, None)
+            return x, stash[0]
+
+        x, pools = _paged_layers(params, x, (s_pool, z_pool), layer_fn)
+        return (_logits(params, x)[:, 0],) + pools
+
+    def forward_ragged_state(
+        params,
+        tokens,        # [T] int32 flattened ragged chunk (token-major)
+        tok_pos,       # [T] int32 absolute position of each token in its row
+        tok_row,       # [T] int32 owning batch row per token (pads -> 0)
+        tok_valid,     # [T] bool real tokens of THIS pass
+        row_last,      # [R] int32 flat index of each row's last real token
+        s_pool,        # [L, slots, Hkv, D, rows] float32 (slot = row)
+        z_pool,        # [L, slots, Hkv, zrows, D] float32
+        row_starts,    # [R] int32 ragged row map (8-aligned starts)
+        row_lens,      # [R] int32 tokens of this pass per row (0 = idle)
+        row_reset,     # [R] bool the row's slot counts as zero before it
+    ):
+        """forward_ragged over the state cache: ONE pass over a ragged mixed
+        batch in which a decode row brings one token and a prefill row a
+        chunk of its prompt. Rows of one token go through the update kernel
+        (bandwidth-bound: the slot streams through once), longer rows
+        through the chunk kernel (the attention form inside the chunk, the
+        read-out of the state before it, one state update); idle rows and
+        pad positions touch no slot. A row whose chunk starts its sequence
+        says so in ``row_reset``: whatever the slot's last owner left counts
+        as zero. Returns (logits [R, vocab] at each row's last real token,
+        s_pool, z_pool)."""
+        del tok_row, tok_valid       # the row map says the same, per row
+        update, chunk = _retention_ops()
+        t = tokens.shape[0]
+        cos, sin = _rope(tok_pos[:, None], head_dim, theta, rope_scaling)
+        x = _embed(params, tokens)[:, None]                        # [T, 1, dim]
+        one, many = row_lens == 1, row_lens > 1
+        first = jnp.clip(row_starts, 0, t - 1)
+
+        def layer_fn(x, layer, li, pools):
+            stash = []
+
+            def attn_fn(layer_, h):
+                q, k, v = _qkv(layer_, h, cos, sin)                # [T,1,H,D]
+                log_g = _log_gate(layer_, h)[:, 0]                 # [T, Hkv]
+                qg = q[:, 0].reshape(t, n_kv, group, head_dim)
+                k0, v0 = k[:, 0], v[:, 0]
+                with jax.named_scope("retention"):
+                    y, s_new, z_new = chunk(
+                        qg, k0, v0, log_g, row_starts, row_lens, many,
+                        row_reset, pools[0], pools[1], layer=li,
+                    )
+                    with jax.named_scope("state_update"):
+                        y1, s_new, z_new = update(
+                            qg[first], k0[first], v0[first], log_g[first],
+                            one, row_reset, s_new, z_new, layer=li,
+                        )
+                    y = y.at[jnp.where(one, first, t)].set(y1, mode="drop")
+                stash.append((s_new, z_new))
+                return y.reshape(t, 1, n_heads * head_dim).astype(x.dtype)
+
+            x = _block(layer, x, attn_fn, None)
+            return x, stash[0]
+
+        x, pools = _paged_layers(params, x, (s_pool, z_pool), layer_fn)
+        last_x = x[:, 0][row_last][:, None]                    # [R, 1, dim]
+        return (_logits(params, last_x)[:, 0],) + pools
+
     def prepare_params(params):
         """Adapt a loaded param pytree to this build's layout: under
         scan_layers, a list/tuple of per-layer dicts (e.g. from a checkpoint
@@ -1738,7 +1941,8 @@ def build(config: dict) -> SimpleNamespace:
         prefill_ring=(
             None
             if (
-                sliding_window
+                retention
+                or sliding_window
                 or attn_softcap
                 or abs(query_scale - head_dim ** -0.5) > 1e-12
             )
@@ -1752,11 +1956,18 @@ def build(config: dict) -> SimpleNamespace:
         # engine's token-budget scheduler drives one of these per iteration
         forward_ragged=forward_ragged,
         forward_ragged_dense=forward_ragged_dense,
+        # power retention (attention="power_retention"): served from the
+        # engine's state cache only (engine.cache=state)
+        attention=attention,
+        init_state=init_state if retention else None,
+        decode_state=decode_state if retention else None,
+        forward_ragged_state=forward_ragged_state if retention else None,
         # pipeline-parallel prefill: gated to configs whose forward the
         # pipeline stage body reproduces exactly (see prefill_pipeline doc)
         prefill_pipeline=(
             prefill_pipeline
-            if (scan_layers and not kv_quant and not n_experts)
+            if (scan_layers and not kv_quant and not n_experts
+                and not retention)
             else None
         ),
         prepare_params=prepare_params,

@@ -478,6 +478,21 @@ class EngineLifecycleCollector(_KeyedCollector):
             p + "_kv_pool_dtype",
             "info gauge (always 1): storage dtype of the paged KV pools",
         )
+        # state cache (docs/state_cache.md): one fixed-size slot per
+        # sequence of a model that keeps a recurrent state; admission is
+        # bounded by free slots, so occupancy is the capacity signal
+        state_pool_slots = GaugeMetricFamily(
+            p + "_state_pool_slots",
+            "slots of the state cache, by state (total, in_use, in_use_peak)",
+        )
+        state_pool_bytes = GaugeMetricFamily(
+            p + "_state_pool_bytes",
+            "device HBM held by the state cache's pools, by kind (pool, slot)",
+        )
+        state_pool_resets = CounterMetricFamily(
+            p + "_state_pool_resets_total",
+            "launches that zeroed a state slot for a new owner",
+        )
         # host-RAM KV tier (docs/kv_tiering.md): where the prefix cache's
         # pages live (hbm vs host) and how many moved each way — the
         # capacity-planning signal the tier exists for
@@ -614,6 +629,7 @@ class EngineLifecycleCollector(_KeyedCollector):
         any_pipeline = False
         any_requests = False
         any_kv_pool = False
+        any_state_pool = False
         any_kv_tier = False
         any_kv_ship = False
         any_kv_wire = False
@@ -631,6 +647,20 @@ class EngineLifecycleCollector(_KeyedCollector):
                         gauge(kv_pool_bytes, key, s, kv_pool[kind], kind=kind)
                 if kv_pool.get("dtype"):
                     gauge(kv_pool_dtype, key, s, 1, dtype=kv_pool["dtype"])
+            state_pool = s.get("state_pool") or {}
+            if state_pool:
+                any_state_pool = True
+                gauge(state_pool_slots, key, s, state_pool["slots"],
+                      state="total")
+                gauge(state_pool_slots, key, s, state_pool["in_use"],
+                      state="in_use")
+                gauge(state_pool_slots, key, s, state_pool["in_use_peak"],
+                      state="in_use_peak")
+                gauge(state_pool_bytes, key, s, state_pool["bytes"],
+                      kind="pool")
+                gauge(state_pool_bytes, key, s, state_pool["bytes_per_slot"],
+                      kind="slot")
+                counter(state_pool_resets, key, s, state_pool["resets"])
             kv_tier = s.get("kv_tier") or {}
             if kv_tier:
                 any_kv_tier = True
@@ -821,6 +851,10 @@ class EngineLifecycleCollector(_KeyedCollector):
         if any_kv_pool:
             yield kv_pool_bytes
             yield kv_pool_dtype
+        if any_state_pool:
+            yield state_pool_slots
+            yield state_pool_bytes
+            yield state_pool_resets
         if any_kv_tier:
             yield kv_tier_pages
             yield kv_tier_bytes

@@ -124,3 +124,84 @@ def test_the_v5e_step_updates_the_stacked_pools_in_place(
     stack_shapes = set(re.findall(
         r"\w+\[{},{}\](\{{[\d,]+)".format(LAYERS, layer), hlo))
     assert stack_shapes == {"{4,3,2,1,0"}, stack_shapes
+
+
+# ------------------------------------------------- the state cache's step
+
+def test_the_v5e_state_step_updates_the_state_pools_in_place(
+        one_chip, monkeypatch):
+    """ISSUE 26: the state pools ride the layer scan's carry like the K/V
+    stacks. One ragged pass and two chained decode passes over donated
+    pools of a deployment's slot size, compiled for the described chip with
+    both retention kernels routed in: no operation but the buffers' own way
+    through the loops may have a pool's shape, and both pools keep the
+    kernels' row-major layout (z's rows are padded to whole tiles for that:
+    with 65 the compiler picks another layout and copies the stack)."""
+    from clearml_serving_tpu.ops import power_retention as pr
+
+    monkeypatch.setattr(pr, "retention_kernel_unsupported_reason",
+                        lambda *a, **k: None)
+    slots, tokens = 16, 48
+    cfg = dict(vocab_size=512, dim=512, n_layers=LAYERS, n_heads=4,
+               n_kv_heads=HKV, head_dim=D, ffn_dim=512, scan_layers=True,
+               dtype="bfloat16", attention="power_retention", qk_norm=True)
+    bundle = models.build_model("llama", cfg)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return on_chip(shape, jnp.int32)
+
+    params = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: bundle.init(jax.random.PRNGKey(0))))
+    s_shape, z_shape = pr.state_shapes(LAYERS, slots, HKV, D)
+
+    def step(params, s_pool, z_pool, tok, valid, row_last, per_row, reset,
+             chain_mask):
+        logits, s_pool, z_pool = bundle.forward_ragged_state(
+            params, tok, tok, tok, valid, row_last, s_pool, z_pool, per_row,
+            per_row, reset)
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+
+        def body(carry, mask):
+            nxt, s_pool, z_pool, i = carry
+            logits, s_pool, z_pool = bundle.decode_state(
+                params, nxt, s_pool, z_pool, per_row + i, mask)
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (nxt, s_pool, z_pool, i + 1), nxt
+
+        (_, s_pool, z_pool, _), toks = jax.lax.scan(
+            body, (nxt, s_pool, z_pool, jnp.int32(0)), chain_mask)
+        return toks, s_pool, z_pool
+
+    lowered = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, on_chip(s_shape, jnp.float32), on_chip(z_shape, jnp.float32),
+        i32(tokens), on_chip((tokens,), jnp.bool_), i32(slots), i32(slots),
+        on_chip((slots,), jnp.bool_), on_chip((2, slots), jnp.bool_))
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        hlo = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for name in ("power_retention_update", "power_retention_chunk"):
+        assert name in hlo, name
+    pools = {"f32[{}]".format(",".join(map(str, s))) for s in (s_shape, z_shape)}
+    shape = re.compile(r"^\s*(?:ROOT )?%?(\S+) = \(?(\w+\[[\d,]+\])\S* ([\w\-]+)\(")
+    movers, layouts = [], set()
+    for line in hlo.splitlines():
+        m = shape.match(line)
+        if not m or m.group(2) not in pools:
+            continue
+        # z, under a hundredth of the bytes, is small enough here to be
+        # prefetched into the chip's fast memory whole (copy-start / -done
+        # to memory space 1), which says nothing of a deployment's: only S
+        # is held to "no operation of its shape"
+        big = m.group(2) == "f32[{}]".format(",".join(map(str, s_shape)))
+        if big and m.group(3) not in ("parameter", "get-tuple-element", "bitcast"):
+            movers.append((m.group(1), m.group(3)))
+        layouts.update(re.findall(re.escape(m.group(2)) + r"(\{[\d,]+)", line))
+    assert not movers, movers
+    assert layouts == {"{4,3,2,1,0"}, layouts
