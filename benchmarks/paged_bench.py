@@ -3,9 +3,9 @@
 Two questions, two scenario families:
 
 1. ``uniform`` (r1-r3 continuity): b=16 hkv=8 g=4 d=128, 16-token pages,
-   sequences uniformly half-full (512 of 1024).  Answers "does the r2
-   multi-page double-buffered-DMA kernel beat the plain-XLA page gather"
-   (r3 on v5e: yes, 2.391 vs 2.744 ms).
+   sequences uniformly half-full (512 of 1024).  Answers "does the
+   double-buffered-DMA kernel beat the plain-XLA page gather"
+   (r3 on v5e, the kernel of that round: yes, 2.391 vs 2.744 ms).
 
 2. ``ragged`` (VERDICT r3 #3): b=32/64 with a realistic serving length
    mix (128..4096 cycling) at 4096-token capacity.  This is where paging
@@ -19,7 +19,8 @@ Two questions, two scenario families:
    paged pool does.
 
 Contenders per scenario:
-- pallas[pb=N]   ops.paged_attention (r2 kernel), pages_per_block sweep
+- pallas         ops.paged_attention (its block of pages follows from the
+                 shapes: ops.paged_attention.decode_pages_per_block)
 - xla_gather     ops.paged_attention_xla (the fallback the kernel must beat)
 - dense          attention over a dense [B, Hkv, cap, D] cache, the
                  no-paging baseline
@@ -92,20 +93,15 @@ def _scenario(name, batch, seq_cap, lengths_list, platform, pa):
     results["xla_gather"] = _time(xla, q, k_pool, v_pool, page_table, lengths_dev)
 
     if platform == "tpu":
-        for pb in (4, 8, 16, 32):
-            fn = jax.jit(
-                lambda q, k, v, pt, ln, pb=pb: pa.paged_attention(
-                    q, k, v, pt, ln, pages_per_block=pb
-                )
+        # the kernel derives its block of pages from the shapes
+        try:
+            results["pallas"] = _time(
+                jax.jit(pa.paged_attention), q, k_pool, v_pool, page_table,
+                lengths_dev,
             )
-            try:
-                results["pallas_pb{}".format(pb)] = _time(
-                    fn, q, k_pool, v_pool, page_table, lengths_dev
-                )
-            except Exception as ex:  # record, keep sweeping
-                print(json.dumps({"scenario": name,
-                                  "contender": "pallas_pb{}".format(pb),
-                                  "error": str(ex)[:200]}))
+        except Exception as ex:  # record, keep going
+            print(json.dumps({"scenario": name, "contender": "pallas",
+                              "error": str(ex)[:200]}))
 
     # dense baseline: full-capacity cache, masked softmax (what the dense
     # cache_mode engine does) — pays capacity-proportional bandwidth

@@ -360,6 +360,19 @@ class _InFlightChunk:
     # paged backend: slots dropped from this chunk because the pool could
     # not hold their page extension (failed by the loop thread on landing)
     exhausted: List[int] = field(default_factory=list)
+    # paged backend: (rows x passes, tokens those rows attended) of the
+    # chunk's decode passes, counted by the loop thread on landing
+    chain_work: tuple = (0, 0)
+
+
+def _decode_pass_work(first, passes) -> tuple:
+    """(rows x passes, tokens attended) of rows that ride ``passes[r]``
+    consecutive decode passes over paged KV, attending ``first[r]`` tokens
+    in the first of them and one more in each next: what the paged decode
+    kernel is given (``ragged.decode_chain_rows`` / ``_kv_tokens``)."""
+    passes = np.asarray(passes, np.int64)
+    tokens = passes * first + passes * (passes - 1) // 2
+    return int(passes.sum()), int(tokens.sum())
 
 
 # ragged scheduler (docs/ragged_attention.md): stage-3 brownout shrinks the
@@ -1415,6 +1428,11 @@ class LLMEngineCore:
             # decode steps): what a roofline of the step needs to count
             "ragged_prefill_tokens": 0,
             "ragged_passes": 0,
+            # rows x decode passes over paged KV (the chained passes of a
+            # ragged launch, the passes of a decode chunk) and the tokens
+            # those rows attended there: the paged decode kernel's work
+            "decode_chain_rows": 0,
+            "decode_chain_kv_tokens": 0,
             # rows the engine.spec.tree chaos seam demoted from spec-verify
             # back to plain decode (docs/spec_decode_trees.md fallback row)
             "spec_tree_fallbacks": 0,
@@ -2305,11 +2323,10 @@ class LLMEngineCore:
                 (tokens, k_pools, v_pools, k_scales, v_scales, counts,
                  step, gstate) = carry
                 step_rng, wp, wo = xs
-                scale_kw = (
-                    {"k_scales": k_scales, "v_scales": v_scales}
-                    if paged_quant
-                    else {}
-                )
+                # an empty slot attends nothing
+                scale_kw = {"active": active}
+                if paged_quant:
+                    scale_kw.update(k_scales=k_scales, v_scales=v_scales)
                 if lora_idx is None:
                     out = bundle.decode_paged(
                         params, tokens, k_pools, v_pools, page_table,
@@ -2632,11 +2649,11 @@ class LLMEngineCore:
                             (tok_c, k_p, v_p, k_s, v_s, counts_c,
                              gstate_c, step) = carry
                             s_rng, m, wp, wo = xs
-                            skw = (
-                                {"k_scales": k_s, "v_scales": v_s}
-                                if paged_quant
-                                else {}
-                            )
+                            # a row whose window has closed, a prefill row
+                            # and an empty slot attend nothing in this pass
+                            skw = {"active": m}
+                            if paged_quant:
+                                skw.update(k_scales=k_s, v_scales=v_s)
                             if lora_idx is None:
                                 o = bundle.decode_paged(
                                     params, tok_c, k_p, v_p, page_table,
@@ -4538,6 +4555,10 @@ class LLMEngineCore:
                     "decode_tokens": self.counters["ragged_decode_tokens"],
                     "prefill_tokens": self.counters["ragged_prefill_tokens"],
                     "passes": self.counters["ragged_passes"],
+                    "decode_chain_rows": self.counters["decode_chain_rows"],
+                    "decode_chain_kv_tokens": (
+                        self.counters["decode_chain_kv_tokens"]
+                    ),
                     "tokens_per_launch": self._hist_launch_tokens.snapshot(),
                     "spec_acceptance": self._hist_spec_accept.snapshot(),
                     # draft-tree verify rows (docs/spec_decode_trees.md):
@@ -7088,6 +7109,12 @@ class LLMEngineCore:
             t for _, t in live_shares
         )
         self.counters["ragged_passes"] += int(plan["launch_steps"])
+        if self.cache_mode == "paged":
+            # a row of window n rides chained passes 1..n-1 and attends
+            # pre_len + 1 + step tokens in pass ``step``
+            self._count_decode_passes(_decode_pass_work(
+                plan["pre_lens"] + 2, np.maximum(plan["row_steps"] - 1, 0)
+            ))
         self._step_rows["decode"] += len(plain_slots)
         self._step_rows["spec_verify"] += len(spec_slots)
         self._step_rows["prefill"] += len(live_shares)
@@ -7553,6 +7580,7 @@ class LLMEngineCore:
             await self._finish_recovery()
             return
         self._inflight.append(entry)
+        self._count_decode_passes(entry.chain_work)
         for slot in entry.exhausted:
             self._fail_slot(
                 slot, MemoryError("kv page pool exhausted for this sequence")
@@ -7632,8 +7660,11 @@ class LLMEngineCore:
         gtables = prep["gtables"]
         want_lp = prep["want_lp"]
         exhausted: List[int] = []
+        chain_work = (0, 0)
         if self.cache_mode == "paged":
-            chunk, lp, gstate_out = self._dispatch_paged(prep, exhausted)
+            chunk, lp, gstate_out, chain_work = self._dispatch_paged(
+                prep, exhausted
+            )
         else:
             chunk, self.cache, new_counts, lp, gstate_out = (
                 self._decode_chunk_jit(
@@ -7670,6 +7701,7 @@ class LLMEngineCore:
             want_lp=want_lp,
             dispatched_at=t0,
             exhausted=exhausted,
+            chain_work=chain_work,
         )
 
     def _dispatch_paged(self, prep: dict, exhausted: List[int]):
@@ -7684,6 +7716,10 @@ class LLMEngineCore:
         pool = self.paged_cache.pool
         n = self.decode_steps
         lengths0 = pool.lengths().copy()          # pre-extension lengths
+        # pass ``s`` of the chunk attends lengths0 + s + 1 tokens in every
+        # row that holds any (the device's ``active``)
+        held = lengths0[lengths0 > 0]
+        chain_work = _decode_pass_work(held + 1, np.full(held.shape, n))
         write_pages = np.zeros((self.max_batch, n), np.int32)   # null page 0
         write_offsets = np.zeros((self.max_batch, n), np.int32)
         for slot in np.nonzero(active_mask)[0]:
@@ -7747,7 +7783,12 @@ class LLMEngineCore:
                 self.paged_cache.v_scale = new_v_scale
         if use_extras:
             self._counts_dev = new_counts
-        return chunk, lp, gstate_out
+        return chunk, lp, gstate_out, chain_work
+
+    def _count_decode_passes(self, work: tuple) -> None:
+        """Loop thread: add a launch's :func:`_decode_pass_work`."""
+        self.counters["decode_chain_rows"] += work[0]
+        self.counters["decode_chain_kv_tokens"] += work[1]
 
     def _chain_input(self, dev, host_vec):
         """Next chunk's [B] input vector: chained from the previous chunk's

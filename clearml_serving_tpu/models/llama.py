@@ -1436,6 +1436,7 @@ def build(config: dict) -> SimpleNamespace:
         *,
         k_scales=None,  # [L, Hkv, N, P] f32 scale pools (kv_quant only)
         v_scales=None,
+        active=None,    # [B] bool: rows this step advances (None = all)
     ):
         """One decode step over paged KV: writes the new token's K/V into the
         stacked pools (at (layer, page, offset)), then attends via
@@ -1444,7 +1445,12 @@ def build(config: dict) -> SimpleNamespace:
         plus the updated scale pools when ``kv_quant`` is on: the new
         token's K/V quantize through the dense path's _kv_store and the
         per-(token, head) scales scatter beside the int8 pages; dequant
-        happens inside the attention kernel."""
+        happens inside the attention kernel.
+
+        A row that ``active`` masks out attends NOTHING (length 0: the
+        kernel spends no DMA and no flash block on it, its attention output
+        is zeros) and its logits mean nothing; the caller discards its
+        token and points its write at the null page."""
         from ..ops.paged_attention import (
             paged_attention,
             paged_attention_xla,
@@ -1470,6 +1476,9 @@ def build(config: dict) -> SimpleNamespace:
         # the Pallas kernel scales scores by head_dim**-0.5 internally; a
         # family query_scale override folds into q before the kernel
         q_prescale = query_scale * (head_dim ** 0.5)
+        attend_lens = lengths + 1
+        if active is not None:
+            attend_lens = jnp.where(active, attend_lens, 0)
 
         def layer_fn(x, layer, li, pools):
             stash = []
@@ -1485,7 +1494,7 @@ def build(config: dict) -> SimpleNamespace:
                     q_grouped = q_grouped * jnp.asarray(q_prescale, q_grouped.dtype)
                 with jax.named_scope("attn"):
                     attn = attend(
-                        q_grouped, new[0], new[1], page_table, lengths + 1,
+                        q_grouped, new[0], new[1], page_table, attend_lens,
                         layer=li, **dict(zip(("k_scale", "v_scale"), new[2:]))
                     )                                              # [B,Hkv,G,D]
                 return attn.reshape(b, 1, n_heads * head_dim).astype(x.dtype)

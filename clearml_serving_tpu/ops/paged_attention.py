@@ -28,21 +28,46 @@ of the donated stack in place. Nothing slices a layer's pool out of the
 stack, which on the chip is a copy of the whole layer (PERF.md, PR 25). A
 4-D pool is the one-layer stack: same kernel, ``layer`` 0.
 
-Pallas design (decode, r2 rewrite): grid (B, Hkv); the kernel owns the whole
-sequence. K/V pools stay in HBM (memory_space=ANY); the kernel issues manual
-double-buffered async copies of ``pages_per_block`` pages at a time into VMEM
-scratch — block i+1's DMAs fly while block i's flash update runs on the MXU.
-Three wins over the r1 BlockSpec-pipeline version (one page per grid step):
+Pallas design (decode; the work plan of ISSUE 28): the grid walks the ROWS,
+in order, and a grid step owns one row with ALL its kv heads (``q`` / ``out``
+blocks ``[1, Hkv, G, D]``; scores and the PV product are batched over the
+heads). K/V pools stay in HBM (memory_space=ANY); the kernel issues manual
+double-buffered async copies of one block of pages at a time into VMEM
+scratch ``[2 slots, Hkv, PB*P, D]`` a side — block i+1's DMAs fly while block
+i's flash update runs on the MXU. What the plan is made of:
 
-- **No dead traffic**: pages past a sequence's length are never copied. The
-  r1 grid iterated all pages_per_seq steps, and the BlockSpec pipeline DMA'd
-  every page before ``@pl.when`` skipped its compute — HBM traffic scaled
-  with max capacity, not actual tokens, forfeiting paged attention's point.
-- **MXU-sized blocks**: flash updates see [G, pages_per_block*P] score tiles
-  (512 wide at the measured-best pb=32 default) instead of [G, 16] slivers.
+- **No dead traffic**: pages past a sequence's length are never copied, and
+  a row of length 0 (an empty slot, a prefill row, a row whose decode window
+  has closed: models/llama.py ``decode_paged(..., active=)``) costs no DMA
+  and no flash block; its output is zeros.
+- **A page is one descriptor a side for all kv heads**
+  (``k_hbm.at[layer, :, page]`` -> ``k_buf.at[slot, :, j*P:(j+1)*P]``, a
+  strided ``[Hkv, P, D]`` copy): Hkv times fewer descriptors, starts and
+  waits than a copy per head. All of a block's copies of one side signal
+  one semaphore and are waited for page by page (ONE wait for a whole full
+  block, which a byte-counting DMA semaphore allows, measured the same: 98.3
+  against 98.5 us, so the second path is not kept). The page loops are
+  ``fori_loop``s over the pages that exist, not unrolled ``pl.when``s.
+- **Only the first live row of a call starts cold**: the page buffers, their
+  semaphores and a block counter (SMEM) persist across grid steps, and while
+  a row's last block is computed the NEXT LIVE row's first block is already
+  in flight into the other slot.
+- **The block follows from the shapes** (:func:`decode_pages_per_block`): as
+  many pages as fit 4 MB of scratch, at most 512 tokens; no caller and no
+  environment variable sets it.
 - **bf16 operand feed**: K/V stream into the dot products in pool dtype
-  (bf16) with f32 accumulation (preferred_element_type) — half the DMA bytes
-  of the r1 kernel's eager f32 casts.
+  (bf16) with f32 accumulation (preferred_element_type); per (row, head) the
+  arithmetic is the block-by-block flash update it always was (same blocks,
+  same order, f32 running max / sum / accumulator).
+
+Kernel speed on the chip (TPU v5 lite, PERF.md PR 28; 32 rows, Hkv 8, G 4,
+D 128, pages of 16, bf16, the 32-layer stack, shuffled page tables; a call
+is one layer): 11 live rows of 0.9-1.7k tokens and 21 dead ones 98 us = 77%
+of the HBM peak (the plan before: 656 us with the dead rows handed in at
+length 1, 463 us at length 0); 32 live rows of 450-900 tokens 147 us = 74%
+(before: 762 us); 8 rows of ~3k 147 us = 84%. With the flash update taken
+out the same calls take 92 / 134 / 143 us, with the DMAs taken out 35 / 58 /
+47: the kernel is bound by its page DMAs, not by the MXU's M = G rows.
 
 Mosaic portability notes baked into the kernels (each one is a refusal of
 the v5e compiler, libtpu 0.0.34):
@@ -56,8 +81,6 @@ the v5e compiler, libtpu 0.0.34):
   — the draft-tree mask ORs per-query terms built from i32 compares only;
 - a 2-D scalar-prefetch table pads its minor dim to 128 SMEM lanes, so the
   ``[T, DMAX]`` ancestor table rides flat (1-D).
-
-Kernel speed on the chip: not measured (the benchmark PR owns that).
 
 int8 paged KV (r4, docs/paged_kv_quant.md): pools may store int8 with a
 per-(token, head) f32 scale pool ``[Hkv, N, P]`` beside each side —
@@ -78,9 +101,11 @@ lanes), and Mosaic requires DMA slices tile-aligned — the same constraint
 that gates D % 128 would reject every scale-row copy. Instead the tiny
 scale vectors (4 bytes per token-head vs 128+ data bytes) are pre-gathered
 by XLA into a lane-aligned [B, Hkv, 1, PP*P] operand that the grid
-pipeline DMAs into VMEM like any blocked input. The gather reads scale
-rows at table capacity rather than live length; that dead traffic is
-bounded by scale_bytes/kv_bytes = 4/D of the int8 stream (~3% at D=128).
+pipeline DMAs into VMEM like any blocked input (the decode kernel: a row's
+scales of all heads a grid step; the ragged kernel: per q block and head).
+The gather reads scale rows at table capacity rather than live length; that
+dead traffic is bounded by scale_bytes/kv_bytes = 4/D of the int8 stream
+(~3% at D=128).
 
 Alignment gates for the int8 path: D % 128 == 0 (unchanged) and
 page_size % 32 == 0 on hardware — the int8 tile is (32, 128), so a 16-row
@@ -155,7 +180,8 @@ SMEM_BYTES = 1 << 20
 def paged_kernel_smem_bytes(
     rows: int, pages_per_seq: int, tokens: int = 0, tree_width: int = 0
 ) -> int:
-    """SMEM the kernels' scalar-prefetch operands take: the decode kernel
+    """SMEM the kernels' scalar-prefetch operands (and the decode kernel's
+    one scalar of scratch) take: the decode kernel
     for ``rows`` sequences (``tokens == 0``), or the ragged kernel for a
     ``tokens``-wide launch (+ a ``[tokens, tree_width]`` ancestor table).
     The 2-D page table pads to (8, 128) int32 tiles — measured against the
@@ -171,7 +197,8 @@ def paged_kernel_smem_bytes(
 
     table = (-(-rows // 8) * 8) * (-(-pages_per_seq // 128) * 128) * 4
     if not tokens:
-        return 2048 + table + vec(rows) + vec(1)       # + lengths, layer
+        # + lengths, layer, and the walk's block counter (SMEM scratch)
+        return 2048 + table + vec(rows) + 2 * vec(1)
     nb = -(-tokens // _RAGGED_QB)
     return (
         2048 + table + 2 * vec(rows) + vec(1)          # kv_lens, row_lens, layer
@@ -247,23 +274,42 @@ def paged_attention_xla(q, k_pool, v_pool, page_table, lengths,
 
 # ----------------------------------------------------------------- pallas
 
+# VMEM the decode kernel's page buffers may take (two slots of K and of V,
+# all kv heads), and the longest block of context one flash update sees. The
+# block of a call follows from its shapes (:func:`decode_pages_per_block`).
+_DECODE_SCRATCH_BYTES = 4 << 20
+_DECODE_BLOCK_TOKENS = 512
+
+
+def decode_pages_per_block(hkv, head_dim, page_size, pages_per_seq, kv_dtype):
+    """Pages the decode kernel fetches and flash-processes as one block, all
+    kv heads together: as many as fit :data:`_DECODE_SCRATCH_BYTES` double
+    buffered on both sides, at most :data:`_DECODE_BLOCK_TOKENS` tokens and
+    a row's table (32 pages at Hkv 8, D 128, bf16 pages of 16)."""
+    per_token = 4 * hkv * head_dim * jnp.dtype(kv_dtype).itemsize
+    tokens = min(_DECODE_SCRATCH_BYTES // per_token, _DECODE_BLOCK_TOKENS)
+    return max(1, min(tokens // page_size, pages_per_seq))
+
+
 def _paged_attention_kernel(
     # scalar prefetch
     page_table_ref,    # [B, PP] int32 (SMEM)
-    lengths_ref,       # [B] int32 (SMEM)
+    lengths_ref,       # [B] int32 (SMEM); 0 = the row asks for nothing
     layer_ref,         # [1] int32 (SMEM): the layer of the stack to attend
     # then, positionally (in_specs order):
-    #   q_ref            [1, 1, G, D] VMEM
+    #   q_ref            [1, Hkv, G, D] VMEM: one row, all its kv heads
     #   k_hbm            [L, Hkv, N, P, D] ANY (stays in HBM)
     #   v_hbm            [L, Hkv, N, P, D] ANY
-    #   k_scale_ref      [1, 1, 1, PP*P] f32 VMEM   (quantized=True only:
-    #   v_scale_ref      [1, 1, 1, PP*P] f32 VMEM    pre-gathered per-token
-    #                    scales in sequence order — module docstring)
-    #   out_ref          [1, 1, G, D] VMEM
-    # scratch:
-    #   k_buf            [2, PB*P, D] VMEM (double-buffered page blocks)
-    #   v_buf            [2, PB*P, D] VMEM
-    #   sems             [2, PB, 2] DMA semaphores (slot, page-in-block, k/v)
+    #   k_scale_ref      [1, Hkv, 1, cap_pad] f32 VMEM  (quantized=True only:
+    #   v_scale_ref      [1, Hkv, 1, cap_pad] f32 VMEM   the row's pre-gathered
+    #                    per-token scales in sequence order — module docstring)
+    #   out_ref          [1, Hkv, G, D] VMEM
+    # scratch, all of it kept from one grid step to the next:
+    #   k_buf            [2, Hkv, PB*P, D] VMEM (double-buffered page blocks)
+    #   v_buf            [2, Hkv, PB*P, D] VMEM
+    #   sems             [2, 2] DMA semaphores (slot, k/v): one a block side,
+    #                    signalled by each of its page copies
+    #   walk             [1] int32 SMEM: blocks consumed so far in this call
     *refs,
     page_size: int,
     pages_per_block: int,
@@ -271,91 +317,117 @@ def _paged_attention_kernel(
 ):
     if quantized:
         (q_ref, k_hbm, v_hbm, k_scale_ref, v_scale_ref,
-         out_ref, k_buf, v_buf, sems) = refs
+         out_ref, k_buf, v_buf, sems, walk) = refs
     else:
-        q_ref, k_hbm, v_hbm, out_ref, k_buf, v_buf, sems = refs
+        q_ref, k_hbm, v_hbm, out_ref, k_buf, v_buf, sems, walk = refs
         k_scale_ref = v_scale_ref = None
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    g, d = q_ref.shape[2], q_ref.shape[3]
+    rows = pl.num_programs(0)
+    hkv, g, d = q_ref.shape[1:]
     p = page_size
     pb = pages_per_block
-    length = lengths_ref[b]
-    layer = layer_ref[0]
     block_tokens = pb * p
+    layer = layer_ref[0]
+    length = lengths_ref[b]
     # blocks that contain live tokens; DMA never touches pages past length
     n_blocks = (length + block_tokens - 1) // block_tokens
 
-    def _copies(block_idx, slot, j):
-        page_idx = block_idx * pb + j
-        page = page_table_ref[b, page_idx]
-        dst = pl.ds(j * p, p)
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[layer, h, page], k_buf.at[slot, dst],
-                sems.at[slot, j, 0]
+    def next_live(row):
+        """First row after ``row`` that attends anything (``rows`` if none)."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < rows, lengths_ref[jnp.minimum(r, rows - 1)] == 0
             ),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, h, page], v_buf.at[slot, dst],
-                sems.at[slot, j, 1]
-            ),
+            lambda r: r + 1, row + 1,
         )
 
-    def start_block(block_idx, slot):
-        for j in range(pb):  # static unroll; ragged tail gated per page
-            @pl.when((block_idx * pb + j) * p < length)
-            def _start(j=j):
-                ck, cv = _copies(block_idx, slot, j)
-                ck.start()
-                cv.start()
+    def block_pages(row, block):
+        left = lengths_ref[row] - block * block_tokens
+        return jnp.minimum((left + p - 1) // p, pb)
 
-    def wait_block(block_idx, slot):
-        for j in range(pb):
-            @pl.when((block_idx * pb + j) * p < length)
-            def _wait(j=j):
-                ck, cv = _copies(block_idx, slot, j)
-                ck.wait()
-                cv.wait()
+    def page_copies(row, block, slot, j):
+        """A page's K and V planes of ALL kv heads: one strided descriptor a
+        side, onto the slot's semaphore of that side."""
+        page = page_table_ref[row, block * pb + j]
+        dst = pl.ds(pl.multiple_of(j * p, p), p)
+        return tuple(
+            pltpu.make_async_copy(
+                hbm.at[layer, pl.ds(0, hkv), page],
+                buf.at[slot, pl.ds(0, hkv), dst],
+                sems.at[slot, side],
+            )
+            for side, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))
+        )
+
+    def block_copies(act, row, block, slot):
+        """Start, or wait for, the copies of the pages a block has."""
+        def page(j, carry):
+            for copy in page_copies(row, block, slot, j):
+                getattr(copy, act)()
+            return carry
+
+        jax.lax.fori_loop(0, block_pages(row, block), page, 0)
+
+    start_block = functools.partial(block_copies, "start")
+    wait_block = functools.partial(block_copies, "wait")
+
+    @pl.when(b == 0)
+    def _first():
+        # the only cold start of a call: the first live row's first block
+        walk[0] = 0
+        first = next_live(-1)
+
+        @pl.when(first < rows)
+        def _():
+            start_block(first, 0, 0)
 
     @pl.when(n_blocks > 0)
     def _run():
-        start_block(0, 0)
+        # this row's block 0 is in flight already, in the slot after the
+        # last block any row consumed; while its last block is computed the
+        # next live row's block 0 flies into the other slot
+        done = walk[0]
+        after = next_live(b)
 
         def body(i, carry):
             m_prev, l_prev, acc_prev = carry
-            slot = jax.lax.rem(i, 2)
+            slot = jax.lax.rem(done + i, 2)
 
             @pl.when(i + 1 < n_blocks)
             def _prefetch():
-                start_block(i + 1, jax.lax.rem(i + 1, 2))
+                start_block(b, i + 1, 1 - slot)
 
-            wait_block(i, slot)
-            # K/V feed the MXU in pool dtype (bf16) with f32 accumulation.
-            # int8 pools (quantized): the block feeds the dot as raw int8
-            # cast to the output compute dtype — int8 -> bf16 is lossless —
-            # and the per-token scales fold into the f32 scores/probs, so
-            # dequant fuses into the flash update without materializing a
-            # dequantized tile (module docstring).
-            q = q_ref[0, 0]                                     # [G, D]
-            k = k_buf[slot]                                     # [PB*P, D]
+            @pl.when(jnp.logical_and(i + 1 == n_blocks, after < rows))
+            def _prefetch_next_row():
+                start_block(after, 0, 1 - slot)
+
+            wait_block(b, i, slot)
+            # K/V feed the MXU in pool dtype (bf16) with f32 accumulation,
+            # batched over the kv heads. int8 pools (quantized): the block
+            # feeds the dot as raw int8 cast to the output compute dtype —
+            # int8 -> bf16 is lossless — and the per-token scales fold into
+            # the f32 scores/probs, so dequant fuses into the flash update
+            # without materializing a dequantized tile (module docstring).
+            q = q_ref[0]                                        # [Hkv, G, D]
+            k = k_buf[slot]                                     # [Hkv, PB*P, D]
             v = v_buf[slot]
             if quantized:
                 op_dtype = out_ref.dtype
                 k = k.astype(op_dtype)
                 v = v.astype(op_dtype)
             scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q, k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            ) * (d ** -0.5)                                     # [G, PB*P]
+            ) * (d ** -0.5)                                     # [Hkv, G, PB*P]
             if quantized:
                 # scale rows of pages past length come from the gathered
                 # null-page padding: finite garbage, masked right below
-                k_s = k_scale_ref[0, 0, :, pl.ds(i * block_tokens,
-                                                 block_tokens)]  # [1, PB*P]
+                k_s = k_scale_ref[0, :, :, pl.ds(i * block_tokens,
+                                                 block_tokens)]  # [Hkv, 1, PB*P]
                 scores = scores * k_s
             token_ids = (
                 i * block_tokens
-                + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
             )
             valid = token_ids < length
             scores = jnp.where(valid, scores, -jnp.inf)
@@ -363,50 +435,54 @@ def _paged_attention_kernel(
             # arbitrary (NaN/inf poisons 0*v), so zero them before the matmul.
             # (int8 garbage is always finite, but the zeroing also keeps the
             # masked rows from polluting the scaled-probs matmul below.)
-            # Mask built as a 2-D i32 iota compare: Mosaic cannot insert a
+            # Mask built as an i32 iota compare: Mosaic cannot insert a
             # minor dim on an i1 vector (bool[:, None] fails to compile).
             row_ids = i * block_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, (block_tokens, 1), 0
+                jnp.int32, (1, block_tokens, 1), 1
             )
             v = jnp.where(row_ids < length, v, jnp.zeros_like(v))
 
-            block_max = jnp.maximum(jnp.max(scores, axis=1), -1e30)
-            m_new = jnp.maximum(m_prev, block_max)              # [G]
-            probs = jnp.exp(scores - m_new[:, None])            # [G, PB*P]
+            block_max = jnp.maximum(
+                jnp.max(scores, axis=2, keepdims=True), -1e30
+            )
+            m_new = jnp.maximum(m_prev, block_max)              # [Hkv, G, 1]
+            probs = jnp.exp(scores - m_new)                     # [Hkv, G, PB*P]
             probs = jnp.where(valid, probs, 0.0)
-            correction = jnp.exp(m_prev - m_new)                # [G]
+            correction = jnp.exp(m_prev - m_new)                # [Hkv, G, 1]
             # the softmax denominator sums the UNSCALED probs; v_scale
             # belongs only to the PV product
-            l_new = l_prev * correction + jnp.sum(probs, axis=1)
+            l_new = l_prev * correction + jnp.sum(
+                probs, axis=2, keepdims=True
+            )
             pv = probs
             if quantized:
                 # V dequant folded into the probs (per value row); probs are
                 # zero past length, so garbage scales multiply into zeros
-                v_s = v_scale_ref[0, 0, :, pl.ds(i * block_tokens,
-                                                 block_tokens)]  # [1, PB*P]
+                v_s = v_scale_ref[0, :, :, pl.ds(i * block_tokens,
+                                                 block_tokens)]  # [Hkv, 1, PB*P]
                 pv = probs * v_s
-            acc_new = acc_prev * correction[:, None] + jax.lax.dot_general(
-                pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            acc_new = acc_prev * correction + jax.lax.dot_general(
+                pv.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            )
+            )                                                   # [Hkv, G, D]
             return m_new, l_new, acc_new
 
-        m0 = jnp.full((g,), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((g,), jnp.float32)
-        acc0 = jnp.zeros((g, d), jnp.float32)
+        m0 = jnp.full((hkv, g, 1), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((hkv, g, 1), jnp.float32)
+        acc0 = jnp.zeros((hkv, g, d), jnp.float32)
         _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+        walk[0] = done + n_blocks
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = (acc / safe_l[:, None]).astype(out_ref.dtype)
+        out_ref[0] = (acc / safe_l).astype(out_ref.dtype)
 
     @pl.when(n_blocks == 0)
     def _empty():
-        out_ref[0, 0] = jnp.zeros((g, d), out_ref.dtype)
+        out_ref[0] = jnp.zeros((hkv, g, d), out_ref.dtype)
 
 
 def paged_attention(
     q, k_pool, v_pool, page_table, lengths, *,
-    k_scale=None, v_scale=None, layer=None,
-    pages_per_block: int = 32, interpret: bool = False,
+    k_scale=None, v_scale=None, layer=None, interpret: bool = False,
 ):
     """Pallas paged decode attention — compiled by Mosaic, or interpreted
     under ``interpret=True``. Never the XLA reference: operands the
@@ -415,9 +491,8 @@ def paged_attention(
     :func:`paged_attention_xla` instead is the caller's decision.
 
     Shapes as in :func:`paged_attention_xla` (head-major pools; the stack
-    of all layers with ``layer``, which the page DMAs index in place).
-    ``pages_per_block``: pages flash-processed per MXU block (DMA'd together,
-    double-buffered against the previous block's compute).
+    of all layers with ``layer``, which the page DMAs index in place). A
+    row of length 0 costs no DMA and no flash block, and reads zeros.
     ``k_scale``/``v_scale`` ([Hkv, N, P] f32, stacked like the pools):
     per-(token, head) dequant scales for int8 pools (required when the
     pools are int8); dequant fuses into the in-kernel flash update (module
@@ -429,7 +504,7 @@ def paged_attention(
     b, hkv, g, d = q.shape
     page_size = k_pool.shape[-2]
     pages_per_seq = page_table.shape[1]
-    pb = max(1, min(pages_per_block, pages_per_seq))
+    pb = decode_pages_per_block(hkv, d, page_size, pages_per_seq, k_pool.dtype)
     cap = pages_per_seq * page_size
 
     kernel = functools.partial(
@@ -438,8 +513,9 @@ def paged_attention(
         pages_per_block=pb,
         quantized=quantized,
     )
+    row_spec = pl.BlockSpec((1, hkv, g, d), lambda b, *_: (b, 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b, h, *_: (b, h, 0, 0)),
+        row_spec,
         pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
     ]
@@ -464,28 +540,34 @@ def paged_attention(
             ).reshape(b, hkv, 1, cap)
             return jnp.pad(seq, pad)
 
-        in_specs += [
-            pl.BlockSpec((1, 1, 1, cap_pad), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, cap_pad), lambda b, h, *_: (b, h, 0, 0)),
-        ]
+        scale_spec = pl.BlockSpec(
+            (1, hkv, 1, cap_pad), lambda b, *_: (b, 0, 0, 0)
+        )
+        in_specs += [scale_spec, scale_spec]
         scales = [gather(k_scale), gather(v_scale)]
     layer, k_pool, v_pool = _stacked(layer, k_pool, v_pool)
     inputs = [q, k_pool, v_pool] + scales
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # page_table, lengths, layer
-        grid=(b, hkv),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b, h, *_: (b, h, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, pb * page_size, d), k_pool.dtype),
-            pltpu.VMEM((2, pb * page_size, d), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, pb, 2)),
+            pltpu.VMEM((2, hkv, pb * page_size, d), k_pool.dtype),
+            pltpu.VMEM((2, hkv, pb * page_size, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        # the rows are walked in order: the page buffers, their semaphores
+        # and the walk's counter carry from one row to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
         name="paged_attention_decode",  # the kernel's name in a trace
     )(page_table, lengths, layer, *inputs)
@@ -693,9 +775,11 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, write_page, write_offset, *,
 # static query block. The flattened layout is Q-BLOCK ALIGNED — every row's
 # segment starts at a QB boundary (ragged_layout below builds it), so each
 # q block belongs to exactly ONE row and the host passes that mapping as two
-# scalar-prefetch vectors (block_rows / block_q0). Each grid step re-uses the
-# decode kernel's manual double-buffered page-DMA plan against its row's
-# pages — including the int8 path's pre-gathered per-row scale operands,
+# scalar-prefetch vectors (block_rows / block_q0). Each grid step runs a
+# manual double-buffered page-DMA plan per (q block, kv head) against its
+# row's pages (a [P, D] plane a descriptor: the decode kernel's plan until
+# ISSUE 28, whose row walk is not carried over here) — including the int8
+# path's pre-gathered per-row scale operands,
 # which pipeline per BLOCK via an index map that reads block_rows — and runs
 # the flash update on a [QB*G, pages_per_block*P] score tile. Pages past the
 # block's causal bound are never copied: a prefill chunk's early q blocks

@@ -178,6 +178,147 @@ def test_kernel_layout_serves_and_matches_the_xla_path(parts, monkeypatch):
     assert engine.counters["ragged_steps"] > 0
 
 
+def test_decode_passes_hand_the_kernel_nothing_for_rows_that_do_not_decode(
+    parts, monkeypatch
+):
+    """Every decode pass over paged KV (the chained passes of a ragged
+    launch, the passes of a decode chunk) gives the kernel length 0 for an
+    empty slot, a prefill row and a row whose window has closed, and the
+    tokens it attends for the others; the streams are the XLA path's, and
+    ``ragged.decode_chain_rows`` / ``decode_chain_kv_tokens`` are the sums
+    of what the kernel was handed."""
+    bundle, params = parts
+    kw = dict(
+        max_batch=4, max_seq_len=96, cache_mode="paged", page_size=16,
+        scheduler="ragged", step_token_budget=24, ragged_decode_steps=4,
+        decode_steps=4, eos_token_id=None,
+    )
+    # the first request is decoding (and goes on for 40 tokens) when the
+    # other two arrive: their prompts ride ragged launches whose leftover
+    # budget gives the decode rows windows of 4, i.e. chained passes with
+    # prefill rows in them; three requests never fill the fourth slot
+    prompts = [[5, 6, 7, 8, 9], [7, 8, 9], list(range(3, 3 + 30))]
+    answers = [40, 3, 6]
+
+    def serve(engine):
+        async def run():
+            first = asyncio.Event()
+
+            async def collect(prompt, n):
+                out = []
+                async for tok in engine.generate(
+                    GenRequest(prompt_ids=list(prompt), max_new_tokens=n)
+                ):
+                    out.append(tok)
+                    first.set()
+                return out
+
+            try:
+                head = asyncio.ensure_future(collect(prompts[0], answers[0]))
+                await first.wait()
+                return await asyncio.gather(head, *[
+                    collect(p, n) for p, n in zip(prompts[1:], answers[1:])
+                ])
+            finally:
+                engine.stop()
+
+        return asyncio.run(run())
+
+    want = serve(LLMEngineCore(bundle, params, **kw))
+
+    handed = []
+
+    def spy(q, k_pool, v_pool, page_table, lengths, **kwargs):
+        jax.debug.callback(lambda l: handed.append(tuple(int(x) for x in l)),
+                           lengths)
+        return kernel(q, k_pool, v_pool, page_table, lengths, **kwargs)
+
+    kernel = functools.partial(pa.paged_attention, interpret=True)
+    monkeypatch.setattr(
+        pa, "paged_kernel_unsupported_reason", lambda *a, **k: None
+    )
+    monkeypatch.setattr(pa, "paged_attention", spy)
+    monkeypatch.setattr(
+        pa, "ragged_paged_attention",
+        functools.partial(pa.ragged_paged_attention, interpret=True),
+    )
+    monkeypatch.setattr(
+        pa, "paged_kv_write",
+        functools.partial(pa.paged_kv_write, interpret=True),
+    )
+    engine = LLMEngineCore(bundle, params, **kw)
+    chained, chunked = [], []     # one vector of lengths per decode pass
+    prefill_rows_masked = []
+    retire, dispatch = engine._retire_ragged, engine._dispatch_paged
+
+    def retire_ragged(plan, result):
+        for i in range(1, int(plan["launch_steps"])):
+            alive = plan["chain_mask"][i - 1]
+            chained.append(tuple(int(x) for x in np.where(
+                alive, plan["pre_lens"] + 1 + i, 0)))
+            prefill_rows_masked.extend(
+                not alive[job.slot] for job, _ in plan["shares"])
+        return retire(plan, result)
+
+    def dispatch_paged(prep, exhausted):
+        held = engine.paged_cache.pool.lengths().copy()
+        for s in range(engine.decode_steps):
+            chunked.append(tuple(
+                int(x) for x in np.where(held > 0, held + s + 1, 0)))
+        return dispatch(prep, exhausted)
+
+    monkeypatch.setattr(engine, "_retire_ragged", retire_ragged)
+    monkeypatch.setattr(engine, "_dispatch_paged", dispatch_paged)
+    got = serve(engine)
+    jax.effects_barrier()
+    assert got == want
+    expected = chained + chunked
+    assert sorted(handed) == sorted(expected * len(params["layers"]))
+    # both kinds of launch ran; a prefill row is dead in every chained pass
+    # it rides, and some slot is empty in every pass
+    assert chained and chunked
+    assert prefill_rows_masked and all(prefill_rows_masked)
+    assert all(0 in v and any(v) for v in expected)
+    stats = engine.lifecycle_stats()["ragged"]
+    assert stats["decode_chain_rows"] == sum(
+        sum(1 for x in v if x) for v in expected)
+    assert stats["decode_chain_kv_tokens"] == sum(map(sum, expected))
+
+
+def test_decode_paged_masks_the_rows_a_pass_does_not_advance(parts, monkeypatch):
+    """``decode_paged(..., active=)``: the attention is handed length 0 for
+    exactly the masked rows (an empty slot, a prefill row, a closed window:
+    the engine's ``chain_mask`` row) and ``lengths + 1`` for the others,
+    whose logits are bit for bit those of the unmasked step."""
+    bundle, params = parts
+    rows, page, pages = 4, 16, 2
+    shape = (bundle.n_layers, bundle.n_kv_heads, 1 + rows * pages, page,
+             bundle.head_dim)
+    k = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+    v = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+    table = jnp.arange(1, 1 + rows * pages, dtype=jnp.int32).reshape(rows, pages)
+    lengths = jnp.asarray([20, 7, 0, 31], jnp.int32)
+    active = jnp.asarray([True, False, False, True])
+    tokens = jnp.asarray([3, 4, 5, 6], jnp.int32)
+    coords = jnp.zeros(rows, jnp.int32)               # the null page
+    handed, reference = [], pa.paged_attention_xla
+
+    def spy(q, k_pool, v_pool, page_table, lens, **kwargs):
+        handed.append(np.asarray(lens))
+        return reference(q, k_pool, v_pool, page_table, lens, **kwargs)
+
+    monkeypatch.setattr(pa, "paged_attention_xla", spy)
+    masked = bundle.decode_paged(params, tokens, k, v, table, lengths,
+                                 coords, coords, active=active)[0]
+    assert all((h == [21, 0, 0, 32]).all() for h in handed) and handed
+    del handed[:]
+    plain = bundle.decode_paged(params, tokens, k, v, table, lengths,
+                                coords, coords)[0]
+    assert all((h == [21, 8, 1, 32]).all() for h in handed) and handed
+    alive = np.asarray(active)
+    assert np.asarray(masked)[alive].tobytes() == np.asarray(plain)[alive].tobytes()
+
+
 # -- limits the compiler enforces are load-time errors --------------------------
 
 

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from clearml_serving_tpu.llm.kv_cache import PagePool, PagedKVCache
+from clearml_serving_tpu.ops import paged_attention as pa
 from clearml_serving_tpu.ops.paged_attention import paged_attention, paged_attention_xla
 from clearml_serving_tpu.ops.quant import (
     dequant_llama_params,
@@ -41,6 +42,18 @@ def _random_paged_setup(rng, b=3, hkv=2, g=4, d=64, page_size=8, pages_per_seq=4
     page_table = jnp.asarray(ids.reshape(b, pages_per_seq))
     lengths = jnp.asarray([page_size * pages_per_seq, 13, 1], jnp.int32)
     return q, k_pool, v_pool, page_table, lengths
+
+
+@pytest.fixture
+def block_pages(monkeypatch):
+    """Pages the decode kernel takes as one block: the kernel derives them
+    from its shapes (``decode_pages_per_block``), under a ceiling of
+    ``_DECODE_BLOCK_TOKENS`` that small test shapes never reach; lowering
+    the ceiling is how a test gets several blocks out of a short row."""
+    def set_pages(pages, page_size=8):
+        monkeypatch.setattr(pa, "_DECODE_BLOCK_TOKENS", pages * page_size)
+
+    return set_pages
 
 
 def test_paged_attention_xla_matches_dense():
@@ -91,7 +104,8 @@ def test_paged_attention_int8_xla_matches_dequantized_dense():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-def test_paged_attention_int8_pallas_interpret_matches_xla():
+@pytest.mark.parametrize("pb", [1, 2, 32])
+def test_paged_attention_int8_pallas_interpret_matches_xla(pb, block_pages):
     """Tentpole parity gate (tier-1): the Pallas int8 kernel — in-kernel
     dequant fused into the flash update — must match the XLA int8 gather
     reference to (better than) bf16 epsilon in interpret mode, including
@@ -101,17 +115,16 @@ def test_paged_attention_int8_pallas_interpret_matches_xla():
     v8, vs = _quantize_pool(v_pool)
     lengths = jnp.asarray([int(lengths[0]), 13, 0], jnp.int32)
     ref = paged_attention_xla(q, k8, v8, page_table, lengths, ks, vs)
-    for pb in (1, 2, 32):
-        out = paged_attention(
-            q, k8, v8, page_table, lengths, k_scale=ks, v_scale=vs,
-            pages_per_block=pb, interpret=True,
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
+    block_pages(pb)
+    out = paged_attention(
+        q, k8, v8, page_table, lengths, k_scale=ks, v_scale=vs, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+    )
 
 
-def test_paged_attention_int8_partial_last_block_scales():
+def test_paged_attention_int8_partial_last_block_scales(block_pages):
     """pages_per_seq NOT a multiple of pages_per_block, with live tokens in
     the final partial block: the kernel's fixed-width scale-window slices
     must not clamp into earlier rows (the gathered scales pad up to a
@@ -125,9 +138,9 @@ def test_paged_attention_int8_partial_last_block_scales():
     # pb=4 (block = 32 tokens): tokens 33..47 live in the partial block
     lengths = jnp.asarray([47, 35, 48], jnp.int32)
     ref = paged_attention_xla(q, k8, v8, page_table, lengths, ks, vs)
+    block_pages(4)
     out = paged_attention(
-        q, k8, v8, page_table, lengths, k_scale=ks, v_scale=vs,
-        pages_per_block=4, interpret=True,
+        q, k8, v8, page_table, lengths, k_scale=ks, v_scale=vs, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -381,20 +394,22 @@ def test_quantized_llama_forward_close():
     assert drift < 0.25, drift
 
 
-def test_paged_attention_block_sizes_and_bf16():
-    """The r2 multi-page kernel must be exact for any pages_per_block split
-    (incl. non-dividing tails) and for bf16 pools."""
+@pytest.mark.parametrize("pb", [1, 2, 3, 4, 8])
+def test_paged_attention_block_sizes(pb, block_pages):
+    """The kernel must be exact for any split of a row into blocks of
+    pages (incl. non-dividing tails)."""
     q, k_pool, v_pool, page_table, lengths = _random_paged_setup(jax.random.PRNGKey(3))
     ref = paged_attention_xla(q, k_pool, v_pool, page_table, lengths)
-    for pb in (1, 2, 3, 4, 8):
-        out = paged_attention(
-            q, k_pool, v_pool, page_table, lengths,
-            pages_per_block=pb, interpret=True,
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
-            err_msg="pages_per_block={}".format(pb),
-        )
+    block_pages(pb)
+    out = paged_attention(q, k_pool, v_pool, page_table, lengths, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+    )
+
+
+def test_paged_attention_block_sizes_and_bf16():
+    """... and for bf16 pools."""
+    q, k_pool, v_pool, page_table, lengths = _random_paged_setup(jax.random.PRNGKey(3))
     qb = q.astype(jnp.bfloat16)
     kb = k_pool.astype(jnp.bfloat16)
     vb = v_pool.astype(jnp.bfloat16)
@@ -422,7 +437,7 @@ def _stack_with(layer, pool, layers=3):
 
 @pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("kind", ["bf16", "int8"])
-def test_paged_attention_reads_its_layer_of_the_stack(kind, layer):
+def test_paged_attention_reads_its_layer_of_the_stack(kind, layer, block_pages):
     """Decode kernel (interpret) and XLA reference on the stack of L = 3 with
     ``layer`` equal, bit for bit, the same entry point on ``pool[layer]`` —
     under jit with a TRACED layer, as the layer scan calls them — and agree
@@ -441,7 +456,8 @@ def test_paged_attention_reads_its_layer_of_the_stack(kind, layer):
     k_stack, v_stack = _stack_with(layer, k_pool), _stack_with(layer, v_pool)
     assert k_stack.shape == (3,) + k_pool.shape
 
-    kernel = functools.partial(paged_attention, pages_per_block=2, interpret=True)
+    block_pages(2, page_size=16)
+    kernel = functools.partial(paged_attention, interpret=True)
     for fn in (kernel, paged_attention_xla):
         want = fn(q, k_pool, v_pool, page_table, lengths, **scales)
         got = jax.jit(
@@ -464,3 +480,110 @@ def test_paged_attention_stack_and_layer_go_together():
     with pytest.raises(ValueError, match="layer indexes a stacked"):
         paged_attention(q, k_pool, v_pool, page_table, lengths, layer=0,
                         interpret=True)
+
+
+# -- the decode kernel's work plan (ISSUE 28): a grid step owns a row with all
+# its kv heads, a row of length 0 does nothing, and the next live row's first
+# block is fetched while this row's last is computed ---------------------------
+
+
+def _plan_setup(lengths, hkv=2, g=4, d=32, page_size=8, pages_per_seq=10, seed=11):
+    b = len(lengths)
+    q, k_pool, v_pool, page_table, _ = _random_paged_setup(
+        jax.random.PRNGKey(seed), b=b, hkv=hkv, g=g, d=d, page_size=page_size,
+        pages_per_seq=pages_per_seq,
+    )
+    return q, k_pool, v_pool, page_table, jnp.asarray(lengths, jnp.int32)
+
+
+def _assert_kernel_is_the_reference(q, k_pool, v_pool, page_table, lengths, **scales):
+    ref = paged_attention_xla(q, k_pool, v_pool, page_table, lengths, **scales)
+    out = paged_attention(q, k_pool, v_pool, page_table, lengths,
+                          interpret=True, **scales)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    dead = np.asarray(lengths) == 0
+    assert not np.asarray(out)[dead].any()          # a dead row reads zeros
+
+
+# blocks of 2 pages of 8: a row of 80 tokens is five blocks
+@pytest.mark.parametrize("lengths", [
+    [0, 0, 37, 80], [37, 0, 0, 80], [37, 80, 0, 0], [0, 37, 0, 80, 0],
+    [0, 0, 0, 0], [0, 0, 23, 0], [23], [0],
+], ids=["dead_first", "dead_between", "dead_last", "dead_around", "all_dead",
+        "one_live", "one_row", "one_dead_row"])
+def test_decode_kernel_live_and_dead_rows_in_every_order(lengths, block_pages):
+    block_pages(2)
+    _assert_kernel_is_the_reference(*_plan_setup(lengths))
+
+
+@pytest.mark.parametrize("length", [1, 8, 9, 16, 17, 32, 33, 79, 80])
+def test_decode_kernel_lengths_at_page_and_block_edges(length, block_pages):
+    """1, a page's multiple (8), a block's multiple (16, 32, the row's whole
+    table 80) and one past each; a neighbour on either side."""
+    block_pages(2)
+    _assert_kernel_is_the_reference(*_plan_setup([5, length, 80]))
+
+
+@pytest.mark.parametrize("lengths", [
+    [80, 3], [3, 80], [80, 0, 3, 0, 80], [16, 16, 16], [17, 1, 33, 1],
+], ids=["long_short", "short_long", "long_dead_short_dead_long", "one_block_each",
+        "tails"])
+def test_decode_kernel_prefetches_across_rows(lengths, block_pages):
+    """The next live row's first block goes into the slot this row's last
+    block does not hold, whatever the parity of the blocks walked so far."""
+    block_pages(2)
+    _assert_kernel_is_the_reference(*_plan_setup(lengths))
+
+
+@pytest.mark.parametrize("g", [1, 4, 8])
+@pytest.mark.parametrize("hkv", [1, 2, 8])
+def test_decode_kernel_heads_and_groups(hkv, g, block_pages):
+    block_pages(2)
+    _assert_kernel_is_the_reference(
+        *_plan_setup([21, 0, 80, 16], hkv=hkv, g=g, seed=hkv * 10 + g))
+
+
+def test_decode_kernel_at_its_own_block_size():
+    """Nothing patched: 70 pages of 8 at Hkv 2 are two blocks of 64 and 6."""
+    args = _plan_setup([560, 0, 513, 512, 1], pages_per_seq=70, d=16)
+    assert pa.decode_pages_per_block(2, 16, 8, 70, jnp.float32) == 64
+    _assert_kernel_is_the_reference(*args)
+
+
+@pytest.mark.parametrize("lengths", [[79, 0, 33, 96], [96, 64, 65, 1]])
+def test_decode_kernel_int8_pools_at_page_32_with_a_partial_last_block(lengths, block_pages):
+    """int8 pools on the 32-token pages the chip wants, blocks of 2 pages, 3
+    pages a row: the last block is one page, and the pre-gathered scales pad
+    up to a block's multiple."""
+    q, k_pool, v_pool, page_table, lengths = _plan_setup(
+        lengths, page_size=32, pages_per_seq=3)
+    (k8, ks), (v8, vs) = _quantize_pool(k_pool), _quantize_pool(v_pool)
+    block_pages(2, page_size=32)
+    _assert_kernel_is_the_reference(
+        q, k8, v8, page_table, lengths, k_scale=ks, v_scale=vs)
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_decode_kernel_dead_rows_on_a_layer_of_the_stack(layer, block_pages):
+    q, k_pool, v_pool, page_table, lengths = _plan_setup([0, 37, 0, 80])
+    block_pages(2)
+    want = paged_attention(q, k_pool, v_pool, page_table, lengths, interpret=True)
+    got = jax.jit(lambda li: paged_attention(
+        q, _stack_with(layer, k_pool), _stack_with(layer, v_pool), page_table,
+        lengths, layer=li, interpret=True))(jnp.int32(layer))
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+@pytest.mark.parametrize("hkv, d, page, pages_per_seq, dtype, want", [
+    (8, 128, 16, 256, jnp.bfloat16, 32),     # the benchmark's cells: 4 MB
+    (8, 128, 32, 128, jnp.int8, 16),         # int8: the token ceiling binds
+    (32, 128, 16, 256, jnp.bfloat16, 8),     # no GQA: a quarter of the tokens
+    (1, 128, 16, 256, jnp.bfloat16, 32),
+    (8, 128, 16, 4, jnp.bfloat16, 4),        # a short table is one block
+    (64, 256, 16, 256, jnp.float32, 1),      # never under one page
+])
+def test_decode_block_follows_from_the_shapes(hkv, d, page, pages_per_seq, dtype, want):
+    pb = pa.decode_pages_per_block(hkv, d, page, pages_per_seq, dtype)
+    assert pb == want
+    if pb > 1:
+        assert 4 * hkv * pb * page * d * jnp.dtype(dtype).itemsize <= 4 << 20

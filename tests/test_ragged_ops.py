@@ -243,6 +243,9 @@ def test_paged_kernel_smem_accounting():
     # decode: s32[256, 896] fit, s32[250, 1024] did not
     assert paged_kernel_smem_bytes(256, 896) <= SMEM_BYTES
     assert paged_kernel_smem_bytes(250, 1024) > SMEM_BYTES
+    # table, lengths, layer, the walk's block counter (ISSUE 28), 2 KB spare
+    assert paged_kernel_smem_bytes(256, 896) == (
+        256 * 896 * 4 + 1024 + 512 + 512 + 2048)
     # ragged + tree: T=8192 R=200 PP=768 compiled at width 12, not at 13
     assert paged_kernel_smem_bytes(200, 768, 8192, 12) <= SMEM_BYTES
     assert paged_kernel_smem_bytes(200, 768, 8192, 13) > SMEM_BYTES

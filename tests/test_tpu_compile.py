@@ -76,9 +76,11 @@ def _compiled_step(one_chip, monkeypatch, quant):
 
         def body(carry, coords):
             nxt, pools, i = carry
+            # rows masked out of a chained pass, as the engine masks them
             out = bundle.decode_paged(
                 params, nxt, pools[0], pools[1], table, per_row + i,
-                coords, coords, **dict(zip(names, pools[2:])))
+                coords, coords, active=coords > 0,
+                **dict(zip(names, pools[2:])))
             nxt = jnp.argmax(out[0], -1).astype(jnp.int32)
             return (nxt, tuple(out[1:]), i + 1), nxt
 
@@ -124,6 +126,57 @@ def test_the_v5e_step_updates_the_stacked_pools_in_place(
     stack_shapes = set(re.findall(
         r"\w+\[{},{}\](\{{[\d,]+)".format(LAYERS, layer), hlo))
     assert stack_shapes == {"{4,3,2,1,0"}, stack_shapes
+
+
+# ------------------------------------------- the decode kernel's work plan
+
+@pytest.mark.parametrize("kind, page", [("bf16", 16), ("int8", 32)])
+def test_the_decode_kernel_compiles_at_the_cells_shapes(one_chip, kind, page):
+    """ISSUE 28: the decode kernel at the benchmark's shapes (32 rows, Hkv 8,
+    G 4, D 128, 4096 tokens a row, a Mistral-7B deployment's pool of 28000
+    tokens over 32 layers) lowers for the described v5e, as one custom call
+    under its trace name; its scratch is what the shapes say: two slots of a
+    32-page block of all 8 heads a side (4 MB), one DMA semaphore per slot and
+    side, one scalar for the walk."""
+    rows, hkv, g, layers, tokens = 32, 8, 4, 32, 28000
+    pages_per_seq, pages = 4096 // page, tokens // page
+    dtype = jnp.int8 if kind == "int8" else jnp.bfloat16
+
+    def on_chip(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = on_chip((layers, hkv, pages, page, D), dtype)
+    scales = {}
+    if kind == "int8":
+        scale = on_chip((layers, hkv, pages, page), jnp.float32)
+        scales = {"k_scale": scale, "v_scale": scale}
+    operands = (on_chip((rows, hkv, g, D), jnp.bfloat16), pool, pool,
+                on_chip((rows, pages_per_seq), jnp.int32),
+                on_chip((rows,), jnp.int32), on_chip((), jnp.int32))
+
+    def attend(q, k, v, table, lengths, layer, **scales):
+        return pa.paged_attention(q, k, v, table, lengths, layer=layer, **scales)
+
+    block = pa.decode_pages_per_block(hkv, D, page, pages_per_seq, dtype)
+    assert block * page == 512
+    call, = [e for e in jax.make_jaxpr(attend)(*operands, **scales).eqns
+             if e.primitive.name == "pallas_call"]
+    n_scratch = call.params["grid_mapping"].num_scratch_operands
+    scratch = [(v.aval.shape, str(v.aval.dtype))
+               for v in call.params["jaxpr"].invars[-n_scratch:]]
+    buf = ((2, hkv, 512, D), jnp.dtype(dtype).name)
+    assert scratch[:2] == [buf, buf]
+    assert [shape for shape, _ in scratch[2:]] == [(2, 2), (1,)]
+    assert call.params["grid_mapping"].grid == (rows,)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        hlo = jax.jit(attend).lower(*operands, **scales).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert hlo.count("tpu_custom_call") == 1
+    assert "paged_attention_decode" in hlo
 
 
 # ------------------------------------------------- the state cache's step
