@@ -377,427 +377,6 @@ def run_pipeline_ab(
     }
 
 
-def run_ragged_ab(
-    cfg: dict,
-    *,
-    batch: int = 4,
-    decode_steps: int = 2,
-    new_tokens: int = 48,
-    decode_prompt_len: int = 12,
-    admit_prompt_len: int = 160,
-    step_token_budget: int = 32,
-    chunk: int = 8,
-    max_seq_len: int = 512,
-    cache_mode: str = "paged",
-    page_size: int = 16,
-    repeats: int = 3,
-) -> dict:
-    """Ragged-vs-two-dispatch A/B on the REAL engine
-    (docs/ragged_attention.md): ``batch-1`` short-prompt requests decode
-    continuously; once every stream is flowing, ONE long-prompt request is
-    admitted. The legacy arm runs the historical two-dispatch scheduler
-    (chunked prefill paced by the prefill gate); the ragged arm runs the
-    token-budget scheduler, whose mixed launches carry the admission as
-    chunk rows BESIDE the decode rows.
-
-    Headline: ``decode_stall_ms`` — the worst inter-token gap any live
-    decode stream sees inside the admission window (submit .. first token
-    of the admitted request). Two-dispatch serializes the admission's
-    prefill dispatches against decode chunks on one device queue, so the
-    gap grows with the prompt; ragged bounds it near one mixed-step time.
-    Also reports the admitted request's TTFT, per-arm TTFT p50/p99 across
-    all requests, token-weighted batch occupancy, tok/s, and stream
-    byte-identity across the arms (greedy; both arms chunk EVERY prompt —
-    full prefill differs from chunked numerically under kv_quant)."""
-    import asyncio
-
-    import numpy as np  # noqa: F401
-
-    import jax
-
-    from clearml_serving_tpu import models
-    from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
-
-    bundle = models.build_model("llama", cfg)
-    params = bundle.init(jax.random.PRNGKey(0))
-    decode_prompts = [
-        [(7 * i + 3 + j) % 250 + 1 for j in range(decode_prompt_len)]
-        for i in range(batch - 1)
-    ]
-    admit_prompt = [(11 * j + 5) % 250 + 1 for j in range(admit_prompt_len)]
-    buckets = sorted({
-        max(16, decode_prompt_len),
-        min(max_seq_len, 1 << (admit_prompt_len - 1).bit_length()),
-    })
-
-    def measure(mode: str):
-        extra = (
-            dict(chunked_prefill_size=chunk)
-            if mode == "two_dispatch"
-            else dict(scheduler="ragged", step_token_budget=step_token_budget)
-        )
-        engine = LLMEngineCore(
-            bundle, params,
-            max_batch=batch,
-            max_seq_len=max_seq_len,
-            prefill_buckets=buckets,
-            eos_token_id=None,      # fixed work per stream
-            decode_steps=decode_steps,
-            cache_mode=cache_mode,
-            page_size=page_size,
-            **extra,
-        )
-        stamps: dict = {}
-        occupancy: list = []
-
-        async def one(key, ids, n):
-            req = GenRequest(
-                prompt_ids=list(ids), max_new_tokens=n, temperature=0.0
-            )
-            out = []
-            stamps[key] = {"submit": time.perf_counter(), "tokens": []}
-            async for tok in engine.generate(req):
-                stamps[key]["tokens"].append(time.perf_counter())
-                occupancy.append(engine.active_slots)
-                out.append(tok)
-            return out
-
-        async def group():
-            decode_tasks = [
-                asyncio.create_task(one(i, p, new_tokens))
-                for i, p in enumerate(decode_prompts)
-            ]
-            # wait until every decode stream is live before admitting
-            while not all(
-                len(stamps.get(i, {}).get("tokens", ())) >= 2
-                for i in range(len(decode_prompts))
-            ):
-                await asyncio.sleep(0.002)
-            t_admit = time.perf_counter()
-            long_out = await one("admit", admit_prompt, new_tokens // 2)
-            outs = [await t for t in decode_tasks]
-            await engine.wait_drained()
-            return outs + [long_out], t_admit
-
-        # warmup group: compile every trace (prefill buckets, ragged step
-        # variants, decode chunk) so the measured windows time scheduling,
-        # not XLA compiles. Then ``repeats`` measured groups — the stall /
-        # TTFT metrics take the MEDIAN across groups so one scheduler hiccup
-        # on a noisy host cannot write the headline.
-        asyncio.run(group())
-        stalls, admit_ttfts, ttft_lists, tok_rates, occs = [], [], [], [], []
-        outs = None
-        for _ in range(max(1, repeats)):
-            stamps.clear()
-            occupancy.clear()
-            t0 = time.perf_counter()
-            outs, t_admit = asyncio.run(group())
-            wall = time.perf_counter() - t0
-            t_first_long = stamps["admit"]["tokens"][0]
-            # worst inter-token gap any decode stream saw inside the
-            # admission window (including the wait from the window's edges
-            # to the neighboring emissions)
-            stall = 0.0
-            for i in range(len(decode_prompts)):
-                ts = stamps[i]["tokens"]
-                if not ts:
-                    continue
-                points = [t_admit] + [
-                    t for t in ts if t_admit <= t <= t_first_long
-                ] + [min(t_first_long, ts[-1])]
-                for a, b in zip(points, points[1:]):
-                    if b > a:
-                        stall = max(stall, b - a)
-            stalls.append(stall)
-            admit_ttfts.append(t_first_long - stamps["admit"]["submit"])
-            ttft_lists.append(sorted(
-                s["tokens"][0] - s["submit"]
-                for s in stamps.values()
-                if s["tokens"]
-            ))
-            tok_rates.append(sum(len(o) for o in outs) / wall)
-            occs.append(sum(occupancy) / max(1, len(occupancy)))
-        engine.stop()
-
-        def med(xs):
-            return sorted(xs)[len(xs) // 2]
-
-        ttfts = ttft_lists[stalls.index(med(stalls))]
-
-        def pct(p):
-            return ttfts[min(len(ttfts) - 1, int(p * (len(ttfts) - 1)))]
-
-        return {
-            "outs": outs,
-            "decode_stall_ms": round(med(stalls) * 1e3, 3),
-            "admit_ttft_ms": round(med(admit_ttfts) * 1e3, 3),
-            "ttft_p50_ms": round(pct(0.50) * 1e3, 3),
-            "ttft_p99_ms": round(pct(0.99) * 1e3, 3),
-            "occupancy": round(med(occs), 3),
-            "tok_s": round(med(tok_rates), 2),
-        }
-
-    legacy = measure("two_dispatch")
-    ragged = measure("ragged")
-    identical = legacy.pop("outs") == ragged.pop("outs")
-    return {
-        "metric": "llm_ragged_scheduler_ab",
-        # headline: how much of the admission-window decode stall the
-        # ragged scheduler removes
-        "value": round(
-            (1.0 - (
-                ragged["decode_stall_ms"]
-                / max(1e-9, legacy["decode_stall_ms"])
-            )) * 100.0,
-            2,
-        ),
-        "unit": "% decode-stall reduction during admission (ragged vs "
-                "two-dispatch)",
-        "two_dispatch": legacy,
-        "ragged": ragged,
-        "identical_tokens": identical,
-        "batch": batch,
-        "decode_steps": decode_steps,
-        "new_tokens": new_tokens,
-        "admit_prompt_len": admit_prompt_len,
-        "step_token_budget": step_token_budget,
-        "chunked_prefill_size": chunk,
-        "cache": cache_mode,
-        "cpus": os.cpu_count() or 1,
-        "note": (
-            "two-dispatch admission prefill runs in a worker thread but "
-            "shares the device (and on CPU, the core) with decode chunks; "
-            "ragged carries it as chunk rows of the decode launch itself"
-        ),
-    }
-
-
-def run_ragged_decode_steps_ab(
-    cfg: dict,
-    *,
-    q: int = 4,
-    new_tokens: int = 96,
-    decode_prompt_len: int = 12,
-    admit_prompt_len: int = 24,
-    step_token_budget: int = 48,
-    max_seq_len: int = 256,
-    cache_mode: str = "paged",
-    page_size: int = 16,
-) -> dict:
-    """Multi-step ragged decode-row A/B (docs/ragged_attention.md, ISSUE
-    13): one long greedy decode stream rides the ragged scheduler's mixed
-    launches while a continuous trickle of short admissions keeps the loop
-    in ragged phases — the steady decode-while-admitting state where q=1
-    rows pay ONE dispatch per token. The arms differ only in
-    ``ragged_decode_steps`` (1 vs ``q``); the headline is
-    dispatches-per-decode-token (ragged launches / decode tokens advanced
-    by ragged launches) with the stream's tok/s beside it, and the streams
-    must be byte-identical across arms (greedy)."""
-    import asyncio
-
-    import jax
-
-    from clearml_serving_tpu import models
-    from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
-
-    bundle = models.build_model("llama", cfg)
-    params = bundle.init(jax.random.PRNGKey(0))
-    stream_prompt = [(7 * j + 3) % 250 + 1 for j in range(decode_prompt_len)]
-    admit_prompt = [(11 * j + 5) % 250 + 1 for j in range(admit_prompt_len)]
-    buckets = sorted({
-        max(16, decode_prompt_len),
-        max(16, 1 << (admit_prompt_len - 1).bit_length()),
-    })
-
-    def measure(steps: int):
-        engine = LLMEngineCore(
-            bundle, params,
-            max_batch=3, max_seq_len=max_seq_len, prefill_buckets=buckets,
-            eos_token_id=None, decode_steps=max(4, q),
-            ragged_decode_steps=steps, scheduler="ragged",
-            step_token_budget=step_token_budget,
-            cache_mode=cache_mode, page_size=page_size,
-        )
-
-        async def group():
-            out: list = []
-            done = asyncio.Event()
-
-            async def stream():
-                req = GenRequest(
-                    prompt_ids=list(stream_prompt),
-                    max_new_tokens=new_tokens, temperature=0.0,
-                )
-                async for tok in engine.generate(req):
-                    out.append(tok)
-                done.set()
-
-            async def feeder():
-                while not done.is_set():
-                    req = GenRequest(
-                        prompt_ids=list(admit_prompt),
-                        max_new_tokens=1, temperature=0.0,
-                    )
-                    async for _ in engine.generate(req):
-                        pass
-
-            await asyncio.gather(stream(), feeder())
-            await engine.wait_drained()
-            return out
-
-        asyncio.run(group())            # warmup pass: compiles every trace
-        base = dict(engine.counters)
-        t0 = time.perf_counter()
-        out = asyncio.run(group())
-        wall = time.perf_counter() - t0
-        launches = engine.counters["ragged_steps"] - base["ragged_steps"]
-        dec_tokens = (
-            engine.counters["ragged_decode_tokens"]
-            - base["ragged_decode_tokens"]
-        )
-        snap = engine.lifecycle_stats()["ragged"]["tokens_per_launch"]
-        engine.stop()
-        return {
-            "out": out,
-            "tok_s": round(len(out) / wall, 2),
-            "ragged_launches": launches,
-            "ragged_decode_tokens": dec_tokens,
-            "dispatches_per_decode_token": round(
-                launches / max(1, dec_tokens), 3
-            ),
-            "tokens_per_launch_mean": round(
-                snap["sum_ms"] / max(1, snap["count"]), 2
-            ),
-        }
-
-    one = measure(1)
-    multi = measure(q)
-    identical = one.pop("out") == multi.pop("out")
-    return {
-        "metric": "llm_ragged_decode_steps_ab",
-        # headline: dispatch-bubble amortization — how many launches each
-        # decode token costs at q vs 1
-        "value": multi["dispatches_per_decode_token"],
-        "unit": "ragged launches per decode token at q={}".format(q),
-        "q1": one,
-        "q{}".format(q): multi,
-        "decode_steps": q,
-        "identical_tokens": identical,
-        "new_tokens": new_tokens,
-        "step_token_budget": step_token_budget,
-        "cache": cache_mode,
-        "cpus": os.cpu_count() or 1,
-    }
-
-
-def run_spec_row_ab(
-    cfg: dict,
-    *,
-    spec_k: int = 3,
-    spec_ngram: int = 2,
-    batch: int = 3,
-    new_tokens: int = 64,
-    step_token_budget: int = 16,
-    max_seq_len: int = 256,
-    cache_mode: str = "paged",
-    page_size: int = 16,
-) -> dict:
-    """Spec-as-row vs legacy serial spec (docs/ragged_attention.md, ISSUE
-    13): the same repetitive-prompt greedy workload (n-gram-friendly, so
-    drafts accept) on the two-dispatch scheduler's serial draft-verify
-    scan vs the ragged scheduler's in-launch q=k+1 verify rows. Streams
-    must be byte-identical; reports tok/s per arm and the ragged arm's
-    measured per-launch acceptance.
-
-    Read the CPU tok/s comparison with care: off-TPU the ragged pass is
-    the XLA reference, which computes the FULL budget-padded token axis
-    every launch (the Pallas kernel skips q-blocks no row owns), and the
-    legacy scan amortizes its ONE dispatch over decode_steps draft-verify
-    rounds while spec-as-row verifies one window per launch — on a 1-core
-    CPU, where a dispatch costs ~nothing and compute is everything, the
-    serial scan wins tok/s by construction. What spec-as-row buys is what
-    the scan structurally cannot do: verify rows ride MIXED launches
-    beside decode windows and admission chunks (no pipeline drain, no
-    whole-batch stall while one request speculates); whether that wins on
-    the chip is not measured. The CPU arm certifies stream identity and
-    acceptance parity, not throughput."""
-    import asyncio
-
-    import jax
-
-    from clearml_serving_tpu import models
-    from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
-
-    bundle = models.build_model("llama", cfg)
-    params = bundle.init(jax.random.PRNGKey(0))
-    prompts = [
-        ([(5 * i + 3) % 29 + 1] * 3 + [(3 * i + 7) % 29 + 1] * 2) * 4
-        for i in range(batch)
-    ]
-
-    def measure(mode: str):
-        extra = (
-            dict(chunked_prefill_size=8)
-            if mode == "two_dispatch"
-            else dict(scheduler="ragged", step_token_budget=step_token_budget)
-        )
-        engine = LLMEngineCore(
-            bundle, params,
-            max_batch=batch, max_seq_len=max_seq_len,
-            prefill_buckets=[32], eos_token_id=None, decode_steps=4,
-            speculation="ngram", spec_k=spec_k, spec_ngram=spec_ngram,
-            cache_mode=cache_mode, page_size=page_size, **extra,
-        )
-
-        async def group():
-            async def one(ids):
-                req = GenRequest(
-                    prompt_ids=list(ids), max_new_tokens=new_tokens,
-                    temperature=0.0,
-                )
-                return [t async for t in engine.generate(req)]
-
-            outs = await asyncio.gather(*(one(p) for p in prompts))
-            await engine.wait_drained()
-            return outs
-
-        asyncio.run(group())            # warmup pass
-        t0 = time.perf_counter()
-        outs = asyncio.run(group())
-        wall = time.perf_counter() - t0
-        row = {
-            "outs": outs,
-            "tok_s": round(sum(len(o) for o in outs) / wall, 2),
-        }
-        if mode == "ragged":
-            s = engine.lifecycle_stats()["ragged"]
-            row["spec_verify_rows"] = s["step_rows"]["spec_verify"]
-            snap = s["spec_acceptance"]
-            row["acceptance_mean"] = round(
-                snap["sum_ms"] / max(1, snap["count"]), 3
-            )
-        engine.stop()
-        return row
-
-    legacy = measure("two_dispatch")
-    ragged = measure("ragged")
-    identical = legacy.pop("outs") == ragged.pop("outs")
-    return {
-        "metric": "llm_spec_row_ab",
-        "value": round(
-            (ragged["tok_s"] / max(1e-9, legacy["tok_s"]) - 1.0) * 100.0, 2
-        ),
-        "unit": "% tok/s, spec-as-row vs legacy serial spec scan",
-        "legacy_spec": legacy,
-        "spec_as_row": ragged,
-        "identical_tokens": identical,
-        "spec_k": spec_k,
-        "batch": batch,
-        "cache": cache_mode,
-        "cpus": os.cpu_count() or 1,
-    }
-
-
 def run_spec_tree_ab(
     cfg: dict,
     *,
@@ -1392,16 +971,18 @@ def run_paged_quant_ab(
                 dtype=base_cfg.get("dtype", "bfloat16"),
                 kv_quant="int8" if name == "int8" else "",
             )
-            scales = ()
-            if name == "int8":
-                scales = (
-                    mini["k_scale"][:, 0, : len(ids)],
-                    mini["v_scale"][:, 0, : len(ids)],
-                )
-            cache.write_prompt(
-                0, mini["k"][:, 0, : len(ids)], mini["v"][:, 0, : len(ids)],
-                len(ids), *scales,
+            # the prompt's K/V (and scales) into the slot's pages
+            cache.pool.allocate(0, len(ids))
+            pg, off = (
+                jnp.asarray(c)
+                for c in zip(*cache.pool.token_coords(0, 0, len(ids)))
             )
+            for buf in ("k", "v") + (
+                ("k_scale", "v_scale") if name == "int8" else ()
+            ):
+                rows = jnp.moveaxis(mini[buf][:, 0, : len(ids)], 1, 2)
+                setattr(cache, buf,
+                        getattr(cache, buf).at[:, :, pg, off].set(rows))
             caches[name] = cache
             state[name] = (jnp.argmax(logits, -1).astype(jnp.int32), logits)
         drift = float(
@@ -1780,50 +1361,6 @@ def _int4_ab_smoke() -> None:
     print(json.dumps(row))
 
 
-def _ragged_ab_smoke() -> None:
-    """CPU smoke for ``--ragged-ab`` (acceptance: byte-identical streams
-    across schedulers and a STRICTLY smaller decode stall during a
-    concurrent long-prompt admission — the ISSUE-9 headline; plus the
-    ISSUE-13 arms: the ``--decode-steps`` q=1-vs-q A/B with
-    dispatches-per-decode-token < 0.5 at q, and spec-as-row vs the legacy
-    serial spec scan with identical streams). Updates
-    benchmarks/RAGGED_AB_cpu.json (asserted by tier-1). Knobs:
-    BENCH_RAGGED_BATCH / BENCH_RAGGED_TOKENS / BENCH_RAGGED_BUDGET /
-    BENCH_RAGGED_ADMIT / BENCH_RAGGED_CACHE, and ``--decode-steps N``
-    (or BENCH_RAGGED_DECODE_STEPS) for the multi-step arm's window."""
-    import jax
-
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-    q = int(os.environ.get("BENCH_RAGGED_DECODE_STEPS", 4))
-    if "--decode-steps" in sys.argv:
-        q = int(sys.argv[sys.argv.index("--decode-steps") + 1])
-    cfg = {"preset": "llama-tiny", "dtype": "float32"}
-    row = run_ragged_ab(
-        cfg,
-        batch=int(os.environ.get("BENCH_RAGGED_BATCH", 3)),
-        new_tokens=int(os.environ.get("BENCH_RAGGED_TOKENS", 64)),
-        step_token_budget=int(os.environ.get("BENCH_RAGGED_BUDGET", 24)),
-        admit_prompt_len=int(os.environ.get("BENCH_RAGGED_ADMIT", 224)),
-        cache_mode=os.environ.get("BENCH_RAGGED_CACHE", "paged"),
-        max_seq_len=256,
-    )
-    row["metric"] += "_cpusmoke"
-    row["platform"] = "cpu"
-    row["decode_steps_ab"] = run_ragged_decode_steps_ab(cfg, q=q)
-    row["spec_row_ab"] = run_spec_row_ab(cfg)
-    artifact = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "benchmarks",
-        "RAGGED_AB_cpu.json",
-    )
-    with open(artifact, "w") as f:
-        json.dump(row, f, indent=2, sort_keys=True)
-        f.write("\n")
-    print(json.dumps(row))
-
-
 def _spec_tree_ab_smoke() -> None:
     """CPU smoke for ``--spec-tree-ab`` (acceptance: byte-identical greedy
     streams across the no-spec / chain / tree arms, and the tree arm's
@@ -2006,10 +1543,6 @@ if __name__ == "__main__":
         os.environ.get("BENCH_SCENARIO") == "pipeline_ab"
     ):
         _pipeline_ab_smoke()
-    elif "--ragged-ab" in sys.argv or (
-        os.environ.get("BENCH_SCENARIO") == "ragged_ab"
-    ):
-        _ragged_ab_smoke()
     elif "--spec-tree-ab" in sys.argv or (
         os.environ.get("BENCH_SCENARIO") == "spec_tree_ab"
     ):

@@ -114,7 +114,6 @@ WARMUP_COVERED: FrozenSet[str] = frozenset({
     "_prefill_pipeline_jit",
     "_prefill_chunk_first_jit",
     "_prefill_chunk_jit",
-    "_gather_pages_jit",
     "_assemble_prefix_jit",
     "_insert_jit",
     "_merge_rows_jit",
@@ -124,9 +123,7 @@ WARMUP_COVERED: FrozenSet[str] = frozenset({
     "_first_lp_jit",
     "_set_sampling_row_jit",
     "_spec_chunk_jit",
-    "_spec_paged_jit",
     "_ragged_paged_jit",
-    "_ragged_dense_jit",
     "_ragged_state_jit",
     "_gather_finish_jit",
 })

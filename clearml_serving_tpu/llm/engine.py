@@ -383,8 +383,8 @@ _RAGGED_BROWNOUT_CHUNK = 16
 
 def _state_cache_refusal(bundle, *, mesh, prefix_cache,
                          prefix_cache_host_pages, prefix_cache_host_bytes,
-                         speculation, spec_tree, lora_adapters,
-                         scheduler) -> Optional[str]:
+                         speculation, spec_tree,
+                         lora_adapters) -> Optional[str]:
     """Why this engine cannot be built on ``engine.cache=state``, or None.
     Every feature that assumes K/V pages is refused by name with its reason
     (docs/state_cache.md lists what each would need)."""
@@ -395,13 +395,6 @@ def _state_cache_refusal(bundle, *, mesh, prefix_cache,
             "surfaces, e.g. config attention='power_retention'); this "
             "model attends over keys and values: use engine.cache=paged or "
             "dense"
-        )
-    if scheduler != "ragged":
-        return (
-            "engine.cache=state is served by scheduler='ragged' only: a "
-            "prompt enters its slot in chunks of the ragged step and decode "
-            "rows chain through the same launch (got scheduler={!r})"
-            .format(scheduler)
         )
     if prefix_cache:
         return (
@@ -782,11 +775,11 @@ class LLMEngineCore:
         "serve": (
             "_prefill_jit", "_prefill_ring_jit", "_prefill_pipeline_jit",
             "_prefill_chunk_first_jit", "_prefill_chunk_jit",
-            "_gather_pages_jit", "_assemble_prefix_jit", "_insert_jit",
+            "_assemble_prefix_jit", "_insert_jit",
             "_merge_rows_jit", "_decode_chunk_jit",
             "_decode_paged_chunk_jit", "_sample_jit", "_first_lp_jit",
-            "_set_sampling_row_jit", "_spec_chunk_jit", "_spec_paged_jit",
-            "_ragged_paged_jit", "_ragged_dense_jit", "_ragged_state_jit",
+            "_set_sampling_row_jit", "_spec_chunk_jit",
+            "_ragged_paged_jit", "_ragged_state_jit",
             "_gather_finish_jit",
         ),
         # prompt scoring runs only for completions echo+logprobs requests:
@@ -888,11 +881,10 @@ class LLMEngineCore:
         # 2); 1 restores the serial dispatch->sync->emit loop
         pipeline_depth: Optional[int] = None,
         # -- ragged scheduling (docs/ragged_attention.md) ------------------
-        # "ragged": admissions ride the decode loop as budget-bounded
-        # prefill-chunk rows of ONE mixed launch per iteration (token-budget
-        # admission replaces the prefill gate); "two_dispatch" (default):
-        # the historical separate prefill/decode dispatches. None defers to
-        # TPUSERVE_SCHEDULER.
+        # not a choice: cache_mode decides ("paged" and "state" run the
+        # ragged token-budget step, "dense" the two-dispatch loop). A value
+        # is checked against that and a mismatch refused by name; the
+        # keyword stays while benchmark/configs pass it (ROADMAP D2a).
         scheduler: Optional[str] = None,
         # ragged mode: max tokens (decode rows + prefill-chunk rows) per
         # launch; must exceed max_batch so admissions always make progress.
@@ -959,17 +951,38 @@ class LLMEngineCore:
                 "state, no keys and values): serve it with engine.cache=state "
                 "(got engine.cache={})".format(cache_mode)
             )
+        # the cache kind decides the scheduler (docs/ragged_attention.md):
+        # pages and state slots are served by the ragged token-budget step,
+        # the dense cache by the two-dispatch loop with its prefill programs,
+        # its gate and its serial speculative scan. ``scheduler`` is no
+        # choice: a given value is only checked against the cache's own.
+        # The dense-only knobs (prefill_buckets, chunked_prefill_size,
+        # long_prefill_threshold, long_bucket_step, pipeline_chunk,
+        # prefill_segments_per_decode, prefill_stall_timeout) are accepted
+        # and reach no program of a ragged engine (prefill_buckets still
+        # sizes the prompts of llm/warmup.py's sweep).
+        self._ragged = cache_mode in ("paged", "state")
+        sched = "ragged" if self._ragged else "two_dispatch"
+        if scheduler is not None and scheduler != sched:
+            if scheduler not in ("two_dispatch", "ragged"):
+                raise ValueError(
+                    "scheduler must be 'two_dispatch' or 'ragged' (got {!r})"
+                    .format(scheduler)
+                )
+            raise ValueError(
+                "cache={} runs the {} scheduler; scheduler={!r} exists only "
+                "on cache={}".format(
+                    cache_mode, sched, scheduler,
+                    "dense" if self._ragged else "paged or state",
+                )
+            )
         if cache_mode == "state":
             refused = _state_cache_refusal(
                 bundle, mesh=mesh, prefix_cache=prefix_cache,
                 prefix_cache_host_pages=prefix_cache_host_pages,
                 prefix_cache_host_bytes=prefix_cache_host_bytes,
                 speculation=speculation, spec_tree=spec_tree,
-                lora_adapters=lora_adapters, scheduler=(
-                    scheduler if scheduler is not None
-                    else os.environ.get("TPUSERVE_SCHEDULER", "")
-                    or "two_dispatch"
-                ),
+                lora_adapters=lora_adapters,
             )
             if refused:
                 raise ValueError(refused)
@@ -1066,27 +1079,12 @@ class LLMEngineCore:
                 "with prefill_chunk (the host tier spills the paged radix "
                 "prefix cache; docs/kv_tiering.md)"
             )
-        # -- ragged scheduling (docs/ragged_attention.md) ------------------
-        # resolved EARLY: the dense cache slack and the prefill gate both
-        # depend on the scheduler choice
-        sched = (
-            scheduler
-            if scheduler is not None
-            else os.environ.get("TPUSERVE_SCHEDULER", "") or "two_dispatch"
-        )
-        if sched not in ("two_dispatch", "ragged"):
+        if cache_mode == "paged" and getattr(
+            bundle, "forward_ragged", None
+        ) is None:
             raise ValueError(
-                "scheduler must be 'two_dispatch' or 'ragged' (got {!r})"
-                .format(sched)
-            )
-        self._ragged = sched == "ragged"
-        if self._ragged and (
-            getattr(bundle, "forward_ragged", None) is None
-            or getattr(bundle, "forward_ragged_dense", None) is None
-        ):
-            raise ValueError(
-                "scheduler='ragged' needs a model bundle with "
-                "forward_ragged/forward_ragged_dense surfaces"
+                "engine.cache=paged runs the ragged scheduler, which needs a "
+                "model bundle with a forward_ragged surface"
             )
         if step_token_budget is None:
             raw = os.environ.get("TPUSERVE_STEP_TOKEN_BUDGET", "")
@@ -1275,18 +1273,6 @@ class LLMEngineCore:
         spec_slack = (
             self.decode_steps * (max(1, int(spec_k)) + 1) if speculation else 0
         )
-        # ragged dense steps write each row's whole C-token chunk window at
-        # its start position (pad tail included, overwritten before it is
-        # ever visible) — the buffer needs chunk-window-wide slack past
-        # max_seq_len or dynamic_update_slice would CLAMP the window
-        # backward over live KV at the sequence edge (the same hazard the
-        # spec slack covers). C buckets to the next power of two of the
-        # step's widest chunk, which can EXCEED the budget (budget 24 ->
-        # C 32), so the slack covers the bucketed bound, not the budget.
-        if self._ragged and cache_mode == "dense":
-            spec_slack = max(
-                spec_slack, 1 << (self._step_token_budget - 1).bit_length()
-            )
         # kept for supervised recovery: a poisoned dense decode step may have
         # consumed (donated) the cache — rebuilding needs the original size
         self._cache_slack = spec_slack
@@ -1767,39 +1753,6 @@ class LLMEngineCore:
                     backend=tier_backend,
                     host_max_bytes=prefix_cache_host_bytes,
                 )
-                paged_quant = self._paged_quant
-
-                def _gather_pages(kp, vp, pages, plen, ksp=None, vsp=None):
-                    # shared pages -> dense mini-cache layout [L,1,S,Hkv,D]
-                    # (compute input for the tail's prefill_chunk; the pool
-                    # pages themselves are mapped by reference at commit).
-                    # `pages` is padded with the null page to the bucket's
-                    # page count so traces stay bucketed; garbage beyond
-                    # plen is masked by the cache length. int8 pools also
-                    # gather the scale rows ([L,1,S,Hkv]) — the dense
-                    # mini-cache layout prefill_chunk already consumes
-                    # under kv_quant.
-                    sk = kp[:, :, pages]                   # [L,H,NP,P,D]
-                    l, h, n, p, d = sk.shape
-                    k = jnp.moveaxis(sk.reshape(l, h, n * p, d), 1, 2)[:, None]
-                    sv = vp[:, :, pages]
-                    v = jnp.moveaxis(sv.reshape(l, h, n * p, d), 1, 2)[:, None]
-                    out = {
-                        "k": k, "v": v,
-                        "length": jnp.reshape(plen, (1,)).astype(jnp.int32),
-                    }
-                    if paged_quant:
-                        sks = ksp[:, :, pages]             # [L,H,NP,P]
-                        out["k_scale"] = jnp.moveaxis(
-                            sks.reshape(l, h, n * p), 1, 2
-                        )[:, None]
-                        svs = vsp[:, :, pages]
-                        out["v_scale"] = jnp.moveaxis(
-                            svs.reshape(l, h, n * p), 1, 2
-                        )[:, None]
-                    return out
-
-                self._gather_pages_jit = jax.jit(_gather_pages)
             else:
                 self._prefix = RadixPrefixCache(
                     int(prefix_cache), int(prefix_block),
@@ -2039,9 +1992,6 @@ class LLMEngineCore:
         # verify positions are nearly free, so a mixed batch never forces
         # the engine off the speculative path.
         self._speculation = None
-        # captured as a local for the jitted closures below (TPU201: a jit
-        # closing over self would trace against stale state)
-        paged_quant = self._paged_quant
         if speculation:
             if speculation != "ngram":
                 raise ValueError("speculation must be 'ngram' (got {!r})".format(speculation))
@@ -2100,206 +2050,151 @@ class LLMEngineCore:
             buf_len = self.max_seq_len + self._spec_slack + 1
             self._tokbuf = np.zeros((self.max_batch, buf_len), np.int32)
 
-            def _make_spec_chunk(paged: bool):
-                def _spec_chunk(params, tokbuf, pending, cachelike, active,
-                                spec_mask, sspec_mask, sampling, rng,
-                                lora_idx=None,
-                                extras=None, counts=None, pmask=None,
-                                guided=None, gstate=None, want_lp=False,
-                                with_sspec=False):
-                    t_idx = jnp.arange(buf_len, dtype=jnp.int32)
-                    nb = pending.shape[0]
-                    # position-0 plain-path slots (extras/guided/logprobs)
-                    ns_mask = active & ~spec_mask
-                    if with_sspec:
-                        ns_mask = ns_mask & ~sspec_mask
-                    if gstate is None:
-                        gstate = jnp.full((nb,), -1, jnp.int32)
-                    if paged:
-                        if paged_quant:
-                            (k_pools, v_pools, k_scales, v_scales,
-                             page_table, lengths) = cachelike
-                        else:
-                            k_pools, v_pools, page_table, lengths = cachelike
-                            k_scales = v_scales = None
+            def _spec_chunk(params, tokbuf, pending, cache, active,
+                            spec_mask, sspec_mask, sampling, rng,
+                            lora_idx=None,
+                            extras=None, counts=None, pmask=None,
+                            guided=None, gstate=None, want_lp=False,
+                            with_sspec=False):
+                t_idx = jnp.arange(buf_len, dtype=jnp.int32)
+                nb = pending.shape[0]
+                # position-0 plain-path slots (extras/guided/logprobs)
+                ns_mask = active & ~spec_mask
+                if with_sspec:
+                    ns_mask = ns_mask & ~sspec_mask
+                if gstate is None:
+                    gstate = jnp.full((nb,), -1, jnp.int32)
 
-                    def round_body(carry, xs):
-                        step_rng, step_off = xs
-                        if paged:
-                            (tokbuf, pending, k_pools, v_pools, k_scales,
-                             v_scales, length, counts, gstate) = carry
-                        else:
-                            tokbuf, pending, cache, counts, gstate = carry
-                            length = cache["length"]                # [B]
-                        hist = length + 1  # known tokens incl. pending
-                        # ---- n-gram proposal from each slot's own history ----
-                        tail_pos = (hist[:, None] - n_ + jnp.arange(n_)[None]).clip(0)
-                        tail = jnp.take_along_axis(tokbuf, tail_pos, axis=1)  # [B,n]
-                        n_win = buf_len - n_ + 1
-                        match = jnp.ones((tokbuf.shape[0], n_win), bool)
-                        for j in range(n_):  # n_ is static and tiny
-                            match = match & (
-                                tokbuf[:, j : n_win + j] == tail[:, j : j + 1]
-                            )
-                        win_idx = jnp.arange(n_win, dtype=jnp.int32)[None]
-                        # window must end before the tail starts (a previous
-                        # occurrence, not the tail matching itself)
-                        valid = match & (win_idx < (hist - n_)[:, None] - n_ + 1)
-                        has = jnp.any(valid, axis=1)
-                        i_best = jnp.argmax(
-                            jnp.where(valid, win_idx, -1), axis=1
-                        ).astype(jnp.int32)                         # [B]
-                        draft_pos = (
-                            i_best[:, None] + n_ + jnp.arange(k_, dtype=jnp.int32)[None]
-                        ).clip(0, buf_len - 1)
-                        drafts = jnp.take_along_axis(tokbuf, draft_pos, axis=1)
-                        # no-match slots: draft the tail's last token repeated —
-                        # cheap, and a reject still emits the bonus token
-                        drafts = jnp.where(has[:, None], drafts, tail[:, -1:])
-                        # ---- one verify pass over pending + drafts ----------
-                        tokens_in = jnp.concatenate([pending[:, None], drafts], axis=1)
-                        if paged:
-                            scale_kw = (
-                                {"k_scales": k_scales, "v_scales": v_scales}
-                                if paged_quant
-                                else {}
-                            )
-                            if lora_idx is None:
-                                vout = bundle.verify_paged(
-                                    params, tokens_in, k_pools, v_pools,
-                                    page_table, length, **scale_kw,
-                                )
-                            else:
-                                vout = bundle.verify_paged(
-                                    params, tokens_in, k_pools, v_pools,
-                                    page_table, length, lora_idx, **scale_kw,
-                                )
-                            if paged_quant:
-                                (logits, k_pools, v_pools, k_scales,
-                                 v_scales) = vout
-                            else:
-                                logits, k_pools, v_pools = vout
-                        else:
-                            if lora_idx is None:
-                                logits, cache = bundle.verify(params, tokens_in, cache)
-                            else:
-                                logits, cache = bundle.verify(
-                                    params, tokens_in, cache, lora_idx
-                                )
-                        logits = logits.astype(jnp.float32)
-                        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B,k+1]
-                        acc = jnp.sum(
-                            jnp.cumprod((drafts == g[:, :k_]).astype(jnp.int32), axis=1),
-                            axis=1,
-                        )                                            # [B] 0..k
-                        if with_sspec:
-                            # rejection-sampled draft chain for plain
-                            # temperature>0 slots (distribution-exact)
-                            step_rng, chain_rng = jax.random.split(step_rng)
-                        # ---- plain-path slots: one token from position 0,
-                        # plain-chunk semantics (mask -> penalize -> sample ->
-                        # count -> DFA advance) -------------------------------
-                        l0 = logits[:, 0, :]
-                        if guided is not None:
-                            l0 = _guided_mask(l0, gstate, guided)
-                        if extras is None:
-                            sampled = sample_tokens(l0, sampling, step_rng)
-                            lp_src = l0
-                        else:
-                            ex = extras._replace(counters=extras.counters + step_off)
-                            sampled = sample_tokens(
-                                l0, sampling, step_rng, ex, counts, pmask
-                            )
-                            lp_src = (
-                                penalize_logits(l0, ex, counts, pmask)
-                                if want_lp
-                                else l0
-                            )
-                            counts = counts.at[jnp.arange(nb), sampled].add(
-                                ns_mask.astype(jnp.int32)
-                            )
-                        if guided is not None:
-                            gstate = _guided_advance(gstate, sampled, ns_mask, guided)
-                        acc = jnp.where(spec_mask, acc, 0)
-                        if with_sspec:
-                            g_s, acc_s = speculative_sample_chain(
-                                logits, drafts, sampling, chain_rng
-                            )
-                            acc = jnp.where(sspec_mask, acc_s, acc)
-                            g = jnp.where(sspec_mask[:, None], g_s, g)
-                            keep = spec_mask | sspec_mask
-                        else:
-                            keep = spec_mask
-                        g = g.at[:, 0].set(jnp.where(keep, g[:, 0], sampled))
-                        new_pending = jnp.take_along_axis(g, acc[:, None], axis=1)[:, 0]
-                        new_len = jnp.where(active, length + 1 + acc, length)
-                        # append the emitted tokens to the history buffer
-                        for i in range(k_ + 1):
-                            w = (t_idx[None] == (hist + i)[:, None]) & (
-                                (i <= acc) & active
-                            )[:, None]
-                            tokbuf = jnp.where(w, g[:, i : i + 1], tokbuf)
-                        pending = jnp.where(active, new_pending, pending)
-                        out = (
-                            (g, acc, _lp_of(lp_src, sampled, nb))
-                            if want_lp
-                            else (g, acc)
+                def round_body(carry, xs):
+                    step_rng, step_off = xs
+                    tokbuf, pending, cache, counts, gstate = carry
+                    length = cache["length"]                # [B]
+                    hist = length + 1  # known tokens incl. pending
+                    # ---- n-gram proposal from each slot's own history ----
+                    tail_pos = (hist[:, None] - n_ + jnp.arange(n_)[None]).clip(0)
+                    tail = jnp.take_along_axis(tokbuf, tail_pos, axis=1)  # [B,n]
+                    n_win = buf_len - n_ + 1
+                    match = jnp.ones((tokbuf.shape[0], n_win), bool)
+                    for j in range(n_):  # n_ is static and tiny
+                        match = match & (
+                            tokbuf[:, j : n_win + j] == tail[:, j : j + 1]
                         )
-                        if paged:
-                            carry = (tokbuf, pending, k_pools, v_pools,
-                                     k_scales, v_scales,
-                                     new_len.astype(jnp.int32), counts, gstate)
-                        else:
-                            cache = {**cache, "length": new_len.astype(jnp.int32)}
-                            carry = (tokbuf, pending, cache, counts, gstate)
-                        return carry, out
-
-                    rngs = jax.random.split(rng, decode_steps)
-                    steps = jnp.arange(decode_steps, dtype=jnp.int32)
-                    if paged:
-                        carry0 = (tokbuf, pending, k_pools, v_pools,
-                                  k_scales, v_scales, lengths, counts, gstate)
+                    win_idx = jnp.arange(n_win, dtype=jnp.int32)[None]
+                    # window must end before the tail starts (a previous
+                    # occurrence, not the tail matching itself)
+                    valid = match & (win_idx < (hist - n_)[:, None] - n_ + 1)
+                    has = jnp.any(valid, axis=1)
+                    i_best = jnp.argmax(
+                        jnp.where(valid, win_idx, -1), axis=1
+                    ).astype(jnp.int32)                         # [B]
+                    draft_pos = (
+                        i_best[:, None] + n_ + jnp.arange(k_, dtype=jnp.int32)[None]
+                    ).clip(0, buf_len - 1)
+                    drafts = jnp.take_along_axis(tokbuf, draft_pos, axis=1)
+                    # no-match slots: draft the tail's last token repeated —
+                    # cheap, and a reject still emits the bonus token
+                    drafts = jnp.where(has[:, None], drafts, tail[:, -1:])
+                    # ---- one verify pass over pending + drafts ----------
+                    tokens_in = jnp.concatenate([pending[:, None], drafts], axis=1)
+                    if lora_idx is None:
+                        logits, cache = bundle.verify(params, tokens_in, cache)
                     else:
-                        carry0 = (tokbuf, pending, cachelike, counts, gstate)
-                    carry, out = jax.lax.scan(round_body, carry0, (rngs, steps))
-                    if want_lp:
-                        gs, accs, lp = out  # lp round-major [R, B, ...]
+                        logits, cache = bundle.verify(
+                            params, tokens_in, cache, lora_idx
+                        )
+                    logits = logits.astype(jnp.float32)
+                    g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B,k+1]
+                    acc = jnp.sum(
+                        jnp.cumprod((drafts == g[:, :k_]).astype(jnp.int32), axis=1),
+                        axis=1,
+                    )                                            # [B] 0..k
+                    if with_sspec:
+                        # rejection-sampled draft chain for plain
+                        # temperature>0 slots (distribution-exact)
+                        step_rng, chain_rng = jax.random.split(step_rng)
+                    # ---- plain-path slots: one token from position 0,
+                    # plain-chunk semantics (mask -> penalize -> sample ->
+                    # count -> DFA advance) -------------------------------
+                    l0 = logits[:, 0, :]
+                    if guided is not None:
+                        l0 = _guided_mask(l0, gstate, guided)
+                    if extras is None:
+                        sampled = sample_tokens(l0, sampling, step_rng)
+                        lp_src = l0
                     else:
-                        (gs, accs), lp = out, None
-                    if paged:
-                        tokbuf, pending, k_pools, v_pools = carry[:4]
-                        counts, gstate = carry[7], carry[8]
-                        if paged_quant:
-                            new_cachelike = (k_pools, v_pools, carry[4],
-                                             carry[5])
-                        else:
-                            new_cachelike = (k_pools, v_pools)
+                        ex = extras._replace(counters=extras.counters + step_off)
+                        sampled = sample_tokens(
+                            l0, sampling, step_rng, ex, counts, pmask
+                        )
+                        lp_src = (
+                            penalize_logits(l0, ex, counts, pmask)
+                            if want_lp
+                            else l0
+                        )
+                        counts = counts.at[jnp.arange(nb), sampled].add(
+                            ns_mask.astype(jnp.int32)
+                        )
+                    if guided is not None:
+                        gstate = _guided_advance(gstate, sampled, ns_mask, guided)
+                    acc = jnp.where(spec_mask, acc, 0)
+                    if with_sspec:
+                        g_s, acc_s = speculative_sample_chain(
+                            logits, drafts, sampling, chain_rng
+                        )
+                        acc = jnp.where(sspec_mask, acc_s, acc)
+                        g = jnp.where(sspec_mask[:, None], g_s, g)
+                        keep = spec_mask | sspec_mask
                     else:
-                        tokbuf, pending, new_cachelike, counts, gstate = carry
-                    # gs [rounds, B, k+1], accs [rounds, B]
-                    return (tokbuf, pending, new_cachelike, gs, accs,
-                            counts, gstate, lp)
+                        keep = spec_mask
+                    g = g.at[:, 0].set(jnp.where(keep, g[:, 0], sampled))
+                    new_pending = jnp.take_along_axis(g, acc[:, None], axis=1)[:, 0]
+                    new_len = jnp.where(active, length + 1 + acc, length)
+                    # append the emitted tokens to the history buffer
+                    for i in range(k_ + 1):
+                        w = (t_idx[None] == (hist + i)[:, None]) & (
+                            (i <= acc) & active
+                        )[:, None]
+                        tokbuf = jnp.where(w, g[:, i : i + 1], tokbuf)
+                    pending = jnp.where(active, new_pending, pending)
+                    out = (
+                        (g, acc, _lp_of(lp_src, sampled, nb))
+                        if want_lp
+                        else (g, acc)
+                    )
+                    cache = {**cache, "length": new_len.astype(jnp.int32)}
+                    return (tokbuf, pending, cache, counts, gstate), out
 
-                return _spec_chunk
+                rngs = jax.random.split(rng, decode_steps)
+                steps = jnp.arange(decode_steps, dtype=jnp.int32)
+                carry, out = jax.lax.scan(
+                    round_body, (tokbuf, pending, cache, counts, gstate),
+                    (rngs, steps),
+                )
+                if want_lp:
+                    gs, accs, lp = out  # lp round-major [R, B, ...]
+                else:
+                    (gs, accs), lp = out, None
+                tokbuf, pending, cache, counts, gstate = carry
+                # gs [rounds, B, k+1], accs [rounds, B]
+                return (tokbuf, pending, cache, gs, accs, counts, gstate, lp)
 
-            if cache_mode == "paged":
-                self._spec_chunk_jit = None
-                self._spec_paged_jit = jax.jit(
-                    _make_spec_chunk(True), donate_argnums=(3,),
+            # the serial scan is the dense engine's; on pages drafts ride
+            # the ragged step as verify rows
+            self._spec_chunk_jit = (
+                jax.jit(
+                    _spec_chunk, donate_argnums=(3,),
                     static_argnames=("want_lp", "with_sspec"),
                 )
-            else:
-                self._spec_chunk_jit = jax.jit(
-                    _make_spec_chunk(False), donate_argnums=(3,),
-                    static_argnames=("want_lp", "with_sspec"),
-                )
-                self._spec_paged_jit = None
+                if cache_mode == "dense"
+                else None
+            )
         else:
             self._tokbuf = None
             self._spec_chunk_jit = None
-            self._spec_paged_jit = None
 
-        paged_quant = getattr(self, "_paged_quant", False)
+        # captured as a local for the jitted closures below (TPU201: a jit
+        # closing over self would trace against stale state)
+        paged_quant = self._paged_quant
 
         def _decode_paged_chunk(
             params, tokens, k_pools, v_pools, k_scales, v_scales,
@@ -2402,12 +2297,12 @@ class LLMEngineCore:
         # -- ragged mixed prefill+decode step (docs/ragged_attention.md) ---
         # ONE launch per loop iteration: every decode row advances one token
         # while prefill rows process budget-bounded prompt chunks, all
-        # through bundle.forward_ragged / forward_ragged_dense. Decode-row
+        # through bundle.forward_ragged / forward_ragged_state. Decode-row
         # sampling mirrors the plain chunk body exactly (guided mask ->
         # penalized sample -> count -> DFA advance), which is what keeps
-        # ragged streams byte-identical to the two-dispatch path; finishing
+        # paged streams byte-identical to the dense engine's; finishing
         # prefill rows return their raw last-token logits for the loop's
-        # host-side first-token sampling (the same code the legacy
+        # host-side first-token sampling (the same code the dense
         # admission path runs).
         if self._ragged:
 
@@ -2706,9 +2601,8 @@ class LLMEngineCore:
                     ),
                     static_argnames=("want_lp",),
                 )
-                self._ragged_dense_jit = None
                 self._ragged_state_jit = None
-            elif cache_mode == "state":
+            else:
 
                 def _ragged_state_step(params, tokens, tok_pos, tok_row,
                                        tok_valid, row_last, s_pool, z_pool,
@@ -2781,109 +2675,6 @@ class LLMEngineCore:
                     static_argnames=("want_lp",),
                 )
                 self._ragged_paged_jit = None
-                self._ragged_dense_jit = None
-            else:
-
-                def _ragged_dense_step(params, tokens, start, last_rel,
-                                       row_active, cache, decode_mask,
-                                       sampling, rng, lora_idx=None,
-                                       extras=None, counts=None, pmask=None,
-                                       guided=None, gstate=None,
-                                       want_lp=False, spec=None, chain=None):
-                    logit_kw = (
-                        {"logit_rel": spec[3]} if spec is not None else {}
-                    )
-                    logits, cache = bundle.forward_ragged_dense(
-                        params, tokens, start, last_rel, row_active, cache,
-                        lora_idx, **logit_kw,
-                    )
-                    spec_g = spec_acc = None
-                    plain_mask = decode_mask
-                    if spec is not None:
-                        logits, spec_logits = logits
-                        spec_g, spec_acc, spec_any, _ = _spec_accept(
-                            spec, spec_logits, sampling
-                        )
-                        plain_mask = decode_mask & ~spec_any
-                        # verify() contract: only the accepted prefix (plus
-                        # the pending token) advances the row's length; K/V
-                        # past it sit beyond ``length`` and are overwritten
-                        # by later writes at the same positions
-                        cache = dict(
-                            cache,
-                            length=jnp.where(
-                                spec_any,
-                                (start + 1 + spec_acc).astype(jnp.int32),
-                                cache["length"],
-                            ),
-                        )
-                    raw = logits.astype(jnp.float32)
-                    sampled, counts, lp, gstate = _sample_rows(
-                        raw, plain_mask, sampling, rng, extras, counts,
-                        pmask, guided, gstate, want_lp,
-                    )
-                    if chain is not None:
-                        step_rngs, chain_mask = chain
-                        nb = sampled.shape[0]
-
-                        def body(carry, xs):
-                            tok_c, cache_c, counts_c, gstate_c, step = carry
-                            s_rng, m = xs
-                            if lora_idx is None:
-                                l, cache_n = bundle.decode(
-                                    params, tok_c, cache_c
-                                )
-                            else:
-                                l, cache_n = bundle.decode(
-                                    params, tok_c, cache_c, lora_idx
-                                )
-                            # rows whose window is closed this step freeze
-                            # their length: the garbage K/V the batched
-                            # write left at the frozen position sits beyond
-                            # ``length`` and the next REAL token's write
-                            # overwrites it in full
-                            cache_n = dict(
-                                cache_n,
-                                length=jnp.where(
-                                    m, cache_n["length"], cache_c["length"]
-                                ),
-                            )
-                            s_tok, counts_c, gstate_c, lp_s = _chain_sample(
-                                l, m, step, s_rng, sampling, extras,
-                                counts_c, pmask, guided, gstate_c, want_lp,
-                                nb,
-                            )
-                            tok_next = jnp.where(m, s_tok, tok_c)
-                            out_s = (
-                                (tok_next, lp_s) if want_lp else tok_next
-                            )
-                            return (
-                                (tok_next, cache_n, counts_c, gstate_c,
-                                 step + 1),
-                                out_s,
-                            )
-
-                        (
-                            (_, cache, counts, gstate, _),
-                            chain_out,
-                        ) = jax.lax.scan(
-                            body,
-                            (sampled, cache, counts, gstate, jnp.int32(0)),
-                            (step_rngs, chain_mask),
-                        )
-                        sampled, lp = _stack_chain(
-                            sampled, lp, chain_out, want_lp
-                        )
-                    return (sampled, raw, cache, counts, lp, gstate,
-                            spec_g, spec_acc)
-
-                self._ragged_dense_jit = jax.jit(
-                    _ragged_dense_step,
-                    donate_argnums=(5,),
-                    static_argnames=("want_lp",),
-                )
-                self._ragged_paged_jit = None
-                self._ragged_state_jit = None
             # static flat-token capacity per launch: ONE trace per
             # (extras/guided/lp variant). When the Pallas kernel serves the
             # launch each row's segment aligns to the kernel's q block
@@ -5104,14 +4895,9 @@ class LLMEngineCore:
         gate_bypass = request.priority == "interactive"
         prefix_result = None
         if self._prefix is not None and not use_ring:
-            if self.cache_mode == "paged":
-                prefix_result = self._prefix_admission_paged(
-                    ids, lora_arr, lora_i, request
-                )
-            else:
-                prefix_result = self._prefix_admission(
-                    ids, lora_arr, lora_i, gate_bypass, request
-                )
+            prefix_result = self._prefix_admission(
+                ids, lora_arr, lora_i, gate_bypass, request
+            )
         c = self._chunked
         # the chunked mini cache must be a multiple of C: a final chunk
         # overflowing the bucket would be CLAMPED backward by
@@ -5181,10 +4967,8 @@ class LLMEngineCore:
             last_logits, mini_cache = prefill_fn(
                 self.params, jnp.asarray(tokens), seq_lens, template, lora_arr
             )
-        if self._prefix is not None and not use_ring and self.cache_mode != "paged":
-            # make this prompt's prefix available to future admissions (the
-            # paged path stores by page reference at commit time instead —
-            # its pages exist only once the loop thread has written them)
+        if self._prefix is not None and not use_ring:
+            # make this prompt's prefix available to future admissions
             self._prefix.store(
                 ids, lora_i,
                 {k: v for k, v in mini_cache.items() if k != "length"},
@@ -5330,64 +5114,6 @@ class LLMEngineCore:
         return self._prefill_tail(cache, ids, prefix_len, lora_arr,
                                   gate_bypass, request)
 
-    def _prefix_admission_paged(self, ids, lora_arr, lora_i, request):
-        """Paged prefix-cache hit path. The shared pages are PINNED by the
-        lookup and carried on the request until the loop-thread commit maps
-        them into the slot's page table by reference (zero KV copies for the
-        shared run — kv_cache.write_prompt_shared). Here they are only
-        GATHERED into the dense mini-cache layout as the compute input for
-        the tail's prefill_chunk; that transient is dropped after admission.
-        Returns (last_logits, mini_cache) or None (miss / doesn't fit)."""
-        with lifecycle_ledger.owner(lifecycle_ledger.request_tag(request)):
-            # hit + pin acquires attributed to this request: the ledger's
-            # per-request audit at emit/fail/cancel proves they released
-            hit = self._prefix.lookup_pages(ids, lora_i)
-        if hit is None:
-            return None
-        try:
-            prefix_len = hit["len"]
-            bucket = self._prefix_bucket(prefix_len, len(ids))
-            page_size = self.paged_cache.pool.page_size
-            if bucket is None or bucket % page_size:
-                with lifecycle_ledger.owner(
-                    lifecycle_ledger.request_tag(request)
-                ):
-                    self._prefix.release(hit)
-                self._prefix.uncount_hit(hit)  # recomputed cold
-                return None
-            # pad the page list with the null page to the bucket's page count
-            # so the gather compiles once per bucket, not per prefix length
-            pages = list(hit["pages"])
-            padded = pages + [0] * (bucket // page_size - len(pages))
-            with self.paged_cache.dispatch_lock:
-                scale_args = (
-                    (self.paged_cache.k_scale, self.paged_cache.v_scale)
-                    if self._paged_quant
-                    else ()
-                )
-                cache = self._gather_pages_jit(
-                    self.paged_cache.k, self.paged_cache.v,
-                    jnp.asarray(padded, jnp.int32),
-                    jnp.asarray(prefix_len, jnp.int32),
-                    *scale_args,
-                )
-            last_logits, cache = self._prefill_tail(
-                cache, ids, prefix_len, lora_arr,
-                gate_bypass=request.priority == "interactive",
-                request=request,
-            )
-        except BaseException:
-            # release() is pop-idempotent by construction: re-entering here
-            # after a raise out of the release/uncount pair above re-pops
-            # nothing
-            with lifecycle_ledger.owner(
-                lifecycle_ledger.request_tag(request)
-            ):
-                self._prefix.release(hit)  # tpuserve: ignore[TPU702] release() pops; re-release is a no-op
-            raise
-        request._prefix_hit = hit
-        return last_logits, cache
-
     def _release_prefix_hit(self, request: GenRequest) -> None:
         """Admission failed/dropped before its slot commit: drop the pin the
         paged lookup took on the shared pages. No-op otherwise."""
@@ -5419,9 +5145,15 @@ class LLMEngineCore:
             self._prefix.unpin_run(pin)
 
     def _commit_admission(self, request: GenRequest, slot: int, first_id: int, mini_cache, first_lp=None) -> None:
-        """Loop-thread-only: route the prefilled KV into the shared cache and
-        activate the slot. Never runs concurrently with a decode chunk."""
-        self._insert_prefill(slot, mini_cache, len(request.prompt_ids), request)
+        """Loop-thread-only: route the prefilled KV into the dense cache's
+        slot row and activate the slot. Never runs concurrently with a
+        decode chunk."""
+        self.cache = self._insert_jit(
+            self.cache,
+            {k: v for k, v in mini_cache.items() if k != "length"},
+            jnp.asarray(len(request.prompt_ids), jnp.int32),  # tpuserve: ignore[TPU601] a scalar operand (the row's length), never a shape
+            slot,
+        )
         self._activate_slot(request, slot, first_id, first_lp)
 
     def _activate_slot(self, request: GenRequest, slot: int, first_id: int,
@@ -5531,94 +5263,6 @@ class LLMEngineCore:
             # loop died between prefill and hand-off: nobody will commit —
             # fail anything stranded in the ready queue (incl. our item)
             self._drain_ready(EngineUnavailableError("engine loop exited"))
-
-    def _insert_prefill(self, slot, mini_cache, n_tokens: int,
-                        request: Optional[GenRequest] = None) -> None:
-        """Route the prefilled prompt KV into the active cache backend."""
-        if self.cache_mode == "paged":
-            hit = request._prefix_hit if request is not None else None
-            page_size = self.paged_cache.pool.page_size
-
-            # loop-thread compile discipline: slice the mini cache with a
-            # DYNAMIC start and a PAGE-MULTIPLE static size, so the eager
-            # slice (and everything _scatter_pages derives from it) compiles
-            # once per (bucket, page-count), not once per token length —
-            # an exact [lo:hi] slice recompiled for every novel prompt/tail
-            # length ON THE COMMIT PATH (measured 80-200 ms stalls of every
-            # active stream under the preemptible lane's arbitrary-length
-            # resume prompts). Rows past `count` land in scatter positions
-            # the slot's length bookkeeping already treats as dead.
-            def _tail(buf, start, count):
-                import jax.lax as lax
-
-                padded = -(-count // page_size) * page_size
-                if start + padded > buf.shape[2]:
-                    # bucket not a page multiple (exotic config): exact
-                    # slice, at per-length compile cost
-                    padded = count
-                return lax.dynamic_slice_in_dim(
-                    buf, jnp.asarray(start, jnp.int32), padded, axis=2
-                )[:, 0]
-
-            # int8 pools: the prefill mini cache already holds quantized K/V
-            # plus per-token scales (the dense kv_quant layout); the scatter
-            # carries the scale stacks [L, S, Hkv] beside the int8 pages
-            def _scales(lo, hi):
-                if not self._paged_quant:
-                    return ()
-                return (
-                    _tail(mini_cache["k_scale"], lo, hi - lo),
-                    _tail(mini_cache["v_scale"], lo, hi - lo),
-                )
-
-            if hit is not None:
-                # prefix-cache hit: shared pages map into the slot's page
-                # table BY REFERENCE (scale rows ride the same page ids);
-                # only the tail's KV (+ scales) is scattered
-                prefix_len = hit["len"]
-                request._prefix_hit = None
-                try:
-                    self.paged_cache.write_prompt_shared(
-                        slot, hit["pages"], prefix_len,
-                        _tail(mini_cache["k"], prefix_len,
-                              n_tokens - prefix_len),
-                        _tail(mini_cache["v"], prefix_len,
-                              n_tokens - prefix_len),
-                        n_tokens,
-                        *_scales(prefix_len, n_tokens),
-                    )
-                finally:
-                    # the slot holds its own refs now; drop the lookup pin
-                    self._prefix.release(hit)
-            else:
-                # mini_cache k/v: [L,1,bucket,Hkv,D] -> stacked [L,S,Hkv,D]
-                self.paged_cache.write_prompt(
-                    slot,
-                    _tail(mini_cache["k"], 0, n_tokens),
-                    _tail(mini_cache["v"], 0, n_tokens),
-                    n_tokens,
-                    *_scales(0, n_tokens),
-                )
-            if self._prefix is not None and request is not None:
-                # zero-copy store: the tree takes references on this slot's
-                # own pages (shared prefix blocks walk existing nodes; only
-                # the newly computed tail blocks add nodes)
-                self._prefix.store_pages(
-                    request.prompt_ids,
-                    self._slot_lora(request),
-                    self.paged_cache.pool.slot_pages(slot),
-                )
-                # disaggregated ship-at-commit (docs/disaggregation.md):
-                # the slot's pages now hold the whole prompt — export the
-                # storable prefix to the destination decode replica
-                self._maybe_ship(request, slot)
-        else:
-            self.cache = self._insert_jit(
-                self.cache,
-                {k: v for k, v in mini_cache.items() if k != "length"},
-                jnp.asarray(n_tokens, jnp.int32),
-                slot,
-            )
 
     def _emit(self, slot: int, token_id: int, lp: dict | None = None) -> None:
         request = self._slot_req[slot]
@@ -5745,7 +5389,7 @@ class LLMEngineCore:
         return greedy, sampled
 
     def _spec_common_args(self, active_mask, spec_mask, sspec_mask, sampling):
-        """Argument tail shared by the dense and paged spec dispatches."""
+        """Argument tail of the dense spec dispatch."""
         use_extras = self._extras_active(active_mask)
         use_guided = bool(np.any(self._gstate[active_mask] >= 0))
         gtables = self._guided_device_tables() if use_guided else None
@@ -5811,109 +5455,6 @@ class LLMEngineCore:
             tokbuf, new_counts, gstate_out, lp, use_extras, gtables
         )
         return np.asarray(gs), np.asarray(accs), np.asarray(pending), lp_np
-
-    def _dispatch_spec_paged_chunk(self, active_mask: np.ndarray, spec_mask,
-                                   sspec_mask, sampling,
-                                   want_lp: bool = False):
-        """Paged-cache speculative dispatch. Pages for the worst-case chunk
-        growth (decode_steps*(k+1) tokens per slot) are allocated up front —
-        accepted counts are a device-side value, so write coordinates must
-        stay dynamic (verify_paged derives them from the page table) — and
-        rolled back to what was actually emitted afterwards
-        (PagePool.truncate). Returns None when the pool cannot hold the
-        over-allocation; the caller falls back to the plain paged chunk for
-        this iteration (sequences truly out of memory then fail there,
-        per-request, not engine-wide)."""
-        if faults.active():
-            faults.fire(
-                "engine.decode.stall",
-                requests=[r for r in self._slot_req if r is not None],
-            )
-        pool = self.paged_cache.pool
-        lengths0 = pool.lengths().copy()
-        extended: List[int] = []
-        for slot in np.nonzero(active_mask)[0]:
-            slot = int(slot)
-            # position-0 plain-path slots keep 1 token/round and only the
-            # last round's draft writes can land past the kept run — they
-            # need rounds+k tokens of headroom, not rounds*(k+1); the
-            # smaller ask avoids whole-batch fallback near pool capacity.
-            # Both speculating classes (greedy chain AND rejection-sampled
-            # chain) can accept drafts, so they take the full slack.
-            slack = (
-                self._spec_slack
-                if (spec_mask[slot] or sspec_mask[slot])
-                else self.decode_steps + self._spec_k
-            )
-            try:
-                pool.extend(slot, slack)
-            except MemoryError:
-                for s in extended:
-                    pool.truncate(s, int(lengths0[s]))
-                return None
-            extended.append(slot)
-        try:
-            self.paged_cache.apply_pending_cow()
-            page_table = pool.page_table(self._pages_per_seq)
-            tail, use_extras, gtables = self._spec_common_args(
-                active_mask, spec_mask, sspec_mask, sampling
-            )
-            with self.paged_cache.dispatch_lock:
-                # pool handles read under the lock: a racing donating dispatch
-                # would invalidate a handle grabbed outside it
-                if self._paged_quant:
-                    cachelike = (
-                        self.paged_cache.k,
-                        self.paged_cache.v,
-                        self.paged_cache.k_scale,
-                        self.paged_cache.v_scale,
-                        jnp.asarray(page_table),
-                        jnp.asarray(lengths0),
-                    )
-                else:
-                    cachelike = (
-                        self.paged_cache.k,
-                        self.paged_cache.v,
-                        jnp.asarray(page_table),
-                        jnp.asarray(lengths0),
-                    )
-                (tokbuf, pending, new_pools, gs, accs, new_counts,
-                 gstate_out, lp) = self._spec_paged_jit(
-                    self.params,
-                    # copies: worker-thread upload of loop-owned host mirrors
-                    # (tpuserve-analyze TPU502)
-                    jnp.asarray(self._tokbuf.copy()),
-                    jnp.asarray(self._next_token.copy()),
-                    cachelike,
-                    *tail,
-                    want_lp=want_lp,
-                    with_sspec=bool(sspec_mask.any()),
-                )
-                self.paged_cache.k = new_pools[0]
-                self.paged_cache.v = new_pools[1]
-                if self._paged_quant:
-                    self.paged_cache.k_scale = new_pools[2]
-                    self.paged_cache.v_scale = new_pools[3]
-            lp_np = self._spec_commit_state(
-                tokbuf, new_counts, gstate_out, lp, use_extras, gtables
-            )
-            gs_np, accs_np = np.asarray(gs), np.asarray(accs)
-            appended = gs_np.shape[0] + accs_np.sum(axis=0)          # [B]
-        except BaseException:
-            # tpuserve-analyze TPU701: the speculative over-allocation must
-            # roll back on EVERY exit — a dispatch failure here would
-            # otherwise strand the slack pages on the surviving slots until
-            # the next retire (slot_len inflated past what was ever
-            # written). The armed ownership ledger audits exactly this.
-            for slot in extended:
-                pool.truncate(slot, int(lengths0[slot]))
-            raise
-        # roll back each slot's over-allocation to the tokens actually
-        # written: rounds*(1 token) + accepted drafts. Must happen BEFORE
-        # emission — _emit frees a finishing slot's pages entirely.
-        for slot in extended:
-            pool.truncate(slot, int(lengths0[slot]) + int(appended[slot]))
-        return gs_np, accs_np, np.asarray(pending), lp_np
 
     # -- ragged scheduler: token-budget admission (docs/ragged_attention.md) --
 
@@ -6385,184 +5926,126 @@ class LLMEngineCore:
         }
         job_of = {job.slot: job for job, _ in shares}
         take_of = {job.slot: take for job, take in shares}
-        if self.cache_mode in ("paged", "state"):
-            from ..ops.paged_attention import ragged_layout
+        from ..ops.paged_attention import ragged_layout
 
-            # tokens each row's cache holds before this launch: the page
-            # pool's slot length, or the state slot's
-            pool = (
-                self.paged_cache.pool
-                if self.paged_cache is not None
-                else None
-            )
-            # layout lens reserve each row's WHOLE window in the flat token
-            # axis (a q=N decode row owns N positions: position 0 rides the
-            # mixed pass, positions 1.. are written by the in-launch chain);
-            # kernel row_lens count only the positions the ragged pass
-            # itself computes
-            span_lens = np.zeros(self.max_batch, np.int32)
-            row_lens = np.zeros(self.max_batch, np.int32)
-            for slot in np.nonzero(decode_mask)[0]:
-                slot = int(slot)
-                if spec_any[slot]:
-                    span_lens[slot] = row_lens[slot] = k_ + 1
-                else:
-                    span_lens[slot] = row_steps[slot]
-                    row_lens[slot] = 1
-            for slot, take in take_of.items():
-                span_lens[slot] = row_lens[slot] = take
-            starts, block_rows, block_q0, tpad = ragged_layout(
-                span_lens, self._ragged_qb, total=self._ragged_tpad
-            )
-            tokens = np.zeros(tpad, np.int32)
-            tok_pos = np.zeros(tpad, np.int32)
-            tok_row = np.zeros(tpad, np.int32)
-            tok_valid = np.zeros(tpad, bool)
-            row_last = np.zeros(self.max_batch, np.int32)
-            kv_lens = np.zeros(self.max_batch, np.int32)
-            pre_lens = np.zeros(self.max_batch, np.int32)
-            spans: Dict[int, tuple] = {}
-            for slot in range(self.max_batch):
-                n = int(span_lens[slot])
-                if n == 0:
-                    continue
-                s = int(starts[slot])
-                v = int(row_lens[slot])
-                pre = (
-                    pool.slot_length(slot)
-                    if pool is not None
-                    else self.state_cache.length(slot)
-                )
-                pre_lens[slot] = pre
-                if slot in job_of:
-                    job = job_of[slot]
-                    tokens[s : s + n] = job.request.prompt_ids[
-                        job.pos : job.pos + n
-                    ]
-                elif spec_any[slot]:
-                    tokens[s] = self._next_token[slot]
-                    tokens[s + 1 : s + n] = drafts[slot]
-                else:
-                    tokens[s] = self._next_token[slot]
-                spans[slot] = (s, n)
-                if tree_depths is not None and spec_any[slot]:
-                    # a tree node's ABSOLUTE position is its path depth,
-                    # not its node index: sibling drafts at the same depth
-                    # share a RoPE position, and the accepted path's K/V
-                    # (compacted in-launch to positions pre+1..pre+acc)
-                    # was embedded at exactly those positions
-                    tok_pos[s : s + n] = pre + tree_depths[slot, :n]
-                else:
-                    tok_pos[s : s + n] = pre + np.arange(n, dtype=np.int32)
-                tok_row[s : s + n] = slot
-                # reserved multi-step positions stay invalid in the mixed
-                # pass: their tokens are sampled in-launch and their K/V
-                # written by the chained decode steps
-                tok_valid[s : s + v] = True
-                row_last[slot] = s + v - 1
-                kv_lens[slot] = pre + v
-            if tree_parents is not None and n_spec:
-                # flat per-token ancestor lists for the kernel's tree mask
-                # (ops.paged_attention.tree_ancestors layout): every
-                # non-tree token keeps the -2 plain-causal sentinel
-                from ..ops.paged_attention import tree_ancestors
-
-                tree_anc = np.full((tpad, k_ + 1), -1, np.int32)
-                tree_anc[:, 0] = -2
-                for slot in np.nonzero(spec_any)[0]:
-                    slot = int(slot)
-                    s = int(starts[slot])
-                    tree_anc[s : s + k_ + 1] = tree_ancestors(
-                        tree_parents[slot], int(tree_n[slot]),
-                        width=k_ + 1,
-                    )
-                plan["tree_anc"] = tree_anc
-            if n_spec:
-                row_logit_idx = np.zeros(
-                    (self.max_batch, k_ + 1), np.int32
-                )
-                for slot in range(self.max_batch):
-                    if row_lens[slot] > 0:
-                        row_logit_idx[slot] = starts[slot] + np.minimum(
-                            np.arange(k_ + 1), row_lens[slot] - 1
-                        )
+        # tokens each row's cache holds before this launch: the page
+        # pool's slot length, or the state slot's
+        pool = (
+            self.paged_cache.pool
+            if self.paged_cache is not None
+            else None
+        )
+        # layout lens reserve each row's WHOLE window in the flat token
+        # axis (a q=N decode row owns N positions: position 0 rides the
+        # mixed pass, positions 1.. are written by the in-launch chain);
+        # kernel row_lens count only the positions the ragged pass
+        # itself computes
+        span_lens = np.zeros(self.max_batch, np.int32)
+        row_lens = np.zeros(self.max_batch, np.int32)
+        for slot in np.nonzero(decode_mask)[0]:
+            slot = int(slot)
+            if spec_any[slot]:
+                span_lens[slot] = row_lens[slot] = k_ + 1
             else:
-                row_logit_idx = None
-            plan.update(
-                tokens=tokens, tok_pos=tok_pos, tok_row=tok_row,
-                tok_valid=tok_valid, row_last=row_last, kv_lens=kv_lens,
-                pre_lens=pre_lens, row_starts=starts, row_lens=row_lens,
-                span_lens=span_lens, spans=spans,
-                # state cache: a row whose tokens start its sequence finds
-                # its slot as the last owner left it — the launch zeroes it
-                row_reset=(pre_lens == 0) & (row_lens > 0),
-                row_logit_idx=row_logit_idx,
-                write_page=np.zeros(tpad, np.int32),
-                write_offset=np.zeros(tpad, np.int32),
-                block_rows=(
-                    jnp.asarray(block_rows) if self._ragged_kernel else None
-                ),
-                block_q0=(
-                    jnp.asarray(block_q0) if self._ragged_kernel else None
-                ),
+                span_lens[slot] = row_steps[slot]
+                row_lens[slot] = 1
+        for slot, take in take_of.items():
+            span_lens[slot] = row_lens[slot] = take
+        starts, block_rows, block_q0, tpad = ragged_layout(
+            span_lens, self._ragged_qb, total=self._ragged_tpad
+        )
+        tokens = np.zeros(tpad, np.int32)
+        tok_pos = np.zeros(tpad, np.int32)
+        tok_row = np.zeros(tpad, np.int32)
+        tok_valid = np.zeros(tpad, bool)
+        row_last = np.zeros(self.max_batch, np.int32)
+        kv_lens = np.zeros(self.max_batch, np.int32)
+        pre_lens = np.zeros(self.max_batch, np.int32)
+        spans: Dict[int, tuple] = {}
+        for slot in range(self.max_batch):
+            n = int(span_lens[slot])
+            if n == 0:
+                continue
+            s = int(starts[slot])
+            v = int(row_lens[slot])
+            pre = (
+                pool.slot_length(slot)
+                if pool is not None
+                else self.state_cache.length(slot)
             )
-        else:
-            # dense ragged: the rectangular chunk layout [B, C] — C buckets
-            # to the next power of two of the widest chunk so traces stay
-            # bounded (log2(budget) shapes per variant). Decode rows keep a
-            # 1-token chunk (their multi-step window chains through
-            # bundle.decode in the same launch); spec rows carry the whole
-            # k+1 candidate chain.
-            c_need = max([take for _, take in shares], default=1)
-            if n_spec:
-                c_need = max(c_need, k_ + 1)
-            c = 1
-            while c < c_need:
-                c *= 2
-            tokens = np.zeros((self.max_batch, c), np.int32)
-            start = np.zeros(self.max_batch, np.int32)
-            last_rel = np.zeros(self.max_batch, np.int32)
-            row_active = np.zeros(self.max_batch, bool)
-            for slot in np.nonzero(decode_mask)[0]:
-                slot = int(slot)
-                request = self._slot_req[slot]
-                tokens[slot, 0] = self._next_token[slot]
-                if spec_any[slot]:
-                    tokens[slot, 1 : k_ + 1] = drafts[slot]
-                    last_rel[slot] = k_
-                # dense cache length = prompt_len + produced - 1 (the
-                # pending token's KV is written by the step consuming it)
-                start[slot] = request.prompt_len + request.produced - 1
-                row_active[slot] = True
-            for job, take in shares:
-                tokens[job.slot, :take] = job.request.prompt_ids[
-                    job.pos : job.pos + take
+            pre_lens[slot] = pre
+            if slot in job_of:
+                job = job_of[slot]
+                tokens[s : s + n] = job.request.prompt_ids[
+                    job.pos : job.pos + n
                 ]
-                start[job.slot] = job.pos
-                last_rel[job.slot] = take - 1
-                row_active[job.slot] = True
-            if n_spec:
-                row_logit_idx = np.minimum(
-                    np.arange(k_ + 1)[None, :], last_rel[:, None]
-                ).astype(np.int32)
-                plan["row_logit_idx"] = row_logit_idx
+            elif spec_any[slot]:
+                tokens[s] = self._next_token[slot]
+                tokens[s + 1 : s + n] = drafts[slot]
             else:
-                plan["row_logit_idx"] = None
-            for job in self._prefill_jobs:
-                if not row_active[job.slot]:
-                    # budget-starved job rows still get their garbage chunk
-                    # window WRITTEN (the dense layer loop writes every
-                    # row): pin it to job.pos so it lands where the job's
-                    # next chunk overwrites it before any read — at the
-                    # default start=0 it would clobber already-written
-                    # prompt KV. (In-order whole-budget serving currently
-                    # implies a starved job has pos == 0, but correctness
-                    # must not hang on that scheduling subtlety.)
-                    start[job.slot] = job.pos
-            plan.update(
-                tokens=tokens, start=start, last_rel=last_rel,
-                row_active=row_active, chunk=c,
+                tokens[s] = self._next_token[slot]
+            spans[slot] = (s, n)
+            if tree_depths is not None and spec_any[slot]:
+                # a tree node's ABSOLUTE position is its path depth,
+                # not its node index: sibling drafts at the same depth
+                # share a RoPE position, and the accepted path's K/V
+                # (compacted in-launch to positions pre+1..pre+acc)
+                # was embedded at exactly those positions
+                tok_pos[s : s + n] = pre + tree_depths[slot, :n]
+            else:
+                tok_pos[s : s + n] = pre + np.arange(n, dtype=np.int32)
+            tok_row[s : s + n] = slot
+            # reserved multi-step positions stay invalid in the mixed
+            # pass: their tokens are sampled in-launch and their K/V
+            # written by the chained decode steps
+            tok_valid[s : s + v] = True
+            row_last[slot] = s + v - 1
+            kv_lens[slot] = pre + v
+        if tree_parents is not None and n_spec:
+            # flat per-token ancestor lists for the kernel's tree mask
+            # (ops.paged_attention.tree_ancestors layout): every
+            # non-tree token keeps the -2 plain-causal sentinel
+            from ..ops.paged_attention import tree_ancestors
+
+            tree_anc = np.full((tpad, k_ + 1), -1, np.int32)
+            tree_anc[:, 0] = -2
+            for slot in np.nonzero(spec_any)[0]:
+                slot = int(slot)
+                s = int(starts[slot])
+                tree_anc[s : s + k_ + 1] = tree_ancestors(
+                    tree_parents[slot], int(tree_n[slot]),
+                    width=k_ + 1,
+                )
+            plan["tree_anc"] = tree_anc
+        if n_spec:
+            row_logit_idx = np.zeros(
+                (self.max_batch, k_ + 1), np.int32
             )
+            for slot in range(self.max_batch):
+                if row_lens[slot] > 0:
+                    row_logit_idx[slot] = starts[slot] + np.minimum(
+                        np.arange(k_ + 1), row_lens[slot] - 1
+                    )
+        else:
+            row_logit_idx = None
+        plan.update(
+            tokens=tokens, tok_pos=tok_pos, tok_row=tok_row,
+            tok_valid=tok_valid, row_last=row_last, kv_lens=kv_lens,
+            pre_lens=pre_lens, row_starts=starts, row_lens=row_lens,
+            span_lens=span_lens, spans=spans,
+            # state cache: a row whose tokens start its sequence finds
+            # its slot as the last owner left it — the launch zeroes it
+            row_reset=(pre_lens == 0) & (row_lens > 0),
+            row_logit_idx=row_logit_idx,
+            write_page=np.zeros(tpad, np.int32),
+            write_offset=np.zeros(tpad, np.int32),
+            block_rows=(
+                jnp.asarray(block_rows) if self._ragged_kernel else None
+            ),
+            block_q0=(
+                jnp.asarray(block_q0) if self._ragged_kernel else None
+            ),
+        )
         if faults.active():
             # yield-point seam parity with _prepare_dispatch: snapshot
             # complete, worker not yet started
@@ -6746,10 +6229,11 @@ class LLMEngineCore:
                 if self._paged_quant:
                     self.paged_cache.k_scale = new_ks
                     self.paged_cache.v_scale = new_vs
-        elif self.cache_mode == "state":
-            # a launch's plan carries (slot = row, reset) per row in place
-            # of page tables and write coordinates; nothing is allocated
-            # here (admission gave the row its slot) and nothing can run out
+        else:
+            # state cache: a launch's plan carries (slot = row, reset) per
+            # row in place of page tables and write coordinates; nothing is
+            # allocated here (admission gave the row its slot) and nothing
+            # can run out
             chain_arrays = None
             if launch_steps > 1:
                 chain_arrays = (
@@ -6791,45 +6275,12 @@ class LLMEngineCore:
             # retire, its slot zeroed for the next owner)
             for slot, (_s, n) in plan["spans"].items():
                 cache.advance(slot, n)
-        else:
-            chain_arrays = None
-            if launch_steps > 1:
-                chain_arrays = (
-                    plan["step_rngs"],
-                    jnp.asarray(plan["chain_mask"].copy()),
-                )
-            (
-                sampled, logits, self.cache, new_counts, lp, gstate_out,
-                spec_g, spec_acc,
-            ) = self._ragged_dense_jit(
-                self.params,
-                jnp.asarray(plan["tokens"]),
-                jnp.asarray(plan["start"]),
-                jnp.asarray(plan["last_rel"]),
-                jnp.asarray(plan["row_active"]),
-                self.cache,
-                jnp.asarray(plan["decode_mask"].copy()),
-                plan["sampling"],
-                plan["rng"],
-                plan["lora"],
-                plan["extras"],
-                self._counts_dev if use_extras else None,
-                self._pmask_dev if use_extras else None,
-                gtables,
-                plan["gstate"],
-                want_lp=want_lp,
-                spec=_spec_arrays(),
-                chain=chain_arrays,
-            )
         if use_extras:
             self._counts_dev = new_counts
         # finishing-row logit gather: keep only rows whose admission
         # completes this step (minus any the pool-exhaustion path dropped)
         # — the [R, vocab] matrix never crosses the device boundary
-        finish = [
-            s for s in plan["finish_slots"]
-            if self.cache_mode == "dense" or s in plan["spans"]
-        ]
+        finish = [s for s in plan["finish_slots"] if s in plan["spans"]]
         if finish:
             pad = 1 << (len(finish) - 1).bit_length()
             rows = np.zeros(pad, np.int32)
@@ -7012,7 +6463,7 @@ class LLMEngineCore:
         decode_slots = [int(s) for s in np.nonzero(plan["decode_mask"])[0]]
         plain_slots = [s for s in decode_slots if not spec_any[s]]
         spec_slots = [s for s in decode_slots if spec_any[s]]
-        if spec_slots and self.cache_mode == "paged":
+        if spec_slots:
             # roll each verify row's over-allocation back to the tokens the
             # acceptance actually kept (pending + accepted drafts). BEFORE
             # emission: _emit frees a finishing slot's pages entirely. A
@@ -7932,50 +7383,35 @@ class LLMEngineCore:
         sampling = self._batch_sampling()
         # draft-and-verify rounds: device work off-loop, emission on
         # the loop thread like the plain path
-        if self.cache_mode == "paged":
-            res = await asyncio.to_thread(
-                self._dispatch_spec_paged_chunk,
-                active_mask, spec_mask, sspec_mask, sampling,
-                want_lp,
-            )
-        else:
-            res = await asyncio.to_thread(
-                self._dispatch_spec_chunk,
-                active_mask, spec_mask, sspec_mask, sampling,
-                want_lp,
-            )
+        gs, accs, pending, lp_np = await asyncio.to_thread(
+            self._dispatch_spec_chunk,
+            active_mask, spec_mask, sspec_mask, sampling,
+            want_lp,
+        )
         if epoch != self._recover_epoch:
             await self._finish_recovery()
             return
-        if res is not None:
-            gs, accs, pending, lp_np = res
-            for r in range(gs.shape[0]):
-                for slot in np.nonzero(active_mask)[0]:
-                    slot = int(slot)
-                    for i in range(int(accs[r, slot]) + 1):
-                        entry = None
-                        if (
-                            lp_np is not None
-                            and i == 0
-                            and not spec_mask[slot]
-                            and not sspec_mask[slot]
-                        ):
-                            chosen, top_id, top_lp = lp_np
-                            entry = {
-                                "id": int(gs[r, slot, 0]),
-                                "logprob": float(chosen[r, slot]),
-                                "top_ids": top_id[r, slot].tolist(),
-                                "top_logprobs": top_lp[r, slot].tolist(),
-                            }
-                        self._emit(slot, int(gs[r, slot, i]), entry)
+        for r in range(gs.shape[0]):
             for slot in np.nonzero(active_mask)[0]:
-                self._next_token[slot] = int(pending[slot])
-            if self._prefill_gate is not None:
-                self._prefill_gate.deposit()
-            self._last_progress = time.monotonic()
-            return
-        # paged pool couldn't hold the speculative over-allocation: run one
-        # plain (serial) chunk for this iteration instead
-        await self._dispatch_or_recover(active_mask.copy(), epoch)
-        if self._inflight:
-            await self._retire_oldest()
+                slot = int(slot)
+                for i in range(int(accs[r, slot]) + 1):
+                    entry = None
+                    if (
+                        lp_np is not None
+                        and i == 0
+                        and not spec_mask[slot]
+                        and not sspec_mask[slot]
+                    ):
+                        chosen, top_id, top_lp = lp_np
+                        entry = {
+                            "id": int(gs[r, slot, 0]),
+                            "logprob": float(chosen[r, slot]),
+                            "top_ids": top_id[r, slot].tolist(),
+                            "top_logprobs": top_lp[r, slot].tolist(),
+                        }
+                    self._emit(slot, int(gs[r, slot, i]), entry)
+        for slot in np.nonzero(active_mask)[0]:
+            self._next_token[slot] = int(pending[slot])
+        if self._prefill_gate is not None:
+            self._prefill_gate.deposit()
+        self._last_progress = time.monotonic()

@@ -691,14 +691,6 @@ class PagedKVCache:
             chunks = jnp.moveaxis(chunks, 0, 2)          # [L, Hkv, NP, P(, D)]
             return pool.at[:, :, pages].set(chunks)
 
-        def _write_token(pool, kv, page, offset):
-            # kv [L, Hkv, D] -> pool[:, :, page, offset]; scale pools drop
-            # the trailing D (kv [L, Hkv] -> [L, Hkv, N, P] pool)
-            idx = (0, 0, page, offset) + (0,) * (pool.ndim - 4)
-            return jax.lax.dynamic_update_slice(
-                pool, kv[:, :, None, None], idx
-            )
-
         def _copy_page(pool, src, dst):
             # copy-on-write: duplicate one page inside the pool (src read,
             # dst written, one fused donated program — no host round trip)
@@ -718,7 +710,6 @@ class PagedKVCache:
             return pool.at[:, :, dsts].set(pool[:, :, srcs])
 
         self._write_pages = jax.jit(_write_pages, donate_argnums=(0,))
-        self._write_token = jax.jit(_write_token, donate_argnums=(0,))
         self._copy_page = jax.jit(_copy_page, donate_argnums=(0,))
         self._copy_pages = jax.jit(_copy_pages, donate_argnums=(0,))
 
@@ -1048,105 +1039,6 @@ class PagedKVCache:
             )
         if not self.kv_quant and (k_scales is not None or v_scales is not None):
             raise ValueError("scale operands given but the pools are not int8")
-
-    def _scatter_pages(self, pages: List[int], k_stack, v_stack,
-                       k_scales=None, v_scales=None) -> None:
-        """Scatter token KV (stacked [L, S, Hkv, D], S <= len(pages)*P) into
-        the given pages via the donated jitted page write. int8 pools also
-        take the per-token scales ([L, S, Hkv]) for the same positions."""
-        import jax.numpy as jnp
-
-        self._require_scales(k_scales, v_scales)
-        page_size = self.pool.page_size
-        n_pages = len(pages)
-        pad_to = n_pages * page_size
-
-        def to_chunks(stack, ndim5):
-            # [L, S, Hkv(, D)] -> [NP, L, Hkv, P(, D)]
-            hm = jnp.moveaxis(jnp.asarray(stack), 2, 1)   # [L, Hkv, S(, D)]
-            pad = ((0, 0), (0, 0), (0, pad_to - hm.shape[2]))
-            if ndim5:
-                pad = pad + ((0, 0),)
-            hm = jnp.pad(hm, pad)
-            shape = hm.shape[:2] + (n_pages, page_size) + hm.shape[3:]
-            perm = (2, 0, 1, 3, 4) if ndim5 else (2, 0, 1, 3)
-            return hm.reshape(shape).transpose(perm)
-
-        k_chunks = to_chunks(k_stack, True)
-        v_chunks = to_chunks(v_stack, True)
-        # page-multiple key space: one trace per page COUNT (the commit
-        # path already rounds through pool.pages_needed, and llm/warmup.py
-        # compiles counts 1..N before the serve fence)
-        page_ids = jnp.asarray(pages, jnp.int32)  # tpuserve: ignore[TPU601] page-count-keyed, warmup-covered
-        with self.dispatch_lock:
-            self.k = self._write_pages(self.k, k_chunks, page_ids)
-            self.v = self._write_pages(self.v, v_chunks, page_ids)
-            if self.kv_quant:
-                self.k_scale = self._write_pages(
-                    self.k_scale, to_chunks(k_scales, False), page_ids
-                )
-                self.v_scale = self._write_pages(
-                    self.v_scale, to_chunks(v_scales, False), page_ids
-                )
-
-    def write_prompt(self, slot: int, k_stack, v_stack, length: int,
-                     k_scales=None, v_scales=None) -> None:
-        """Scatter a prefilled prompt's KV (stacked [L, S, Hkv, D]) into this
-        slot's pages via donated jitted writes (plus [L, S, Hkv] scales on
-        int8 pools)."""
-        self.pool.free(slot)
-        # the pages ride the slot's table from here; a failed admission
-        # frees the slot in the engine (cross-function pairing the
-        # ownership ledger audits at drain)
-        self.pool.allocate(slot, length)  # tpuserve: ignore[TPU701] pages ride the slot table
-        self._scatter_pages(
-            self.pool.slot_pages(slot), k_stack, v_stack, k_scales, v_scales
-        )
-
-    def write_prompt_shared(
-        self, slot: int, shared_pages: List[int], prefix_len: int,
-        k_tail, v_tail, length: int,
-        k_scales_tail=None, v_scales_tail=None,
-    ) -> None:
-        """Prefix-cache hit admission: map ``shared_pages`` (holding the
-        first ``prefix_len`` tokens, page-aligned) into the slot's page table
-        BY REFERENCE — zero KV copies for the shared run (on int8 pools the
-        shared pages' scale rows come along for free: same page ids) — then
-        scatter only the tail's KV ([L, length - prefix_len, Hkv, D], plus
-        tail scales on int8 pools) into freshly allocated pages."""
-        if prefix_len % self.pool.page_size:
-            raise ValueError(
-                "shared prefix length {} is not page-aligned".format(prefix_len)
-            )
-        self.pool.free(slot)
-        self.pool.map_shared(slot, shared_pages, prefix_len)  # tpuserve: ignore[TPU701] pages ride the slot table
-        tail_pages = self.pool.allocate(slot, length)  # tpuserve: ignore[TPU701] pages ride the slot table
-        if tail_pages:
-            self._scatter_pages(
-                tail_pages, k_tail, v_tail, k_scales_tail, v_scales_tail
-            )
-
-    def append_token(self, slot: int, k_token, v_token,
-                     k_scale=None, v_scale=None) -> None:
-        """Append one token's KV (stacked [L, Hkv, D]; [L, Hkv] scales on
-        int8 pools) to the slot."""
-        import jax.numpy as jnp
-
-        self._require_scales(k_scale, v_scale)
-        length = self.pool.slot_length(slot)
-        self.pool.extend(slot, 1)  # tpuserve: ignore[TPU701] pages ride the slot table
-        self.apply_pending_cow()
-        ((page, offset),) = self.pool.token_coords(slot, length, 1)
-        with self.dispatch_lock:
-            self.k = self._write_token(self.k, jnp.asarray(k_token), page, offset)
-            self.v = self._write_token(self.v, jnp.asarray(v_token), page, offset)
-            if self.kv_quant:
-                self.k_scale = self._write_token(
-                    self.k_scale, jnp.asarray(k_scale), page, offset
-                )
-                self.v_scale = self._write_token(
-                    self.v_scale, jnp.asarray(v_scale), page, offset
-                )
 
 
 class StateCache:

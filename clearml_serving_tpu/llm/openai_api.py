@@ -333,10 +333,10 @@ class LLMEngineRequest(BaseEngineRequest):
                 else None
             ),
             # ragged token-budget scheduler (docs/ragged_attention.md):
-            # aux engine.scheduler = "ragged" puts chunked prefill and
-            # decode in one launch per step, paced by
-            # engine.step_token_budget; unset defers to TPUSERVE_SCHEDULER
-            # (constructor validates values at ENDPOINT LOAD)
+            # engine.cache decides it (paged and state run it, paced by
+            # engine.step_token_budget; dense runs the two-dispatch loop).
+            # aux engine.scheduler is no choice: a value that contradicts
+            # the cache is refused by the constructor at ENDPOINT LOAD
             scheduler=engine_cfg.get("scheduler"),
             step_token_budget=(
                 int(engine_cfg["step_token_budget"])

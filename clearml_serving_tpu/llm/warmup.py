@@ -22,11 +22,13 @@ to appear here, so a new dispatch-path jit entry cannot land without
 either a warmup extension or an explicit role reclassification.
 
 What the sweep enumerates (derived from engine attributes, never
-hard-coded): cold prefill per bucket; radix-hit gather + tail chunk per
+hard-coded): cold prefill per bucket; radix-hit assemble + tail chunk per
 bucket; every resume-commit final-segment length 1..block per hit bucket
-(preempted histories resume with arbitrary tails); cold-commit scatters at
-every page count up to the largest bucket; multi-segment tails (partially
-evicted prefixes replay tails longer than one block); power-of-two CoW
+(preempted histories resume with arbitrary tails); multi-segment tails
+(partially evicted prefixes replay tails longer than one block) - the
+bucketed sweep is the dense engine's compile surface, a ragged engine
+(paged, state) runs the same requests through the one static shape of its
+step; power-of-two CoW
 copy buckets (and, on int8 pools, their scale-row copies); the ragged
 finish-row gathers at every power of two; a spec-decode round when
 speculation is on. Coverage assumption, stated plainly: the sweep warms
@@ -40,17 +42,17 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 # The engine jit entries whose compile keys the sweep drives (conditional
-# on the engine's configuration: a dense engine has no paged entries to
-# warm, a two-dispatch engine no ragged ones). Parsed from source by
-# analyze/rules_compile.py (TPU603) — MUST stay a literal; the analyzer's
-# build-time mirror is consistency-tested in tests/test_analyze_compile.py.
+# on the engine's configuration: a dense engine has no paged or ragged
+# entries to warm, a paged or state engine no prefill programs). Parsed
+# from source by analyze/rules_compile.py (TPU603) — MUST stay a literal;
+# the analyzer's build-time mirror is consistency-tested in
+# tests/test_analyze_compile.py.
 WARMUP_COVERED = frozenset({
     "_prefill_jit",
     "_prefill_ring_jit",
     "_prefill_pipeline_jit",
     "_prefill_chunk_first_jit",
     "_prefill_chunk_jit",
-    "_gather_pages_jit",
     "_assemble_prefix_jit",
     "_insert_jit",
     "_merge_rows_jit",
@@ -60,9 +62,7 @@ WARMUP_COVERED = frozenset({
     "_first_lp_jit",
     "_set_sampling_row_jit",
     "_spec_chunk_jit",
-    "_spec_paged_jit",
     "_ragged_paged_jit",
-    "_ragged_dense_jit",
     "_ragged_state_jit",
     "_gather_finish_jit",
 })
@@ -97,7 +97,6 @@ def warmup_plan(engine, full: bool = True) -> List[Dict[str, Any]]:
         buckets.append(engine.max_seq_len)
     prefix = engine._prefix
     block = prefix.block if prefix is not None else 0
-    paged = engine.paged_cache is not None
     plan: List[Dict[str, Any]] = []
 
     def req(ids: List[int], max_new: int = 2) -> None:
@@ -137,38 +136,6 @@ def warmup_plan(engine, full: bool = True) -> List[Dict[str, Any]]:
         for t in range(1, block + 1):
             req(head + _tail(t, t, vocab))
 
-    # 2b) resume-commit tails, multi-page: the commit slices the mini
-    # cache with a DYNAMIC start and a PAGE-MULTIPLE static size
-    # (engine._insert_prefill._tail), so its key space is (mini-cache
-    # bucket, padded tail pages) — and eviction can shorten a stored run
-    # to ANY block-multiple depth, which makes EVERY (bucket, k*page)
-    # pair reachable at serve time (the strict sentry caught exactly the
-    # missing (128, 2-page) pair during this sweep's own development).
-    # A stored head's trie path contains all its block-aligned prefixes,
-    # so head[:p'] + a fresh tail forces each pair deliberately.
-    if paged:
-        page = engine.paged_cache.pool.page_size
-        for b in buckets:
-            p_b = bucket_prefix_len(b)
-            if p_b < block:
-                continue
-            head = _ids(b, p_b, vocab)
-            for k in range(2, (b - block) // page + 1):
-                p_prime = ((b - k * page) // block) * block
-                if p_prime < block or p_prime > p_b:
-                    continue
-                tail_len = (k - 1) * page + 1
-                req(head[:p_prime] + _tail(200 + b + k, tail_len, vocab))
-
-    # 3) cold-commit scatter at every page count: the page-bucketed commit
-    # write compiles once per page COUNT (kv_cache._scatter_pages)
-    if paged:
-        page = engine.paged_cache.pool.page_size
-        for n_pages in range(1, engine.paged_cache.pool.pages_needed(
-                buckets[-1]) + 1):
-            n = n_pages * page - min(3, page - 1)
-            req(_ids(67 + n_pages, n, vocab))
-
     # 4) multi-segment tails: when eviction shortened a stored run, a hit
     # replays a tail LONGER than one block — non-final chunk segments
     # (with_logits=False) are a distinct trace per bucket
@@ -201,8 +168,8 @@ def warm_ragged_variants(engine) -> int:
     in :func:`run_warmup`. Returns the number of launches run. Operand
     construction mirrors ``engine._dispatch_ragged_device_inner`` one for
     one (dtype-strong numpy uploads, same None-ness per variant); every
-    scatter lands in the dead null page / a frozen dense position, so the
-    pools/cache round-trip through the donated call value-unchanged."""
+    scatter lands in the dead null page (no state slot is touched), so
+    the pools round-trip through the donated call value-unchanged."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -307,7 +274,7 @@ def warm_ragged_variants(engine) -> int:
                         cache.v_scale = new_vs
                 jax.block_until_ready(sampled)
                 ran += 1
-    elif getattr(engine, "state_cache", None) is not None:
+    else:
         # state cache (docs/state_cache.md): the flat token axis is one
         # static size and there are no spec rows, so the decode window is
         # the only compile key. Null rows (row_lens 0, chain masks False)
@@ -344,50 +311,6 @@ def warm_ragged_variants(engine) -> int:
                 )
             jax.block_until_ready(sampled)
             ran += 1
-    else:
-        # dense ragged: the rectangular chunk width C is its own compile
-        # key (pow2 of the widest row — admission takes up to the budget),
-        # so the full certification sweeps every reachable width per
-        # (window, spec) variant; spec variants start at the k+1-wide
-        # chunks serve guarantees them
-        from .shapes import pow2_bucket
-
-        widths = []
-        c = 1
-        cap = pow2_bucket(engine._step_token_budget)
-        while c <= cap:
-            widths.append(c)
-            c *= 2
-        for steps in windows:
-            for spec_on in spec_opts:
-                chain = None
-                if steps > 1:
-                    chain = (
-                        jnp.stack([key() for _ in range(steps - 1)]),
-                        jnp.asarray(np.zeros((steps - 1, b), bool)),
-                    )
-                for c in widths:
-                    if spec_on and c < k_ + 1:
-                        continue
-                    (
-                        sampled, _logits, engine.cache,
-                        _counts, _lp, _gs, _sg, _sa,
-                    ) = engine._ragged_dense_jit(
-                        engine.params,
-                        jnp.asarray(np.zeros((b, c), np.int32)),
-                        jnp.asarray(np.zeros(b, np.int32)),
-                        jnp.asarray(np.zeros(b, np.int32)),
-                        jnp.asarray(np.zeros(b, bool)),
-                        engine.cache,
-                        jnp.asarray(np.zeros(b, bool)),
-                        sampling, key(), lora,
-                        None, None, None, None, None,
-                        want_lp=False,
-                        spec=spec_args(spec_on),
-                        chain=chain,
-                    )
-                    jax.block_until_ready(sampled)
-                    ran += 1
     return ran
 
 

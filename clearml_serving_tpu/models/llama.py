@@ -1710,47 +1710,6 @@ def build(config: dict) -> SimpleNamespace:
             return ((logits, gathered),) + pools
         return (logits,) + pools
 
-    def forward_ragged_dense(params, tokens, start, last_rel, row_active,
-                             cache, lora_idx=None, *, logit_rel=None):
-        """Dense-cache ragged step (docs/ragged_attention.md): the mixed
-        batch takes the RECTANGULAR chunk layout — tokens [B, C] where
-        decode rows carry one real token, spec-verify rows a known
-        draft chain (k+1 tokens), prefill rows a prompt chunk, and
-        idle rows garbage their frozen length masks. Each row's chunk
-        writes at its own absolute positions (the chunked-prefill layer
-        loop) and attends causally over its slot's cache; logits return at
-        ``last_rel`` — plus, when ``logit_rel`` [B, W] is given, the
-        spec-verify gather at the W requested chunk-relative positions per
-        row (``(last [B, vocab], gathered [B, W, vocab])``; the last-token
-        path stays byte-for-byte the default one) — and lengths advance
-        only where ``row_active`` (a spec caller re-clamps verify rows'
-        lengths to the accepted prefix itself, the :func:`verify`
-        contract)."""
-        b, c = tokens.shape
-        ffn_valid = (
-            jnp.arange(c, dtype=jnp.int32)[None] <= last_rel[:, None]
-        ) & row_active[:, None]
-        x, new_kv = _cached_chunk_layers(
-            params, tokens, start, cache, ffn_kwargs={"valid": ffn_valid},
-            lora_idx=lora_idx,
-        )
-        new_len = jnp.maximum(
-            cache["length"], start + last_rel + 1
-        ).astype(jnp.int32)
-        cache = dict(
-            new_kv, length=jnp.where(row_active, new_len, cache["length"])
-        )
-        last_x = jnp.take_along_axis(
-            x, last_rel[:, None, None].clip(0, c - 1), axis=1
-        )                                                      # [B, 1, dim]
-        last = _logits(params, last_x)[:, 0]                   # [B, vocab]
-        if logit_rel is not None:
-            sel_x = jnp.take_along_axis(
-                x, logit_rel[:, :, None].clip(0, c - 1), axis=1
-            )                                                  # [B, W, dim]
-            return (last, _logits(params, sel_x)), cache
-        return last, cache
-
     # -- power retention over the state cache (docs/state_cache.md) ----------
     #
     # The second implementer of the engine's cache contract: a sequence owns
@@ -1964,7 +1923,6 @@ def build(config: dict) -> SimpleNamespace:
         # ragged mixed prefill+decode step (docs/ragged_attention.md): the
         # engine's token-budget scheduler drives one of these per iteration
         forward_ragged=forward_ragged,
-        forward_ragged_dense=forward_ragged_dense,
         # power retention (attention="power_retention"): served from the
         # engine's state cache only (engine.cache=state)
         attention=attention,

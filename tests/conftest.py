@@ -54,3 +54,34 @@ def state_root(tmp_path):
     os.environ["TPUSERVE_STATE_ROOT"] = str(root)
     yield root
     os.environ.pop("TPUSERVE_STATE_ROOT", None)
+
+
+def fill_pages(cache, slot, k, v, k_scale=None, v_scale=None, *, append=False):
+    """Test reference for putting token K/V into a slot's pages, for tests
+    of what READS a pool (kernels, tiers, shipment): ``k``/``v`` are stacked
+    [L, S, Hkv, D] (scales [L, S, Hkv] on int8 pools) and land at the
+    coordinates ``pool.token_coords`` names, by plain ``.at[].set``. The
+    slot is started anew, or with ``append`` grown by S tokens. The serving
+    path never fills pages from the host: its launches write them."""
+    import jax.numpy as jnp
+
+    pool = cache.pool
+    n = int(k.shape[1])
+    if append:
+        start = pool.slot_length(slot)
+        pool.extend(slot, n)
+        cache.apply_pending_cow()
+    else:
+        start = 0
+        pool.free(slot)
+        pool.allocate(slot, n)
+    pages, offsets = (
+        jnp.asarray(c) for c in zip(*pool.token_coords(slot, start, n))
+    )
+    for name, rows in (("k", k), ("v", v),
+                       ("k_scale", k_scale), ("v_scale", v_scale)):
+        if rows is not None:
+            buf = getattr(cache, name)
+            setattr(cache, name, buf.at[:, :, pages, offsets].set(
+                jnp.moveaxis(jnp.asarray(rows, buf.dtype), 1, 2)
+            ))

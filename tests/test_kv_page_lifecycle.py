@@ -9,6 +9,7 @@ rollback (truncate) never strands or double-frees shared pages.
 
 import numpy as np
 import pytest
+from conftest import fill_pages
 
 from clearml_serving_tpu.llm.kv_cache import PagedKVCache, PagePool
 from clearml_serving_tpu.llm.kv_sanitizer import (
@@ -165,7 +166,7 @@ def test_paged_kv_cache_cow_copies_device_page():
     pool = cache.pool
     # write a 6-token prompt (2 pages, tail half full)
     k = np.arange(6 * 2, dtype=np.float32).reshape(1, 6, 1, 2)
-    cache.write_prompt(0, k, k * 10.0, 6)
+    fill_pages(cache, 0, k, k * 10.0)
     pages = pool.slot_pages(0)
     pool.ref_pages([pages[1]])            # share the tail page
     pool.extend(0, 1)
@@ -179,37 +180,6 @@ def test_paged_kv_cache_cow_copies_device_page():
     np.testing.assert_array_equal(
         np.asarray(cache.v[0, 0, new_tail]), np.asarray(cache.v[0, 0, pages[1]])
     )
-
-
-def test_write_prompt_shared_scatters_only_tail():
-    """write_prompt_shared maps the prefix by reference and scatters only
-    the tail KV; the shared pages' contents are untouched."""
-    cache = PagedKVCache(
-        n_layers=1, n_kv_heads=1, head_dim=2,
-        num_pages=8, page_size=4, max_slots=2, dtype="float32",
-    )
-    pool = cache.pool
-    k = np.arange(8 * 2, dtype=np.float32).reshape(1, 8, 1, 2)
-    cache.write_prompt(0, k, k, 8)
-    shared = pool.slot_pages(0)
-    pool.ref_pages(shared)  # "cache" keeps them
-    before = np.asarray(cache.k[0, 0, shared[0]]).copy()
-    tail = 100.0 + np.arange(3 * 2, dtype=np.float32).reshape(1, 3, 1, 2)
-    cache.write_prompt_shared(1, shared, 8, tail, tail, 11)
-    assert pool.slot_pages(1)[:2] == shared
-    assert len(pool.slot_pages(1)) == 3
-    np.testing.assert_array_equal(np.asarray(cache.k[0, 0, shared[0]]), before)
-    own = pool.slot_pages(1)[2]
-    np.testing.assert_array_equal(
-        np.asarray(cache.k[0, 0, own, :3]), tail[0, :, 0]
-    )
-    # misaligned prefix refused (would put live writes inside shared pages)
-    cache2 = PagedKVCache(
-        n_layers=1, n_kv_heads=1, head_dim=2,
-        num_pages=8, page_size=4, max_slots=2, dtype="float32",
-    )
-    with pytest.raises(ValueError):
-        cache2.write_prompt_shared(0, [1], 3, tail, tail, 6)
 
 
 # -- int8 pools: a page and its scale rows share one lifecycle ----------------
@@ -234,7 +204,7 @@ def test_int8_cow_copies_scale_rows_with_the_page():
     k = np.clip(np.arange(6 * 2, dtype=np.float32), 0, 126).reshape(1, 6, 1, 2)
     k_q = k.astype(np.int8)
     k_s = (0.25 + np.arange(6, dtype=np.float32)).reshape(1, 6, 1)
-    cache.write_prompt(0, k_q, k_q, 6, k_s, k_s * 2.0)
+    fill_pages(cache, 0, k_q, k_q, k_s, k_s * 2.0)
     pages = pool.slot_pages(0)
     pool.ref_pages([pages[1]])            # share the tail page
     pool.extend(0, 1)
@@ -251,32 +221,6 @@ def test_int8_cow_copies_scale_rows_with_the_page():
     np.testing.assert_array_equal(
         np.asarray(cache.v_scale[0, 0, new_tail]),
         np.asarray(cache.v_scale[0, 0, pages[1]]),
-    )
-
-
-def test_int8_write_prompt_shared_scatters_tail_scales():
-    """Shared-prefix admission on int8 pools: prefix scale rows ride the
-    shared page ids untouched; only the tail's scales scatter."""
-    cache = _int8_cache()
-    pool = cache.pool
-    k = np.arange(8 * 2, dtype=np.float32).reshape(1, 8, 1, 2).astype(np.int8)
-    s = (1.0 + np.arange(8, dtype=np.float32)).reshape(1, 8, 1)
-    cache.write_prompt(0, k, k, 8, s, s)
-    shared = pool.slot_pages(0)
-    pool.ref_pages(shared)
-    before = np.asarray(cache.k_scale[0, 0, shared[0]]).copy()
-    tail = np.full((1, 3, 1, 2), 7, np.int8)
-    tail_s = np.full((1, 3, 1), 0.5, np.float32)
-    # int8 pools refuse a shared-tail scatter without its scales
-    with pytest.raises(ValueError):
-        cache.write_prompt_shared(1, shared, 8, tail, tail, 11)
-    cache.write_prompt_shared(1, shared, 8, tail, tail, 11, tail_s, tail_s)
-    np.testing.assert_array_equal(
-        np.asarray(cache.k_scale[0, 0, shared[0]]), before
-    )
-    own = pool.slot_pages(1)[2]
-    np.testing.assert_array_equal(
-        np.asarray(cache.k_scale[0, 0, own, :3]), tail_s[0, :, 0]
     )
 
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import fill_pages
 
 from clearml_serving_tpu import models
 from clearml_serving_tpu.llm import faults
@@ -85,11 +86,7 @@ def _fill_slot(pc, slot, tokens, seed=0):
     v = rng.integers(-100, 100, (L, tokens, H, D)).astype(np.int8)
     ks = rng.random((L, tokens, H)).astype(np.float32)
     vs = rng.random((L, tokens, H)).astype(np.float32)
-    pc.pool.allocate(slot, tokens)
-    pc._scatter_pages(
-        pc.pool.slot_pages(slot), jnp.asarray(k), jnp.asarray(v),
-        jnp.asarray(ks), jnp.asarray(vs),
-    )
+    fill_pages(pc, slot, k, v, ks, vs)
 
 
 def test_demote_promote_pages_byte_identical():
@@ -142,8 +139,7 @@ def test_bf16_pools_tier_without_scales():
     k = jnp.arange(L * S * H * D, dtype=jnp.float32).reshape(
         L, S, H, D
     ).astype(jnp.bfloat16)
-    pc.pool.allocate(0, 9)
-    pc._scatter_pages(pc.pool.slot_pages(0), k, k + 1)
+    fill_pages(pc, 0, k, k + 1)
     ids = list(range(9))
     cache.store_pages(ids, 0, pc.pool.slot_pages(0))
     before = np.asarray(
@@ -443,22 +439,19 @@ def _gen(engine, prompt, n=8, **req_kw):
 PROMPT = [(7 * i + 3) % 100 + 1 for i in range(40)]  # 2 cached blocks
 
 
-@pytest.mark.parametrize("scheduler", ["two_dispatch", "ragged"])
 @pytest.mark.parametrize("depth", [1, 2])
-def test_demoted_warm_hit_streams_byte_identical(parts, scheduler, depth):
+def test_demoted_warm_hit_streams_byte_identical(parts, depth):
     """ACCEPTANCE: a demoted-then-promoted prefix run produces streams
-    byte-identical to an always-resident warm hit — greedy, int8 KV, both
-    schedulers, pipeline depth 1 and 2, armed sanitizer."""
+    byte-identical to an always-resident warm hit — greedy, int8 KV,
+    pipeline depth 1 and 2, armed sanitizer."""
     bundle, params = parts
-    control = _engine(bundle, params, scheduler=scheduler,
-                      pipeline_depth=depth)
+    control = _engine(bundle, params, pipeline_depth=depth)
     _gen(control, PROMPT)
     resident = _gen(control, PROMPT)
     assert control._prefix.stats()["hits_by_tier"]["hbm"] >= 1
     control.stop()
 
-    tiered = _engine(bundle, params, host_pages=16, scheduler=scheduler,
-                     pipeline_depth=depth)
+    tiered = _engine(bundle, params, host_pages=16, pipeline_depth=depth)
     _gen(tiered, PROMPT)
     assert tiered._prefix.spill(0) == 2
     promoted = _gen(tiered, PROMPT)

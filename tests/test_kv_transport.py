@@ -11,6 +11,7 @@ import asyncio
 import jax
 import numpy as np
 import pytest
+from conftest import fill_pages
 
 from clearml_serving_tpu import models
 from clearml_serving_tpu.errors import HostTierAutoSizeError
@@ -149,11 +150,11 @@ def _fill_slot(pc, slot, tokens, seed=0):
         v = rng.integers(-100, 100, shape).astype(np.int8)
         ks = rng.random(shape[:-1], np.float32)
         vs = rng.random(shape[:-1], np.float32)
-        pc.write_prompt(slot, k, v, tokens, ks, vs)
+        fill_pages(pc, slot, k, v, ks, vs)
     else:
         k = rng.random(shape, np.float32)
         v = rng.random(shape, np.float32)
-        pc.write_prompt(slot, k, v, tokens)
+        fill_pages(pc, slot, k, v)
 
 
 @pytest.mark.parametrize("kv_quant", ["int8", ""])

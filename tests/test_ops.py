@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import fill_pages
 
 from clearml_serving_tpu.llm.kv_cache import PagePool, PagedKVCache
 from clearml_serving_tpu.ops import paged_attention as pa
@@ -212,13 +213,13 @@ def test_paged_kv_cache_roundtrip():
          for li in range(2)]
     )
     v_stack = k_stack + 100
-    cache.write_prompt(0, k_stack, v_stack, length)
+    fill_pages(cache, 0, k_stack, v_stack)
     assert cache.pool.slot_length(0) == 6
 
     # append one token: [L, Hkv, D]
     k_new = jnp.stack([jnp.full((2, 8), 7.0 + li) for li in range(2)])
     v_new = k_new + 2
-    cache.append_token(0, k_new, v_new)
+    fill_pages(cache, 0, k_new[:, None], v_new[:, None], append=True)
     assert cache.pool.slot_length(0) == 7
 
     # reconstruct the sequence from pages and compare (layer 0)
@@ -253,16 +254,11 @@ def test_paged_kv_cache_int8_roundtrip():
 
     k_q, k_s = store(k_src)
     v_q, v_s = store(v_src)
-    # scale operands are mandatory on int8 pools
-    with pytest.raises(ValueError):
-        cache.write_prompt(0, k_q, v_q, length)
-    cache.write_prompt(0, k_q, v_q, length, k_s, v_s)
+    fill_pages(cache, 0, k_q, v_q, k_s, v_s)
 
     k_tok = rng.normal(size=(2, 2, 8)).astype(np.float32)
-    kt_q, kt_s = store(k_tok[:, None])  # [L,1,Hkv,D] -> squeeze below
-    cache.append_token(
-        0, kt_q[:, 0], kt_q[:, 0], kt_s[:, 0], kt_s[:, 0]
-    )
+    kt_q, kt_s = store(k_tok[:, None])  # [L,1,Hkv,D]
+    fill_pages(cache, 0, kt_q, kt_q, kt_s, kt_s, append=True)
     assert cache.pool.slot_length(0) == 7
 
     table = cache.pool.page_table(cache.max_pages_per_seq(16))

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import fill_pages
 
 from clearml_serving_tpu import models
 from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
@@ -40,7 +41,7 @@ def test_decode_paged_matches_dense(tiny):
             params, tokens[slot:slot + 1, :16][:, : mini["k"].shape[2]],
             jnp.asarray([n], jnp.int32), mini,
         )
-        cache.write_prompt(slot, filled["k"][:, 0, :n], filled["v"][:, 0, :n], n)
+        fill_pages(cache, slot, filled["k"][:, 0, :n], filled["v"][:, 0, :n])
 
     next_tokens = jnp.argmax(last_dense, axis=-1).astype(jnp.int32)
     pool = cache.pool
@@ -156,11 +157,16 @@ def test_paged_engine_concurrent(tiny):
     assert engine.paged_cache.pool.free_pages == engine.paged_cache.pool.num_pages - 1
 
 
+def _verify_rows(engine) -> int:
+    """Speculative verify rows the engine's ragged launches have carried."""
+    return engine.lifecycle_stats()["ragged"]["step_rows"]["spec_verify"]
+
+
 def test_paged_speculative_matches_plain_paged(tiny):
-    """Speculation over the paged cache (verify_paged + over-allocate /
-    truncate) is greedy-EXACT: outputs are token-identical to the plain
-    paged engine — drafts hitting (repetitive prompt) and missing alike —
-    and every over-allocated page rolls back to the pool."""
+    """Speculation over the paged cache (verify rows of the ragged step:
+    over-allocate / truncate) is greedy-EXACT: outputs are token-identical
+    to the plain paged engine — drafts hitting (repetitive prompt) and
+    missing alike — and every over-allocated page rolls back to the pool."""
     bundle, params = tiny
     prompts = [
         [256] + [10, 20, 30, 10, 20, 30, 10, 20],   # repetitive: drafts hit
@@ -176,19 +182,12 @@ def test_paged_speculative_matches_plain_paged(tiny):
         bundle, params, cache_mode="paged", page_size=4,
         speculation="ngram", spec_k=3, spec_ngram=2, **common,
     )
-    dispatches = [0]
-    orig = spec._spec_paged_jit
-
-    def counting(*a, **k):
-        dispatches[0] += 1
-        return orig(*a, **k)
-
-    spec._spec_paged_jit = counting
     for p in prompts:
         r_plain = _collect(plain, GenRequest(prompt_ids=p, max_new_tokens=24))
         r_spec = _collect(spec, GenRequest(prompt_ids=p, max_new_tokens=24))
         assert r_plain == r_spec, (p, r_plain, r_spec)
-    assert dispatches[0] > 0, "paged speculative path never dispatched"
+    assert _verify_rows(spec) > 0, "no verify row ever rode a launch"
+    assert _verify_rows(plain) == 0
     # truncate + finish-free bookkeeping: no page leaked
     assert spec.paged_cache.pool.free_pages == spec.paged_cache.pool.num_pages - 1
 
@@ -230,9 +229,9 @@ def test_paged_speculative_mixed_batch(tiny):
 
 
 def test_paged_speculative_pool_slack_fallback(tiny):
-    """When the pool cannot hold the speculative over-allocation, the
-    dispatch declines (returns None) and the iteration falls back to the
-    plain paged chunk — requests still complete with exact greedy output."""
+    """A pool with no room for the serial scan's slack (decode_steps * (k+1)
+    tokens a slot) still serves verify rows, which over-allocate k+1 tokens
+    for one launch and roll back: exact greedy output, nothing leaked."""
     bundle, params = tiny
     common = dict(max_batch=1, max_seq_len=64, prefill_buckets=[16],
                   eos_token_id=257, decode_steps=3)
@@ -242,28 +241,19 @@ def test_paged_speculative_pool_slack_fallback(tiny):
                           **common)
     want = _collect(plain, GenRequest(prompt_ids=p, max_new_tokens=8))
 
-    # pool: 5 usable pages = 20 tokens — enough for the 6-token prompt plus
-    # every plain chunk (max length 6+3*3=15 => 4 pages), but NOT for the
-    # spec slack (6 + decode_steps*(k+1)=18 => 24 tokens => 6 pages)
+    # pool: 5 usable pages = 20 tokens — enough for the 6-token prompt, the
+    # 8 new tokens and one verify row's k+1 = 6 on top (at most 19), but
+    # NOT for 6 + decode_steps*(k+1) = 24 tokens => 6 pages
     spec = LLMEngineCore(
         bundle, params, cache_mode="paged", page_size=4,
         speculation="ngram", spec_k=5,
         num_pages=6,
         **common,
     )
-    declines = [0]
-    orig = spec._dispatch_spec_paged_chunk
-
-    def counting(*a, **k):
-        res = orig(*a, **k)
-        if res is None:
-            declines[0] += 1
-        return res
-
-    spec._dispatch_spec_paged_chunk = counting
     got = _collect(spec, GenRequest(prompt_ids=p, max_new_tokens=8))
     assert got == want
-    assert declines[0] > 0, "undersized pool never triggered the fallback"
+    assert _verify_rows(spec) > 0, "no verify row ever rode a launch"
+    assert spec.paged_cache.pool.free_pages == spec.paged_cache.pool.num_pages - 1
 
 
 def test_paged_pool_exhaustion_fails_only_that_request(tiny):
@@ -302,8 +292,8 @@ def test_paged_pool_exhaustion_fails_only_that_request(tiny):
 
 def test_paged_sampled_speculation(tiny):
     """Rejection-sampled speculation over the paged cache: a temperature>0
-    request alone drives the spec dispatch, completes the full budget, and
-    the over-allocated pages roll back (pool fully free afterwards)."""
+    request alone rides verify rows, completes the full budget, and the
+    over-allocated pages roll back (pool fully free afterwards)."""
     bundle, params = tiny
     engine = LLMEngineCore(
         bundle, params, cache_mode="paged", page_size=4,
@@ -311,18 +301,10 @@ def test_paged_sampled_speculation(tiny):
         max_batch=2, max_seq_len=64, prefill_buckets=[16],
         eos_token_id=None, decode_steps=2,
     )
-    dispatches = [0]
-    orig = engine._spec_paged_jit
-
-    def counting(*a, **k):
-        dispatches[0] += 1
-        return orig(*a, **k)
-
-    engine._spec_paged_jit = counting
     out = _collect(engine, GenRequest(
         prompt_ids=[256, 5, 6, 5, 6], max_new_tokens=12, temperature=0.9))
     assert len(out) == 12
-    assert dispatches[0] > 0, "sampled-only paged batch skipped the chain"
+    assert _verify_rows(engine) > 0, "sampled-only paged batch skipped the chain"
     assert engine.paged_cache.pool.free_pages == (
         engine.paged_cache.pool.num_pages - 1
     )

@@ -376,15 +376,15 @@ def test_paged_hit_physically_shares_pages(parts):
     assert stats["cached_pages"] >= 32 // 4
 
     captured = {}
-    orig = engine.paged_cache.write_prompt_shared
+    orig = pool.map_shared
 
-    def spy(slot, shared_pages, prefix_len, k_tail, v_tail, length):
+    def spy(slot, shared_pages, prefix_len):
         captured["pages"] = list(shared_pages)
         captured["prefix_len"] = prefix_len
         captured["slot"] = slot
-        return orig(slot, shared_pages, prefix_len, k_tail, v_tail, length)
+        return orig(slot, shared_pages, prefix_len)
 
-    engine.paged_cache.write_prompt_shared = spy
+    pool.map_shared = spy
     _gen(engine, system + [100, 101, 102])  # hit -> maps shared pages
     assert captured, "paged hit never took the zero-copy mapping path"
     assert captured["prefix_len"] == 32

@@ -1,12 +1,10 @@
 """Ragged scheduler engine tests (docs/ragged_attention.md): byte-identity
-of the token-budget single-launch scheduler against the legacy two-dispatch
-path (greedy + seeded, dense + paged, int8 KV, pipeline depths), prefix
-cache / speculation composition, chaos behavior mid-ragged-dispatch, and
-the committed ``bench.py --ragged-ab`` CPU smoke artifact."""
+of the paged cache's token-budget single-launch scheduler against the dense
+cache's two-dispatch loop (greedy + seeded, int8 KV, pipeline depths),
+prefix cache / speculation composition, and chaos behavior
+mid-ragged-dispatch."""
 
 import asyncio
-import json
-import pathlib
 
 import jax
 import pytest
@@ -15,8 +13,6 @@ from clearml_serving_tpu import models
 from clearml_serving_tpu.errors import EngineOverloadedError
 from clearml_serving_tpu.llm import faults
 from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
-
-REPO = pathlib.Path(__file__).resolve().parents[1]
 
 CFG = {"preset": "llama-tiny", "dtype": "float32"}
 QCFG = dict(CFG, kv_quant="int8")
@@ -67,16 +63,18 @@ def _staggered(engine, prompts, n=8, seeds=None):
 
 def _ab(bundle, params, prompts, *, seeds=None, n=8, legacy_kw=None,
         ragged_kw=None, **common):
-    """(legacy streams, ragged streams) for the same staggered workload.
-    The legacy arm chunks EVERY prompt (chunk below the shortest prompt):
-    under kv_quant, full prefill attends live precision while chunked
-    prefill reads back what it quantized — different caches by design —
-    and the ragged scheduler is a chunked path by construction."""
-    legacy = _engine(bundle, params, chunked_prefill_size=4,
+    """(legacy streams, ragged streams) for the same staggered workload:
+    the dense cache's two-dispatch loop against the paged cache's ragged
+    step. The legacy arm chunks EVERY prompt (chunk below the shortest
+    prompt): under kv_quant, full prefill attends live precision while
+    chunked prefill reads back what it quantized — different caches by
+    design — and the ragged scheduler is a chunked path by construction."""
+    legacy = _engine(bundle, params, cache_mode="dense",
+                     chunked_prefill_size=4,
                      **{**common, **(legacy_kw or {})})
     a = _staggered(legacy, prompts, n=n, seeds=seeds)
     legacy.stop()
-    ragged = _engine(bundle, params, scheduler="ragged",
+    ragged = _engine(bundle, params, cache_mode="paged",
                      step_token_budget=12, **{**common, **(ragged_kw or {})})
     b = _staggered(ragged, prompts, n=n, seeds=seeds)
     stats = ragged.lifecycle_stats()
@@ -84,47 +82,34 @@ def _ab(bundle, params, prompts, *, seeds=None, n=8, legacy_kw=None,
     return a, b, stats
 
 
-def test_ragged_ab_dense_greedy_and_seeded(parts, monkeypatch):
+def test_ragged_ab_paged_greedy_seeded_depth2(parts, monkeypatch):
     """One mixed batch carries a GREEDY decode stream (row 0, seed None)
-    and a SEEDED temperature>0 admission (row 1) — both must replay the
-    two-dispatch arm exactly, serial pipeline."""
+    and a SEEDED temperature>0 admission (row 1). At pipeline depth 2 the
+    ragged phases drain the in-flight queue and reset the device chains;
+    both streams still replay the dense two-dispatch arm exactly (depth 1
+    is covered by the int8 cells below)."""
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     bundle, _, params = parts
-    a, b, stats = _ab(bundle, params, [SHORT, LONG], seeds=[None, 22],
-                      cache_mode="dense", legacy_kw={"pipeline_depth": 1},
-                      ragged_kw={"pipeline_depth": 1})
+    a, b, stats = _ab(
+        bundle, params, [SHORT, LONG], seeds=[None, 22],
+        legacy_kw={"pipeline_depth": 2},
+        ragged_kw={"pipeline_depth": 2},
+    )
     assert a == b
     assert stats["ragged"]["steps"] >= 2           # chunked admission ran
     assert stats["ragged"]["step_rows"]["prefill"] >= 2
     assert stats["ragged"]["step_rows"]["decode"] >= 1  # mixed launches
 
 
-def test_ragged_ab_paged_greedy_seeded_depth2(parts, monkeypatch):
-    """Paged backend at pipeline depth 2: ragged phases drain the
-    in-flight queue and reset the device chains; greedy + seeded streams
-    still replay the two-dispatch arm exactly (depth 1 is covered by the
-    dense cell above and the int8 cells below)."""
-    monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
-    bundle, _, params = parts
-    a, b, _ = _ab(
-        bundle, params, [SHORT, LONG], seeds=[None, 22],
-        cache_mode="paged",
-        legacy_kw={"pipeline_depth": 2},
-        ragged_kw={"pipeline_depth": 2},
-    )
-    assert a == b
-
-
 def test_ragged_ab_int8_kv(parts, monkeypatch):
     """int8 KV through the ragged path: chunk K/V quantize via the same
     _kv_store math and the ragged kernel/reference dequantizes like the
-    decode path — streams match the (fully chunked) two-dispatch arm on
-    BOTH backends."""
+    decode path — streams match the (fully chunked) dense two-dispatch
+    arm."""
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     _, qbundle, params = parts
-    for cache_mode in ("dense", "paged"):
-        a, b, _ = _ab(qbundle, params, [SHORT, LONG], cache_mode=cache_mode)
-        assert a == b, cache_mode
+    a, b, _ = _ab(qbundle, params, [SHORT, LONG])
+    assert a == b
 
 
 def test_ragged_prefix_cache_tail_chunks(parts, monkeypatch):
@@ -134,7 +119,7 @@ def test_ragged_prefix_cache_tail_chunks(parts, monkeypatch):
     exactly, under the armed KV sanitizer, leak-free."""
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     bundle, _, params = parts
-    plain = _engine(bundle, params, cache_mode="paged",
+    plain = _engine(bundle, params, cache_mode="dense",
                     chunked_prefill_size=4, max_seq_len=160)
     want = _staggered(plain, [LONG], n=6)
     plain.stop()
@@ -201,46 +186,42 @@ def test_ragged_multistep_byte_identity(parts, monkeypatch):
     """Multi-step decode rows (ISSUE 13 tentpole): q=decode_steps windows
     chain sampled tokens device-side inside ONE mixed launch. Greedy +
     seeded streams at ragged window ∈ {2, 4} equal the q=1 ragged streams
-    AND the legacy two-dispatch streams exactly — dense + paged, armed
+    AND the dense two-dispatch streams exactly — pipeline depth 2, armed
     sanitizer."""
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     bundle, _, params = parts
-    for cache_mode, depth in (("dense", 1), ("paged", 2)):
-        legacy = _engine(bundle, params, chunked_prefill_size=4,
-                         cache_mode=cache_mode, pipeline_depth=depth,
-                         decode_steps=4)
-        want = _overlapped(legacy)
-        legacy.stop()
-        for q in (1, 2, 4):
-            ragged = _engine(bundle, params, scheduler="ragged",
-                             step_token_budget=24, cache_mode=cache_mode,
-                             pipeline_depth=depth, decode_steps=4,
-                             ragged_decode_steps=q)
-            got = _overlapped(ragged)
-            stats = ragged.lifecycle_stats()["ragged"]
-            ragged.stop()
-            assert got == want, (cache_mode, depth, q)
-            if q > 1:
-                # the window actually engaged: some launch advanced a
-                # decode row by more than one token
-                snap = stats["tokens_per_launch"]
-                assert snap["count"] >= 1, (cache_mode, q)
-                assert snap["sum_ms"] > snap["count"], (cache_mode, q)
+    legacy = _engine(bundle, params, chunked_prefill_size=4,
+                     cache_mode="dense", pipeline_depth=2, decode_steps=4)
+    want = _overlapped(legacy)
+    legacy.stop()
+    for q in (1, 2, 4):
+        ragged = _engine(bundle, params, step_token_budget=24,
+                         cache_mode="paged", pipeline_depth=2,
+                         decode_steps=4, ragged_decode_steps=q)
+        got = _overlapped(ragged)
+        stats = ragged.lifecycle_stats()["ragged"]
+        ragged.stop()
+        assert got == want, q
+        if q > 1:
+            # the window actually engaged: some launch advanced a
+            # decode row by more than one token
+            snap = stats["tokens_per_launch"]
+            assert snap["count"] >= 1, q
+            assert snap["sum_ms"] > snap["count"], q
 
 
 def test_ragged_multistep_int8_kv(parts, monkeypatch):
     """int8 KV through multi-step windows: the chained steps quantize each
     token's K/V via the same _kv_store math as the q=1 path — streams
-    match the fully-chunked two-dispatch arm on both backends."""
+    match the fully-chunked dense two-dispatch arm."""
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     _, qbundle, params = parts
-    for cache_mode in ("dense", "paged"):
-        a, b, _ = _ab(
-            qbundle, params, [SHORT, LONG], cache_mode=cache_mode,
-            legacy_kw={"decode_steps": 4},
-            ragged_kw={"decode_steps": 4, "ragged_decode_steps": 4},
-        )
-        assert a == b, cache_mode
+    a, b, _ = _ab(
+        qbundle, params, [SHORT, LONG],
+        legacy_kw={"decode_steps": 4},
+        ragged_kw={"decode_steps": 4, "ragged_decode_steps": 4},
+    )
+    assert a == b
 
 
 def test_ragged_multistep_logprobs(parts):
@@ -279,13 +260,13 @@ def test_ragged_multistep_logprobs(parts):
 
 
 def test_spec_as_row_matches_legacy_spec(parts):
-    """Spec-as-row reproduces the legacy serial spec path's accepted
-    streams (greedy): the two-dispatch engine's draft-verify scan and the
-    ragged engine's in-launch verify rows emit identical tokens, and the
-    ragged engine never touches the serial scan path."""
+    """Spec-as-row reproduces the serial spec path's accepted streams
+    (greedy): the dense engine's draft-verify scan and the paged engine's
+    in-launch verify rows emit identical tokens, and the paged engine
+    never touches the serial scan path."""
     bundle, _, params = parts
     prompts = [[5, 9, 2, 17, 5, 9, 2], [3, 3, 7, 3, 3, 7, 3]]
-    legacy = _engine(bundle, params, cache_mode="paged",
+    legacy = _engine(bundle, params, cache_mode="dense",
                      chunked_prefill_size=4, speculation="ngram",
                      spec_k=2, spec_ngram=2)
     want = _staggered(legacy, prompts, n=10)
@@ -299,7 +280,6 @@ def test_spec_as_row_matches_legacy_spec(parts):
             "legacy serial spec scan ran under the ragged scheduler"
         )
 
-    ragged._dispatch_spec_paged_chunk = boom
     ragged._dispatch_spec_chunk = boom
     got = _staggered(ragged, prompts, n=10)
     stats = ragged.lifecycle_stats()["ragged"]
@@ -311,21 +291,21 @@ def test_spec_as_row_matches_legacy_spec(parts):
 def test_ragged_decode_steps_validation(parts):
     bundle, _, params = parts
     with pytest.raises(ValueError, match="ragged_decode_steps"):
-        _engine(bundle, params, scheduler="ragged", step_token_budget=16,
+        _engine(bundle, params, cache_mode="paged", step_token_budget=16,
                 decode_steps=2, ragged_decode_steps=8)
 
 
 def test_ragged_budget_validation(parts):
     bundle, _, params = parts
     with pytest.raises(ValueError, match="step_token_budget"):
-        _engine(bundle, params, scheduler="ragged", step_token_budget=2)
+        _engine(bundle, params, cache_mode="paged", step_token_budget=2)
     with pytest.raises(ValueError, match="scheduler"):
         _engine(bundle, params, scheduler="nope")
 
 
 def test_ragged_health_and_stats_blocks(parts):
     bundle, _, params = parts
-    engine = _engine(bundle, params, scheduler="ragged", step_token_budget=16)
+    engine = _engine(bundle, params, cache_mode="paged", step_token_budget=16)
     try:
         assert engine._prefill_gate is None  # the gate is REPLACED
         h = engine.health()
@@ -651,7 +631,6 @@ def test_ragged_retire_reads_back_only_finishing_rows(parts, monkeypatch):
 
     monkeypatch.setattr(LLMEngineCore, "_dispatch_ragged_device", spy)
     a, b, stats = _ab(bundle, params, [SHORT, LONG], seeds=[None, 22],
-                      cache_mode="paged",
                       legacy_kw={"pipeline_depth": 1},
                       ragged_kw={"pipeline_depth": 1})
     assert a == b, "streams must stay byte-identical under the gather"
@@ -668,46 +647,3 @@ def test_ragged_retire_reads_back_only_finishing_rows(parts, monkeypatch):
         # gather is at most 2 rows
         assert shape[1] == vocab
         assert shape[0] <= 2
-
-
-# -- committed CPU smoke artifact -------------------------------------------
-
-def test_ragged_ab_artifact_schema():
-    """benchmarks/RAGGED_AB_cpu.json (committed by ``bench.py --ragged-ab``)
-    carries the acceptance headlines: byte-identical streams across
-    schedulers and decode-stall-during-admission STRICTLY below the
-    two-dispatch arm (ISSUE 9), plus the ISSUE-13 arms — the
-    ``--decode-steps`` q=1-vs-q=4 A/B (dispatches-per-decode-token < 0.5
-    at q=4, tok/s no worse than q=1, identical streams) and spec-as-row
-    vs the legacy serial scan (identical streams, acceptance measured)."""
-    path = REPO / "benchmarks" / "RAGGED_AB_cpu.json"
-    row = json.loads(path.read_text())
-    assert row["metric"] == "llm_ragged_scheduler_ab_cpusmoke"
-    assert row["identical_tokens"] is True
-    assert (
-        row["ragged"]["decode_stall_ms"]
-        < row["two_dispatch"]["decode_stall_ms"]
-    )
-    for arm in ("two_dispatch", "ragged"):
-        assert row[arm]["tok_s"] > 0
-        assert row[arm]["admit_ttft_ms"] > 0
-        assert row[arm]["ttft_p99_ms"] >= row[arm]["ttft_p50_ms"]
-        assert 0 < row[arm]["occupancy"] <= row["batch"]
-    # ISSUE 13: multi-step decode rows kill the per-launch decode bubble
-    ds = row["decode_steps_ab"]
-    q = ds["decode_steps"]
-    assert ds["identical_tokens"] is True
-    assert ds["q{}".format(q)]["dispatches_per_decode_token"] < 0.5
-    assert (
-        ds["q{}".format(q)]["dispatches_per_decode_token"]
-        < ds["q1"]["dispatches_per_decode_token"]
-    )
-    assert ds["q{}".format(q)]["tok_s"] >= ds["q1"]["tok_s"]
-    # ISSUE 13: spec rides mixed launches as verify rows — stream
-    # identity with the legacy serial scan is the certified property
-    # (the CPU tok/s comparison is reference-path-bound by construction;
-    # see run_spec_row_ab's docstring)
-    sr = row["spec_row_ab"]
-    assert sr["identical_tokens"] is True
-    assert sr["spec_as_row"]["spec_verify_rows"] >= 1
-    assert 0 <= sr["spec_as_row"]["acceptance_mean"] <= 1

@@ -584,7 +584,7 @@ def test_brownout_stage3_shrinks_ragged_step_token_budget(parts):
     engine = LLMEngineCore(
         bundle, params, max_batch=2, max_seq_len=64, prefill_buckets=[16],
         eos_token_id=None, brownout=True, brownout_dwell=120.0,
-        scheduler="ragged", step_token_budget=128,
+        cache_mode="paged", step_token_budget=128,
     )
     try:
         assert engine._prefill_gate is None  # the gate is gone in ragged mode
@@ -733,5 +733,77 @@ def test_brownout_stage3_still_sets_gate_budget_on_two_dispatch(parts):
         engine._brownout_checked = 0.0
         engine._update_brownout()
         assert gate._spc == 3
+    finally:
+        engine.stop()
+
+
+# -- the cache kind decides the scheduler --------------------------------------
+
+_RETENTION_CFG = dict(
+    vocab_size=97, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16,
+    ffn_dim=96, scan_layers=True, dtype="float32",
+    attention="power_retention", retention_degree=2, qk_norm=True,
+    norm_eps=1e-6, rope_theta=1e6, max_seq_len=512,
+)
+
+
+@pytest.fixture(scope="module")
+def parts_of(parts):
+    """(bundle, params) that a cache kind can serve: the softmax model of
+    ``parts`` on dense and paged, a power-retention model on state."""
+    retention = models.build_model("llama", _RETENTION_CFG)
+    state = (retention, retention.init(jax.random.PRNGKey(3)))
+    return {"dense": parts, "paged": parts, "state": state}
+
+
+def _kind_engine(parts_of, cache_mode, **kw):
+    bundle, params = parts_of[cache_mode]
+    return LLMEngineCore(
+        bundle, params, max_batch=2, max_seq_len=64, prefill_buckets=[16],
+        eos_token_id=None, cache_mode=cache_mode, **kw,
+    )
+
+
+@pytest.mark.parametrize("cache_mode,scheduler", [
+    ("dense", "two_dispatch"), ("paged", "ragged"), ("state", "ragged"),
+])
+def test_cache_kind_decides_the_scheduler(parts_of, cache_mode, scheduler):
+    """No ``scheduler`` argument: the engine runs, and reports, the cache
+    kind's own; naming that same scheduler changes nothing."""
+    for kw in ({}, {"scheduler": scheduler}):
+        engine = _kind_engine(parts_of, cache_mode, **kw)
+        try:
+            assert engine.health()["scheduler"] == scheduler
+            assert engine.lifecycle_stats()["scheduler"] == scheduler
+            ragged = scheduler == "ragged"
+            assert (engine._prefill_gate is None) == ragged
+            assert (engine.lifecycle_stats()["ragged"] is not None) == ragged
+        finally:
+            engine.stop()
+
+
+@pytest.mark.parametrize("cache_mode,scheduler", [
+    ("paged", "two_dispatch"), ("state", "two_dispatch"), ("dense", "ragged"),
+])
+def test_scheduler_that_contradicts_the_cache_is_refused(
+        parts_of, cache_mode, scheduler):
+    with pytest.raises(ValueError) as err:
+        _kind_engine(parts_of, cache_mode, scheduler=scheduler)
+    message = str(err.value)
+    assert "cache={} runs the".format(cache_mode) in message
+    assert "scheduler={!r} exists only on cache=".format(scheduler) in message
+
+
+def test_scheduler_environment_variable_is_gone(parts_of, monkeypatch):
+    """TPUSERVE_SCHEDULER chose the scheduler once; it is read no more."""
+    monkeypatch.setenv("TPUSERVE_SCHEDULER", "two_dispatch")
+    engine = _kind_engine(parts_of, "paged")
+    try:
+        assert engine.health()["scheduler"] == "ragged"
+        out = asyncio.run(_collect(
+            engine, GenRequest(prompt_ids=[1, 2, 3], max_new_tokens=3)
+        ))
+        assert len(out) == 3
+        assert engine.counters["ragged_steps"] >= 1
     finally:
         engine.stop()

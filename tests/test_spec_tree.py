@@ -459,8 +459,8 @@ def test_spec_tree_engine_requires_ngram_and_paged(eparts):
     with pytest.raises(ValueError, match="spec_tree"):
         _engine(bundle, params, spec_tree=True)
     with pytest.raises(ValueError, match="spec_tree"):
-        _engine(bundle, params, cache_mode="dense", speculation="ngram",
-                spec_k=2, spec_ngram=2, spec_tree=True)
+        _engine(bundle, params, cache_mode="dense", scheduler=None,
+                speculation="ngram", spec_k=2, spec_ngram=2, spec_tree=True)
 
 
 def test_spec_tree_engine_greedy_three_arm_identity(eparts, monkeypatch):

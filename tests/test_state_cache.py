@@ -262,7 +262,9 @@ def test_preemption_frees_the_slot_and_recompute_reproduces_the_stream(parts):
     (dict(prefix_cache=64), "prefix_cache cannot serve engine.cache=state"),
     (dict(prefix_cache_host_pages=8), "HostKVTier"),
     (dict(speculation="ngram"), "speculation cannot serve engine.cache=state"),
-    (dict(scheduler="two_dispatch"), "scheduler='ragged' only"),
+    (dict(scheduler="two_dispatch"),
+     "cache=state runs the ragged scheduler; scheduler='two_dispatch' exists "
+     "only on cache=dense"),
     (dict(lora_adapters={"a": {}}), "lora_adapters are not served"),
     (dict(cache_mode="paged"), "serve it with engine.cache=state"),
     (dict(cache_mode="dense"), "serve it with engine.cache=state"),
