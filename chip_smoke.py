@@ -632,62 +632,81 @@ def kernel_child(size):
 
     # -- ragged: decode rows + a multi-block prefill row + a verify row (on
     # a 3-token history, so masking its siblings moves its output), an idle
-    # row, and trailing q blocks no row owns (block_rows == -1)
-    for quant, page in ((False, 16), (True, 32)):
-        for tree in (False, True):
-            rows = 8
-            cap = pages_per_seq * page
-            row_lens = np.asarray([1, 43, 1, 0, 5, 1, 1, 9], np.int32)
-            history = np.clip(np.asarray(
-                [cap - 2, 100, 16, 0, 3, 0, 777, cap - 60], np.int32),
-                0, cap - row_lens)
-            kv_lens = history + row_lens
-            starts, block_rows, block_q0, t_pad = pa.ragged_layout(
-                row_lens, total=128)
-            check((block_rows == -1).sum() >= 2, "ragged batch has no empty q block")
-            k, v, table, scales = pools(jax.random.PRNGKey(3), page, rows, quant)
-            q = jax.random.normal(jax.random.PRNGKey(4), (t_pad, hkv, g, d),
-                                  jnp.float32).astype(dtype)
-            anc = None
-            if tree:
-                # row 4 is a 5-node draft tree (two branches); every
-                # other token keeps the plain-causal sentinel
-                anc_np = np.full((t_pad, 5), -1, np.int32)
-                anc_np[:, 0] = -2
-                s4 = int(starts[4])
-                anc_np[s4:s4 + 5] = pa.tree_ancestors(
-                    np.asarray([-1, 0, 0, 1, 2], np.int32), width=5)
-                anc = jnp.asarray(anc_np)
-            args = (k, v, table, jnp.asarray(kv_lens), jnp.asarray(starts),
-                    jnp.asarray(row_lens))
-            out = jax.jit(functools.partial(
-                pa.ragged_paged_attention, interpret=interpret))(
-                q, *args, block_rows=jnp.asarray(block_rows),
-                block_q0=jnp.asarray(block_q0), tree_anc=anc, **scales)
-            ref = reference(
+    # row, and trailing work items no row owns (item_rows == -1); then a
+    # chunk row of two query tiles beside decode rows
+    def ragged_case(name, row_lens, history, page, quant, tree_row=None,
+                    stack_too=False):
+        rows = len(row_lens)
+        cap = pages_per_seq * page
+        row_lens = np.asarray(row_lens, np.int32)
+        history = np.clip(np.asarray(history, np.int32), 0, cap - row_lens)
+        kv_lens = history + row_lens
+        starts, t_pad = pa.ragged_layout(row_lens)
+        t_pad += 16
+        tile = pa.ragged_query_tile(hkv, g, d, dtype)
+        # (two more than any batch on these shapes fills: empty items)
+        item_rows, item_q0 = pa.ragged_work_items(
+            row_lens, tile, total=pa.ragged_item_count(rows, t_pad, tile) + 2)
+        k, v, table, scales = pools(jax.random.PRNGKey(3), page, rows, quant)
+        q = jax.random.normal(jax.random.PRNGKey(4), (t_pad, hkv, g, d),
+                              jnp.float32).astype(dtype)
+        anc = None
+        if tree_row is not None:
+            # a 5-node draft tree (two branches); every other token keeps
+            # the plain-causal sentinel
+            anc_np = np.full((t_pad, 5), -1, np.int32)
+            anc_np[:, 0] = -2
+            s4 = int(starts[tree_row])
+            anc_np[s4:s4 + 5] = pa.tree_ancestors(
+                np.asarray([-1, 0, 0, 1, 2], np.int32), width=5)
+            anc = jnp.asarray(anc_np)
+        args = (k, v, table, jnp.asarray(kv_lens), jnp.asarray(starts),
+                jnp.asarray(row_lens))
+        plan = {"item_rows": jnp.asarray(item_rows),
+                "item_q0": jnp.asarray(item_q0)}
+        kernel = jax.jit(functools.partial(
+            pa.ragged_paged_attention, interpret=interpret))
+        out = kernel(q, *args, tree_anc=anc, **plan, **scales)
+        ref = reference(
+            pa.ragged_paged_attention_xla, f32(q),
+            *ref_pools(k, v, quant), *args[2:],
+            scales.get("k_scale"), scales.get("v_scale"), anc)
+        if anc is not None:
+            # the operand must matter: the tree row's reference differs
+            # from its plain-causal reference (siblings masked out)
+            plain = reference(
                 pa.ragged_paged_attention_xla, f32(q),
                 *ref_pools(k, v, quant), *args[2:],
-                scales.get("k_scale"), scales.get("v_scale"), anc)
-            if tree:
-                # the operand must matter: the tree row's reference differs
-                # from its plain-causal reference (siblings masked out)
-                plain = reference(
-                    pa.ragged_paged_attention_xla, f32(q),
-                    *ref_pools(k, v, quant), *args[2:],
-                    scales.get("k_scale"), scales.get("v_scale"), None)
-                moved = float(jnp.max(jnp.abs((ref - plain)[s4:s4 + 5])))
-                check(moved > 0.05, "tree_anc changed the tree row by only "
-                      "{}".format(moved))
-            compare("ragged_paged_attention {}/P{}{}".format(
-                "int8" if quant else "bf16", page,
-                " tree_anc" if tree else ""), out, ref)
-            if not quant and not tree:
-                out = jax.jit(functools.partial(
-                    pa.ragged_paged_attention, interpret=interpret))(
-                    q, stacked(k), stacked(v), *args[2:],
-                    block_rows=jnp.asarray(block_rows),
-                    block_q0=jnp.asarray(block_q0), layer=jnp.int32(2))
-                compare("ragged_paged_attention bf16/P16 stacked", out, ref)
+                scales.get("k_scale"), scales.get("v_scale"), None)
+            moved = float(jnp.max(jnp.abs((ref - plain)[s4:s4 + 5])))
+            check(moved > 0.05, "tree_anc changed the tree row by only "
+                  "{}".format(moved))
+        compare(name, out, ref)
+        if stack_too:
+            out = kernel(q, stacked(k), stacked(v), *args[2:],
+                         layer=jnp.int32(2), **plan)
+            compare(name + " stacked", out, ref)
+
+    for quant, page in ((False, 16), (True, 32)):
+        cap = pages_per_seq * page
+        for tree in (False, True):
+            ragged_case(
+                "ragged_paged_attention {}/P{}{}".format(
+                    "int8" if quant else "bf16", page,
+                    " tree_anc" if tree else ""),
+                [1, 43, 1, 0, 5, 1, 1, 9],
+                [cap - 2, 100, 16, 0, 3, 0, 777, cap - 60], page, quant,
+                tree_row=4 if tree else None,
+                stack_too=not quant and not tree)
+    # a chunk row of two tiles beside decode rows (ISSUE 30): one tile and
+    # 40 queries, so the row is two work items on the same context
+    tile = pa.ragged_query_tile(hkv, g, d, dtype)
+    cap = pages_per_seq * 16
+    # (the tiny walk's rows hold 64 tokens: its chunk is half a row)
+    chunk = tile + 40 if cap > 2 * tile else cap // 2
+    ragged_case(
+        "ragged_paged_attention bf16/P16 chunk of {}".format(chunk),
+        [1, chunk, 1, 1], [900, 200, 30, 1200], 16, False, stack_too=True)
 
     # -- the write of a launch's new K/V into the stacked pools: a prefill
     # run that crosses pages, decode rows between pads on the null page, a

@@ -85,7 +85,7 @@ REQUEST_VARYING: FrozenSet[str] = frozenset({
 # Call leaf names that collapse request-varying values into a finite key
 # space. Project-level homes: llm/shapes.py (pow2_bucket/pad_to_multiple/
 # pad_pages), the engine's prefill bucket picker, the pool's page-count
-# round-up, and the ragged layout builder (its outputs are q-block-aligned
+# round-up, and the ragged layout builder (its outputs are 8-aligned
 # and total-padded by construction). A module can extend the set for its
 # own helpers with a literal module-level declaration::
 #

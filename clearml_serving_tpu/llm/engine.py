@@ -375,6 +375,19 @@ def _decode_pass_work(first, passes) -> tuple:
     return int(passes.sum()), int(tokens.sum())
 
 
+def _mixed_pass_work(row_lens, kv_lens) -> tuple:
+    """(rows, tokens of context, (query, key) pairs under the causal mask)
+    of a mixed pass over paged KV: a row of ``row_lens[r]`` queries on
+    ``kv_lens[r]`` tokens is read once and computes its history times its
+    queries plus their triangle: what the ragged kernel is given
+    (``ragged.mixed_rows`` / ``mixed_kv_tokens`` / ``mixed_qk_pairs``)."""
+    n = np.asarray(row_lens, np.int64)
+    kv = np.asarray(kv_lens, np.int64)[n > 0]
+    n = n[n > 0]
+    pairs = n * (kv - n) + n * (n + 1) // 2
+    return int(n.size), int(kv.sum()), int(pairs.sum())
+
+
 # ragged scheduler (docs/ragged_attention.md): stage-3 brownout shrinks the
 # per-step admission share to roughly one minimal chunk instead of the
 # legacy gate's one-segment-per-chunk budget
@@ -1419,6 +1432,12 @@ class LLMEngineCore:
             # those rows attended there: the paged decode kernel's work
             "decode_chain_rows": 0,
             "decode_chain_kv_tokens": 0,
+            # rows of the mixed passes over paged KV, the context those rows
+            # hold and the (query, key) pairs they compute: the ragged
+            # kernel's work, a row once a pass and layer
+            "mixed_rows": 0,
+            "mixed_kv_tokens": 0,
+            "mixed_qk_pairs": 0,
             # rows the engine.spec.tree chaos seam demoted from spec-verify
             # back to plain decode (docs/spec_decode_trees.md fallback row)
             "spec_tree_fallbacks": 0,
@@ -2445,8 +2464,8 @@ class LLMEngineCore:
                                        tok_valid, row_last, k_pools, v_pools,
                                        k_scales, v_scales, page_table,
                                        kv_lens, row_starts, row_lens,
-                                       write_page, write_offset, block_rows,
-                                       block_q0, decode_mask, sampling, rng,
+                                       write_page, write_offset, item_rows,
+                                       item_q0, decode_mask, sampling, rng,
                                        lora_idx=None, extras=None,
                                        counts=None, pmask=None, guided=None,
                                        gstate=None, want_lp=False,
@@ -2468,7 +2487,7 @@ class LLMEngineCore:
                         params, tokens, tok_pos, tok_row, tok_valid,
                         row_last, k_pools, v_pools, page_table, kv_lens,
                         row_starts, row_lens, write_page, write_offset,
-                        block_rows, block_q0, lora_idx, **scale_kw,
+                        item_rows, item_q0, lora_idx, **scale_kw,
                         **logit_kw,
                     )
                     if paged_quant:
@@ -2677,12 +2696,12 @@ class LLMEngineCore:
                 self._ragged_paged_jit = None
             # static flat-token capacity per launch: ONE trace per
             # (extras/guided/lp variant). When the Pallas kernel serves the
-            # launch each row's segment aligns to the kernel's q block
-            # (worst-case alignment waste = one block per row); the XLA
-            # reference needs no alignment and rows pack densely. The
-            # q-block size is the KERNEL'S constant — the layout the engine
-            # builds and the grid forward_ragged launches must share one
-            # contract, not two constants that happen to agree.
+            # launch each row's segment aligns to the 8 tokens the kernel's
+            # q / out copies move (worst-case alignment waste = one copy per
+            # row); the XLA reference needs no alignment and rows pack
+            # densely. The alignment is the KERNEL'S constant — the layout
+            # the engine builds and the copies forward_ragged launches must
+            # share one contract, not two constants that happen to agree.
             from ..ops.paged_attention import _RAGGED_QB
 
             self._ragged_kernel = self._paged_kernel_reason is None
@@ -2696,9 +2715,24 @@ class LLMEngineCore:
             waste = self.max_batch * (qb - 1) if qb > 1 else 0
             self._ragged_tpad = -(-(budget + waste) // qb) * qb
             if self._ragged_kernel and cache_mode == "paged":
+                # the kernel's work plan: a row, or a query tile of it, per
+                # item; the tile follows from the shapes the kernel sees,
+                # and the list has one static length that holds any launch
+                from ..ops.paged_attention import (
+                    ragged_item_count, ragged_query_tile,
+                )
+
+                self._ragged_tile = ragged_query_tile(
+                    bundle.n_kv_heads, bundle.n_heads // bundle.n_kv_heads,
+                    bundle.head_dim, bundle.config.get("dtype", "bfloat16"),
+                )
+                self._ragged_items = ragged_item_count(
+                    self.max_batch, self._ragged_tpad, self._ragged_tile
+                )
                 self._check_kernel_smem(
                     self._ragged_tpad,
                     self._spec_k + 1 if self._spec_tree else 0,
+                    self._ragged_items,
                 )
 
             def _gather_finish_logits(logits, rows):
@@ -4180,15 +4214,17 @@ class LLMEngineCore:
                 return "{}: {}".format(name, why)
         return None
 
-    def _check_kernel_smem(self, tokens: int = 0, tree_width: int = 0) -> None:
+    def _check_kernel_smem(
+        self, tokens: int = 0, tree_width: int = 0, items: int = 0
+    ) -> None:
         """The paged kernels' scalar-prefetch operands (page table, row
-        vectors, q-block map, ancestor table) live in SMEM; a configuration
+        vectors, work plan, ancestor table) live in SMEM; a configuration
         that overflows it must fail at construction (= endpoint load), not
         as a compile error on the first request."""
         from ..ops.paged_attention import SMEM_BYTES, paged_kernel_smem_bytes
 
         need = paged_kernel_smem_bytes(
-            self.max_batch, self._pages_per_seq, tokens, tree_width
+            self.max_batch, self._pages_per_seq, tokens, tree_width, items
         )
         if need > SMEM_BYTES:
             raise ValueError(
@@ -4350,6 +4386,9 @@ class LLMEngineCore:
                     "decode_chain_kv_tokens": (
                         self.counters["decode_chain_kv_tokens"]
                     ),
+                    "mixed_rows": self.counters["mixed_rows"],
+                    "mixed_kv_tokens": self.counters["mixed_kv_tokens"],
+                    "mixed_qk_pairs": self.counters["mixed_qk_pairs"],
                     "tokens_per_launch": self._hist_launch_tokens.snapshot(),
                     "spec_acceptance": self._hist_spec_accept.snapshot(),
                     # draft-tree verify rows (docs/spec_decode_trees.md):
@@ -5926,7 +5965,7 @@ class LLMEngineCore:
         }
         job_of = {job.slot: job for job, _ in shares}
         take_of = {job.slot: take for job, take in shares}
-        from ..ops.paged_attention import ragged_layout
+        from ..ops.paged_attention import ragged_layout, ragged_work_items
 
         # tokens each row's cache holds before this launch: the page
         # pool's slot length, or the state slot's
@@ -5951,7 +5990,7 @@ class LLMEngineCore:
                 row_lens[slot] = 1
         for slot, take in take_of.items():
             span_lens[slot] = row_lens[slot] = take
-        starts, block_rows, block_q0, tpad = ragged_layout(
+        starts, tpad = ragged_layout(
             span_lens, self._ragged_qb, total=self._ragged_tpad
         )
         tokens = np.zeros(tpad, np.int32)
@@ -6039,13 +6078,16 @@ class LLMEngineCore:
             row_logit_idx=row_logit_idx,
             write_page=np.zeros(tpad, np.int32),
             write_offset=np.zeros(tpad, np.int32),
-            block_rows=(
-                jnp.asarray(block_rows) if self._ragged_kernel else None
-            ),
-            block_q0=(
-                jnp.asarray(block_q0) if self._ragged_kernel else None
-            ),
+            item_rows=None, item_q0=None,
         )
+        if self._ragged_kernel and pool is not None:
+            item_rows, item_q0 = ragged_work_items(
+                row_lens, self._ragged_tile, total=self._ragged_items
+            )
+            plan.update(
+                item_rows=jnp.asarray(item_rows),
+                item_q0=jnp.asarray(item_q0),
+            )
         if faults.active():
             # yield-point seam parity with _prepare_dispatch: snapshot
             # complete, worker not yet started
@@ -6210,8 +6252,8 @@ class LLMEngineCore:
                     jnp.asarray(plan["row_lens"]),
                     jnp.asarray(plan["write_page"]),
                     jnp.asarray(plan["write_offset"]),
-                    plan["block_rows"],
-                    plan["block_q0"],
+                    plan["item_rows"],
+                    plan["item_q0"],
                     jnp.asarray(plan["decode_mask"].copy()),
                     plan["sampling"],
                     plan["rng"],
@@ -6566,6 +6608,11 @@ class LLMEngineCore:
             self._count_decode_passes(_decode_pass_work(
                 plan["pre_lens"] + 2, np.maximum(plan["row_steps"] - 1, 0)
             ))
+            for name, n in zip(
+                ("mixed_rows", "mixed_kv_tokens", "mixed_qk_pairs"),
+                _mixed_pass_work(plan["row_lens"], plan["kv_lens"]),
+            ):
+                self.counters[name] += n
         self._step_rows["decode"] += len(plain_slots)
         self._step_rows["spec_verify"] += len(spec_slots)
         self._step_rows["prefill"] += len(live_shares)

@@ -205,7 +205,6 @@ def warm_ragged_variants(engine) -> int:
     if engine.paged_cache is not None:
         cache = engine.paged_cache
         tpad = engine._ragged_tpad
-        nb = tpad // engine._ragged_qb
         page_table = jnp.asarray(
             np.zeros((b, engine._pages_per_seq), np.int32)
         )
@@ -228,9 +227,10 @@ def warm_ragged_variants(engine) -> int:
                 jnp.asarray(np.full(b, k_ + 1, np.int32)),
                 jnp.asarray(anc),
             )
-        blocks = (
-            jnp.asarray(np.full(nb, -1, np.int32)),
-            jnp.asarray(np.zeros(nb, np.int32)),
+        # the kernel's work plan at its one static length, owned by no row
+        items = (
+            jnp.asarray(np.full(engine._ragged_items, -1, np.int32)),
+            jnp.asarray(np.zeros(engine._ragged_items, np.int32)),
         ) if engine._ragged_kernel else (None, None)
         for steps in windows:
             for spec_on in spec_opts:
@@ -260,7 +260,7 @@ def warm_ragged_variants(engine) -> int:
                         jnp.asarray(np.zeros(b, np.int32)),
                         jnp.asarray(np.zeros(tpad, np.int32)),
                         jnp.asarray(np.zeros(tpad, np.int32)),
-                        blocks[0], blocks[1],
+                        items[0], items[1],
                         jnp.asarray(np.zeros(b, bool)),
                         sampling, key(), lora,
                         None, None, None, None, None,

@@ -1607,8 +1607,8 @@ def build(config: dict) -> SimpleNamespace:
         row_lens,      # [R] int32 query tokens per row (0 = idle row)
         write_page,    # [T] int32 per-token write coords (pads -> null page)
         write_offset,  # [T] int32
-        block_rows=None,  # [T/QB] int32 kernel q-block map (host-built;
-        block_q0=None,    #  required whenever the Pallas kernel is taken)
+        item_rows=None,   # [NI] int32 the kernel's work plan (host-built,
+        item_q0=None,     #  ops.ragged_work_items; required with the kernel)
         lora_idx=None,    # [R] int32 adapter index per row (None = base)
         *,
         k_scales=None,  # [L, Hkv, N, P] f32 scale pools (kv_quant only)
@@ -1647,7 +1647,7 @@ def build(config: dict) -> SimpleNamespace:
         pools = _paged_pools(k_pools, v_pools, k_scales, v_scales,
                              "forward_ragged")
         # same pure decision as decode_paged; the kernel needs the caller's
-        # q-block-aligned layout (block_rows/block_q0) and raises without it
+        # work plan (item_rows/item_q0) and raises without it
         use_kernel = paged_kernel_unsupported_reason(
             head_dim, k_pools.shape[3], k_pools.dtype
         ) is None
@@ -1679,7 +1679,7 @@ def build(config: dict) -> SimpleNamespace:
                         attn = ragged_paged_attention(
                             q_grouped, new[0], new[1], page_table, kv_lens,
                             row_starts, row_lens,
-                            block_rows=block_rows, block_q0=block_q0, **kw,
+                            item_rows=item_rows, item_q0=item_q0, **kw,
                         )                                          # [T,Hkv,G,D]
                     else:
                         attn = ragged_paged_attention_xla(

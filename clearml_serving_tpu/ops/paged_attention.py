@@ -69,6 +69,18 @@ length 1, 463 us at length 0); 32 live rows of 450-900 tokens 147 us = 74%
 out the same calls take 92 / 134 / 143 us, with the DMAs taken out 35 / 58 /
 47: the kernel is bound by its page DMAs, not by the MXU's M = G rows.
 
+The ragged kernel (mixed prefill + decode rows; its section below) walks a
+host-built list of work items with the same plan since ISSUE 30: a decode or
+verify row, or a 128-query tile of a prompt chunk, a grid step, all kv heads,
+a tile's context read once. On the chip (PERF.md PR 30; 352 flat tokens, 32
+rows, the shapes above; a call is one layer): 31 decode rows of ~420 tokens
+and a 97-query chunk on 512 tokens 107 us (the plan before, a grid step per 8
+queries and head: 549 us); 8 decode rows of ~1,250 and a 100-query chunk at
+1,500 tokens 111 us (866 us); 7 decode rows of 1-4k and a 128-query row at
+4,096 tokens 221 us (2,318 us); outputs bit for bit the old plan's. The two
+transposes that make q and out head-major around it are XLA operations of 5
+us each.
+
 Mosaic portability notes baked into the kernels (each one is a refusal of
 the v5e compiler, libtpu 0.0.34):
 
@@ -101,8 +113,9 @@ lanes), and Mosaic requires DMA slices tile-aligned — the same constraint
 that gates D % 128 would reject every scale-row copy. Instead the tiny
 scale vectors (4 bytes per token-head vs 128+ data bytes) are pre-gathered
 by XLA into a lane-aligned [B, Hkv, 1, PP*P] operand that the grid
-pipeline DMAs into VMEM like any blocked input (the decode kernel: a row's
-scales of all heads a grid step; the ragged kernel: per q block and head).
+pipeline DMAs into VMEM like any blocked input (a row's scales of all heads
+a grid step, in the decode kernel and, by the work item's row, in the ragged
+kernel).
 The gather reads scale rows at table capacity rather than live length; that
 dead traffic is bounded by scale_bytes/kv_bytes = 4/D of the int8 stream
 (~3% at D=128).
@@ -178,32 +191,36 @@ SMEM_BYTES = 1 << 20
 
 
 def paged_kernel_smem_bytes(
-    rows: int, pages_per_seq: int, tokens: int = 0, tree_width: int = 0
+    rows: int, pages_per_seq: int, tokens: int = 0, tree_width: int = 0,
+    items: int = 0,
 ) -> int:
-    """SMEM the kernels' scalar-prefetch operands (and the decode kernel's
-    one scalar of scratch) take: the decode kernel
-    for ``rows`` sequences (``tokens == 0``), or the ragged kernel for a
-    ``tokens``-wide launch (+ a ``[tokens, tree_width]`` ancestor table).
+    """SMEM the kernels' scalar-prefetch operands and scalar scratch take:
+    the decode kernel for ``rows`` sequences (``tokens == 0``), or the
+    ragged kernel for a launch of ``tokens`` flat tokens on a work plan of
+    ``items`` (+ a ``[tokens, tree_width]`` ancestor table).
     The 2-D page table pads to (8, 128) int32 tiles — measured against the
     compiler: s32[250, 1023] allocates 256 * 1024 * 4 bytes — and each 1-D
-    vector to a 512-byte line; 2 KB covers the compiler's own scalars (at
-    the boundary it reported 1.1 KB more than the operands sum to). The
+    vector to a 512-byte line, or to 4 KB once it is longer than that
+    (s32[140000] allocates 548 KB); 2 KB covers the compiler's own scalars
+    (at the boundary it reported 1.1 KB more than the operands sum to; with
+    a work plan of 140,000 items 0.6 KB more). The
     engine compares this with
     :data:`SMEM_BYTES` at construction, so an oversized max_seq_len /
     max_batch / token budget is a load-time error instead of a Mosaic
     RESOURCE_EXHAUSTED on the first request."""
     def vec(n):
-        return -(-4 * n // 512) * 512
+        line = 4096 if 4 * n > 4096 else 512
+        return -(-4 * n // line) * line
 
     table = (-(-rows // 8) * 8) * (-(-pages_per_seq // 128) * 128) * 4
     if not tokens:
         # + lengths, layer, and the walk's block counter (SMEM scratch)
         return 2048 + table + vec(rows) + 2 * vec(1)
-    nb = -(-tokens // _RAGGED_QB)
     return (
-        2048 + table + 2 * vec(rows) + vec(1)          # kv_lens, row_lens, layer
-        + 2 * vec(nb)                                  # block_rows, block_q0
-        + vec(tokens * tree_width)                     # flat ancestor table
+        2048 + table + 3 * vec(rows)           # kv_lens, row_starts, row_lens
+        + 2 * vec(1)                           # layer, the walk's counters
+        + 2 * vec(items)                       # item_rows, item_q0
+        + vec(tokens * tree_width)             # flat ancestor table
     )
 
 
@@ -771,29 +788,57 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, write_page, write_offset, *,
 # rows (row_lens 1) degenerate to exactly the decode kernel's masking,
 # prefill rows get the standard causal triangle against their own history.
 #
-# Pallas design: the grid runs (T/QB, Hkv) where QB (`q_block`) is a small
-# static query block. The flattened layout is Q-BLOCK ALIGNED — every row's
-# segment starts at a QB boundary (ragged_layout below builds it), so each
-# q block belongs to exactly ONE row and the host passes that mapping as two
-# scalar-prefetch vectors (block_rows / block_q0). Each grid step runs a
-# manual double-buffered page-DMA plan per (q block, kv head) against its
-# row's pages (a [P, D] plane a descriptor: the decode kernel's plan until
-# ISSUE 28, whose row walk is not carried over here) — including the int8
-# path's pre-gathered per-row scale operands,
-# which pipeline per BLOCK via an index map that reads block_rows — and runs
-# the flash update on a [QB*G, pages_per_block*P] score tile. Pages past the
-# block's causal bound are never copied: a prefill chunk's early q blocks
-# stop their DMA train at their own triangle's edge.
+# Pallas design (the work plan of ISSUE 30, the decode kernel's of ISSUE 28
+# carried over): the grid walks a host-built list of WORK ITEMS, in order. An
+# item is (row, first query) and owns up to ``query_tile`` queries of its row
+# with ALL the kv heads: a decode row and a verify row are one item, a prompt
+# chunk is one item per query tile (:func:`ragged_work_items`; the count
+# follows in the kernel from ``row_lens``, so a row the engine dropped after
+# planning, an idle row and the padding of the list are items of zero queries:
+# no DMA, and the zeros the output was born with). What the plan is made of:
+#
+# - **A page is one strided descriptor a side for all kv heads**, into
+#   ``[2 slots, Hkv, block, D]`` buffers, semaphores per (slot, side),
+#   ``fori_loop``s over the pages that exist: the decode kernel's, with its
+#   block (:func:`decode_pages_per_block`, at most 512 tokens). Pages past an
+#   item's causal bound are never copied.
+# - **A tile's context is read once**: an item of up to 8 queries (decode,
+#   verify) runs the flash update on ``[Hkv, 8*G, block]`` scores; a larger
+#   one keeps the flash state of its whole tile in VMEM and updates it, sub
+#   tile by sub tile (``_RAGGED_SUB_ROWS`` MXU rows a head), against each
+#   block while the block is there. Sub tiles whose causal bound lies under a
+#   block skip it. The tile (:func:`ragged_query_tile`) follows from the
+#   shapes and ``_RAGGED_SCRATCH_BYTES`` of VMEM: 128 queries at Hkv 8, G 4,
+#   D 128.
+# - **Only the first live item of a call starts cold**: buffers, semaphores
+#   and the walk's counters (SMEM) persist across grid steps; while an item's
+#   last block is computed, the next live item's first block and its queries
+#   are in flight. A further tile of the same row is a next item like any
+#   other: its first block is fetched again, into the other slot.
+# - **q and out are head-major** ``[Hkv, T*G, D]`` in HBM (the wrapper's two
+#   transposes) and move by manual copies of ``_RAGGED_QB`` = 8 tokens, all
+#   heads a descriptor: that a row's segment starts at a multiple of 8
+#   (:func:`ragged_layout`) is what these copies need, and all they need.
+#   ``out`` is born zero (an aliased operand), so tokens no item owns read 0.
+# - the int8 path's pre-gathered per-row scale operands pipeline per ITEM via
+#   an index map that reads the item's row, all heads a block.
 
-_RAGGED_QB = 8  # default query block (sublane-friendly; decode rows pad to it)
+_RAGGED_QB = 8  # tokens a q / out copy moves; ragged_layout's row alignment
+# The most queries an item holds, the MXU rows a head of one flash update of
+# a large item, and the VMEM the tile's flash state and q / out buffers may
+# take: the tile of a call follows from its shapes (ragged_query_tile).
+_RAGGED_TILE_QUERIES = 128
+_RAGGED_SUB_ROWS = 128
+_RAGGED_SCRATCH_BYTES = 12 << 20
+_RAGGED_VMEM_LIMIT = 64 << 20   # of the v5e's 128 MiB: scratch, scales, temporaries
 
 
 def ragged_layout(row_lens, q_block: int = _RAGGED_QB, total: int | None = None):
-    """Host-side layout of a ragged batch: returns (row_starts [R],
-    block_rows [NB], block_q0 [NB], t_pad) as numpy int32, with every row's
-    flat segment aligned to ``q_block`` (the kernel's one-row-per-q-block
-    contract). ``total`` pads the flat token axis to a fixed static size so
-    engine traces stay bucketed; blocks not owned by any row carry -1."""
+    """Host-side layout of a ragged batch: returns (row_starts [R], t_pad)
+    with every row's flat segment aligned to ``q_block`` (8 for the kernel's
+    q / out copies, 1 packs rows densely for the XLA reference). ``total``
+    pads the flat token axis to a fixed static size so engine traces stay
+    bucketed."""
     import numpy as np
 
     lens = np.asarray(row_lens, np.int32)
@@ -810,17 +855,58 @@ def ragged_layout(row_lens, q_block: int = _RAGGED_QB, total: int | None = None)
                 "ragged layout needs {} tokens but total={}".format(t_pad, total)
             )
         t_pad = -(-int(total) // q_block) * q_block
-    nb = t_pad // q_block
-    block_rows = np.full(nb, -1, np.int32)
-    block_q0 = np.zeros(nb, np.int32)
-    for r, n in enumerate(lens):
-        if n <= 0:
-            continue
-        b0 = int(starts[r]) // q_block
-        for j in range(-(-int(n) // q_block)):
-            block_rows[b0 + j] = r
-            block_q0[b0 + j] = j * q_block
-    return starts, block_rows, block_q0, int(t_pad)
+    return starts, int(t_pad)
+
+
+def _ragged_sub_queries(g):
+    """Queries of one flash update of a large item: ``_RAGGED_SUB_ROWS``
+    MXU rows a head, whole 8-query copies."""
+    return max(_RAGGED_QB, _RAGGED_SUB_ROWS // g // _RAGGED_QB * _RAGGED_QB)
+
+
+def ragged_query_tile(hkv, g, head_dim, q_dtype):
+    """Queries one work item of the ragged kernel owns at most: whole sub
+    tiles, as many as keep the tile's flash state (f32 accumulator, running
+    max and sum, a lane tile each), two q buffers and the out buffer inside
+    :data:`_RAGGED_SCRATCH_BYTES`, at most :data:`_RAGGED_TILE_QUERIES`
+    (128 at Hkv 8, G 4, D 128, bf16). The engine builds its item list with
+    the same function over the same shapes."""
+    sq = _ragged_sub_queries(g)
+    per_query = hkv * g * (
+        4 * (head_dim + 2 * 128) + 3 * head_dim * jnp.dtype(q_dtype).itemsize
+    )
+    fit = min(_RAGGED_SCRATCH_BYTES // per_query, _RAGGED_TILE_QUERIES)
+    return max(sq, fit // sq * sq)
+
+
+def ragged_item_count(rows: int, tokens: int, query_tile: int) -> int:
+    """Length of the item list that holds any batch of ``rows`` rows on
+    ``tokens`` flat tokens: a live row is one item, and one more for every
+    whole tile of queries before its last."""
+    return rows + tokens // query_tile
+
+
+def ragged_work_items(row_lens, query_tile: int, total: int | None = None):
+    """Host-side work plan of the ragged kernel: (item_rows [NI], item_q0
+    [NI]) numpy int32 — per item its row and the first query it owns; it
+    owns ``min(query_tile, row_lens[row] - q0)`` of them. Rows in order,
+    a row's tiles in order. ``total`` pads the list with items of no row
+    (-1) to a static length."""
+    import numpy as np
+
+    rows, q0s = [], []
+    for r, n in enumerate(np.asarray(row_lens, np.int32)):
+        for q0 in range(0, int(n), query_tile):
+            rows.append(r)
+            q0s.append(q0)
+    n_items = max(len(rows), 1) if total is None else int(total)
+    if len(rows) > n_items:
+        raise ValueError(
+            "ragged batch needs {} work items but total={}".format(
+                len(rows), n_items))
+    pad = n_items - len(rows)
+    return (np.asarray(rows + [-1] * pad, np.int32),
+            np.asarray(q0s + [0] * pad, np.int32))
 
 
 def tree_ancestors(parents, n_nodes=None, *, width=None):
@@ -931,140 +1017,200 @@ def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
     return out.astype(q.dtype)
 
 
+
+
 def _ragged_attention_kernel(
-    # scalar prefetch (SMEM): block_rows [NB], block_q0 [NB],
-    # page_table [R, PP], kv_lens [R], row_lens [R], layer [1],
-    # tree only: tree_anc [T * tree_width] (flat; per token: in-row
+    # scalar prefetch (SMEM): item_rows [NI], item_q0 [NI],
+    # page_table [R, PP], kv_lens [R], row_starts [R], row_lens [R],
+    # layer [1], tree only: tree_anc [T * tree_width] (flat; per token: in-row
     # ancestor indices incl. self, -1 padded; first entry -2 => plain causal)
     *refs,
     page_size: int,
     pages_per_block: int,
-    q_block: int,
+    query_tile: int,
+    sub_queries: int,
+    group: int,
     quantized: bool = False,
     tree_width: int = 0,
 ):
-    # then positionally: q_ref [QB,1,G,D]; k_hbm/v_hbm [L,Hkv,N,P,D] (ANY);
-    # quantized only: k_scale_ref/v_scale_ref [1,1,1,cap_pad] (per-ROW
-    # pre-gathered scales, pipelined by the block_rows index map);
-    # out_ref [QB,1,G,D]; scratch k_buf/v_buf [2, PB*P, D], sems [2, PB, 2]
-    (block_rows_ref, block_q0_ref, page_table_ref, kv_lens_ref,
-     row_lens_ref, layer_ref) = refs[:6]
-    refs = refs[6:]
+    # then positionally: q_hbm [Hkv, T*G, D] (ANY); k_hbm/v_hbm
+    # [L,Hkv,N,P,D] (ANY); quantized only: k_scale_ref/v_scale_ref
+    # [1,Hkv,1,cap_pad] (the item's ROW's pre-gathered scales, pipelined by
+    # the item_rows index map); the zeros out is born as (ANY, aliased,
+    # unused); out_hbm [Hkv, T*G, D] (ANY).
+    # Scratch, all of it kept from one grid step to the next:
+    #   q_buf  [2, Hkv, QT*G, D]   the item's queries / the next item's
+    #   out_buf   [Hkv, QT*G, D]
+    #   k_buf, v_buf [2, Hkv, PB*P, D]   double-buffered page blocks
+    #   m_ref, l_ref [NSUB, Hkv, SQ*G, 1], acc_ref [NSUB, Hkv, SQ*G, D] f32:
+    #          the flash state of a large item's tile
+    #   kv_sems [2, 2] (slot, k/v), q_sems [2] (slot), out_sem [1]
+    #   walk [4] int32 SMEM: context blocks consumed so far, the pages of
+    #          the coming item's first block that are in flight, live items
+    #          so far, out copies in flight
+    (item_rows_ref, item_q0_ref, page_table_ref, kv_lens_ref,
+     row_starts_ref, row_lens_ref, layer_ref) = refs[:7]
+    refs = refs[7:]
     tree = tree_width > 0
     if tree:
         tree_anc_ref, refs = refs[0], refs[1:]
     if quantized:
-        (q_ref, k_hbm, v_hbm, k_scale_ref, v_scale_ref,
-         out_ref, k_buf, v_buf, sems) = refs
+        q_hbm, k_hbm, v_hbm, k_scale_ref, v_scale_ref = refs[:5]
+        refs = refs[5:]
     else:
-        q_ref, k_hbm, v_hbm, out_ref, k_buf, v_buf, sems = refs
+        (q_hbm, k_hbm, v_hbm), refs = refs[:3], refs[3:]
         k_scale_ref = v_scale_ref = None
-    bi = pl.program_id(0)
-    h = pl.program_id(1)
-    g, d = q_ref.shape[2], q_ref.shape[3]
+    (_zeros, out_hbm, q_buf, out_buf, k_buf, v_buf, m_ref, l_ref, acc_ref,
+     kv_sems, q_sems, out_sem, walk) = refs
+    i = pl.program_id(0)
+    ni = pl.num_programs(0)
+    hkv, d = q_buf.shape[1], q_buf.shape[3]
+    g = group
     p = page_size
     pb = pages_per_block
-    qb = q_block
-    row_raw = block_rows_ref[bi]
-    live = row_raw >= 0
-    row = jnp.maximum(row_raw, 0)
-    q0 = block_q0_ref[bi]
-    kv_len = kv_lens_ref[row]
-    row_len = row_lens_ref[row]
+    bt = pb * p                      # tokens of a context block
+    qt = query_tile
+    sq = sub_queries
+    sm = sq * g                      # MXU rows a head of a large flash update
+    cq = _RAGGED_QB                  # queries a q / out copy moves
+    cr = cq * g
+    n_tokens = q_hbm.shape[1] // g
     layer = layer_ref[0]
-    base = kv_len - row_len          # absolute position of the row's query 0
-    # causal bound of this block's LAST query — pages past it never DMA
-    bound = jnp.where(live, jnp.minimum(kv_len, base + q0 + qb), 0)
-    block_tokens = pb * p
-    n_blocks = (bound + block_tokens - 1) // block_tokens
 
-    def _copies(block_idx, slot, j):
-        page_idx = block_idx * pb + j
-        page = page_table_ref[row, page_idx]
-        dst = pl.ds(j * p, p)
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[layer, h, page], k_buf.at[slot, dst],
-                sems.at[slot, j, 0]
+    def item(j):
+        """(row, first query, queries, position of the row's query 0, causal
+        bound of the last query) of item ``j``; 0 queries = nothing to do."""
+        row = jnp.maximum(item_rows_ref[j], 0)
+        q0 = item_q0_ref[j]
+        row_len = row_lens_ref[row]
+        qn = jnp.where(
+            item_rows_ref[j] >= 0, jnp.clip(row_len - q0, 0, qt), 0
+        )
+        base = kv_lens_ref[row] - row_len
+        return row, q0, qn, base, jnp.where(qn > 0, base + q0 + qn, 0)
+
+    def next_live(j):
+        """First item after ``j`` with queries (``ni`` if none)."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < ni, item(jnp.minimum(r, ni - 1))[2] == 0
             ),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, h, page], v_buf.at[slot, dst],
-                sems.at[slot, j, 1]
-            ),
+            lambda r: r + 1, j + 1,
         )
 
-    def start_block(block_idx, slot):
-        for j in range(pb):  # static unroll; ragged tail gated per page
-            @pl.when((block_idx * pb + j) * p < bound)
-            def _start(j=j):
-                ck, cv = _copies(block_idx, slot, j)
-                ck.start()
-                cv.start()
+    def block_pages(bound, block):
+        return jnp.clip((bound - block * bt + p - 1) // p, 0, pb)
 
-    def wait_block(block_idx, slot):
-        for j in range(pb):
-            @pl.when((block_idx * pb + j) * p < bound)
-            def _wait(j=j):
-                ck, cv = _copies(block_idx, slot, j)
-                ck.wait()
-                cv.wait()
+    def block_copies(act, row, block, slot, pages):
+        """Start, or wait for, the first ``pages`` of a block: a page's K and V
+        planes of ALL kv heads are one strided descriptor a side, onto the
+        slot's semaphore of that side."""
+        def page(j, carry):
+            page_id = page_table_ref[row, block * pb + j]
+            dst = pl.ds(pl.multiple_of(j * p, p), p)
+            for side, (hbm, buf) in enumerate(
+                ((k_hbm, k_buf), (v_hbm, v_buf))
+            ):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[layer, pl.ds(0, hkv), page_id],
+                    buf.at[slot, pl.ds(0, hkv), dst],
+                    kv_sems.at[slot, side],
+                ), act)()
+            return carry
 
-    @pl.when(n_blocks > 0)
-    def _run():
-        start_block(0, 0)
+        jax.lax.fori_loop(0, pages, page, 0)
 
-        def body(i, carry):
-            m_prev, l_prev, acc_prev = carry
-            slot = jax.lax.rem(i, 2)
+    def q_copies(act, j, slot):
+        """Item ``j``'s queries, 8 tokens of all heads a descriptor."""
+        row, q0, qn, _, _ = item(j)
+        t0 = row_starts_ref[row] + q0
 
-            @pl.when(i + 1 < n_blocks)
-            def _prefetch():
-                start_block(i + 1, jax.lax.rem(i + 1, 2))
+        def chunk(c, carry):
+            getattr(pltpu.make_async_copy(
+                q_hbm.at[pl.ds(0, hkv),
+                         pl.ds(pl.multiple_of((t0 + c * cq) * g, cr), cr)],
+                q_buf.at[slot, pl.ds(0, hkv),
+                         pl.ds(pl.multiple_of(c * cr, cr), cr)],
+                q_sems.at[slot],
+            ), act)()
+            return carry
 
-            wait_block(i, slot)
-            # queries flatten to [QB*G, D]: query-in-block index = ri // G
-            q = q_ref[:, 0].reshape(qb * g, d)                  # [QB*G, D]
-            k = k_buf[slot]                                     # [PB*P, D]
-            v = v_buf[slot]
-            if quantized:
-                op_dtype = out_ref.dtype
-                k = k.astype(op_dtype)
-                v = v.astype(op_dtype)
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * (d ** -0.5)                                     # [QB*G, PB*P]
-            if quantized:
-                k_s = k_scale_ref[0, 0, :, pl.ds(i * block_tokens,
-                                                 block_tokens)]  # [1, PB*P]
-                scores = scores * k_s
-            # per-query causal masking: query q0+qi attends KV positions
-            # <= base+q0+qi; 2-D i32 iota compares (Mosaic: no i1 minor dim)
-            token_ids = (
-                i * block_tokens
-                + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            )
-            qi = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // g
-            q_live = (q0 + qi) < row_len                        # query exists
-            valid = (token_ids < base + q0 + qi + 1) & q_live
-            if tree:
-                # tree-topology pruning inside the unchanged causal
-                # bound (docs/spec_decode_trees.md): the DMA plan above
-                # is untouched — parent-before-child node order keeps
-                # base+q0+qi+1 a valid upper bound, so trees only MASK
-                # within the pages already copied. Ancestor lists live
-                # flat in SMEM (scalar prefetch); the per-query unroll is
-                # static (q_block x DMAX scalar reads). Every term is an
-                # i32 vector compare ORed/ANDed together: Mosaic refuses
-                # a select between i1 vectors and a scalar bool broadcast
-                # into one (module docstring), so the plain-causal
-                # sentinel and the av >= 0 guard are vector compares too.
-                tok_off = token_ids - base          # in-row kv offset
-                history = tok_off < 0               # history always
-                in_row = tok_off >= 0
+        jax.lax.fori_loop(0, (qn + cq - 1) // cq, chunk, 0)
+
+    def out_copies(act, t0, chunks):
+        def chunk(c, carry):
+            getattr(pltpu.make_async_copy(
+                out_buf.at[pl.ds(0, hkv),
+                           pl.ds(pl.multiple_of(c * cr, cr), cr)],
+                out_hbm.at[pl.ds(0, hkv),
+                           pl.ds(pl.multiple_of((t0 + c * cq) * g, cr), cr)],
+                out_sem.at[0],
+            ), act)()
+            return carry
+
+        jax.lax.fori_loop(0, chunks, chunk, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        # the only cold start of a call: the first live item's first block
+        # and its queries
+        first = next_live(-1)
+        row, _, _, _, bound = item(jnp.minimum(first, ni - 1))
+        pages = jnp.where(first < ni, block_pages(bound, 0), 0)
+        walk[0] = 0
+        walk[1] = pages
+        walk[2] = 0
+        walk[3] = 0
+        block_copies("start", row, 0, 0, pages)
+
+        @pl.when(first < ni)
+        def _():
+            q_copies("start", first, 0)
+
+    row, q0, qn, base, bound = item(i)
+    row_len = row_lens_ref[row]
+    n_blocks = (bound + bt - 1) // bt
+    t0 = row_starts_ref[row] + q0
+
+    def flash(q, k, v, block, carry, q_first):
+        """One flash update of queries ``q_first ...`` of the row (``q``
+        [Hkv, M, D], row index = query * G + head of the group) against a
+        context block, batched over the kv heads: the decode kernel's
+        arithmetic, with the ragged terms in the mask."""
+        m_prev, l_prev, acc_prev = carry
+        rows_m = q.shape[1]
+        scores = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * (d ** -0.5)                                     # [Hkv, M, PB*P]
+        if quantized:
+            scores = scores * k_scale_ref[0, :, :, pl.ds(block * bt, bt)]
+        # per-query causal masking: query q_first+qi attends KV positions
+        # <= base+q_first+qi; i32 iota compares (Mosaic: no i1 minor dim)
+        shape = (1, rows_m, bt)
+        token_ids = block * bt + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        qi = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // g
+        q_live = (q_first + qi) < row_len                   # query exists
+        valid = (token_ids < base + q_first + qi + 1) & q_live
+        if tree:
+            # tree-topology pruning inside the unchanged causal bound
+            # (docs/spec_decode_trees.md): parent-before-child node order
+            # keeps base+q+1 a valid upper bound, so trees only MASK within
+            # the pages already copied. Ancestor lists live flat in SMEM;
+            # the per-query unroll is static (queries x DMAX scalar reads).
+            # Every term is an i32 vector compare ORed/ANDed together:
+            # Mosaic refuses a select between i1 vectors and a scalar bool
+            # broadcast into one (module docstring), so the plain-causal
+            # sentinel and the av >= 0 guard are vector compares too.
+            tok_off = token_ids - base          # in-row kv offset
+            history = tok_off < 0               # history always
+            in_row = tok_off >= 0
+            tok0 = row_starts_ref[row] + q_first
+
+            def allow_mask():
                 allow = None
-                for qs in range(qb):
-                    a0 = (bi * qb + qs) * tree_width
+                for qs in range(rows_m // g):
+                    a0 = jnp.minimum(tok0 + qs, n_tokens - 1) * tree_width
                     # anc[t, 0] == -2: plain-causal token, mask unchanged
                     match = history | (
                         jnp.full_like(tok_off, tree_anc_ref[a0]) == -2
@@ -1077,50 +1223,159 @@ def _ragged_attention_kernel(
                         )
                     term = (qi == qs) & match
                     allow = term if allow is None else allow | term
-                valid = valid & allow
-            scores = jnp.where(valid, scores, -jnp.inf)
+                return allow.astype(jnp.int32)
+
+            if rows_m == cr:
+                allow = allow_mask()
+            else:
+                # a prompt chunk's sub tile: plain causal throughout, unless
+                # a verify row of more than 8 nodes came this way
+                n_tree = jax.lax.fori_loop(
+                    0, rows_m // g,
+                    lambda qs, n: n + (tree_anc_ref[
+                        jnp.minimum(tok0 + qs, n_tokens - 1) * tree_width
+                    ] != -2).astype(jnp.int32),
+                    0,
+                )
+                allow = jax.lax.cond(
+                    n_tree > 0, allow_mask,
+                    lambda: jnp.ones(shape, jnp.int32),
+                )
+            valid = valid & (allow != 0)
+        scores = jnp.where(valid, scores, -jnp.inf)
+        block_max = jnp.maximum(jnp.max(scores, axis=2, keepdims=True), -1e30)
+        m_new = jnp.maximum(m_prev, block_max)              # [Hkv, M, 1]
+        probs = jnp.exp(scores - m_new)
+        probs = jnp.where(valid, probs, 0.0)
+        correction = jnp.exp(m_prev - m_new)
+        # the softmax denominator sums the UNSCALED probs; v_scale belongs
+        # only to the PV product
+        l_new = l_prev * correction + jnp.sum(probs, axis=2, keepdims=True)
+        pv = probs
+        if quantized:
+            pv = probs * v_scale_ref[0, :, :, pl.ds(block * bt, bt)]
+        acc_new = acc_prev * correction + jax.lax.dot_general(
+            pv.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                                   # [Hkv, M, D]
+        return m_new, l_new, acc_new
+
+    def run(small):
+        """The item: its context blocks in order through the two slots, the
+        next live item's first block and queries in flight before its last
+        block is computed. ``small`` (static): up to 8 queries, the flash
+        state a loop carry; else the tile's state in VMEM, sub tile by sub
+        tile against each block."""
+        done, pending = walk[0], walk[1]
+        q_slot = jax.lax.rem(walk[2], 2)
+        nxt = next_live(i)
+        more = nxt < ni
+        n_row, _, _, _, n_bound = item(jnp.minimum(nxt, ni - 1))
+        n_pages = jnp.where(more, block_pages(n_bound, 0), 0)
+
+        @pl.when(more)
+        def _next_queries():
+            q_copies("start", nxt, 1 - q_slot)
+
+        q_copies("wait", i, q_slot)
+        n_sub = (qn + sq - 1) // sq
+        if not small:
+            def init(s, carry):
+                m_ref[s] = jnp.full(m_ref.shape[1:], -jnp.inf, jnp.float32)
+                l_ref[s] = jnp.zeros(l_ref.shape[1:], jnp.float32)
+                acc_ref[s] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+                return carry
+
+            jax.lax.fori_loop(0, n_sub, init, 0)
+
+        def body(block, carry):
+            slot = jax.lax.rem(done + block, 2)
+
+            @pl.when(block + 1 < n_blocks)
+            def _prefetch():
+                block_copies("start", row, block + 1, 1 - slot,
+                             block_pages(bound, block + 1))
+
+            @pl.when(block + 1 == n_blocks)
+            def _prefetch_next_item():
+                block_copies("start", n_row, 0, 1 - slot, n_pages)
+
+            block_copies("wait", row, block, slot, jnp.where(
+                block == 0, pending, block_pages(bound, block)))
+
+            # K/V feed the MXU in pool dtype (bf16) with f32 accumulation;
+            # int8 pools as raw int8 cast to the compute dtype (lossless),
+            # their per-token scales folded into the f32 scores / probs
+            k = k_buf[slot]                                 # [Hkv, PB*P, D]
+            v = v_buf[slot]
+            if quantized:
+                k = k.astype(out_buf.dtype)
+                v = v.astype(out_buf.dtype)
             # rows past the bound were never DMA'd: zero before the matmul
-            row_ids = i * block_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, (block_tokens, 1), 0
+            row_ids = block * bt + jax.lax.broadcasted_iota(
+                jnp.int32, (1, bt, 1), 1
             )
             v = jnp.where(row_ids < bound, v, jnp.zeros_like(v))
+            if small:
+                return flash(q_buf[q_slot, :, :cr], k, v, block, carry, q0)
 
-            block_max = jnp.maximum(jnp.max(scores, axis=1), -1e30)
-            m_new = jnp.maximum(m_prev, block_max)              # [QB*G]
-            probs = jnp.exp(scores - m_new[:, None])
-            probs = jnp.where(valid, probs, 0.0)
-            correction = jnp.exp(m_prev - m_new)
-            l_new = l_prev * correction + jnp.sum(probs, axis=1)
-            pv = probs
-            if quantized:
-                v_s = v_scale_ref[0, 0, :, pl.ds(i * block_tokens,
-                                                 block_tokens)]  # [1, PB*P]
-                pv = probs * v_s
-            acc_new = acc_prev * correction[:, None] + jax.lax.dot_general(
-                pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, acc_new
+            def sub(s, c):
+                at = pl.ds(pl.multiple_of(s * sm, sm), sm)
+                state = flash(
+                    q_buf[q_slot, :, at], k, v, block,
+                    (m_ref[s], l_ref[s], acc_ref[s]), q0 + s * sq,
+                )
+                m_ref[s], l_ref[s], acc_ref[s] = state
+                return c
 
-        m0 = jnp.full((qb * g,), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((qb * g,), jnp.float32)
-        acc0 = jnp.zeros((qb * g, d), jnp.float32)
-        _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out_ref[:, 0] = (acc / safe_l[:, None]).reshape(qb, g, d).astype(
-            out_ref.dtype
-        )
+            # sub tiles whose last query's bound lies under this block
+            # have nothing in it
+            under = jnp.maximum(block * bt - base - q0, 0) // sq
+            jax.lax.fori_loop(jnp.minimum(under, n_sub - 1), n_sub, sub, 0)
+            return carry
 
-    @pl.when(n_blocks == 0)
-    def _empty():
-        out_ref[:, 0] = jnp.zeros((qb, g, d), out_ref.dtype)
+        def result(l, acc):
+            return (acc / jnp.where(l == 0.0, 1.0, l)).astype(out_buf.dtype)
+
+        if small:
+            _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (
+                jnp.full((hkv, cr, 1), -jnp.inf, jnp.float32),
+                jnp.zeros((hkv, cr, 1), jnp.float32),
+                jnp.zeros((hkv, cr, d), jnp.float32),
+            ))
+        else:
+            jax.lax.fori_loop(0, n_blocks, body, 0)
+        # the out buffer is the previous item's until its copies landed
+        out_copies("wait", 0, walk[3])
+        if small:
+            out_buf[:, :cr] = result(l, acc)
+        else:
+            def write(s, carry):
+                at = pl.ds(pl.multiple_of(s * sm, sm), sm)
+                out_buf[:, at] = result(l_ref[s], acc_ref[s])
+                return carry
+
+            jax.lax.fori_loop(0, n_sub, write, 0)
+        chunks = (qn + cq - 1) // cq
+        out_copies("start", t0, chunks)
+        walk[0] = done + n_blocks
+        walk[1] = n_pages
+        walk[2] = walk[2] + 1
+        walk[3] = chunks
+
+    pl.when(jnp.logical_and(qn > 0, qn <= cq))(lambda: run(True))
+    pl.when(qn > cq)(lambda: run(False))
+
+    @pl.when(i == ni - 1)
+    def _drain():
+        out_copies("wait", 0, walk[3])
+        walk[3] = 0
 
 
 def ragged_paged_attention(
     q, k_pool, v_pool, page_table, kv_lens, row_starts, row_lens, *,
-    block_rows=None, block_q0=None,
+    item_rows=None, item_q0=None,
     k_scale=None, v_scale=None, tree_anc=None, layer=None,
-    pages_per_block: int = 32, q_block: int = _RAGGED_QB,
     interpret: bool = False,
 ):
     """Ragged paged attention over mixed prefill+decode rows — compiled by
@@ -1132,10 +1387,14 @@ def ragged_paged_attention(
     scale pools are one layer's, or the stack with ``layer``, as in
     :func:`paged_attention`.
 
-    ``block_rows``/``block_q0`` ([T/q_block] int32) are the host-computed
-    q-block -> row map (:func:`ragged_layout`); the kernel REQUIRES them
-    (they cannot be derived from traced row metadata on device) and the
-    flat layout must be q_block-aligned per row.
+    ``item_rows``/``item_q0`` ([NI] int32, any NI that holds the batch) are
+    the host-built work plan (:func:`ragged_work_items`); the kernel
+    REQUIRES them (a grid cannot be derived from traced row metadata on
+    device), and ``row_starts`` at multiples of 8 (:func:`ragged_layout`).
+    The plan MUST be built at :func:`ragged_query_tile` of THIS call's
+    shapes and ``q.dtype``: the kernel cuts every item at that tile and
+    cannot check the list against it. A plan of a larger tile leaves
+    queries at zero, one of a smaller tile writes outputs twice.
 
     ``tree_anc`` ([T, DMAX] int32, optional) turns spec-verify rows into
     draft-TREE rows (docs/spec_decode_trees.md): per flat token, the
@@ -1143,51 +1402,50 @@ def ragged_paged_attention(
     padded); ``tree_anc[t, 0] == -2`` keeps token t plain-causal. Only
     the mask changes — the page DMA plan is topology-blind."""
     quantized = k_scale is not None
-    if block_rows is None or block_q0 is None:
+    if item_rows is None or item_q0 is None:
         raise ValueError(
-            "ragged_paged_attention needs the host-built block_rows/block_q0 "
-            "q-block map (ragged_layout)"
+            "ragged_paged_attention needs the host-built item_rows/item_q0 "
+            "work plan (ragged_work_items)"
         )
     _check_kernel_operands(
         "ragged_paged_attention", q, k_pool, quantized, interpret
     )
 
-    t, hkv, g, d = q.shape
+    t, hkv, g0, d = q.shape
     page_size = k_pool.shape[-2]
     pages_per_seq = page_table.shape[1]
-    if t % q_block:
+    if t % _RAGGED_QB:
         raise ValueError(
-            "ragged q length {} must be a multiple of q_block {}".format(
-                t, q_block
-            )
+            "ragged q length {} must be a multiple of {}".format(t, _RAGGED_QB)
         )
-    pb = max(1, min(pages_per_block, pages_per_seq))
+    # 8 tokens of a head are 8*G rows of q's minor tile: whole sublane tiles
+    # of its dtype, or the group pads with query heads of zeros (G 1 in bf16)
+    g = g0
+    while (_RAGGED_QB * g) % (32 // q.dtype.itemsize):
+        g += 1
+    qt = ragged_query_tile(hkv, g0, d, q.dtype)
+    sq = _ragged_sub_queries(g0)
+    pb = decode_pages_per_block(hkv, d, page_size, pages_per_seq, k_pool.dtype)
+    block_tokens = pb * page_size
     cap = pages_per_seq * page_size
 
     kernel = functools.partial(
         _ragged_attention_kernel,
         page_size=page_size,
         pages_per_block=pb,
-        q_block=q_block,
+        query_tile=qt,
+        sub_queries=sq,
+        group=g,
         quantized=quantized,
         tree_width=0 if tree_anc is None else tree_anc.shape[1],
     )
-    nb = t // q_block
-    # index maps take *_ for the scalar-prefetch refs: their count is 6
-    # or 7 (tree_anc) and the maps never read beyond block_rows
-    in_specs = [
-        pl.BlockSpec(
-            (q_block, 1, g, d), lambda b, h, *_: (b, h, 0, 0)
-        ),
-        pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM
-        pl.BlockSpec(memory_space=pl.ANY),   # V pool stays in HBM
-    ]
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [anywhere, anywhere, anywhere]   # q, K and V pools stay in HBM
     scales = []
     if quantized:
         # per-ROW pre-gathered scales (same rationale/padding as the decode
         # kernel's: f32 scale rows are not tile-alignable for the page DMA
-        # plan); the grid pipeline picks each q block's row via block_rows
-        block_tokens = pb * page_size
+        # plan); the grid pipeline picks each item's row via item_rows
         cap_pad = -(-cap // block_tokens) * block_tokens
         pad = ((0, 0), (0, 0), (0, 0), (0, cap_pad - cap))
         r = page_table.shape[0]
@@ -1199,17 +1457,21 @@ def ragged_paged_attention(
             ).reshape(r, hkv, 1, cap)
             return jnp.pad(seq, pad)
 
-        def scale_idx(b, h, br, *_):
-            return (jnp.maximum(br[b], 0), h, 0, 0)
-
-        in_specs += [
-            pl.BlockSpec((1, 1, 1, cap_pad), scale_idx),
-            pl.BlockSpec((1, 1, 1, cap_pad), scale_idx),
-        ]
+        # index maps take *_ for the scalar-prefetch refs: their count is 7
+        # or 8 (tree_anc) and the map never reads beyond item_rows
+        scale_spec = pl.BlockSpec(
+            (1, hkv, 1, cap_pad),
+            lambda i, rows, *_: (jnp.maximum(rows[i], 0), 0, 0, 0),
+        )
+        in_specs += [scale_spec, scale_spec]
         scales = [gather(k_scale), gather(v_scale)]
     layer, k_pool, v_pool = _stacked(layer, k_pool, v_pool)
-    inputs = [q, k_pool, v_pool] + scales
-    prefetch = [block_rows, block_q0, page_table, kv_lens, row_lens, layer]
+    if g != g0:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, g - g0), (0, 0)))
+    q_heads = jnp.moveaxis(q, 0, 1).reshape(hkv, t * g, d)
+    inputs = [q_heads, k_pool, v_pool] + scales + [jnp.zeros_like(q_heads)]
+    prefetch = [item_rows, item_q0, page_table, kv_lens, row_starts,
+                row_lens, layer]
     if tree_anc is not None:
         if tree_anc.shape[0] != t:
             raise ValueError(
@@ -1217,23 +1479,40 @@ def ragged_paged_attention(
                     tree_anc.shape[0], t))
         # flat: a 2-D SMEM operand pads its minor dim to 128 lanes
         prefetch.append(tree_anc.astype(jnp.int32).reshape(-1))
+    n_sub = qt // sq
+    state = (n_sub, hkv, sq * g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # maps, tables, layer (+ tree)
-        grid=(nb, hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (q_block, 1, g, d), lambda b, h, *_: (b, h, 0, 0)
-        ),
+        num_scalar_prefetch=len(prefetch),  # plan, tables, layer (+ tree)
+        grid=(item_rows.shape[0],),
+        in_specs=in_specs + [anywhere],
+        out_specs=anywhere,
         scratch_shapes=[
-            pltpu.VMEM((2, pb * page_size, d), k_pool.dtype),
-            pltpu.VMEM((2, pb * page_size, d), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, pb, 2)),
+            pltpu.VMEM((2, hkv, qt * g, d), q.dtype),
+            pltpu.VMEM((hkv, qt * g, d), q.dtype),
+            pltpu.VMEM((2, hkv, block_tokens, d), k_pool.dtype),
+            pltpu.VMEM((2, hkv, block_tokens, d), v_pool.dtype),
+            pltpu.VMEM(state + (1,), jnp.float32),
+            pltpu.VMEM(state + (1,), jnp.float32),
+            pltpu.VMEM(state + (d,), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SMEM((4,), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_heads.shape, q.dtype),
+        # operands count the scalar prefetch; the last input is the output
+        input_output_aliases={len(prefetch) + len(inputs) - 1: 0},
+        # the items are walked in order: buffers, semaphores and the walk's
+        # counters carry from one item to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_RAGGED_VMEM_LIMIT,
+        ),
         interpret=interpret,
-        name="ragged_paged_attention",
+        name="ragged_paged_attention",  # the kernel's name in a trace
     )(*prefetch, *inputs)
+    return jnp.moveaxis(out.reshape(hkv, t, g, d), 0, 1)[:, :, :g0]

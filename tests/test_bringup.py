@@ -2,8 +2,9 @@
 
 - the engine's ``health()`` names the device and which implementation each
   kernel's call sites take, with the routing functions' own reasons;
-- the TPU-only ragged layout (q-block-aligned rows, block maps, padded
-  ``_ragged_tpad``, null-row warmup operands) EXECUTES in tier-1: the
+- the TPU-only ragged layout (rows aligned to the kernel's 8-token copies,
+  the work plan, padded ``_ragged_tpad``, null-row warmup operands)
+  EXECUTES in tier-1: the
   routing function is patched to answer "kernel" and the kernels run in
   the Pallas interpreter, so the branch no CPU test used to reach serves
   real requests and must agree with the XLA path token for token;
@@ -136,16 +137,16 @@ def test_int4_route_reported_for_the_decode_shape(monkeypatch):
 
 def test_kernel_layout_serves_and_matches_the_xla_path(parts, monkeypatch):
     """Route the paged engine to the kernels (interpreted): rows align to
-    the kernel's q block, the block map rides every launch, the null-row
-    warmup launch uses the same operands — and the greedy streams equal
-    the XLA path's."""
+    the kernel's 8-token copies, the work plan rides every launch, the
+    null-row warmup launch uses the same operands — and the greedy streams
+    equal the XLA path's."""
     bundle, params = parts
     kw = dict(
         max_batch=2, max_seq_len=64, cache_mode="paged", page_size=16,
         scheduler="ragged", step_token_budget=16, ragged_decode_steps=1,
         eos_token_id=None,
     )
-    prompts = [list(range(3, 3 + 21)), [7, 8, 9]]  # 3 q blocks + a short row
+    prompts = [list(range(3, 3 + 21)), [7, 8, 9]]  # 3 copies + a short row
     want = _serve(LLMEngineCore(bundle, params, **kw), prompts)
 
     monkeypatch.setattr(
@@ -165,17 +166,45 @@ def test_kernel_layout_serves_and_matches_the_xla_path(parts, monkeypatch):
     )
     engine = LLMEngineCore(bundle, params, **kw)
     assert engine._ragged_kernel and engine._ragged_qb == pa._RAGGED_QB
-    # budget 16 + one q block of alignment waste per row, q-block aligned
+    # budget 16 + one copy of alignment waste per row, aligned to 8
     assert engine._ragged_tpad == 32
+    # a tile holds any chunk of this model: an item a row, none further
+    assert (engine._ragged_tile, engine._ragged_items) == (128, 2)
     assert engine.health()["kernels"] == {
         "decode": "pallas", "ragged": "pallas", "int4": None, "reason": None,
     }
     from clearml_serving_tpu.llm.warmup import warm_ragged_variants
 
-    assert warm_ragged_variants(engine) >= 1  # null rows, block_rows == -1
+    assert warm_ragged_variants(engine) >= 1  # null rows, item_rows == -1
     got = _serve(engine, prompts)
     assert got == want
     assert engine.counters["ragged_steps"] > 0
+
+
+def _serve_staggered(engine, prompts, answers):
+    """The first request is decoding when the others arrive together."""
+    async def run():
+        first = asyncio.Event()
+
+        async def collect(prompt, n):
+            out = []
+            async for tok in engine.generate(
+                GenRequest(prompt_ids=list(prompt), max_new_tokens=n)
+            ):
+                out.append(tok)
+                first.set()
+            return out
+
+        try:
+            head = asyncio.ensure_future(collect(prompts[0], answers[0]))
+            await first.wait()
+            return await asyncio.gather(head, *[
+                collect(p, n) for p, n in zip(prompts[1:], answers[1:])
+            ])
+        finally:
+            engine.stop()
+
+    return asyncio.run(run())
 
 
 def test_decode_passes_hand_the_kernel_nothing_for_rows_that_do_not_decode(
@@ -200,30 +229,8 @@ def test_decode_passes_hand_the_kernel_nothing_for_rows_that_do_not_decode(
     prompts = [[5, 6, 7, 8, 9], [7, 8, 9], list(range(3, 3 + 30))]
     answers = [40, 3, 6]
 
-    def serve(engine):
-        async def run():
-            first = asyncio.Event()
-
-            async def collect(prompt, n):
-                out = []
-                async for tok in engine.generate(
-                    GenRequest(prompt_ids=list(prompt), max_new_tokens=n)
-                ):
-                    out.append(tok)
-                    first.set()
-                return out
-
-            try:
-                head = asyncio.ensure_future(collect(prompts[0], answers[0]))
-                await first.wait()
-                return await asyncio.gather(head, *[
-                    collect(p, n) for p, n in zip(prompts[1:], answers[1:])
-                ])
-            finally:
-                engine.stop()
-
-        return asyncio.run(run())
-
+    serve = functools.partial(
+        _serve_staggered, prompts=prompts, answers=answers)
     want = serve(LLMEngineCore(bundle, params, **kw))
 
     handed = []
@@ -283,6 +290,85 @@ def test_decode_passes_hand_the_kernel_nothing_for_rows_that_do_not_decode(
     assert stats["decode_chain_rows"] == sum(
         sum(1 for x in v if x) for v in expected)
     assert stats["decode_chain_kv_tokens"] == sum(map(sum, expected))
+
+
+def test_mixed_passes_hand_the_kernel_the_plans_work_items(parts, monkeypatch):
+    """Two prompts arrive under a decoding row: every mixed pass hands the
+    ragged kernel exactly its plan's items — a decode row one item, a prompt
+    chunk one per query tile (8 here, so a chunk of 20 is three), the list
+    padded to its one static length — with the plan's ``row_lens`` /
+    ``kv_lens``; the streams are the XLA path's, and ``ragged.mixed_rows`` /
+    ``mixed_kv_tokens`` / ``mixed_qk_pairs`` are the sums over the plans."""
+    bundle, params = parts
+    kw = dict(
+        max_batch=4, max_seq_len=96, cache_mode="paged", page_size=16,
+        scheduler="ragged", step_token_budget=24, ragged_decode_steps=4,
+        decode_steps=4, eos_token_id=None,
+    )
+    prompts = [[5, 6, 7, 8, 9], list(range(40, 40 + 26)), list(range(3, 3 + 30))]
+    answers = [40, 3, 6]
+    want = _serve_staggered(LLMEngineCore(bundle, params, **kw), prompts, answers)
+
+    handed, tiles = [], set()
+
+    def spy(q, k_pool, v_pool, page_table, kv_lens, row_starts, row_lens, *,
+            item_rows, item_q0, **kwargs):
+        # the kernel cuts an item at the tile of ITS operands: the engine's
+        # plan has to be built at that tile, for the dtype the model passes
+        tiles.add(pa.ragged_query_tile(*q.shape[1:], q.dtype))
+        jax.debug.callback(
+            lambda *a: handed.append(tuple(tuple(int(x) for x in v) for v in a)),
+            item_rows, item_q0, row_lens, kv_lens)
+        return kernel(q, k_pool, v_pool, page_table, kv_lens, row_starts,
+                      row_lens, item_rows=item_rows, item_q0=item_q0, **kwargs)
+
+    kernel = functools.partial(pa.ragged_paged_attention, interpret=True)
+    monkeypatch.setattr(pa, "_RAGGED_TILE_QUERIES", 8)
+    monkeypatch.setattr(pa, "_RAGGED_SUB_ROWS", 16)
+    monkeypatch.setattr(
+        pa, "paged_kernel_unsupported_reason", lambda *a, **k: None
+    )
+    monkeypatch.setattr(pa, "ragged_paged_attention", spy)
+    monkeypatch.setattr(
+        pa, "paged_attention",
+        functools.partial(pa.paged_attention, interpret=True),
+    )
+    monkeypatch.setattr(
+        pa, "paged_kv_write",
+        functools.partial(pa.paged_kv_write, interpret=True),
+    )
+    engine = LLMEngineCore(bundle, params, **kw)
+    # 4 rows on 24 + 4 x 7 = 52 -> 56 flat tokens: 4 + 56 // 8 items
+    assert (engine._ragged_tile, engine._ragged_items) == (8, 11)
+    expected, work = [], np.zeros(3, np.int64)
+    retire = engine._retire_ragged
+
+    def retire_ragged(plan, result):
+        row_lens, kv_lens = plan["row_lens"], plan["kv_lens"]
+        items = pa.ragged_work_items(row_lens, 8, total=11)
+        expected.append(tuple(
+            tuple(int(x) for x in v) for v in (*items, row_lens, kv_lens)))
+        for n, kv in zip(row_lens, kv_lens):
+            if n:
+                hist = int(kv) - int(n)
+                work[:] += (1, kv, sum(hist + i + 1 for i in range(int(n))))
+        return retire(plan, result)
+
+    monkeypatch.setattr(engine, "_retire_ragged", retire_ragged)
+    got = _serve_staggered(engine, prompts, answers)
+    jax.effects_barrier()
+    assert got == want
+    assert tiles == {engine._ragged_tile}
+    assert sorted(handed) == sorted(expected * len(params["layers"]))
+    # prompt chunks of several tiles rode beside a decode row's one item
+    plans = [dict(zip(("rows", "q0", "row_lens", "kv_lens"), e)) for e in expected]
+    assert any(
+        max(p["q0"]) >= 16 and 1 in p["row_lens"] for p in plans)
+    assert all(p["rows"].count(-1) >= 11 - 4 - 2 for p in plans)
+    stats = engine.lifecycle_stats()["ragged"]
+    assert [stats[k] for k in ("mixed_rows", "mixed_kv_tokens", "mixed_qk_pairs")
+            ] == [int(x) for x in work]
+    assert work[0] > len(expected) and work[2] > work[1]
 
 
 def test_decode_paged_masks_the_rows_a_pass_does_not_advance(parts, monkeypatch):

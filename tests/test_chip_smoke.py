@@ -132,8 +132,12 @@ def test_tiny_size_walks_every_phase_and_still_fails_off_chip():
     assert c["mode"] == "interpret"
     names = [v["variant"] for v in c["variants"]]
     assert any("tree_anc" in n for n in names)
-    # stacked pools (ISSUE 25): one case of each attention kernel, the write
-    assert sum(n.endswith(" stacked") for n in names) == 4
+    # stacked pools (ISSUE 25): one case of each attention kernel, the
+    # write; and the ragged kernel's chunk row of two tiles beside decode
+    # rows, with its stacked twin (ISSUE 30)
+    assert sum(n.endswith(" stacked") for n in names) == 5
+    assert sum(" chunk of " in n for n in names) == 2
+    assert len(set(names)) == len(names)
     assert sum(n.startswith("paged_kv_write") for n in names) == 2
     assert any(n.startswith("fused_int4_matmul") for n in names)
     assert all(v["ok"] for v in c["variants"])

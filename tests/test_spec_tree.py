@@ -32,6 +32,8 @@ from clearml_serving_tpu.llm.spec_proposer import (
 )
 from clearml_serving_tpu.ops.paged_attention import (
     ragged_layout,
+    ragged_query_tile,
+    ragged_work_items,
     ragged_paged_attention,
     ragged_paged_attention_xla,
     tree_ancestors,
@@ -148,7 +150,7 @@ def test_tree_ancestors_chain_and_forest():
 
 
 def _tree_setup(key, parents_rows, *, hkv=2, g=2, d=64, page=16,
-                pages_per_seq=4, hist=(12, 5), q_block=8):
+                pages_per_seq=4, hist=(12, 5)):
     """Rows: one tree row per parents list (row_len = node count), with
     per-row history. Returns operands + flat tree_anc."""
     rows = len(parents_rows)
@@ -161,7 +163,9 @@ def _tree_setup(key, parents_rows, *, hkv=2, g=2, d=64, page=16,
     page_table = np.zeros((rows, pages_per_seq), np.int32)
     for r in range(rows):
         page_table[r] = 1 + r * pages_per_seq + np.arange(pages_per_seq)
-    starts, block_rows, block_q0, t_pad = ragged_layout(row_lens, q_block)
+    starts, t_pad = ragged_layout(row_lens)
+    item_rows, item_q0 = ragged_work_items(
+        row_lens, ragged_query_tile(hkv, g, d, jnp.float32))
     q = jax.random.normal(ks[2], (t_pad, hkv, g, d), jnp.float32)
     dmax = max(len(p) for p in parents_rows)
     tree_anc = np.full((t_pad, dmax), -1, np.int32)
@@ -172,7 +176,7 @@ def _tree_setup(key, parents_rows, *, hkv=2, g=2, d=64, page=16,
         tree_anc[s: s + len(parents)] = anc
     return (q, k_pool, v_pool, jnp.asarray(page_table), jnp.asarray(kv_lens),
             jnp.asarray(starts), jnp.asarray(row_lens),
-            jnp.asarray(block_rows), jnp.asarray(block_q0),
+            jnp.asarray(item_rows), jnp.asarray(item_q0),
             jnp.asarray(tree_anc))
 
 
@@ -208,6 +212,9 @@ TOPOLOGIES = {
     "chain": [list(chain_parents(4))],
     "binary": [[-1, 0, 0, 1, 1, 2, 2]],
     "forest": [[-1, 0, 0, 1, 2], list(chain_parents(4)), [-1, 0, 0, 0]],
+    # more than the 8 nodes of a small work item: the ragged kernel takes
+    # this row as a query tile (its mask behind a check of the sentinels)
+    "wide": [[-1, 0, 0, 1, 1, 2, 2, 3, 3, 4, 4, 5], list(chain_parents(3))],
 }
 
 
@@ -245,26 +252,33 @@ def test_tree_mask_chain_topology_equals_plain_causal():
 
 @pytest.mark.parametrize("topo", sorted(TOPOLOGIES))
 @pytest.mark.parametrize("page", [16, 32])
-def test_tree_mask_kernel_interpret_matches_xla(topo, page):
+def test_tree_mask_kernel_interpret_matches_xla(monkeypatch, topo, page):
     """Pallas kernel (interpret) vs XLA reference across topologies,
-    including a partial final page (history not page-aligned)."""
+    including a partial final page (history not page-aligned), over context
+    blocks of two pages."""
+    from clearml_serving_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_DECODE_BLOCK_TOKENS", 2 * page)
     args = _tree_setup(jax.random.PRNGKey(2), TOPOLOGIES[topo],
                        page=page, hist=(page + 3, 5, 2 * page))
     (q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
-     block_rows, block_q0, tree_anc) = args
+     item_rows, item_q0, tree_anc) = args
     ref = ragged_paged_attention_xla(
         q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
         tree_anc=tree_anc)
     out = ragged_paged_attention(
         q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
-        block_rows=block_rows, block_q0=block_q0, tree_anc=tree_anc,
-        pages_per_block=2, interpret=True,
+        item_rows=item_rows, item_q0=item_q0, tree_anc=tree_anc,
+        interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-def test_tree_mask_kernel_int8_interpret_matches_xla():
+def test_tree_mask_kernel_int8_interpret_matches_xla(monkeypatch):
+    from clearml_serving_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_DECODE_BLOCK_TOKENS", 32)
     def _quantize(pool):
         x = np.asarray(pool, np.float32)
         absmax = np.abs(x).max(axis=-1)
@@ -275,7 +289,7 @@ def test_tree_mask_kernel_int8_interpret_matches_xla():
     args = _tree_setup(jax.random.PRNGKey(3), TOPOLOGIES["forest"],
                        hist=(9, 5, 17))
     (q, k_pool, v_pool, page_table, kv_lens, starts, row_lens,
-     block_rows, block_q0, tree_anc) = args
+     item_rows, item_q0, tree_anc) = args
     k8, ks = _quantize(k_pool)
     v8, vs = _quantize(v_pool)
     ref = ragged_paged_attention_xla(
@@ -283,9 +297,9 @@ def test_tree_mask_kernel_int8_interpret_matches_xla():
         tree_anc=tree_anc)
     out = ragged_paged_attention(
         q, k8, v8, page_table, kv_lens, starts, row_lens,
-        block_rows=block_rows, block_q0=block_q0,
+        item_rows=item_rows, item_q0=item_q0,
         k_scale=ks, v_scale=vs, tree_anc=tree_anc,
-        pages_per_block=2, interpret=True,
+        interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)

@@ -37,6 +37,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_text(lowered):
+    """The HLO of a lowered program compiled for the described chip. Such a
+    compile could be written to the persistent cache and never read back
+    without a chip (the next run would warn): keep it out."""
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
 def _compiled_step(one_chip, monkeypatch, quant):
     """One ragged pass and two chained decode passes over donated stacked
     pools, as the engine's ``_ragged_paged_step`` chains them, compiled for
@@ -92,14 +104,7 @@ def _compiled_step(one_chip, monkeypatch, quant):
         params, pools, i32(TOKENS), on_chip((TOKENS,), jnp.bool_), i32(ROWS),
         i32(ROWS, PAGES_PER_SEQ), i32(ROWS), i32(TOKENS),
         i32(TOKENS // 8), i32(2, ROWS))
-    # this compile could be written to the persistent cache and never read
-    # back without a chip (the next run would warn): keep it out
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        return lowered.compile().as_text(), page
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
+    return _compiled_text(lowered), page
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -169,14 +174,101 @@ def test_the_decode_kernel_compiles_at_the_cells_shapes(one_chip, kind, page):
     assert [shape for shape, _ in scratch[2:]] == [(2, 2), (1,)]
     assert call.params["grid_mapping"].grid == (rows,)
 
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        hlo = jax.jit(attend).lower(*operands, **scales).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
+    hlo = _compiled_text(jax.jit(attend).lower(*operands, **scales))
     assert hlo.count("tpu_custom_call") == 1
     assert "paged_attention_decode" in hlo
+
+
+# ------------------------------------------- the ragged kernel's work plan
+
+def _ragged_operands(one_chip, kind, page, pages_per_seq=None, items=None):
+    """The ragged kernel's operands at the benchmark's shapes: 352 flat
+    tokens, 32 rows, Hkv 8, G 4, D 128, a Mistral-7B deployment's pool of
+    28000 tokens over 32 layers, rows of 4096 tokens."""
+    rows, tokens, hkv, g, layers = 32, 352, 8, 4, 32
+    pages_per_seq = pages_per_seq or 4096 // page
+    dtype = jnp.int8 if kind == "int8" else jnp.bfloat16
+
+    def on_chip(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = on_chip((layers, hkv, 28000 // page, page, D), dtype)
+    scales = {}
+    if kind == "int8":
+        scale = on_chip((layers, hkv, 28000 // page, page), jnp.float32)
+        scales = {"k_scale": scale, "v_scale": scale}
+    tile = pa.ragged_query_tile(hkv, g, D, jnp.bfloat16)
+    items = items or pa.ragged_item_count(rows, tokens, tile)
+    operands = (on_chip((tokens, hkv, g, D), jnp.bfloat16), pool, pool,
+                on_chip((rows, pages_per_seq)), on_chip((rows,)),
+                on_chip((rows,)), on_chip((rows,)), on_chip((items,)),
+                on_chip((items,)), on_chip(()))
+
+    def attend(q, k, v, table, kv_lens, starts, row_lens, item_rows, item_q0,
+               layer, **scales):
+        return pa.ragged_paged_attention(
+            q, k, v, table, kv_lens, starts, row_lens, item_rows=item_rows,
+            item_q0=item_q0, layer=layer, **scales)
+
+    return attend, operands, scales
+
+
+@pytest.mark.parametrize("kind, page", [("bf16", 16), ("int8", 32)])
+def test_the_ragged_kernel_compiles_at_the_cells_shapes(one_chip, kind, page):
+    """ISSUE 30: the ragged kernel at the benchmark's shapes lowers for the
+    described v5e as ONE custom call under its trace name. The plan is what
+    the shapes say: a grid step per work item (32 rows + 352 // 128 further
+    tiles = 34), a tile of 128 queries in four sub tiles of 32 (128 MXU rows
+    a head), two slots of a 512-token block of all 8 heads a side, two q
+    slots and the out buffer head-major, the tile's flash state, one DMA
+    semaphore per (slot, side), per q slot and for the out copies, four
+    scalars for the walk."""
+    attend, operands, scales = _ragged_operands(one_chip, kind, page)
+    call, = [e for e in jax.make_jaxpr(attend)(*operands, **scales).eqns
+             if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (34,)
+    n_scratch = call.params["grid_mapping"].num_scratch_operands
+    scratch = [(v.aval.shape, str(v.aval.dtype))
+               for v in call.params["jaxpr"].invars[-n_scratch:]]
+    pool = jnp.dtype(jnp.int8 if kind == "int8" else jnp.bfloat16).name
+    assert scratch[:7] == [
+        ((2, 8, 512, D), "bfloat16"), ((8, 512, D), "bfloat16"),
+        ((2, 8, 512, D), pool), ((2, 8, 512, D), pool),
+        ((4, 8, 128, 1), "float32"), ((4, 8, 128, 1), "float32"),
+        ((4, 8, 128, D), "float32")]
+    assert [shape for shape, _ in scratch[7:]] == [(2, 2), (2,), (1,), (4,)]
+
+    hlo = _compiled_text(jax.jit(attend).lower(*operands, **scales))
+    assert hlo.count("tpu_custom_call") == 1
+    assert "ragged_paged_attention" in hlo
+
+
+@pytest.mark.parametrize("pages_per_seq", [256, 272])
+def test_the_smem_estimate_agrees_with_the_compiler_on_the_work_plan(
+        one_chip, pages_per_seq):
+    """paged_kernel_smem_bytes against the v5e compiler for the ragged
+    kernel's scalar operands (page table, three row vectors, layer, the
+    plan's two vectors, the walk's counters), at the cells' page tables
+    ([32, 256] Mistral, [32, 272] Mixtral): the longest plan the estimate
+    lets through compiles, and one 1,024 items longer (8 KB) does not, for
+    want of scalar memory."""
+    def estimate(items):
+        return pa.paged_kernel_smem_bytes(32, pages_per_seq, 352, 0, items)
+
+    fit = max(n for n in range(120000, 132000, 8)
+              if estimate(n) <= pa.SMEM_BYTES)
+    attend, operands, _ = _ragged_operands(
+        one_chip, "bf16", 16, pages_per_seq, items=fit)
+    assert "ragged_paged_attention" in _compiled_text(
+        jax.jit(attend).lower(*operands))
+    attend, operands, _ = _ragged_operands(
+        one_chip, "bf16", 16, pages_per_seq, items=fit + 1024)
+    assert estimate(fit + 1024) > pa.SMEM_BYTES
+    with pytest.raises(Exception, match="smem"):
+        _compiled_text(jax.jit(attend).lower(*operands))
+    # the engine's own plan of 34 items is well inside (its page table is
+    # nearly all of the 53.5 KB)
+    assert estimate(34) < pa.SMEM_BYTES // 16
 
 
 # ------------------------------------------------- the state cache's step
@@ -233,12 +325,7 @@ def test_the_v5e_state_step_updates_the_state_pools_in_place(
         params, on_chip(s_shape, jnp.float32), on_chip(z_shape, jnp.float32),
         i32(tokens), on_chip((tokens,), jnp.bool_), i32(slots), i32(slots),
         on_chip((slots,), jnp.bool_), on_chip((2, slots), jnp.bool_))
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        hlo = lowered.compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
+    hlo = _compiled_text(lowered)
     for name in ("power_retention_update", "power_retention_chunk"):
         assert name in hlo, name
     pools = {"f32[{}]".format(",".join(map(str, s))) for s in (s_shape, z_shape)}
