@@ -71,6 +71,7 @@ from .sampling import (
     penalize_logits,
     speculative_sample_chain,
     speculative_sample_tree,
+    row_needs,
     sample_tokens,
 )
 
@@ -363,6 +364,9 @@ class _InFlightChunk:
     # paged backend: (rows x passes, tokens those rows attended) of the
     # chunk's decode passes, counted by the loop thread on landing
     chain_work: tuple = (0, 0)
+    # rows the chunk's sampler calls treat as live ([B] bool; the paged
+    # chunk's device-side ``active`` is every row that holds tokens)
+    live: Any = None
 
 
 def _decode_pass_work(first, passes) -> tuple:
@@ -1442,6 +1446,14 @@ class LLMEngineCore:
             # back to plain decode (docs/spec_decode_trees.md fallback row)
             "spec_tree_fallbacks": 0,
         }
+        # the ``sampler`` block of health() / lifecycle_stats(): sampler calls
+        # inside launches (a ragged launch's mixed pass and chained steps, a
+        # decode chunk's steps) and those of them whose live rows switch on
+        # the sort / the random draw (llm/sampling.py row_needs); a request's
+        # first token is sampled alone at [1, V] and is not a launch pass
+        self._sampler_passes = {
+            "passes": 0, "filtered_passes": 0, "drawn_passes": 0,
+        }
         # -- SLO-aware scheduling state (docs/slo_scheduling.md) ----------
         # per-(reason, class) shed counters backing engine_sheds_total
         self._class_sheds: Dict[str, Dict[str, int]] = {}
@@ -1901,12 +1913,15 @@ class LLMEngineCore:
                 if guided is not None:
                     logits = _guided_mask(logits, gstate, guided)
                 if extras is None:
-                    sampled = sample_tokens(logits, sampling, step_rng)
+                    sampled = sample_tokens(
+                        logits, sampling, step_rng, live=active
+                    )
                     lp_src = logits
                 else:
                     ex = extras._replace(counters=extras.counters + step_off)
                     sampled = sample_tokens(
-                        logits, sampling, step_rng, ex, counts, pmask
+                        logits, sampling, step_rng, ex, counts, pmask,
+                        live=active,
                     )
                     # reported logprobs reflect bias/penalties (OpenAI
                     # semantics); XLA CSEs this against the sampler's own
@@ -2138,12 +2153,15 @@ class LLMEngineCore:
                     if guided is not None:
                         l0 = _guided_mask(l0, gstate, guided)
                     if extras is None:
-                        sampled = sample_tokens(l0, sampling, step_rng)
+                        sampled = sample_tokens(
+                            l0, sampling, step_rng, live=ns_mask
+                        )
                         lp_src = l0
                     else:
                         ex = extras._replace(counters=extras.counters + step_off)
                         sampled = sample_tokens(
-                            l0, sampling, step_rng, ex, counts, pmask
+                            l0, sampling, step_rng, ex, counts, pmask,
+                            live=ns_mask,
                         )
                         lp_src = (
                             penalize_logits(l0, ex, counts, pmask)
@@ -2259,12 +2277,15 @@ class LLMEngineCore:
                 if guided is not None:
                     logits = _guided_mask(logits, gstate, guided)
                 if extras is None:
-                    sampled = sample_tokens(logits, sampling, step_rng)
+                    sampled = sample_tokens(
+                        logits, sampling, step_rng, live=active
+                    )
                     lp_src = logits
                 else:
                     ex = extras._replace(counters=extras.counters + step)
                     sampled = sample_tokens(
-                        logits, sampling, step_rng, ex, counts, pmask
+                        logits, sampling, step_rng, ex, counts, pmask,
+                        live=active,
                     )
                     lp_src = (
                         penalize_logits(logits, ex, counts, pmask)
@@ -2334,11 +2355,12 @@ class LLMEngineCore:
                 if guided is not None:
                     masked = _guided_mask(masked, gstate, guided)
                 if extras is None:
-                    sampled = sample_tokens(masked, sampling, rng)
+                    sampled = sample_tokens(masked, sampling, rng, live=mask)
                     lp_src = masked
                 else:
                     sampled = sample_tokens(
-                        masked, sampling, rng, extras, counts, pmask
+                        masked, sampling, rng, extras, counts, pmask,
+                        live=mask,
                     )
                     lp_src = (
                         penalize_logits(masked, extras, counts, pmask)
@@ -2422,14 +2444,14 @@ class LLMEngineCore:
                 if guided is not None:
                     l = _guided_mask(l, gstate, guided)
                 if extras is None:
-                    s_tok = sample_tokens(l, sampling, s_rng)
+                    s_tok = sample_tokens(l, sampling, s_rng, live=m)
                     lp_src = l
                 else:
                     ex = extras._replace(
                         counters=extras.counters + step + 1
                     )
                     s_tok = sample_tokens(
-                        l, sampling, s_rng, ex, counts, pmask
+                        l, sampling, s_rng, ex, counts, pmask, live=m
                     )
                     lp_src = (
                         penalize_logits(l, ex, counts, pmask)
@@ -4269,6 +4291,7 @@ class LLMEngineCore:
                 if self._ragged
                 else None
             ),
+            "sampler": dict(self._sampler_passes),
             "kv_pool": self._kv_pool_snapshot(),
             "state_pool": self._state_pool_snapshot(),
             "kv_tier": self._kv_tier_snapshot(),
@@ -4416,6 +4439,7 @@ class LLMEngineCore:
                 if self._ragged
                 else None
             ),
+            "sampler": dict(self._sampler_passes),
             "kv_pool": self._kv_pool_snapshot(),
             "state_pool": self._state_pool_snapshot(),
             "kv_tier": self._kv_tier_snapshot(),
@@ -6602,6 +6626,9 @@ class LLMEngineCore:
             t for _, t in live_shares
         )
         self.counters["ragged_passes"] += int(plan["launch_steps"])
+        self._count_sampler_passes(
+            int(plan["launch_steps"]), plan["row_steps"]
+        )
         if self.cache_mode == "paged":
             # a row of window n rides chained passes 1..n-1 and attends
             # pre_len + 1 + step tokens in pass ``step``
@@ -7079,6 +7106,9 @@ class LLMEngineCore:
             return
         self._inflight.append(entry)
         self._count_decode_passes(entry.chain_work)
+        self._count_sampler_passes(
+            self.decode_steps, entry.live * self.decode_steps
+        )
         for slot in entry.exhausted:
             self._fail_slot(
                 slot, MemoryError("kv page pool exhausted for this sequence")
@@ -7159,8 +7189,9 @@ class LLMEngineCore:
         want_lp = prep["want_lp"]
         exhausted: List[int] = []
         chain_work = (0, 0)
+        live = active_mask
         if self.cache_mode == "paged":
-            chunk, lp, gstate_out, chain_work = self._dispatch_paged(
+            chunk, lp, gstate_out, chain_work, live = self._dispatch_paged(
                 prep, exhausted
             )
         else:
@@ -7200,6 +7231,7 @@ class LLMEngineCore:
             dispatched_at=t0,
             exhausted=exhausted,
             chain_work=chain_work,
+            live=live,
         )
 
     def _dispatch_paged(self, prep: dict, exhausted: List[int]):
@@ -7216,7 +7248,8 @@ class LLMEngineCore:
         lengths0 = pool.lengths().copy()          # pre-extension lengths
         # pass ``s`` of the chunk attends lengths0 + s + 1 tokens in every
         # row that holds any (the device's ``active``)
-        held = lengths0[lengths0 > 0]
+        live = lengths0 > 0
+        held = lengths0[live]
         chain_work = _decode_pass_work(held + 1, np.full(held.shape, n))
         write_pages = np.zeros((self.max_batch, n), np.int32)   # null page 0
         write_offsets = np.zeros((self.max_batch, n), np.int32)
@@ -7281,12 +7314,30 @@ class LLMEngineCore:
                 self.paged_cache.v_scale = new_v_scale
         if use_extras:
             self._counts_dev = new_counts
-        return chunk, lp, gstate_out, chain_work
+        return chunk, lp, gstate_out, chain_work, live
 
     def _count_decode_passes(self, work: tuple) -> None:
         """Loop thread: add a launch's :func:`_decode_pass_work`."""
         self.counters["decode_chain_rows"] += work[0]
         self.counters["decode_chain_kv_tokens"] += work[1]
+
+    def _count_sampler_passes(self, passes: int, row_passes) -> None:
+        """Loop thread: a launch sampled ``passes`` times and slot ``r`` was
+        live in the first ``row_passes[r]`` of them. Counts how many of those
+        calls took the sampler's sorted / drawing branch, from the host rows
+        the launch's ``SamplingParams`` were copied from: a slot's rows
+        change only at commit, which needs the slot free."""
+        filters, draws = row_needs(
+            self._temperature, self._top_k, self._top_p, row_passes > 0
+        )
+        count = self._sampler_passes
+        count["passes"] += passes
+        count["filtered_passes"] += int(
+            np.max(row_passes, where=filters, initial=0)
+        )
+        count["drawn_passes"] += int(
+            np.max(row_passes, where=draws, initial=0)
+        )
 
     def _chain_input(self, dev, host_vec):
         """Next chunk's [B] input vector: chained from the previous chunk's
@@ -7438,6 +7489,11 @@ class LLMEngineCore:
         if epoch != self._recover_epoch:
             await self._finish_recovery()
             return
+        # each round samples the rows that are not speculating
+        self._count_sampler_passes(
+            gs.shape[0],
+            gs.shape[0] * (active_mask & ~spec_mask & ~sspec_mask),
+        )
         for r in range(gs.shape[0]):
             for slot in np.nonzero(active_mask)[0]:
                 slot = int(slot)

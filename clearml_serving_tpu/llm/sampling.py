@@ -5,6 +5,23 @@ the batch), never as Python branches, so a single XLA executable covers every
 mix of sampling settings in the continuous batch (recompilation-free,
 SURVEY.md §7 hard part 1).
 
+What a launch's sampling tail COSTS follows from what its live rows asked
+for, decided on the device by two ``lax.cond``s over those same arrays
+(:func:`row_needs`); the settings stay data and the executable stays one:
+- every live row greedy (``temperature == 0``): the argmax of the (penalized)
+  logits, and nothing else of the vocabulary's size — no temperature scaling,
+  sort, softmax, cumulative sum or random draw;
+- some live row samples, none filters: the scaling and the Gumbel draw;
+- some live row samples with ``top_k > 0`` or ``top_p < 1``: one sort of the
+  whole ``[B, V]`` block more (for every row of the launch — the known
+  remainder), with the softmax, cumulative sum and cutoff behind it.
+A row's result never depends on which of these its launch took: a row that
+asked for no filter gets none in the sorted branch either. (Until PR 34 a
+temperature-only row went through the nucleus mask at ``top_p == 1`` and
+could lose a few tail tokens of ~1e-8 mass to the float32 rounding of a
+cumulative sum that reaches 1 early; it now samples from
+``softmax(logits / T)`` exactly.)
+
 OpenAI/vLLM sampling-parameter parity (reference §2.8 route surface):
 - ``presence_penalty`` / ``frequency_penalty``: subtracted from the logits of
   tokens already generated (vLLM semantics: output tokens only), presence as
@@ -122,6 +139,20 @@ def _row_keys(rng: jax.Array, extras: SamplingExtras, batch: int):
     return jnp.where(use_seed, seeded, shared)
 
 
+def row_needs(temperature, top_k, top_p, live=None):
+    """Per row, what the sampler has to do for it: ``(filters, draws)``.
+
+    ``draws``: the row samples (``temperature > 0``) and is ``live`` (a slot
+    that is not advancing in this launch keeps its last request's settings,
+    and nobody reads its token). ``filters``: it also asked for top-k or
+    top-p. Plain comparisons, so the engine counts its launches from its
+    host rows (numpy) with the predicate the device branches on."""
+    draws = temperature > 0.0
+    if live is not None:
+        draws = draws & live
+    return draws & ((top_k > 0) | (top_p < 1.0)), draws
+
+
 def warp_logits(
     logits: jnp.ndarray,
     temperature: jnp.ndarray,
@@ -131,29 +162,39 @@ def warp_logits(
     """Temperature-scale + top-k + top-p mask: [N, V] logits with per-row
     params [N] -> masked scaled logits (softmax of the result IS the
     sampling distribution). Shared by sample_tokens and the speculative
-    rejection sampler so both sample from the identical law."""
+    rejection sampler so both sample from the identical law.
+
+    The sort, softmax, cumulative sum and cutoff run only when some row
+    samples WITH a filter (``row_needs``); a row without one gets its
+    scaled logits back in either branch."""
     n, v = logits.shape
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = logits / temp
+    filters, _ = row_needs(temperature, top_k, top_p)
 
-    # top-k mask (k == 0 disables)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]              # [N, V]
-    k = jnp.where(top_k > 0, top_k, v)
-    kth = jnp.take_along_axis(
-        sorted_desc, jnp.minimum(k - 1, v - 1)[:, None], axis=-1
-    )                                                              # [N, 1]
-    scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
+    def masked(scaled):
+        # top-k (k == 0 disables): masking below the k-th value is monotone,
+        # so the masked row's descending sort IS the sort with the same mask
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]          # [N, V]
+        k = jnp.where(top_k > 0, top_k, v)
+        kth = jnp.take_along_axis(
+            sorted_desc, jnp.minimum(k - 1, v - 1)[:, None], axis=-1
+        )                                                          # [N, 1]
+        kept = jnp.where(scaled < kth, -jnp.inf, scaled)
+        sorted_kept = jnp.where(sorted_desc < kth, -jnp.inf, sorted_desc)
 
-    # top-p (nucleus) mask over the sorted distribution
-    sorted_scaled = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs_sorted = jax.nn.softmax(sorted_scaled, axis=-1)
-    cumulative = jnp.cumsum(probs_sorted, axis=-1)
-    # keep tokens while cumulative(prev) < top_p  (always keep the first)
-    keep_sorted = (cumulative - probs_sorted) < top_p[:, None]
-    cutoff = jnp.where(
-        keep_sorted, sorted_scaled, jnp.inf
-    ).min(axis=-1, keepdims=True)                                  # lowest kept logit
-    return jnp.where(scaled < cutoff, -jnp.inf, scaled)
+        # top-p (nucleus) mask over the sorted distribution
+        probs_sorted = jax.nn.softmax(sorted_kept, axis=-1)
+        cumulative = jnp.cumsum(probs_sorted, axis=-1)
+        # keep tokens while cumulative(prev) < top_p  (always keep the first)
+        keep_sorted = (cumulative - probs_sorted) < top_p[:, None]
+        cutoff = jnp.where(
+            keep_sorted, sorted_kept, jnp.inf
+        ).min(axis=-1, keepdims=True)                              # lowest kept logit
+        kept = jnp.where(kept < cutoff, -jnp.inf, kept)
+        return jnp.where(filters[:, None], kept, scaled)
+
+    return jax.lax.cond(jnp.any(filters), masked, lambda scaled: scaled, scaled)
 
 
 @partial(jax.jit, donate_argnums=())
@@ -165,27 +206,37 @@ def sample_tokens(
     extras: Optional[SamplingExtras] = None,
     counts: Optional[jnp.ndarray] = None,
     prompt_mask: Optional[jnp.ndarray] = None,
+    live: Optional[jnp.ndarray] = None,
 ):
     """logits: [B, V] float32 -> token ids [B] int32.
 
     Rows with temperature == 0 take the argmax; others sample from the
     temperature-scaled, top-k/top-p-filtered distribution. Penalties/bias
     (extras) apply to BOTH paths — greedy decoding respects them too.
+
+    ``live`` [B] bool: the rows whose token the caller reads. A row outside
+    it is treated as greedy, so a freed slot's stale settings switch on
+    neither the draw nor (in ``warp_logits``) the sort.
     """
     b, v = logits.shape
     if extras is not None:
         logits = penalize_logits(logits, extras, counts, prompt_mask)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = warp_logits(logits, params.temperature, params.top_k, params.top_p)
+    temperature = params.temperature
+    if live is not None:
+        temperature = jnp.where(live, temperature, 0.0)
 
-    if extras is None:
-        sampled = jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
-    else:
-        keys = _row_keys(rng, extras, b)
-        sampled = jax.vmap(
-            lambda key, row: jax.random.categorical(key, row)
-        )(keys, scaled).astype(jnp.int32)
-    return jnp.where(params.temperature <= 0.0, greedy, sampled)
+    def draw():
+        scaled = warp_logits(logits, temperature, params.top_k, params.top_p)
+        if extras is None:
+            sampled = jax.random.categorical(rng, scaled, axis=-1)
+        else:
+            sampled = jax.vmap(
+                lambda key, row: jax.random.categorical(key, row)
+            )(_row_keys(rng, extras, b), scaled)
+        return jnp.where(temperature <= 0.0, greedy, sampled.astype(jnp.int32))
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), draw, lambda: greedy)
 
 
 def greedy_tree_walk(

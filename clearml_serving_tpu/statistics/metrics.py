@@ -493,6 +493,16 @@ class EngineLifecycleCollector(_KeyedCollector):
             p + "_state_pool_resets_total",
             "launches that zeroed a state slot for a new owner",
         )
+        # the sampler's cost follows from what a launch's live rows asked
+        # for (llm/sampling.py): kind="filtered" / "drawn" passes ran the
+        # whole-vocabulary sort / the random draw; 0 of "all" on greedy
+        # traffic
+        sampler_passes = CounterMetricFamily(
+            p + "_sampler_passes",
+            "sampler calls inside launches, by kind (all, filtered = some "
+            "live row asked for top-k or top-p, drawn = some live row "
+            "samples)",
+        )
         # host-RAM KV tier (docs/kv_tiering.md): where the prefix cache's
         # pages live (hbm vs host) and how many moved each way — the
         # capacity-planning signal the tier exists for
@@ -630,6 +640,7 @@ class EngineLifecycleCollector(_KeyedCollector):
         any_requests = False
         any_kv_pool = False
         any_state_pool = False
+        any_sampler = False
         any_kv_tier = False
         any_kv_ship = False
         any_kv_wire = False
@@ -661,6 +672,14 @@ class EngineLifecycleCollector(_KeyedCollector):
                 gauge(state_pool_bytes, key, s, state_pool["bytes_per_slot"],
                       kind="slot")
                 counter(state_pool_resets, key, s, state_pool["resets"])
+            sampler = s.get("sampler") or {}
+            if sampler:
+                any_sampler = True
+                counter(sampler_passes, key, s, sampler["passes"], kind="all")
+                counter(sampler_passes, key, s, sampler["filtered_passes"],
+                        kind="filtered")
+                counter(sampler_passes, key, s, sampler["drawn_passes"],
+                        kind="drawn")
             kv_tier = s.get("kv_tier") or {}
             if kv_tier:
                 any_kv_tier = True
@@ -855,6 +874,8 @@ class EngineLifecycleCollector(_KeyedCollector):
             yield state_pool_slots
             yield state_pool_bytes
             yield state_pool_resets
+        if any_sampler:
+            yield sampler_passes
         if any_kv_tier:
             yield kv_tier_pages
             yield kv_tier_bytes

@@ -38,27 +38,34 @@ def _engine(bundle, params, **kw):
     return LLMEngineCore(bundle, params, **kw)
 
 
-def _staggered(engine, prompts, n=8, seeds=None):
-    """Submit prompts 50 ms apart so later admissions overlap live decode
-    streams — the mixed prefill+decode batch the ragged scheduler exists
-    for. Seeded entries sample at temperature (deterministic per seed)."""
+def _serve(engine, requests):
+    """Submit (prompt, max_new_tokens, sampling keywords) requests 50 ms
+    apart, so later admissions overlap live decode streams — the mixed
+    prefill+decode batch the ragged scheduler exists for — and drain."""
 
-    async def one(i, ids):
+    async def one(i, ids, n, kw):
         if i:
             await asyncio.sleep(0.05 * i)
-        seed = seeds[i] if seeds else None
-        req = GenRequest(
-            prompt_ids=list(ids), max_new_tokens=n,
-            temperature=0.7 if seed is not None else 0.0, seed=seed,
-        )
+        req = GenRequest(prompt_ids=list(ids), max_new_tokens=n, **kw)
         return [t async for t in engine.generate(req)]
 
     async def run():
-        outs = await asyncio.gather(*(one(i, p) for i, p in enumerate(prompts)))
+        outs = await asyncio.gather(
+            *(one(i, *r) for i, r in enumerate(requests)))
         await engine.wait_drained()
         return outs
 
     return asyncio.run(run())
+
+
+def _staggered(engine, prompts, n=8, seeds=None):
+    """:func:`_serve` of ``prompts``; seeded entries sample at temperature
+    (deterministic per seed), the others are greedy."""
+    seeds = seeds or [None] * len(prompts)
+    return _serve(engine, [
+        (ids, n, {"temperature": 0.7, "seed": seed} if seed is not None else {})
+        for ids, seed in zip(prompts, seeds)
+    ])
 
 
 def _ab(bundle, params, prompts, *, seeds=None, n=8, legacy_kw=None,
@@ -647,3 +654,130 @@ def test_ragged_retire_reads_back_only_finishing_rows(parts, monkeypatch):
         # gather is at most 2 rows
         assert shape[1] == vocab
         assert shape[0] <= 2
+
+
+# -- the sampler does what the launch's live rows asked for (ISSUE 34) --------
+
+
+def test_sampler_counters_follow_the_live_rows(parts, monkeypatch):
+    """``lifecycle_stats()["sampler"]``: counted on the host from the host
+    rows and the launch's own row windows, and equal, call for call, to the
+    predicates the two conds see on the device (a spy evaluates
+    ``row_needs`` inside every launch's sampler calls). Greedy traffic
+    moves neither ``filtered_passes`` nor ``drawn_passes``; one
+    ``temperature=0.7, top_p=0.9`` request moves both for exactly the
+    passes it is live in; they stop when it finishes, although its freed
+    slot keeps its settings in the host rows."""
+    import jax.numpy as jnp
+    from prometheus_client import CollectorRegistry
+
+    from clearml_serving_tpu.llm import engine as engine_mod
+    from clearml_serving_tpu.llm.sampling import row_needs
+    from clearml_serving_tpu.statistics.metrics import register_engine_lifecycle
+
+    seen = []                    # (filtered, drawn) per in-launch sampler call
+    real = engine_mod.sample_tokens
+
+    def spy(logits, params, rng, *extras, live=None):
+        if live is not None:     # a first token is sampled alone, uncounted
+            filters, draws = row_needs(*params, live)
+            jax.debug.callback(
+                lambda f, d: seen.append((bool(f), bool(d))),
+                jnp.any(filters), jnp.any(draws))
+        return real(logits, params, rng, *extras, live=live)
+
+    monkeypatch.setattr(engine_mod, "sample_tokens", spy)
+    bundle, _, params = parts
+    engine = _engine(bundle, params, cache_mode="paged", max_batch=4,
+                     step_token_budget=12, decode_steps=2)
+
+    def stats():
+        jax.effects_barrier()
+        s = engine.lifecycle_stats()["sampler"]
+        assert s == engine.health()["sampler"]
+        assert s["passes"] == len(seen)
+        assert s["filtered_passes"] == sum(f for f, _ in seen)
+        assert s["drawn_passes"] == sum(d for _, d in seen)
+        return s
+
+    try:
+        _serve(engine, [(SHORT, 6, {}), (LONG, 8, {})])
+        greedy = stats()
+        assert greedy["passes"] > 0
+        assert greedy["filtered_passes"] == greedy["drawn_passes"] == 0
+
+        sampled = {"temperature": 0.7, "top_p": 0.9, "seed": 5}
+        _serve(engine, [(LONG, 30, {}), (SHORT, 6, sampled), (SHORT, 4, {})])
+        mixed = stats()
+        moved = mixed["filtered_passes"]
+        assert 0 < moved == mixed["drawn_passes"]
+        # the greedy stream outlives it: launches after it left sort nothing
+        assert moved < mixed["passes"] - greedy["passes"]
+        assert (engine._temperature > 0).any()     # the freed slot's stale row
+
+        _serve(engine, [(SHORT, 6, {}), (LONG, 8, {"temperature": 0.6})])
+        after = stats()
+        assert after["filtered_passes"] == moved   # temperature only: no sort
+        assert after["drawn_passes"] > moved
+        registry = CollectorRegistry()
+        register_engine_lifecycle(
+            engine.lifecycle_stats, registry=registry, key="m")
+        for kind, name in (("all", "passes"), ("filtered", "filtered_passes"),
+                           ("drawn", "drawn_passes")):
+            assert registry.get_sample_value(
+                "engine_sampler_passes_total", {"model": "m", "kind": kind}
+            ) == after[name]
+    finally:
+        engine.stop()
+
+
+def _primitives(jaxpr, names, under_cond=False):
+    """(primitive, whether a ``cond`` encloses it) of every equation of
+    ``jaxpr``, and of every jaxpr nested in it, whose primitive is in
+    ``names``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in names:
+            found.append((eqn.primitive.name, under_cond))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _primitives(
+                sub, names, under_cond or eqn.primitive.name == "cond")
+    return found
+
+
+def test_launch_programs_sort_and_draw_only_under_a_cond(parts):
+    """The two launch programs of a paged engine, traced with the arguments
+    the engine itself hands them while serving: every whole-vocabulary
+    ``sort`` and every random-bits primitive sits under a ``cond``, in the
+    mixed pass, the chained steps and the decode chunk's scan alike."""
+    bundle, _, params = parts
+    engine = _engine(bundle, params, cache_mode="paged", max_batch=4,
+                     step_token_budget=12, decode_steps=2)
+    programs = {}
+
+    def record(name):
+        jitted = getattr(engine, name)
+
+        def call(*args, **kw):
+            if name not in programs:
+                shapes = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                    if hasattr(a, "shape") else a, (args, kw))
+                programs[name] = jitted.trace(*shapes[0], **shapes[1]).jaxpr
+            return jitted(*args, **kw)
+
+        setattr(engine, name, call)
+
+    try:
+        record("_ragged_paged_jit")
+        record("_decode_paged_chunk_jit")
+        _serve(engine, [(LONG, 12, {}),
+                        (SHORT, 8, {"temperature": 0.7, "top_p": 0.9})])
+    finally:
+        engine.stop()
+    assert set(programs) == {"_ragged_paged_jit", "_decode_paged_chunk_jit"}
+    costly = {"sort", "random_bits", "threefry2x32"}
+    for name, jaxpr in programs.items():
+        found = _primitives(jaxpr.jaxpr, costly)
+        assert ("sort", True) in found and len(found) > 1, name
+        assert all(under_cond for _, under_cond in found), (name, found)

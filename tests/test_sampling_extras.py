@@ -11,11 +11,16 @@ import pytest
 
 from clearml_serving_tpu import models
 from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
+from clearml_serving_tpu.llm import sampling
 from clearml_serving_tpu.llm.sampling import (
     SamplingExtras,
+    SamplingParams,
+    _row_keys,
     make_sampling_params,
     penalize_logits,
+    row_needs,
     sample_tokens,
+    warp_logits,
 )
 
 CFG = {"preset": "llama-tiny", "dtype": "float32"}
@@ -340,3 +345,201 @@ def test_paged_cache_with_penalties(parts):
     )
     engine.stop()
     assert toks == [42, 42, 42, 42]
+
+# -- the sampler does what the launch's rows asked for (PR 34) ----------------
+# Plain reference: warp_logits / sample_tokens as they stood before the two
+# conds (two whole-vocabulary sorts, softmax, cumsum and a draw in every
+# call), copied here so the rewritten module is held to the old tokens.
+
+
+def _ref_warp_logits(logits, temperature, top_k, top_p):
+    n, v = logits.shape
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    scaled = logits / temp
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k = jnp.where(top_k > 0, top_k, v)
+    kth = jnp.take_along_axis(
+        sorted_desc, jnp.minimum(k - 1, v - 1)[:, None], axis=-1
+    )
+    scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
+    sorted_scaled = jnp.sort(scaled, axis=-1)[:, ::-1]
+    probs_sorted = jax.nn.softmax(sorted_scaled, axis=-1)
+    cumulative = jnp.cumsum(probs_sorted, axis=-1)
+    keep_sorted = (cumulative - probs_sorted) < top_p[:, None]
+    cutoff = jnp.where(
+        keep_sorted, sorted_scaled, jnp.inf
+    ).min(axis=-1, keepdims=True)
+    return jnp.where(scaled < cutoff, -jnp.inf, scaled)
+
+
+@jax.jit
+def _ref_sample_tokens(logits, params, rng, extras=None, counts=None,
+                       prompt_mask=None):
+    b, v = logits.shape
+    if extras is not None:
+        logits = penalize_logits(logits, extras, counts, prompt_mask)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = _ref_warp_logits(
+        logits, params.temperature, params.top_k, params.top_p
+    )
+    if extras is None:
+        sampled = jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
+    else:
+        keys = _row_keys(rng, extras, b)
+        sampled = jax.vmap(
+            lambda key, row: jax.random.categorical(key, row)
+        )(keys, scaled).astype(jnp.int32)
+    return jnp.where(params.temperature <= 0.0, greedy, sampled)
+
+
+# (temperature, top_k, top_p) a row can ask for. "greedy_k" is a greedy row
+# that also sent filter settings: still the argmax, and no sort for it.
+_KINDS = {
+    "greedy": (0.0, 0, 1.0),
+    "greedy_k": (0.0, 7, 0.5),
+    "temp": (0.8, 0, 1.0),
+    "topk": (0.8, 5, 1.0),
+    "topp": (0.7, 0, 0.9),
+    "both": (1.3, 40, 0.95),
+}
+_FILTERED = ("topk", "topp", "both")
+
+
+def _params_of(kinds):
+    t, k, p = zip(*(_KINDS[kind] for kind in kinds))
+    return SamplingParams(
+        temperature=jnp.asarray(t, jnp.float32),
+        top_k=jnp.asarray(k, jnp.int32),
+        top_p=jnp.asarray(p, jnp.float32),
+    )
+
+
+def _compositions(batch):
+    """Launches that cross both branches of both conds: every kind beside
+    every class of neighbour (all greedy / sampling without a filter /
+    filtering), and one launch of all kinds."""
+    names = list(_KINDS)
+    out = [[names[(r + shift) % len(names)] for r in range(batch)]
+           for shift in range(len(names))]
+    for others in ("greedy", "temp", "topp"):
+        for first in names:
+            out.append([first] + [others] * (batch - 1))
+    return out
+
+
+@pytest.mark.parametrize("with_extras", [False, True], ids=["plain", "extras"])
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("vocab", [257, 32000])
+def test_sampler_rows_match_reference_in_any_launch(vocab, batch, with_extras):
+    rng = np.random.default_rng(vocab + batch)
+    logits = jnp.asarray(rng.normal(0.0, 3.0, (batch, vocab)), jnp.float32)
+    key = jax.random.PRNGKey(34)
+    tail = ()
+    if with_extras:
+        seeds = np.where(np.arange(batch) % 2 == 0, 11 + np.arange(batch), -1)
+        extras = _extras(
+            batch, vocab, presence=0.4, frequency=0.1, repetition=1.2,
+            bias=rng.normal(0.0, 1.0, (batch, vocab)).astype(np.float32),
+            seeds=seeds.astype(np.int32),
+            counters=np.arange(batch, dtype=np.int32),
+        )
+        counts = jnp.asarray(rng.integers(0, 3, (batch, vocab)), jnp.int32)
+        pmask = jnp.asarray(rng.random((batch, vocab)) < 0.1)
+        tail = (extras, counts, pmask)
+        penalized = penalize_logits(logits, extras, counts, pmask)
+        row_keys = _row_keys(key, extras, batch)
+    else:
+        penalized = logits
+    greedy = np.asarray(jnp.argmax(penalized, axis=-1))
+
+    seen = {}
+    cache0 = sample_tokens._cache_size()
+    for kinds in _compositions(batch):
+        params = _params_of(kinds)
+        got = np.asarray(sample_tokens(logits, params, key, *tail))
+        ref = np.asarray(_ref_sample_tokens(logits, params, key, *tail))
+        warped = np.asarray(warp_logits(penalized, *params))
+        ref_warped = np.asarray(_ref_warp_logits(penalized, *params))
+        scaled = penalized / jnp.maximum(params.temperature, 1e-6)[:, None]
+        if with_extras:
+            plain = jax.vmap(jax.random.categorical)(row_keys, scaled)
+        else:
+            plain = jax.random.categorical(key, scaled, axis=-1)
+        plain = np.asarray(plain)
+        for r, kind in enumerate(kinds):
+            if kind.startswith("greedy"):
+                assert got[r] == greedy[r] == ref[r], (kinds, r)
+            elif kind in _FILTERED:
+                # bit for bit the old masked logits, so the old token
+                assert got[r] == ref[r], (kinds, r)
+                np.testing.assert_array_equal(warped[r], ref_warped[r])
+                assert np.isinf(warped[r]).any()
+            else:
+                # no filter asked for, none applied, in either branch
+                assert got[r] == plain[r], (kinds, r)
+                np.testing.assert_array_equal(warped[r], np.asarray(scaled[r]))
+            # the same row with the same settings draws the same token
+            # whoever shares its launch
+            assert seen.setdefault((r, kind), got[r]) == got[r], (kinds, r)
+    # the settings are data: every mix above ran one executable
+    assert sample_tokens._cache_size() - cache0 <= 1
+
+
+def test_row_needs_is_the_predicate_of_both_conds():
+    t = np.asarray([0.0, 0.0, 0.8, 0.8, 0.7, 0.7], np.float32)
+    k = np.asarray([0, 7, 0, 5, 0, 0], np.int32)
+    p = np.asarray([1.0, 0.5, 1.0, 1.0, 0.9, 0.9], np.float32)
+    live = np.asarray([True, True, True, True, True, False])
+    filters, draws = row_needs(t, k, p, live)
+    assert filters.tolist() == [False, False, False, True, True, False]
+    assert draws.tolist() == [False, False, True, True, True, False]
+    filters, draws = row_needs(jnp.asarray(t), jnp.asarray(k), jnp.asarray(p))
+    assert np.asarray(filters).tolist() == [False, False, False, True, True, True]
+    assert np.asarray(draws).tolist() == [False, False, True, True, True, True]
+
+
+@pytest.mark.parametrize(
+    "kinds, live, sorts, draws",
+    [
+        (["greedy", "greedy_k", "greedy", "greedy"], None, 0, 0),
+        (["greedy", "temp", "greedy", "greedy"], None, 0, 1),
+        (["greedy", "temp", "topp", "greedy"], None, 1, 1),
+        (["topk", "both", "topp", "topp"], None, 1, 1),
+        # a freed slot keeps its last request's settings: neither branch
+        (["greedy", "greedy", "topp", "temp"], [True, True, False, False], 0, 0),
+        (["greedy", "temp", "topp", "greedy"], [True, True, False, True], 0, 1),
+        (["greedy", "temp", "topp", "greedy"], [True, False, True, True], 1, 1),
+    ],
+)
+def test_sampler_runs_only_the_branch_its_live_rows_need(
+    monkeypatch, kinds, live, sorts, draws
+):
+    """Eagerly (no jit) a cond runs the branch its predicate picks and
+    nothing of the other, so spies on the sort and the draw see exactly
+    what a launch of these rows costs."""
+    calls = {"sort": 0, "draw": 0}
+    real_sort, real_draw = jnp.sort, jax.random.categorical
+
+    def sort(*a, **kw):
+        calls["sort"] += 1
+        return real_sort(*a, **kw)
+
+    def draw(*a, **kw):
+        calls["draw"] += 1
+        return real_draw(*a, **kw)
+
+    monkeypatch.setattr(sampling.jnp, "sort", sort)
+    monkeypatch.setattr(sampling.jax.random, "categorical", draw)
+    logits = jnp.asarray(
+        np.random.default_rng(5).normal(0.0, 3.0, (4, 257)), jnp.float32
+    )
+    mask = None if live is None else jnp.asarray(live)
+    with jax.disable_jit():
+        got = np.asarray(sample_tokens(
+            logits, _params_of(kinds), jax.random.PRNGKey(2), live=mask
+        ))
+    assert (calls["sort"], calls["draw"]) == (sorts, draws)
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    for r, kind in enumerate(kinds):
+        if kind.startswith("greedy") or (live is not None and not live[r]):
+            assert got[r] == greedy[r]

@@ -345,3 +345,71 @@ def test_the_v5e_state_step_updates_the_state_pools_in_place(
         layouts.update(re.findall(re.escape(m.group(2)) + r"(\{[\d,]+)", line))
     assert not movers, movers
     assert layouts == {"{4,3,2,1,0"}, layouts
+
+
+# ------------------------------------------- the sampler's two conditionals
+
+def _outside_conditionals(hlo):
+    """The instructions of a compiled module that run whatever a
+    conditional's predicate says: every computation reached from ENTRY
+    through calls, fusions, loops and reducers, but not through
+    ``branch_computations``."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    seen, todo, lines = set(), ["ENTRY"], []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in bodies[name]:
+            lines.append(line)
+            always = re.sub(r"branch_computations=\{[^}]*\}", "", line)
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", always)
+    return lines
+
+
+@pytest.mark.parametrize(
+    "rows, vocab", [(32, 32768), (32, 32000), (16, 151936)],
+    ids=["mistral", "mixtral", "brumby"])
+def test_the_sampler_sorts_and_draws_only_inside_a_conditional(
+        one_chip, rows, vocab):
+    """ISSUE 34: ``sample_tokens`` as the cells call it (bias extras, a live
+    mask) at their ``[max_batch, vocab]``. The v5e compiler keeps both
+    ``lax.cond``s as conditionals: ONE whole-vocabulary sort, and it, the
+    per-row keys and the Gumbel bits are all inside a branch, so a launch
+    of greedy rows runs none of them."""
+    from clearml_serving_tpu.llm.sampling import (
+        SamplingExtras, SamplingParams, sample_tokens)
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    params = SamplingParams(
+        on_chip(rows), on_chip(rows, dtype=i32), on_chip(rows))
+    extras = SamplingExtras(
+        presence=on_chip(rows), frequency=on_chip(rows),
+        repetition=on_chip(rows), bias=on_chip(rows, vocab),
+        seeds=on_chip(rows, dtype=i32), counters=on_chip(rows, dtype=i32),
+        min_new=on_chip(rows, dtype=i32), stop=on_chip(rows, 8, dtype=i32))
+    hlo = _compiled_text(sample_tokens.lower(
+        on_chip(rows, vocab), params, on_chip(2, dtype=jnp.uint32), extras,
+        on_chip(rows, vocab, dtype=i32), on_chip(rows, vocab, dtype=jnp.bool_),
+        live=on_chip(rows, dtype=jnp.bool_)))
+    sort = re.compile(r" sort\(")
+    draw = re.compile(r'op_name="[^"]*(_threefry|_gumbel|_uniform|random_bits)')
+    assert len(sort.findall(hlo)) == 1
+    assert draw.search(hlo) and " conditional(" in hlo
+    always = _outside_conditionals(hlo)
+    assert len(always) > 20       # the walk found the entry's fusions
+    assert not [l for l in always if sort.search(l) or draw.search(l)]
