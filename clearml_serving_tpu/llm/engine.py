@@ -172,17 +172,22 @@ class GenRequest:
     # the shipped prefix as a ship hit or a recompute)
     _ship_to: Optional[str] = None
     _shipped: bool = False
-    # engine-internal, monotonic seconds: the request's way to its first
+    # engine-internal, ``_clock`` seconds: the request's way to its first
     # token (lifecycle_stats()["requests"]). _queued is the submission, or
     # the re-queue of a preempted request (a new wait, not a new TTFT);
     # _slot_at the pop from the queue with a slot reserved; _job_at the
     # ragged job's opening (legacy path: the admission task's start);
-    # _prefill_launches the launches that carried one of its prompt chunks
+    # _prefill_launches the launches that carried one of its prompt chunks;
+    # _enqueue_at the first of those launches' ``enqueue`` stamp and
+    # _ready_at the last one's ``ready`` stamp (_CycleClock; 0.0 on the
+    # dense cache's path, whose prefill rides no launch)
     _submitted: float = 0.0
     _queued: float = 0.0
     _slot_at: float = 0.0
     _job_at: float = 0.0
     _prefill_launches: int = 0
+    _enqueue_at: float = 0.0
+    _ready_at: float = 0.0
 
     def cancel(self) -> None:
         self.cancelled = True
@@ -264,6 +269,11 @@ class _MsHistogram:
         }
 
 
+# the one clock of every stamp on the launch timeline and on a request's way
+# to its first token (_CycleClock, _WorkerStamps, GenRequest's stamps), which
+# are subtracted from each other across threads
+_clock = time.monotonic
+
 # request-phase grid: the default grid ends at 1 s, and a long prompt's
 # first token can take tens of seconds behind other prefills
 _REQUEST_MS_BUCKETS = (
@@ -271,6 +281,8 @@ _REQUEST_MS_BUCKETS = (
     1000.0, 2500.0, 5000.0, 10000.0, 30000.0,
 )
 _PREFILL_LAUNCH_BUCKETS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64, 128)
+# prefill_ms of a request whose prompt rode launches, cut on their timeline
+_PREFILL_STRETCHES = ("first_launch_wait_ms", "prefill_span_ms", "first_emit_ms")
 
 
 class _CycleClock:
@@ -285,19 +297,42 @@ class _CycleClock:
     ``mark`` closes the running phase and opens the next on ONE clock read,
     so the phases partition the cycle (``cycle_ms`` is their sum), and each
     phase is an ``engine.<phase>`` annotation on the profiler's host plane
-    while a profiler session is open (a flag test otherwise). Loop-thread
-    only."""
+    while a profiler session is open (a flag test otherwise).
+
+    One launch timeline lies over the phases. ``landed`` takes the dispatch
+    worker's four reads of a launch (_WorkerStamps) between the loop's own
+    around the hop, ``ready`` the instant the retire's FIRST device-to-host
+    copy returned (the device has finished). ``parts`` cuts the way from
+    the ``launch`` mark to the loop having the result into ``hop_out`` /
+    ``upload`` / ``enqueue`` / ``tail`` / ``hop_back``; ``readback`` is
+    ``ready`` until ``wait`` closes; ``starve`` is ``enqueue(N) -
+    ready(N-1)``, never below 0: the stretch in which the program KNOWS the
+    chip had nothing queued (none for a launch with no predecessor since a
+    park: an engine without work is not starved). In the serial ragged step
+    the parts add up to the ``launch`` phase and a starve is readback + emit
+    + yield of one cycle plus admin + plan + hop_out + upload of the next
+    (shared reads). Where launches overlap (pipelined step) the worker's
+    three parts still add up to ``dispatch_ms``, the ``launch`` phase is only
+    what the concurrent retire left of the hop, and a launch whose
+    predecessor is not back yet starves 0. Loop-thread only."""
 
     PHASES = ("admin", "plan", "launch", "wait", "emit", "yield")
+    PARTS = ("hop_out", "upload", "enqueue", "tail", "hop_back")
 
     def __init__(self):
         self.phases = {p: _MsHistogram() for p in self.PHASES}
         self.cycle = _MsHistogram()
+        self.parts = {p: _MsHistogram() for p in self.PARTS}
+        self.readback = _MsHistogram()
+        self.starve = _MsHistogram()
         self._acc = dict.fromkeys(self.PHASES, 0.0)
         self._phase = None   # None = between cycles (parked or stopped)
         self._span = None
         self._t = 0.0
         self._worked = False
+        self._prev = None    # seq of the last launch landed since a park
+        self._ready = None   # (seq, read) of the last first copy
+        self._copied = None  # this wait's ready read, until the wait closes
 
     def _open(self, phase: str, seq: int, now: float) -> None:
         self._phase, self._t = phase, now
@@ -306,6 +341,9 @@ class _CycleClock:
 
     def _close(self, now: float) -> None:
         if self._phase is not None:
+            if self._copied is not None:
+                self.readback.observe((now - self._copied) * 1e3)
+                self._copied = None
             self._acc[self._phase] += now - self._t
             self._span.__exit__(None, None, None)
             self._phase = self._span = None
@@ -313,7 +351,7 @@ class _CycleClock:
     def top(self, seq: int) -> None:
         """Loop top: close the iteration that just ran (observed only when
         it dispatched or retired) and open the next one's ``admin``."""
-        now = time.perf_counter()
+        now = _clock()
         self._close(now)
         if self._worked:
             for phase, acc in self._acc.items():
@@ -326,7 +364,7 @@ class _CycleClock:
     def mark(self, phase: str, seq: int) -> float:
         """Enter ``phase`` (no-op when already in it or between cycles);
         returns the boundary's clock read for callers that share it."""
-        now = time.perf_counter()
+        now = _clock()
         if self._phase is not None and phase != self._phase:
             self._close(now)
             self._open(phase, seq, now)
@@ -336,11 +374,88 @@ class _CycleClock:
 
     def park(self) -> None:
         """The loop waits for work or exits: not a cycle, drop the stretch."""
-        self._close(time.perf_counter())
+        self._close(_clock())
         self._worked = False
+        self._prev = self._ready = None
+
+    def landed(self, seq: int, launch_at: float, stamps: tuple,
+               now: float) -> None:
+        """Launch ``seq`` is back from the dispatch worker: ``stamps`` are
+        the worker's reads (worker_in, enqueue, enqueued, worker_out),
+        ``launch_at`` and ``now`` the loop's own around the hop."""
+        edges = (launch_at, *stamps, now)
+        for part, a, b in zip(self.PARTS, edges, edges[1:]):
+            self.parts[part].observe((b - a) * 1e3)
+        if self._prev is not None:
+            # a predecessor whose first copy has not returned is still in
+            # flight: the chip had work queued when this launch arrived
+            back = self._ready is not None and self._ready[0] == self._prev
+            self.starve.observe(
+                max(0.0, stamps[1] - self._ready[1]) * 1e3 if back else 0.0
+            )
+        self._prev = seq
+
+    def ready(self, seq: int, at: Optional[float] = None) -> float:
+        """The retire's first device-to-host copy of launch ``seq`` returned
+        (``at`` where a readback worker took the read)."""
+        at = _clock() if at is None else at
+        self._ready, self._copied = (seq, at), at
+        return at
 
     def snapshot(self) -> dict:
         return {p + "_ms": h.snapshot() for p, h in self.phases.items()}
+
+    def timeline(self) -> dict:
+        """The launch timeline's blocks of lifecycle_stats()["pipeline"]."""
+        return {
+            "launch_parts": {
+                p + "_ms": h.snapshot() for p, h in self.parts.items()
+            },
+            "readback_ms": self.readback.snapshot(),
+            "starve_ms": self.starve.snapshot(),
+        }
+
+
+class _WorkerStamps:
+    """The dispatch worker's side of a launch's timeline: four ``_clock``
+    reads, ``worker_in`` (first line of the worker's half), ``enqueue``
+    (immediately before the jitted step is called: every upload, page
+    allocation and table build lies before it), ``enqueued`` (the call
+    returned) and ``worker_out`` (before the return), handed back with the
+    worker's result for the loop thread to account (_CycleClock.landed: the
+    clock stays loop-thread only). While a profiler session is open,
+    ``engine.upload`` and ``engine.enqueue`` annotations cover the first two
+    stretches inside the caller's ``engine.dispatch``. The jitted step is
+    called between ``enqueue()`` and ``enqueued()`` with plain positional
+    operands uploaded before: called through a ``call(fn, *args)`` helper
+    or with starred operands, the first call of each program took 1.7-3.4 s
+    longer on the state cache (set-up +10-15%; PERF.md section 6, PR 40)."""
+
+    def __init__(self, seq: int):
+        self.seq, self.reads, self._span = seq, [_clock()], None
+
+    def _open(self, name: str) -> None:
+        # an annotation starts where it is built
+        self._span = jax.profiler.TraceAnnotation(name, seq=self.seq)
+        self._span.__enter__()
+
+    def __enter__(self):
+        self._open("engine.upload")
+        return self
+
+    def __exit__(self, *exc):
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def enqueue(self) -> None:
+        self.__exit__()
+        self.reads.append(_clock())
+        self._open("engine.enqueue")
+
+    def enqueued(self) -> None:
+        self.__exit__()
+        self.reads.append(_clock())
 
 
 @dataclass
@@ -357,7 +472,8 @@ class _InFlightChunk:
     gstate: Any = None
     lp: Any = None
     want_lp: bool = False
-    dispatched_at: float = 0.0
+    # the dispatch worker's four reads (_WorkerStamps), for the loop thread
+    stamps: tuple = ()
     # paged backend: slots dropped from this chunk because the pool could
     # not hold their page extension (failed by the loop thread on landing)
     exhausted: List[int] = field(default_factory=list)
@@ -1595,6 +1711,7 @@ class LLMEngineCore:
         self._hist_request = {
             name: _MsHistogram(_REQUEST_MS_BUCKETS)
             for name in ("queue_wait_ms", "admit_ms", "prefill_ms", "ttft_ms")
+            + _PREFILL_STRETCHES
         }
         self._hist_prefill_launches = _MsHistogram(_PREFILL_LAUNCH_BUCKETS)
         # host-tier promotion reaping (docs/kv_tiering.md): loop-affine —
@@ -3706,7 +3823,7 @@ class LLMEngineCore:
         self._slot_req[slot] = None
         self._release_guided(slot, request)  # no-op for victims; kept for symmetry
         self._free_slot_pages(slot)
-        request._queued = time.monotonic()  # the resume leg's own wait
+        request._queued = _clock()  # the resume leg's own wait
         self._pending.put_nowait(request)
         self._wake_loop()
         return True
@@ -3743,7 +3860,7 @@ class LLMEngineCore:
         self._resolve_deadlines(request)
         request.prompt_len = len(request.prompt_ids)
         request.out_queue = asyncio.Queue()
-        request._submitted = request._queued = time.monotonic()
+        request._submitted = request._queued = _clock()
         self._pending.put_nowait(request)
         self._ensure_loop()
         self._wake_loop()
@@ -4276,6 +4393,9 @@ class LLMEngineCore:
             "pipeline": {
                 "depth": self.pipeline_depth,
                 "inflight": len(self._inflight),
+                # over pipeline.cycle_ms: the share of the chip the host
+                # wastes, as far as the program knows (_CycleClock)
+                "starve_ms": self._cycle.starve.snapshot(),
             },
             "scheduler": "ragged" if self._ragged else "two_dispatch",
             "ragged": (
@@ -4380,6 +4500,9 @@ class LLMEngineCore:
                 # to cycle_ms (_CycleClock)
                 "phases": self._cycle.snapshot(),
                 "cycle_ms": self._cycle.cycle.snapshot(),
+                # the launch timeline across the loop thread and the
+                # dispatch worker: launch_parts, readback_ms, starve_ms
+                **self._cycle.timeline(),
             },
             "requests": dict(
                 {k: h.snapshot() for k, h in self._hist_request.items()},
@@ -5295,7 +5418,7 @@ class LLMEngineCore:
     async def _admission_task(self, request: GenRequest, slot: int) -> None:
         """Background prefill for one request; reserves `slot` via
         self._admitting until committed or failed."""
-        request._job_at = time.monotonic()
+        request._job_at = _clock()
         try:
             first_id, mini_cache, first_lp = await asyncio.to_thread(
                 self._prefill_device, request
@@ -5394,15 +5517,24 @@ class LLMEngineCore:
     def _observe_first_token(self, request: GenRequest) -> None:
         """The request's stamps into lifecycle_stats()["requests"]: with
         queue_wait_ms (observed at the slot reservation) the three stretches
-        add up to ttft_ms for a request that was never preempted."""
+        add up to ttft_ms for a request that was never preempted. Where its
+        prompt rode launches, prefill_ms is cut on the launch timeline into
+        first_launch_wait_ms (job opened -> the ``enqueue`` of the first
+        launch that carried a chunk of it), prefill_span_ms (-> the ``ready``
+        of the launch that carried the last) and first_emit_ms (-> now)."""
         if not request._job_at:
             return  # activated without an admission (a test's direct commit)
-        now = time.monotonic()
+        now = _clock()
         h = self._hist_request
         h["admit_ms"].observe((request._job_at - request._slot_at) * 1e3)
         h["prefill_ms"].observe((now - request._job_at) * 1e3)
         h["ttft_ms"].observe((now - request._submitted) * 1e3)
         self._hist_prefill_launches.observe(request._prefill_launches)
+        if request._enqueue_at:
+            edges = (request._job_at, request._enqueue_at,
+                     request._ready_at, now)
+            for name, a, b in zip(_PREFILL_STRETCHES, edges, edges[1:]):
+                h[name].observe((b - a) * 1e3)
 
     def _drain_ready(self, err: BaseException) -> None:
         """Fail every completed-but-uncommitted admission (loop is exiting)."""
@@ -5698,7 +5830,7 @@ class LLMEngineCore:
             # launch that carries the job's first chunk (row_reset)
             self.state_cache.free(slot)
             self.state_cache.allocate(slot)
-        request._job_at = time.monotonic()
+        request._job_at = _clock()
         return _RaggedJob(request=request, slot=slot, pos=pos)
 
     def _free_ragged_slot(self, slot: int) -> None:
@@ -6118,6 +6250,19 @@ class LLMEngineCore:
             faults.fire("engine.dispatch.prepare", requests=plan["requests"])
         return plan
 
+    @staticmethod
+    def _ragged_operands(plan: dict, *more: str) -> dict:
+        """Worker thread: a ragged launch's host vectors as device operands,
+        uploaded before the jitted call is entered (the ``enqueue`` stamp
+        lies between): what every cache kind's step takes, and ``more``."""
+        names = (
+            "tokens", "tok_pos", "tok_row", "tok_valid", "row_last",
+            "kv_lens", "row_starts", "row_lens",
+        ) + more
+        dev = {name: jnp.asarray(plan[name]) for name in names}
+        dev["decode_mask"] = jnp.asarray(plan["decode_mask"].copy())
+        return dev
+
     def _ragged_drop_row(self, plan: dict, slot: int) -> None:
         """Worker-side removal of a row whose page extension failed: its
         tokens become pads (null-page writes, masked compute); the retire
@@ -6159,12 +6304,15 @@ class LLMEngineCore:
         row's chunk plus the ONE device launch (donated pools/cache,
         rebound under the dispatch lock — same discipline as the legacy
         dispatch workers)."""
+        stamps = _WorkerStamps(plan["seq"])
         with self._sentry_scope("ragged", seq=plan["seq"]), \
-                jax.profiler.TraceAnnotation("engine.dispatch", seq=plan["seq"]):
-            return self._dispatch_ragged_device_inner(plan)
+                jax.profiler.TraceAnnotation("engine.dispatch", seq=plan["seq"]), \
+                stamps:
+            return self._dispatch_ragged_device_inner(plan, stamps)
 
-    def _dispatch_ragged_device_inner(self, plan: dict) -> dict:
-        t0 = time.perf_counter()
+    def _dispatch_ragged_device_inner(
+        self, plan: dict, stamps: _WorkerStamps
+    ) -> dict:
         if faults.active():
             # chaos seam, BEFORE any device work: a per-request poison
             # fails only its row's request/job, never the launch
@@ -6252,8 +6400,13 @@ class LLMEngineCore:
                     jnp.asarray(chain_wo),
                 )
             self.paged_cache.apply_pending_cow()
-            page_table = pool.page_table(self._pages_per_seq)
+            plan["page_table"] = pool.page_table(self._pages_per_seq)
+            dev = self._ragged_operands(
+                plan, "page_table", "write_page", "write_offset"
+            )
+            spec, tree = _spec_arrays(), _tree_arrays()
             with self.paged_cache.dispatch_lock:
+                stamps.enqueue()
                 (
                     sampled, logits,
                     self.paged_cache.k, self.paged_cache.v,
@@ -6261,24 +6414,24 @@ class LLMEngineCore:
                     spec_g, spec_acc,
                 ) = self._ragged_paged_jit(
                     self.params,
-                    jnp.asarray(plan["tokens"]),
-                    jnp.asarray(plan["tok_pos"]),
-                    jnp.asarray(plan["tok_row"]),
-                    jnp.asarray(plan["tok_valid"]),
-                    jnp.asarray(plan["row_last"]),
+                    dev["tokens"],
+                    dev["tok_pos"],
+                    dev["tok_row"],
+                    dev["tok_valid"],
+                    dev["row_last"],
                     self.paged_cache.k,
                     self.paged_cache.v,
                     self.paged_cache.k_scale,
                     self.paged_cache.v_scale,
-                    jnp.asarray(page_table),
-                    jnp.asarray(plan["kv_lens"]),
-                    jnp.asarray(plan["row_starts"]),
-                    jnp.asarray(plan["row_lens"]),
-                    jnp.asarray(plan["write_page"]),
-                    jnp.asarray(plan["write_offset"]),
+                    dev["page_table"],
+                    dev["kv_lens"],
+                    dev["row_starts"],
+                    dev["row_lens"],
+                    dev["write_page"],
+                    dev["write_offset"],
                     plan["item_rows"],
                     plan["item_q0"],
-                    jnp.asarray(plan["decode_mask"].copy()),
+                    dev["decode_mask"],
                     plan["sampling"],
                     plan["rng"],
                     plan["lora"],
@@ -6288,10 +6441,11 @@ class LLMEngineCore:
                     gtables,
                     plan["gstate"],
                     want_lp=want_lp,
-                    spec=_spec_arrays(),
+                    spec=spec,
                     chain=chain_arrays,
-                    tree=_tree_arrays(),
+                    tree=tree,
                 )
+                stamps.enqueued()
                 if self._paged_quant:
                     self.paged_cache.k_scale = new_ks
                     self.paged_cache.v_scale = new_vs
@@ -6307,24 +6461,26 @@ class LLMEngineCore:
                     jnp.asarray(plan["chain_mask"].copy()),
                 )
             cache = self.state_cache
+            dev = self._ragged_operands(plan, "row_reset")
             with cache.dispatch_lock:
+                stamps.enqueue()
                 (
                     sampled, logits, cache.s, cache.z, new_counts, lp,
                     gstate_out,
                 ) = self._ragged_state_jit(
                     self.params,
-                    jnp.asarray(plan["tokens"]),
-                    jnp.asarray(plan["tok_pos"]),
-                    jnp.asarray(plan["tok_row"]),
-                    jnp.asarray(plan["tok_valid"]),
-                    jnp.asarray(plan["row_last"]),
+                    dev["tokens"],
+                    dev["tok_pos"],
+                    dev["tok_row"],
+                    dev["tok_valid"],
+                    dev["row_last"],
                     cache.s,
                     cache.z,
-                    jnp.asarray(plan["kv_lens"]),
-                    jnp.asarray(plan["row_starts"]),
-                    jnp.asarray(plan["row_lens"]),
-                    jnp.asarray(plan["row_reset"]),
-                    jnp.asarray(plan["decode_mask"].copy()),
+                    dev["kv_lens"],
+                    dev["row_starts"],
+                    dev["row_lens"],
+                    dev["row_reset"],
+                    dev["decode_mask"],
                     plan["sampling"],
                     plan["rng"],
                     plan["extras"],
@@ -6335,6 +6491,7 @@ class LLMEngineCore:
                     want_lp=want_lp,
                     chain=chain_arrays,
                 )
+                stamps.enqueued()
             spec_g = spec_acc = None
             # the state took every token of every row's span (a decode
             # row's whole window: a row that stops inside it is freed at
@@ -6355,8 +6512,8 @@ class LLMEngineCore:
         else:
             logits = None
         self._last_progress = time.monotonic()
-        self._hist_dispatch.observe((time.perf_counter() - t0) * 1e3)
         return {
+            "stamps": self._worker_out(stamps),
             "sampled": sampled,
             "logits": logits,
             "lp": lp,
@@ -6380,7 +6537,7 @@ class LLMEngineCore:
         plan = self._prepare_ragged(active_mask, epoch)
         if plan is None:
             return
-        self._cycle.mark("launch", plan["seq"])
+        plan["launch_at"] = self._cycle.mark("launch", plan["seq"])
         self._dispatching = (plan["seq"], plan["decode_mask"], time.monotonic())
         try:
             result = await asyncio.to_thread(self._dispatch_ragged_device, plan)
@@ -6455,24 +6612,30 @@ class LLMEngineCore:
         admission code path) and activate their slot."""
         seq = plan["seq"]
         t0 = self._cycle.mark("wait", seq)
+        self._cycle.landed(seq, plan["launch_at"], result["stamps"], t0)
         sampled = np.asarray(result["sampled"])
+        # the device has finished: what follows in ``wait`` is host time
+        ready_at = self._cycle.ready(seq)
         if sampled.ndim == 1:
             sampled = sampled[None]               # step-major [S, B]
-        gstate_np = (
-            np.array(result["gstate"]) if result["gstate"] is not None else None
-        )
-        lp_np = (
-            tuple(np.asarray(a) for a in result["lp"])
-            if result["lp"] is not None
-            else None
-        )
+        with jax.profiler.TraceAnnotation("engine.readback", seq=seq):
+            gstate_np = (
+                np.array(result["gstate"])
+                if result["gstate"] is not None
+                else None
+            )
+            lp_np = (
+                tuple(np.asarray(a) for a in result["lp"])
+                if result["lp"] is not None
+                else None
+            )
+            spec_acc = (
+                np.asarray(result["spec_acc"])
+                if result["spec_acc"] is not None
+                else None
+            )
         if lp_np is not None and lp_np[0].ndim == 1:
             lp_np = tuple(a[None] for a in lp_np)  # step-major [S, B, ...]
-        spec_acc = (
-            np.asarray(result["spec_acc"])
-            if result["spec_acc"] is not None
-            else None
-        )
         self._cycle.mark("emit", seq)
         spec_g = (
             np.asarray(result["spec_g"])
@@ -6663,6 +6826,9 @@ class LLMEngineCore:
                 continue
             job.pos += take
             job.request._prefill_launches += 1
+            if not job.request._enqueue_at:
+                job.request._enqueue_at = result["stamps"][1]
+            job.request._ready_at = ready_at
             if job.pos < len(job.request.prompt_ids):
                 # draft-ahead KV shipping: the chunk boundary just made
                 # whole storable pages final — overlap the transport with
@@ -6809,7 +6975,7 @@ class LLMEngineCore:
                     continue
                 slot = free.pop(0)
                 self._admitting.add(slot)
-                request._slot_at = time.monotonic()
+                request._slot_at = _clock()
                 self._hist_request["queue_wait_ms"].observe(
                     (request._slot_at - request._queued) * 1e3
                 )
@@ -7088,7 +7254,7 @@ class LLMEngineCore:
         Appends the in-flight entry and fails pool-exhausted slots."""
         self._cycle.mark("plan", self._dispatch_seq + 1)
         prep = self._prepare_dispatch(active_mask, epoch)
-        self._cycle.mark("launch", prep["seq"])
+        launch_at = self._cycle.mark("launch", prep["seq"])
         # barrier visibility: a slot freed by the concurrent retire stage
         # must see this chunk before its entry lands in the queue. The
         # timestamp bounds the watchdog's compile-tolerance grace.
@@ -7097,6 +7263,7 @@ class LLMEngineCore:
             entry = await asyncio.to_thread(self._dispatch_device, prep)
         finally:
             self._dispatching = None
+        self._cycle.landed(entry.seq, launch_at, entry.stamps, _clock())
         if entry.epoch != self._recover_epoch:
             # the watchdog tripped while this chunk was being dispatched:
             # it was failed wholesale. Queue the entry so the discard path
@@ -7173,12 +7340,14 @@ class LLMEngineCore:
         on the paged backend, the host page allocation it needs). Only
         touches state the retire stage never reads: the cache/pool handles,
         the device-resident chains, and the dispatch histogram."""
+        # in ``prep`` for _dispatch_paged, whose signature tests spy on
+        stamps = prep["stamps"] = _WorkerStamps(prep["seq"])
         with self._sentry_scope("decode", seq=prep["seq"]), \
-                jax.profiler.TraceAnnotation("engine.dispatch", seq=prep["seq"]):
+                jax.profiler.TraceAnnotation("engine.dispatch", seq=prep["seq"]), \
+                stamps:
             return self._dispatch_device_inner(prep)
 
     def _dispatch_device_inner(self, prep: dict) -> "_InFlightChunk":
-        t0 = time.perf_counter()
         if faults.active():
             # chaos seam (BEFORE any device dispatch, so a per-request
             # poison never corrupts innocent slots' cache state)
@@ -7195,6 +7364,7 @@ class LLMEngineCore:
                 prep, exhausted
             )
         else:
+            prep["stamps"].enqueue()
             chunk, self.cache, new_counts, lp, gstate_out = (
                 self._decode_chunk_jit(
                     self.params,
@@ -7212,6 +7382,7 @@ class LLMEngineCore:
                     want_lp=want_lp,
                 )
             )
+            prep["stamps"].enqueued()
             if use_extras:
                 self._counts_dev = new_counts
         # device-resident chaining: the NEXT dispatch reads these without
@@ -7219,7 +7390,6 @@ class LLMEngineCore:
         self._next_token_dev = chunk[:, -1]
         self._gstate_dev = gstate_out if gtables is not None else None
         self._last_progress = time.monotonic()
-        self._hist_dispatch.observe((time.perf_counter() - t0) * 1e3)
         return _InFlightChunk(
             seq=prep["seq"],
             epoch=prep["epoch"],
@@ -7228,7 +7398,7 @@ class LLMEngineCore:
             gstate=gstate_out if gtables is not None else None,
             lp=lp,
             want_lp=want_lp,
-            dispatched_at=t0,
+            stamps=self._worker_out(prep["stamps"]),
             exhausted=exhausted,
             chain_work=chain_work,
             live=live,
@@ -7278,7 +7448,12 @@ class LLMEngineCore:
         use_extras = prep["use_extras"]
         # dispatch under the pool lock: admission workers concurrently
         # enqueue prefix-page gathers against the same (here donated) pools
+        page_table, lengths0_dev, write_pages, write_offsets = (
+            jnp.asarray(page_table), jnp.asarray(lengths0),
+            jnp.asarray(write_pages), jnp.asarray(write_offsets),
+        )
         with self.paged_cache.dispatch_lock:
+            prep["stamps"].enqueue()
             (
                 chunk,
                 self.paged_cache.k,
@@ -7295,10 +7470,10 @@ class LLMEngineCore:
                 self.paged_cache.v,
                 self.paged_cache.k_scale,
                 self.paged_cache.v_scale,
-                jnp.asarray(page_table),
-                jnp.asarray(lengths0),
-                jnp.asarray(write_pages),
-                jnp.asarray(write_offsets),
+                page_table,
+                lengths0_dev,
+                write_pages,
+                write_offsets,
                 prep["sampling"],
                 prep["rng"],
                 prep["lora"],
@@ -7309,12 +7484,22 @@ class LLMEngineCore:
                 prep["gstate_in"],
                 want_lp=prep["want_lp"],
             )
+            prep["stamps"].enqueued()
             if self._paged_quant:
                 self.paged_cache.k_scale = new_k_scale
                 self.paged_cache.v_scale = new_v_scale
         if use_extras:
             self._counts_dev = new_counts
         return chunk, lp, gstate_out, chain_work, live
+
+    def _worker_out(self, stamps: _WorkerStamps) -> tuple:
+        """Worker thread, before its return: the launch's four reads for the
+        loop thread. ``pipeline.dispatch_ms`` is worker_in -> worker_out of
+        the same reads (upload + enqueue + tail of ``pipeline.launch_parts``),
+        not a second measurement; its count is the launches."""
+        reads = (*stamps.reads, _clock())
+        self._hist_dispatch.observe((reads[3] - reads[0]) * 1e3)
+        return reads
 
     def _count_decode_passes(self, work: tuple) -> None:
         """Loop thread: add a launch's :func:`_decode_pass_work`."""
@@ -7376,26 +7561,33 @@ class LLMEngineCore:
                     requests=[r for r in self._slot_req if r is not None],
                 )
             chunk_np = np.asarray(entry.chunk)
-            # np.array (copy): asarray would alias the immutable device
-            # buffer and commit/release paths write rows in place
-            gstate_np = (
-                np.array(entry.gstate) if entry.gstate is not None else None
-            )
-            lp_np = (
-                tuple(np.asarray(a) for a in entry.lp)
-                if entry.lp is not None
-                else None
-            )
-            return chunk_np, gstate_np, lp_np
+            ready_at = _clock()        # the device has finished the chunk
+            with jax.profiler.TraceAnnotation("engine.readback", seq=entry.seq):
+                # np.array (copy): asarray would alias the immutable device
+                # buffer and commit/release paths write rows in place
+                gstate_np = (
+                    np.array(entry.gstate)
+                    if entry.gstate is not None
+                    else None
+                )
+                lp_np = (
+                    tuple(np.asarray(a) for a in entry.lp)
+                    if entry.lp is not None
+                    else None
+                )
+            return chunk_np, gstate_np, lp_np, ready_at
 
         t0 = self._cycle.mark("wait", entry.seq)
         ready = getattr(entry.chunk, "is_ready", None)
         if not faults.active() and ready is not None and ready():
             # chunk already landed (device ran ahead): the copies are
             # microseconds — skip the worker-thread hop entirely
-            chunk_np, gstate_np, lp_np = _sync()
+            chunk_np, gstate_np, lp_np, ready_at = _sync()
         else:
-            chunk_np, gstate_np, lp_np = await asyncio.to_thread(_sync)
+            chunk_np, gstate_np, lp_np, ready_at = await asyncio.to_thread(
+                _sync
+            )
+        self._cycle.ready(entry.seq, ready_at)
         self._cycle.mark("emit", entry.seq)
         if entry.epoch != self._recover_epoch:
             # the watchdog failed this batch while the pipeline was in

@@ -401,13 +401,33 @@ class EngineLifecycleCollector(_KeyedCollector):
             "loop-thread time per scheduling cycle by phase (ms): admin, "
             "plan, launch, wait, emit, yield; cycle = their sum",
         )
+        # the launch timeline across the loop thread and the dispatch worker
+        # (llm/engine.py _CycleClock.landed): the way from the launch mark to
+        # the loop having the result, cut at the worker's four stamps
+        launch_part_ms = HistogramMetricFamily(
+            p + "_launch_part_ms",
+            "one launch's way through the dispatch worker by part (ms): "
+            "hop_out, upload (everything before the jitted call), enqueue "
+            "(the call), tail, hop_back",
+        )
+        # over engine_step_phase_ms{phase="cycle"}: the share of the chip
+        # the host wastes, with no profiler (docs/pipelined_decode.md)
+        device_starve_ms = HistogramMetricFamily(
+            p + "_device_starve_ms",
+            "per launch: the stretch in which the chip had nothing queued, "
+            "as the program knows it (ms): the previous launch's first "
+            "device-to-host copy returned -> this launch's jitted call",
+        )
         # a request's way to its first token (vLLM request_queue_time /
         # request_prefill_time / time_to_first_token)
         request_phase_ms = HistogramMetricFamily(
             p + "_request_phase_ms",
             "a request's way to its first token by phase (ms): queue_wait "
             "(submit -> slot), admit (slot -> job open), prefill (job open "
-            "-> first token), ttft (submit -> first token)",
+            "-> first token), ttft (submit -> first token); prefill cut on "
+            "the launch timeline: first_launch_wait (job open -> its first "
+            "launch's jitted call), prefill_span (-> its last launch's "
+            "result on the host), first_emit (-> first token)",
         )
         request_prefill_launches = HistogramMetricFamily(
             p + "_request_prefill_launches",
@@ -795,6 +815,11 @@ class EngineLifecycleCollector(_KeyedCollector):
                 if pipe.get("cycle_ms"):
                     hist(step_phase_ms, key, s, pipe["cycle_ms"],
                          phase="cycle")
+                for field, snap in (pipe.get("launch_parts") or {}).items():
+                    hist(launch_part_ms, key, s, snap,
+                         part=field.removesuffix("_ms"))
+                if pipe.get("starve_ms"):
+                    hist(device_starve_ms, key, s, pipe["starve_ms"])
             for field, snap in (s.get("requests") or {}).items():
                 any_requests = True
                 if field == "prefill_launches":
@@ -855,6 +880,8 @@ class EngineLifecycleCollector(_KeyedCollector):
             yield dispatch_ms
             yield retire_ms
             yield step_phase_ms
+            yield launch_part_ms
+            yield device_starve_ms
         if any_requests:
             yield request_phase_ms
             yield request_prefill_launches

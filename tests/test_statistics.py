@@ -358,8 +358,12 @@ def _phase_snap(i):
         ("admin", "plan", "launch", "wait", "emit", "yield", "cycle"))
 ] + [
     ("engine_request_phase_ms", "requests", p, i) for i, p in enumerate(
-        ("queue_wait", "admit", "prefill", "ttft"))
-] + [("engine_request_prefill_launches", "requests", None, 5)])
+        ("queue_wait", "admit", "prefill", "ttft", "first_launch_wait",
+         "prefill_span", "first_emit"))
+] + [("engine_request_prefill_launches", "requests", None, 5)] + [
+    ("engine_launch_part_ms", "launch_parts", p, i) for i, p in enumerate(
+        ("hop_out", "upload", "enqueue", "tail", "hop_back"))
+] + [("engine_device_starve_ms", "starve_ms", None, 4)])
 def test_engine_phase_families_exported(family, block, phase, index):
     """The cycle clock's phases and a request's way to its first token
     (docs/pipelined_decode.md "Observability"): one histogram family each,
@@ -369,7 +373,9 @@ def test_engine_phase_families_exported(family, block, phase, index):
     from clearml_serving_tpu.statistics.metrics import register_engine_lifecycle
 
     steps = ("admin", "plan", "launch", "wait", "emit", "yield")
-    reqs = ("queue_wait", "admit", "prefill", "ttft")
+    reqs = ("queue_wait", "admit", "prefill", "ttft", "first_launch_wait",
+            "prefill_span", "first_emit")
+    parts = ("hop_out", "upload", "enqueue", "tail", "hop_back")
     stats = {
         "queue_depth": 0,
         "replica": "r1",
@@ -377,6 +383,11 @@ def test_engine_phase_families_exported(family, block, phase, index):
             "depth": 2, "inflight": 0,
             "phases": {p + "_ms": _phase_snap(i) for i, p in enumerate(steps)},
             "cycle_ms": _phase_snap(6),
+            # the launch timeline (tests/test_launch_timeline.py)
+            "launch_parts": {p + "_ms": _phase_snap(i)
+                             for i, p in enumerate(parts)},
+            "readback_ms": _phase_snap(3),       # no family: a benchmark reads it
+            "starve_ms": _phase_snap(4),
         },
         "requests": dict(
             {p + "_ms": _phase_snap(i) for i, p in enumerate(reqs)},
@@ -387,7 +398,7 @@ def test_engine_phase_families_exported(family, block, phase, index):
     register_engine_lifecycle(lambda: stats, registry=registry, key="m1")
     labels = {"model": "m1", "replica": "r1"}
     if phase is not None:
-        labels["phase"] = phase
+        labels["part" if block == "launch_parts" else "phase"] = phase
 
     def val(suffix, **extra):
         return registry.get_sample_value(family + suffix, {**labels, **extra})
