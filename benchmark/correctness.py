@@ -188,13 +188,14 @@ def verdict(positions: list, tolerance: dict) -> dict:
     }
 
 
+def compiles(health: dict) -> int:
+    """Programs the engine has compiled so far, by its own health block."""
+    return health["compile"]["warmup"] + health["compile"]["serve"]
+
+
 def health_checks(before: dict, after: dict, want_tpu: bool) -> list:
     """Faults the program's own health block shows across the window."""
     faults = []
-
-    def compiles(h):
-        return h["compile"]["warmup"] + h["compile"]["serve"]
-
     if compiles(after) != compiles(before):
         faults.append("{} programs compiled inside the window".format(
             compiles(after) - compiles(before)))

@@ -2,8 +2,9 @@
 the clock of the device's operations: which host line holds them, how long
 each phase ran, which phase of the engine's loop thread enclosed each stretch
 in which the device ran nothing, and the device's time under each
-``jax.named_scope`` of the step. By hand only: ``run.py`` removes the trace
-before any reader runs, so no metric reads this yet (PERF.md section 7).
+``jax.named_scope`` of the step. ``breakdown.idle_gaps`` of a traced run is
+``idle_by_span`` (through ``layer_metrics/_common.py``); the rest is by hand,
+on a trace that ``run.py --keep-trace`` left under ``.bench_tmp/trace/``:
 
     python3 benchmark/host_spans.py <file.xplane.pb>
 """
@@ -64,6 +65,65 @@ def idle_by_phase(busy: list, spans: list) -> dict:
                 out[name] = out.get(name, 0.0) + cut
                 left -= cut
         out[None] = out.get(None, 0.0) + max(left, 0.0)
+    return out
+
+
+NO_SPAN = "no_cycle"
+
+
+def innermost(spans: list) -> list:
+    """Sorted disjoint (start, end, name): over every stretch that some span
+    covers, the name of the covering span that started last. On one thread
+    that is the innermost annotation; ``engine.upload`` / ``engine.enqueue``
+    of the dispatch worker start inside the loop thread's ``engine.launch``
+    and take its place while they run, ``engine.readback`` that of
+    ``engine.wait``."""
+    edges = sorted({t for _, _, s, e, _ in spans for t in (s, e)})
+    by_start = sorted((s, e, name) for name, _, s, e, _ in spans)
+    out, live, k = [], [], 0
+    for lo, hi in zip(edges, edges[1:]):
+        while k < len(by_start) and by_start[k][0] <= lo:
+            live.append(by_start[k])
+            k += 1
+        live = [x for x in live if x[1] > lo]
+        if live:
+            name = max(live, key=lambda x: (x[0], -x[1]))[2]   # the shorter of a tie
+            if out and out[-1][2] == name and out[-1][1] == lo:
+                out[-1] = (out[-1][0], hi, name)
+            else:
+                out.append((lo, hi, name))
+    return out
+
+
+def idle_by_span(gaps: list, spans: list) -> list:
+    """For every idle gap (start, length) of the device, in order of start:
+    {name: seconds} of the gap under each innermost ``engine.*`` span; what
+    no span encloses is under ``NO_SPAN`` (the engine was in no cycle)."""
+    cover = innermost(spans)
+    out, k = [], 0
+    for g0, length in sorted(gaps):
+        g1, parts, left = g0 + length, {}, length
+        while k < len(cover) and cover[k][1] <= g0:
+            k += 1
+        i = k
+        while i < len(cover) and cover[i][0] < g1:
+            cut = min(cover[i][1], g1) - max(cover[i][0], g0)
+            if cut > 0:
+                parts[cover[i][2]] = parts.get(cover[i][2], 0.0) + cut
+                left -= cut
+            i += 1
+        if left > 0:
+            parts[NO_SPAN] = parts.get(NO_SPAN, 0.0) + left
+        out.append(parts)
+    return out
+
+
+def summed(parts: list) -> dict:
+    """{name: seconds} over all the gaps of ``idle_by_span``."""
+    out = {}
+    for split in parts:
+        for name, secs in split.items():
+            out[name] = out.get(name, 0.0) + secs
     return out
 
 
@@ -192,6 +252,11 @@ def _dump(path: str) -> None:
         sum(idle.values())))
     for name, secs in sorted(idle.items(), key=lambda kv: -kv[1]):
         print("  {:16s} {:.3f} s".format(name or "(no phase)", secs))
+    gaps = [(e0, s1 - e0) for (_, e0), (s1, _) in zip(busy, busy[1:])]
+    inner = summed(idle_by_span(gaps, spans))
+    print("the same idle time by the innermost span (breakdown.idle_gaps):")
+    for name, secs in sorted(inner.items(), key=lambda kv: -kv[1]):
+        print("  {:16s} {:.3f} s".format(name, secs))
     by_scope = device_ms_by_scope(path)
     print("device operations {:.1f} ms, by the named scope in their op_name:".format(
         sum(by_scope.values())))

@@ -4,8 +4,9 @@ returns None and the harness leaves the metric out of the line. ``ctx`` holds:
 ``summary`` and ``records`` (client side), ``before`` / ``after`` (program
 counters at the window's edges; histograms are cumulative), ``samples``
 (health twice a second), ``trace`` (xplane.reduce_trace of the traced tail)
-with ``trace_counters`` (counters at its edges), ``cfg``, ``mix``, ``device``,
-``front_probe_ms``, ``window_s``.
+with ``trace_counters`` (counters at its edges) and ``trace_path`` (the
+``.xplane.pb`` itself, there until every reader ran), ``cfg``, ``mix``,
+``device``, ``front_probe_ms``, ``window_s``.
 """
 
 from __future__ import annotations
@@ -73,37 +74,23 @@ def raw_percentile(values, q, at_least=10):
     return percentile(values, q) if len(values) >= at_least else None
 
 
-def _slots_label(n) -> str:
-    if n is None:
-        return "unknown_requests_in_the_engine"
-    return ("{}_requests_in_the_engine".format(
-        "0" if n == 0 else "1-8" if n <= 8 else "9-24" if n <= 24 else "25+"))
-
-
 def breakdown(ctx) -> dict:
-    """The ten device operations with most time, and the idle gaps: their
-    total and the five longest, each labelled by how many requests the engine
-    held at the nearest health sample."""
+    """The ten device operations with most time, and the idle gaps by what the
+    host was doing: the seconds of all gaps under each innermost ``engine.*``
+    annotation of the host plane (``host_spans.idle_by_span``; ``no_cycle``
+    where none encloses), the seven largest first, then the longest single
+    gaps up to ten entries, each under the annotation that held most of it."""
+    from benchmark import host_spans
+
     t = traced(ctx)
     if t is None:
         return {"device_ops": [], "idle_gaps": []}
     ops = [[name[:64], secs] for name, secs, _ in t["ops"][:10]]
-    win = ctx["window"]
-    offset = win.get("trace_t0", win["t_open"]) - win["t_open"]
-    samples = ctx.get("samples") or []
-
-    def label(gap_start):
-        if not samples:
-            return _slots_label(None)
-        at = gap_start - t["t_lo"] + offset
-        near = min(samples, key=lambda s: abs(s["t"] - at))
-        return _slots_label(near.get("active_slots"))
-
-    gaps = sorted(t["gaps"], key=lambda g: -g[1])
-    by_label = {}
-    for start, length in t["gaps"]:
-        by_label[label(start)] = by_label.get(label(start), 0.0) + length
+    gaps = sorted(t["gaps"])
+    parts = host_spans.idle_by_span(gaps, t.get("host_spans") or [])
     out = [["all_gaps:_" + k, v] for k, v in
-           sorted(by_label.items(), key=lambda kv: -kv[1])[:4]]
-    out += [["one_gap:_" + label(s), length] for s, length in gaps[:10 - len(out)]]
-    return {"device_ops": ops, "idle_gaps": out[:10]}
+           sorted(host_spans.summed(parts).items(), key=lambda kv: -kv[1])[:7]]
+    longest = sorted(zip(gaps, parts), key=lambda gp: -gp[0][1])
+    out += [["one_gap:_" + max(split, key=split.get), length]
+            for (_, length), split in longest[:10 - len(out)]]
+    return {"device_ops": ops, "idle_gaps": out}

@@ -1,5 +1,7 @@
-"""scheduler: 90th percentile of per-request TPOT, in a cell whose population
-is under 100 requests, where the tail is not judged. Source: host_clock."""
+"""scheduler: 90th percentile of per-request TPOT, beside the layers because no
+cell can judge it: a closed cell's population is under 100 requests, and an
+open cell's 121-135 leave 13 beyond it, whose runs spread by 0.4% to 9% from
+one machine to the next (PERF.md section 2). Source: host_clock."""
 
 from benchmark.layer_metrics import _common
 

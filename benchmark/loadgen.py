@@ -14,7 +14,9 @@ predecessor has ended, if that is later) and its latency is timed from when
 it was due. The schedule goes on through the drain after the window, so the
 last judged requests end under the same load as the first; the run ends when
 the last of them has. Closed loop: each client sends its next request when
-its last one ends, and latency is timed from the send.
+its last one ends, and latency is timed from the send; the summary's
+``deepest_request`` is the highest index of its list that any client reached
+(an open loop has none).
 """
 
 from __future__ import annotations
@@ -162,13 +164,16 @@ async def run_open(plan, session, url, model, t0, w0, w1, stop_at, records):
 
 async def run_closed(plan, session, url, model, t0, w0, w1, stop_at, records):
     """Each client sends its next request when its last one ends; none is
-    started after the window has closed."""
+    started after the window has closed. Returns the highest index in its
+    list that any client reached: how far from the plan's end the run was."""
+    reached = [-1] * len(plan["clients"])
 
-    async def client(seq):
+    async def client(c, seq):
         await asyncio.sleep(max(0.0, t0 - now()))
-        for r in seq:
+        for j, r in enumerate(seq):
             if now() >= w1:
                 return
+            reached[c] = j
             rec = new_record(r, late=0.0)
             records.append(rec)
             try:
@@ -186,12 +191,19 @@ async def run_closed(plan, session, url, model, t0, w0, w1, stop_at, records):
             rec["due"] = rec["sent"]
             # judged: the requests that ended inside the window
             rec["judged"] = rec["end"] is not None and w0 <= rec["end"] < w1
+        floor = plan.get("floor_request_s")
         raise RuntimeError(
-            "client ran out of requests before the window closed: raise "
-            "nominal_request_s's divisor in the mix file"
-        )
+            "client {} ran out of requests before the window closed: all {} "
+            "of its list ended {:.1f} s before the close (block 0 held {}); "
+            "{}".format(
+                c, len(seq), w1 - now(), plan.get("block0_per_client"),
+                "the mix has no floor_request_s: give it one" if not floor else
+                "a request took under the mix's floor_request_s = {}: the "
+                "floor's arithmetic in the mix file is at fault, not the "
+                "program".format(floor)))
 
-    tasks = [asyncio.create_task(client(seq)) for seq in plan["clients"]]
+    tasks = [asyncio.create_task(client(c, seq))
+             for c, seq in enumerate(plan["clients"])]
     await asyncio.sleep(max(0.0, stop_at - now()))
     for t in tasks:
         t.cancel()
@@ -199,6 +211,7 @@ async def run_closed(plan, session, url, model, t0, w0, w1, stop_at, records):
     for res in results:
         if isinstance(res, RuntimeError):
             raise res
+    return max(reached)
 
 
 async def front_probe(session, base, model, w0, w1, interval, out):
@@ -238,8 +251,8 @@ async def main_async(args) -> dict:
         ))
         run = run_open if plan["loop"] == "open" else run_closed
         try:
-            await run(plan, session, url, args.model, t0, w0, w1, stop_at,
-                      records)
+            deepest = await run(plan, session, url, args.model, t0, w0, w1,
+                                stop_at, records)
         finally:
             probe.cancel()
             await asyncio.gather(probe, return_exceptions=True)
@@ -247,7 +260,7 @@ async def main_async(args) -> dict:
         for rec in records:
             f.write(json.dumps(rec) + "\n")
     return {"t0": t0, "w0": w0, "w1": w1, "records": len(records),
-            "front_probe_ms": probes}
+            "deepest_request": deepest, "front_probe_ms": probes}
 
 
 def main() -> int:

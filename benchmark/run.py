@@ -13,7 +13,9 @@ measured window opens, after the mix's ramp: on an engine already in steady
 state. With ``--trace 1`` the last seconds of the window are profiled.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced). Without a
+``failed``, ``metrics``, ``device`` (``breakdown`` when traced, and for a
+closed loop ``closed_loop``: the deepest request any caller reached beside the
+sizes of its plan). Without a
 TPU, or with fewer chips than the cell asks, it exits 2 and prints no result.
 ``--rehearse`` walks every phase on whatever device there is, says so in
 ``device``, and exits 3: a proof of control flow, never a measurement.
@@ -477,8 +479,8 @@ async def run_cell(args, manifest, cell, cfg_entry, out_dir: Path) -> dict:
             faults.append("the profiler wrote no trace")
         else:
             ctx["trace"] = xplane.reduce_trace(path)
+            ctx["trace_path"] = path
             ctx["trace_counters"] = (win["trace_before"], win["trace_after"])
-        shutil.rmtree(trace_dir, ignore_errors=True)
 
     metrics = {}
     if traced:
@@ -506,6 +508,14 @@ async def run_cell(args, manifest, cell, cfg_entry, out_dir: Path) -> dict:
         result["device"]["busy_s"] = ctx["trace"]["busy_s"]
         result["device"]["window_s"] = ctx["trace"]["window_s"]
         result["breakdown"] = _common.breakdown(ctx)
+    if traced and not args.keep_trace:   # every reader of the trace has run
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    if plan["loop"] == "closed":
+        # how far from its plan's end the run was (traffic._closed_plan)
+        result["closed_loop"] = {
+            "deepest_request": gen.get("deepest_request"),
+            "block0_per_client": plan["block0_per_client"],
+            "per_client": plan["per_client"]}
     detail = {
         "workload": cell["name"], "seed": args.seed, "seconds": args.seconds,
         "trace": args.trace, "faults": faults, "phases": phases,
@@ -519,6 +529,18 @@ async def run_cell(args, manifest, cell, cfg_entry, out_dir: Path) -> dict:
         json.dump(detail, f, indent=1, default=str)
     if faults:
         log("NOT CORRECT: " + "; ".join(faults))
+    # every number compared, beside its limit: the last lines of stderr and
+    # the last key of the line
+    tol = ref.get("tolerance") or {}
+    result["checks"] = {
+        "typical_position_rms": [ref.get("typical_position_rms"), tol.get("typical")],
+        "outlier_share": [ref.get("outlier_share"), tol.get("outlier_share")],
+        "failed_requests": [summary["failed"], 0],
+        "compiles_in_window": [cx.compiles(win["health_after"])
+                               - cx.compiles(win["health_before"]), 0],
+    }
+    for name, (value, limit) in result["checks"].items():
+        log("compared: {} = {} (limit {})".format(name, value, limit))
     return result
 
 
@@ -537,6 +559,9 @@ def main() -> int:
     ap.add_argument("--sweep", default=None,
                     help="comma-separated session rates: find the knee of an "
                          "open-loop mix instead of measuring (prints a table)")
+    ap.add_argument("--keep-trace", action="store_true",
+                    help="leave the traced run's .xplane.pb under "
+                         ".bench_tmp/trace/ (for benchmark/host_spans.py)")
     ap.add_argument("--rehearse", action="store_true",
                     help="walk every phase without a TPU; exits 3")
     args = ap.parse_args()

@@ -13,6 +13,9 @@ round of a closed loop's clients, hold a like spread of the whole. An open
 loop's schedule is periodic with the window's length, so every window holds
 exactly the same requests whatever the seed; the seed turns the schedule by
 a phase, orders a closed loop's rounds, and writes the bytes of every prompt.
+A closed loop's plan is a sequence of such deals (``_closed_plan``: block 0 as
+long as the program needs today, then blocks up to what the chip's peaks could
+serve), and the rule holds for each of them.
 """
 
 from __future__ import annotations
@@ -214,24 +217,21 @@ def _sessions_plan(mix: dict, config: str, seed: int, ramp: float,
 
 # ------------------------------------------------------------- closed loop
 
-def _closed_plan(mix: dict, config: str, seed: int, horizon: float) -> dict:
-    """``clients`` callers, each sending its next request when its last one
-    ends. Every client gets a list long enough to outlast the run; a
-    client's first request is cut short (its answer or its prompt, as the
-    mix says) by a stratified fraction so that the clients do not move in a
-    wave."""
-    rng = random.Random(seed)
+def _closed_block(mix: dict, rng: random.Random, per_client: int, first: int,
+                  stagger) -> list:
+    """One block of a closed loop's plan: ``per_client`` requests for each of
+    the callers, numbered from ``first``. Request k = round * clients +
+    client, so every round of the callers holds a like spread of the lengths.
+    With ``stagger`` ("answer" or "prompt") a caller's first request of the
+    block is cut short by a stratified fraction, so that the callers do not
+    move in a wave."""
     clients = int(mix["clients"])
-    per_client = int(math.ceil(horizon / mix["nominal_request_s"])) + 2
     n = clients * per_client
-    # request k = round * clients + client: every round of the clients
-    # holds a like spread of the lengths
     p_lens = dealt(strata(mix["prompt_tokens"], n), clients, rng)
     o_lens = dealt(strata(mix["answer_tokens"], n), clients, rng)
     first_cut = dealt(
         strata({"dist": "uniform", "min": 0.0, "max": 1.0}, clients), clients, rng
     )
-    stagger = mix.get("stagger_first")  # "answer", "prompt" or absent
     out = []
     for c in range(clients):
         seq = []
@@ -244,12 +244,42 @@ def _closed_plan(mix: dict, config: str, seed: int, horizon: float) -> dict:
                 else:
                     p = max(16, int(round(p * cut)))
             seq.append({
-                "id": "c{}r{}".format(c, j),
+                "id": "c{}r{}".format(c, first + j),
                 "messages": [{"role": "user", "content": text(rng, p)}],
                 "max_tokens": o,
             })
         out.append(seq)
-    return {"loop": "closed", "clients": out}
+    return out
+
+
+def _closed_plan(mix: dict, config: str, seed: int, horizon: float) -> dict:
+    """``clients`` callers, each sending its next request when its last one
+    ends, each with a list that outlasts the run however fast the program is.
+
+    The list is a sequence of blocks. Block 0 is sized for the program as it
+    is: ``ceil(horizon / nominal_request_s) + 2`` requests a caller, drawn
+    from ``random.Random(seed)``, the first of each caller cut short
+    (``stagger_first``). Blocks of the same size, each from a generator of its
+    own (the seed and the block's index), are appended until a caller holds
+    ``ceil(horizon / floor_request_s) + 2`` requests: ``floor_request_s`` is
+    the least time a request of the mix could take on the chip (the mix file
+    gives the arithmetic). So a run sends, request for request, what it sent
+    before there were blocks, a faster program goes on into block 1, and the
+    multiset rule of the module's docstring holds block by block. A mix
+    without ``floor_request_s`` plans block 0 alone."""
+    block = int(math.ceil(horizon / mix["nominal_request_s"])) + 2
+    floor = mix.get("floor_request_s")
+    need = int(math.ceil(horizon / floor)) + 2 if floor else block
+    callers = _closed_block(mix, random.Random(seed), block, 0,
+                            mix.get("stagger_first"))
+    for b in range(1, int(math.ceil(need / block))):
+        more = _closed_block(mix, random.Random(seed * 1000003 + 7919 * b),
+                             block, b * block, None)
+        for seq, tail in zip(callers, more):
+            seq.extend(tail)
+    return {"loop": "closed", "clients": callers,
+            "block0_per_client": block, "per_client": len(callers[0]),
+            "floor_request_s": floor}
 
 
 def make_plan(mix_name: str, config: str, seed: int, seconds: float,

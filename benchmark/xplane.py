@@ -123,8 +123,11 @@ def is_kernel(name: str) -> bool:
 def reduce_trace(path: str) -> dict:
     """What the per-layer metrics read: the traced window's length, busy
     seconds averaged over the chips, per-operation totals, idle gaps, and
-    the kernels' seconds. ``window_s`` runs from the first operation's
+    the kernels' seconds, with the host's ``engine.*`` annotations beside
+    them. ``window_s`` runs from the first operation's
     start to the last operation's end over all chips."""
+    from benchmark import host_spans
+
     profile = load(path)
     planes = device_planes(profile)
     if not planes:
@@ -152,6 +155,8 @@ def reduce_trace(path: str) -> dict:
         "modules": by_name(mods0),
         "n_launches": len(mods0),
         "gaps": gaps(ops0),
+        # the host's engine.* annotations, on the same clock as the gaps
+        "host_spans": host_spans.engine_spans(profile),
     }
 
 

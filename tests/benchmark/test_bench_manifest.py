@@ -99,6 +99,20 @@ def test_tails_only_where_the_population_reaches_100(manifest):
             assert all("chat" in c for c in cells_of(m, manifest))
 
 
+def test_every_cell_reports_its_tpot_tail_once():
+    """The 90th percentile of TPOT is judged (an end-to-end `*_p90_ms`) only
+    where runs hold it steady, which since PR 41 is nowhere (PERF.md section
+    2); everywhere else it stands beside the layers as `req_tpot_p90_ms`: no
+    cell twice, none left out."""
+    manifest = load(MANIFESTS[0])
+    judged = [c for m in manifest["end_to_end"] if m["name"] == "tpot_p90_ms"
+              for c in cells_of(m, manifest)]
+    beside = cells_of(next(m for m in manifest["per_layer"]
+                           if m["name"] == "req_tpot_p90_ms"), manifest)
+    assert not set(judged) & set(beside)
+    assert sorted(judged + beside) == sorted(w["name"] for w in manifest["workloads"])
+
+
 def test_files_behind_the_names(manifest):
     used = {w["config"] for w in manifest["workloads"]}
     files = [c["file"] for c in manifest["configs"]]

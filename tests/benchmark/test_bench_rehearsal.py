@@ -57,6 +57,17 @@ def test_rehearsal_walks_every_phase(tmp_path, cell, trace, metric):
         # filled from the host
         assert "device_idle_share" not in line["metrics"]
         assert "busy_s" not in line["device"]
+    # a closed loop says how far from its plan's end it was; every number
+    # compared stands beside its limit, last on the line
+    if cell == "tiny.closed":
+        far = line["closed_loop"]
+        assert 0 <= far["deepest_request"] < far["block0_per_client"] == far["per_client"]
+    else:
+        assert "closed_loop" not in line
+    assert list(line)[-1] == "checks"
+    assert all(len(pair) == 2 for pair in line["checks"].values())
+    assert line["checks"]["failed_requests"] == [0, 0]
+    assert line["checks"]["compiles_in_window"] == [0, 0]
     detail = json.loads((tmp_path / "out" / "detail.json").read_text())
     assert detail["compiles_in_window"] == []
     assert detail["reference"]["repeat_identical"] and detail["reference"]["within"]

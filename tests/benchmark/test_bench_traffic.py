@@ -1,8 +1,11 @@
 """The traffic generator: a plan is a pure function of (mix, config, seed,
 seconds); the judged population is the same ids in every run of a seed; every
-seed offers the same multiset of sizes and gaps."""
+seed offers the same multiset of sizes and gaps; a closed loop's plan is the
+plan it was before it had blocks, and then outlasts the chip's roofline."""
 
+import hashlib
 import json
+import math
 import sys
 from pathlib import Path
 
@@ -20,10 +23,25 @@ CELLS = [
     ("chat_sessions", "mixtral-8x7b-d6", None),
     ("offline_decode", "mistral-7b-v0.3", None),
     ("offline_prefill", "mixtral-8x7b-d6", None),
+    ("offline_long_decode", "brumby-14b-d12", None),
     ("tiny_sessions", "tiny-dense", TINY),
     ("tiny_closed", "tiny-moe", TINY),
 ]
 BIG_SEED = 2 ** 31 + 12345
+STOCK_CLOSED = [c for c in CELLS if c[0].startswith("offline_")]
+# sha256 of json.dumps(plan["clients"], sort_keys=True) as make_plan(mix, config,
+# seed, 51) gave it on commit 6e992a5, before a plan had blocks: seeds 1, BIG_SEED
+PARENT_PLANS = {
+    "offline_decode": (
+        10, "0d51c1ae62772fbdd13ad7807b1f824ca177a97347bc679e30180493d14ebc38",
+        "7e6a77ecf7dfab103196f2e33c65ecadd0a0329d0d63d087aa5d32d8ded4a1b5"),
+    "offline_prefill": (
+        13, "e2f8a45f72a7cb52adb003661cb8f4995d5a200f07e48d5a7097e2933f514828",
+        "ec36dc204cc89f3b6ecdc2c59f46de96e39b75845cc28f4de41721081f38cfb1"),
+    "offline_long_decode": (
+        8, "11f6cc6bbe619f0ebb17125cb8e31879baba221c567821b179df893d8a8d65aa",
+        "8bb55448a98d5869df36002bb3560dc9879145d227a766efc37a4da9d197acdc"),
+}
 
 
 def _requests(plan):
@@ -108,6 +126,97 @@ def test_closed_loop_clients(mix, config, directory):
     key = "max_tokens" if spec["stagger_first"] == "answer" else None
     if key:
         assert len({r[key] for r in firsts}) > 1
+
+
+def _digest(clients):
+    return hashlib.sha256(json.dumps(clients, sort_keys=True).encode()).hexdigest()
+
+
+def _without_floor(mix, tmp_path):
+    """A copy of the stock mix as it was: no ``floor_request_s``."""
+    spec = traffic.load_mix(mix)
+    del spec["floor_request_s"]
+    (tmp_path / (mix + ".json")).write_text(json.dumps(spec))
+    return tmp_path
+
+
+@pytest.mark.parametrize("which,seed", [(1, 1), (2, BIG_SEED)])
+@pytest.mark.parametrize("mix,config,_", STOCK_CLOSED)
+def test_block_0_is_the_parents_plan_byte_for_byte(mix, config, _, which, seed):
+    plan = traffic.make_plan(mix, config, seed, 51)
+    count = PARENT_PLANS[mix][0]
+    assert plan["block0_per_client"] == count < plan["per_client"]
+    assert _digest([c[:count] for c in plan["clients"]]) == PARENT_PLANS[mix][which]
+
+
+@pytest.mark.parametrize("mix,config,_", STOCK_CLOSED)
+def test_a_plan_with_blocks_extends_the_plan_without(mix, config, _, tmp_path):
+    bare = traffic.make_plan(mix, config, BIG_SEED, 51, _without_floor(mix, tmp_path))
+    plan = traffic.make_plan(mix, config, BIG_SEED, 51)
+    count = bare["per_client"]
+    # a mix without the key plans as before: block 0 alone
+    assert count == bare["block0_per_client"] == PARENT_PLANS[mix][0]
+    assert bare["floor_request_s"] is None
+    assert _digest(bare["clients"]) == PARENT_PLANS[mix][2]
+    assert [c[:count] for c in plan["clients"]] == bare["clients"]
+    assert all(len(c) == plan["per_client"] for c in plan["clients"])
+    assert plan["per_client"] % count == 0            # whole blocks
+    for key in ("ramp_s", "window_s", "drain_s", "probe_interval_s", "seed"):
+        assert plan[key] == bare[key]
+
+
+@pytest.mark.parametrize("mix,config,_", STOCK_CLOSED)
+def test_the_plan_outlasts_the_floor(mix, config, _):
+    """At ``floor_request_s`` a request, the least the chip's peaks allow, the
+    caller with the shortest list is still sending when the window closes."""
+    spec = traffic.load_mix(mix)
+    plan = traffic.make_plan(mix, config, 3, 51)
+    horizon = plan["ramp_s"] + plan["window_s"]
+    assert plan["floor_request_s"] == spec["floor_request_s"] < spec["nominal_request_s"]
+    assert len(spec["floor_why"]) > 100 and "peaks.json" in spec["floor_why"]
+    shortest = min(len(c) for c in plan["clients"])
+    assert shortest >= math.ceil(horizon / spec["floor_request_s"]) + 2
+    assert (shortest - 1) * spec["floor_request_s"] >= horizon
+    times = {"offline_decode": 3, "offline_prefill": 4, "offline_long_decode": 3}
+    assert shortest >= times[mix] * plan["block0_per_client"]
+    ids = [r["id"] for r in _requests(plan)]
+    assert len(ids) == len(set(ids))
+    for c, client in enumerate(plan["clients"]):      # the ids go on counting
+        assert [r["id"] for r in client] == [
+            "c{}r{}".format(c, j) for j in range(len(client))]
+
+
+@pytest.mark.parametrize("mix,config,_", STOCK_CLOSED)
+def test_every_block_offers_the_same_lengths_under_every_seed(mix, config, _):
+    """The multiset rule, block by block: whatever block a fast program
+    reaches, it finds there the work every other seed has there, in another
+    order; only block 0 cuts its first round short (by fractions that are
+    themselves the same set under every seed)."""
+    def blocks(seed):
+        plan = traffic.make_plan(mix, config, seed, 51)
+        size = plan["block0_per_client"]
+        out = []
+        for b in range(plan["per_client"] // size):
+            rows = [c[b * size + (1 if b == 0 else 0):(b + 1) * size]
+                    for c in plan["clients"]]
+            out.append((
+                sorted(traffic.prompt_tokens(r["messages"]) for c in rows for r in c),
+                sorted(r["max_tokens"] for c in rows for r in c),
+                [(len(r["messages"][0]["content"]), r["max_tokens"])
+                 for c in rows for r in c]))
+        return out
+    a, b, c = blocks(1), blocks(BIG_SEED), blocks(77)
+    assert len(a) == len(b) == len(c) >= 3
+    stagger = traffic.load_mix(mix)["stagger_first"]
+    for k, (x, y, z) in enumerate(zip(a, b, c)):
+        if k or stagger != "prompt":
+            assert x[0] == y[0] == z[0]               # prompts
+        if k or stagger != "answer":
+            assert x[1] == y[1] == z[1]               # answers
+        assert x[2] != y[2] != z[2]                   # in another order
+    # a later block is a block like the first, not a copy of it
+    assert a[1][2] != a[2][2]
+    assert a[1][1] == a[2][1] and a[1][0] == a[2][0]
 
 
 @pytest.mark.parametrize("spec,n,expect", [
