@@ -55,7 +55,7 @@ from . import (
     lifecycle_ledger,
     sharding_sentry,
 )
-from .shapes import decode_steps_bucket
+from .shapes import decode_steps_bucket, pad_to_multiple
 from ..errors import (
     DeadlineExceededError,
     EngineOverloadedError,
@@ -1547,6 +1547,9 @@ class LLMEngineCore:
             # decode steps): what a roofline of the step needs to count
             "ragged_prefill_tokens": 0,
             "ragged_passes": 0,
+            # rows the dense layers of the mixed passes multiplied: the
+            # compact axis, whole, a launch
+            "ragged_dense_rows": 0,
             # rows x decode passes over paged KV (the chained passes of a
             # ragged launch, the passes of a decode chunk) and the tokens
             # those rows attended there: the paged decode kernel's work
@@ -2600,7 +2603,8 @@ class LLMEngineCore:
             if cache_mode == "paged":
 
                 def _ragged_paged_step(params, tokens, tok_pos, tok_row,
-                                       tok_valid, row_last, k_pools, v_pools,
+                                       tok_valid, tok_slot, row_last,
+                                       k_pools, v_pools,
                                        k_scales, v_scales, page_table,
                                        kv_lens, row_starts, row_lens,
                                        write_page, write_offset, item_rows,
@@ -2624,7 +2628,8 @@ class LLMEngineCore:
                         logit_kw["tree_anc"] = tree[3]
                     out = bundle.forward_ragged(
                         params, tokens, tok_pos, tok_row, tok_valid,
-                        row_last, k_pools, v_pools, page_table, kv_lens,
+                        tok_slot, row_last, k_pools, v_pools, page_table,
+                        kv_lens,
                         row_starts, row_lens, write_page, write_offset,
                         item_rows, item_q0, lora_idx, **scale_kw,
                         **logit_kw,
@@ -2652,14 +2657,14 @@ class LLMEngineCore:
                             # like a chain row's. Non-moves (and non-tree
                             # rows) scatter to the null page (page 0), the
                             # same discard target every pad write uses.
+                            # The write coordinates are per token, on the
+                            # compact axis: a verify row's first token is
+                            # its first logit index.
                             nn = spec_nodes.shape[1]
                             pos = jnp.arange(1, nn, dtype=jnp.int32)
-                            src = (
-                                row_starts[:, None] + spec_nodes[:, 1:]
-                            ).reshape(-1)
-                            dst = (
-                                row_starts[:, None] + pos[None, :]
-                            ).reshape(-1)
+                            row_first = spec[3][:, :1]
+                            src = (row_first + spec_nodes[:, 1:]).reshape(-1)
+                            dst = (row_first + pos[None, :]).reshape(-1)
                             move = (
                                 spec_any[:, None]
                                 & (spec_nodes[:, 1:] != pos[None, :])
@@ -2755,7 +2760,7 @@ class LLMEngineCore:
                 self._ragged_paged_jit = jax.jit(
                     _ragged_paged_step,
                     donate_argnums=(
-                        (6, 7, 8, 9) if self._paged_quant else (6, 7)
+                        (7, 8, 9, 10) if self._paged_quant else (7, 8)
                     ),
                     static_argnames=("want_lp",),
                 )
@@ -2833,26 +2838,41 @@ class LLMEngineCore:
                     static_argnames=("want_lp",),
                 )
                 self._ragged_paged_jit = None
-            # static flat-token capacity per launch: ONE trace per
-            # (extras/guided/lp variant). When the Pallas kernel serves the
-            # launch each row's segment aligns to the 8 tokens the kernel's
-            # q / out copies move (worst-case alignment waste = one copy per
-            # row); the XLA reference needs no alignment and rows pack
-            # densely. The alignment is the KERNEL'S constant — the layout
-            # the engine builds and the copies forward_ragged launches must
-            # share one contract, not two constants that happen to agree.
-            from ..ops.paged_attention import _RAGGED_QB
+            # Two static token axes per launch, ONE trace per
+            # (extras/guided/lp variant). Everything that is per token (the
+            # dense layers, the pool writes, the logit gathers) runs on the
+            # COMPACT axis: ``_ragged_dense`` rows, the budget rounded up
+            # to whole alignment blocks, the launch's tokens packed row
+            # after row (the budget counts every reserved position, so they
+            # always fit). A token-mixing kernel reads a row's tokens by
+            # the row's start: when the Pallas kernel serves the launch
+            # each row's segment aligns to the 8 tokens its q / out copies
+            # move, in an aligned VIEW of ``_ragged_tpad`` rows (worst-case
+            # waste = one copy less a token per row) that holds q and the
+            # kernel's output and nothing else; the XLA reference needs no
+            # alignment and the view is the compact axis. The alignment is
+            # the KERNEL'S constant and the view's size the kernel module's
+            # arithmetic (ragged_view_tokens, which forward_ragged evaluates
+            # over the same shapes): one contract, not two constants that
+            # happen to agree.
+            from ..ops.paged_attention import _RAGGED_QB, ragged_view_tokens
 
             self._ragged_kernel = self._paged_kernel_reason is None
             qb = _RAGGED_QB if self._ragged_kernel else 1
             if cache_mode == "state":
                 # the chunk kernel slices a row's tokens at its start:
                 # whole 8-row float32 tiles, with the kernel or its twin
-                qb = 8
+                from ..ops.power_retention import ROW_ALIGN
+
+                qb = ROW_ALIGN
             self._ragged_qb = qb
-            budget = self._step_token_budget
-            waste = self.max_batch * (qb - 1) if qb > 1 else 0
-            self._ragged_tpad = -(-(budget + waste) // qb) * qb
+            self._ragged_dense = pad_to_multiple(self._step_token_budget, qb)
+            self._ragged_tpad = ragged_view_tokens(
+                self._ragged_dense, self.max_batch, qb
+            )
+            if cache_mode == "state":
+                # forward_ragged_state runs on ONE axis, the view
+                self._ragged_dense = self._ragged_tpad
             if self._ragged_kernel and cache_mode == "paged":
                 # the kernel's work plan: a row, or a query tile of it, per
                 # item; the tile follows from the shapes the kernel sees,
@@ -4407,6 +4427,8 @@ class LLMEngineCore:
                     "step_rows": dict(self._step_rows),
                     "decode_steps": self._ragged_decode_steps,
                     "decode_tokens": self.counters["ragged_decode_tokens"],
+                    "dense_axis": self._ragged_dense,
+                    "layout_axis": self._ragged_tpad,
                 }
                 if self._ragged
                 else None
@@ -4528,6 +4550,11 @@ class LLMEngineCore:
                     "decode_tokens": self.counters["ragged_decode_tokens"],
                     "prefill_tokens": self.counters["ragged_prefill_tokens"],
                     "passes": self.counters["ragged_passes"],
+                    # the mixed pass's two token axes (static) and the
+                    # rows its dense layers multiplied, summed over launches
+                    "dense_axis": self._ragged_dense,
+                    "layout_axis": self._ragged_tpad,
+                    "dense_rows": self.counters["ragged_dense_rows"],
                     "decode_chain_rows": self.counters["decode_chain_rows"],
                     "decode_chain_kv_tokens": (
                         self.counters["decode_chain_kv_tokens"]
@@ -6130,8 +6157,8 @@ class LLMEngineCore:
             if self.paged_cache is not None
             else None
         )
-        # layout lens reserve each row's WHOLE window in the flat token
-        # axis (a q=N decode row owns N positions: position 0 rides the
+        # layout lens reserve each row's WHOLE window in both token axes
+        # (a q=N decode row owns N positions: position 0 rides the
         # mixed pass, positions 1.. are written by the in-launch chain);
         # kernel row_lens count only the positions the ragged pass
         # itself computes
@@ -6146,13 +6173,25 @@ class LLMEngineCore:
                 row_lens[slot] = 1
         for slot, take in take_of.items():
             span_lens[slot] = row_lens[slot] = take
+        # the compact axis packs the spans (``s`` below, and every index
+        # the launch reads a token by); the aligned view places the same
+        # spans where the kernel's row map says (``starts``), and
+        # ``tok_slot`` takes a token from the one to the other
+        dense = self._ragged_dense
         starts, tpad = ragged_layout(
             span_lens, self._ragged_qb, total=self._ragged_tpad
         )
-        tokens = np.zeros(tpad, np.int32)
-        tok_pos = np.zeros(tpad, np.int32)
-        tok_row = np.zeros(tpad, np.int32)
-        tok_valid = np.zeros(tpad, bool)
+        # one axis where the sizes coincide: the XLA twin (rows pack
+        # densely in its view too) and the state cache
+        packed = (
+            starts if dense == tpad
+            else ragged_layout(span_lens, 1, total=dense)[0]
+        )
+        tokens = np.zeros(dense, np.int32)
+        tok_pos = np.zeros(dense, np.int32)
+        tok_row = np.zeros(dense, np.int32)
+        tok_valid = np.zeros(dense, bool)
+        tok_slot = np.full(dense, tpad, np.int32)
         row_last = np.zeros(self.max_batch, np.int32)
         kv_lens = np.zeros(self.max_batch, np.int32)
         pre_lens = np.zeros(self.max_batch, np.int32)
@@ -6161,7 +6200,7 @@ class LLMEngineCore:
             n = int(span_lens[slot])
             if n == 0:
                 continue
-            s = int(starts[slot])
+            s = int(packed[slot])
             v = int(row_lens[slot])
             pre = (
                 pool.slot_length(slot)
@@ -6194,12 +6233,14 @@ class LLMEngineCore:
             # pass: their tokens are sampled in-launch and their K/V
             # written by the chained decode steps
             tok_valid[s : s + v] = True
+            tok_slot[s : s + v] = starts[slot] + np.arange(v, dtype=np.int32)
             row_last[slot] = s + v - 1
             kv_lens[slot] = pre + v
         if tree_parents is not None and n_spec:
-            # flat per-token ancestor lists for the kernel's tree mask
-            # (ops.paged_attention.tree_ancestors layout): every
-            # non-tree token keeps the -2 plain-causal sentinel
+            # per-token ancestor lists for the kernel's tree mask, in the
+            # aligned view's order (ops.paged_attention.tree_ancestors
+            # layout): every non-tree token keeps the -2 plain-causal
+            # sentinel
             from ..ops.paged_attention import tree_ancestors
 
             tree_anc = np.full((tpad, k_ + 1), -1, np.int32)
@@ -6218,22 +6259,23 @@ class LLMEngineCore:
             )
             for slot in range(self.max_batch):
                 if row_lens[slot] > 0:
-                    row_logit_idx[slot] = starts[slot] + np.minimum(
+                    row_logit_idx[slot] = packed[slot] + np.minimum(
                         np.arange(k_ + 1), row_lens[slot] - 1
                     )
         else:
             row_logit_idx = None
         plan.update(
             tokens=tokens, tok_pos=tok_pos, tok_row=tok_row,
-            tok_valid=tok_valid, row_last=row_last, kv_lens=kv_lens,
+            tok_valid=tok_valid, tok_slot=tok_slot, row_last=row_last,
+            kv_lens=kv_lens,
             pre_lens=pre_lens, row_starts=starts, row_lens=row_lens,
             span_lens=span_lens, spans=spans,
             # state cache: a row whose tokens start its sequence finds
             # its slot as the last owner left it — the launch zeroes it
             row_reset=(pre_lens == 0) & (row_lens > 0),
             row_logit_idx=row_logit_idx,
-            write_page=np.zeros(tpad, np.int32),
-            write_offset=np.zeros(tpad, np.int32),
+            write_page=np.zeros(dense, np.int32),
+            write_offset=np.zeros(dense, np.int32),
             item_rows=None, item_q0=None,
         )
         if self._ragged_kernel and pool is not None:
@@ -6272,6 +6314,7 @@ class LLMEngineCore:
         plan["tok_pos"][s : s + n] = 0
         plan["tok_row"][s : s + n] = 0
         plan["tok_valid"][s : s + n] = False
+        plan["tok_slot"][s : s + n] = self._ragged_tpad
         plan["row_lens"][slot] = 0
         plan["span_lens"][slot] = 0
         plan["kv_lens"][slot] = plan["pre_lens"][slot]
@@ -6287,8 +6330,9 @@ class LLMEngineCore:
             # the dropped verify row's pad tokens revert to plain-causal
             # sentinels (they are never live queries, but the mask arrays
             # must not carry a freed row's topology into the launch)
-            plan["tree_anc"][s : s + n] = -1
-            plan["tree_anc"][s : s + n, 0] = -2
+            a = int(plan["row_starts"][slot])
+            plan["tree_anc"][a : a + n] = -1
+            plan["tree_anc"][a : a + n, 0] = -2
         if plan["decode_mask"][slot]:
             plan["decode_mask"][slot] = False
             plan["exhausted"].append(slot)
@@ -6402,7 +6446,7 @@ class LLMEngineCore:
             self.paged_cache.apply_pending_cow()
             plan["page_table"] = pool.page_table(self._pages_per_seq)
             dev = self._ragged_operands(
-                plan, "page_table", "write_page", "write_offset"
+                plan, "tok_slot", "page_table", "write_page", "write_offset"
             )
             spec, tree = _spec_arrays(), _tree_arrays()
             with self.paged_cache.dispatch_lock:
@@ -6418,6 +6462,7 @@ class LLMEngineCore:
                     dev["tok_pos"],
                     dev["tok_row"],
                     dev["tok_valid"],
+                    dev["tok_slot"],
                     dev["row_last"],
                     self.paged_cache.k,
                     self.paged_cache.v,
@@ -6789,6 +6834,7 @@ class LLMEngineCore:
             t for _, t in live_shares
         )
         self.counters["ragged_passes"] += int(plan["launch_steps"])
+        self.counters["ragged_dense_rows"] += len(plan["tokens"])
         self._count_sampler_passes(
             int(plan["launch_steps"]), plan["row_steps"]
         )

@@ -204,7 +204,9 @@ def warm_ragged_variants(engine) -> int:
 
     if engine.paged_cache is not None:
         cache = engine.paged_cache
-        tpad = engine._ragged_tpad
+        # the launch's two token axes: per-token operands on the compact
+        # one, the ancestor lists in the kernel's aligned view
+        dense, tpad = engine._ragged_dense, engine._ragged_tpad
         page_table = jnp.asarray(
             np.zeros((b, engine._pages_per_seq), np.int32)
         )
@@ -248,18 +250,19 @@ def warm_ragged_variants(engine) -> int:
                         new_ks, new_vs, _counts, _lp, _gs, _sg, _sa,
                     ) = engine._ragged_paged_jit(
                         engine.params,
-                        jnp.asarray(np.zeros(tpad, np.int32)),
-                        jnp.asarray(np.zeros(tpad, np.int32)),
-                        jnp.asarray(np.zeros(tpad, np.int32)),
-                        jnp.asarray(np.zeros(tpad, bool)),
+                        jnp.asarray(np.zeros(dense, np.int32)),
+                        jnp.asarray(np.zeros(dense, np.int32)),
+                        jnp.asarray(np.zeros(dense, np.int32)),
+                        jnp.asarray(np.zeros(dense, bool)),
+                        jnp.asarray(np.full(dense, tpad, np.int32)),
                         jnp.asarray(np.zeros(b, np.int32)),
                         cache.k, cache.v, cache.k_scale, cache.v_scale,
                         page_table,
                         jnp.asarray(np.zeros(b, np.int32)),
                         jnp.asarray(np.zeros(b, np.int32)),
                         jnp.asarray(np.zeros(b, np.int32)),
-                        jnp.asarray(np.zeros(tpad, np.int32)),
-                        jnp.asarray(np.zeros(tpad, np.int32)),
+                        jnp.asarray(np.zeros(dense, np.int32)),
+                        jnp.asarray(np.zeros(dense, np.int32)),
                         items[0], items[1],
                         jnp.asarray(np.zeros(b, bool)),
                         sampling, key(), lora,
@@ -275,10 +278,11 @@ def warm_ragged_variants(engine) -> int:
                 jax.block_until_ready(sampled)
                 ran += 1
     else:
-        # state cache (docs/state_cache.md): the flat token axis is one
-        # static size and there are no spec rows, so the decode window is
-        # the only compile key. Null rows (row_lens 0, chain masks False)
-        # touch no slot: the donated pools come back value-unchanged
+        # state cache (docs/state_cache.md): the one token axis (the
+        # kernels' view) is a static size and there are no spec rows, so
+        # the decode window is the only compile key. Null rows (row_lens 0,
+        # chain masks False) touch no slot: the donated pools come back
+        # value-unchanged
         cache = engine.state_cache
         tpad = engine._ragged_tpad
         for steps in windows:

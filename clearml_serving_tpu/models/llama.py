@@ -751,9 +751,14 @@ def build(config: dict) -> SimpleNamespace:
         weights = jnp.zeros((b * s, n_experts), jnp.float32).at[
             jnp.arange(b * s)[:, None], top_e
         ].add(top_p)
+        # the expert axis is a BATCH axis of every product, on both
+        # operands: left free on the weights alone ("td,edf->etf") the v5e
+        # compiler may re-lay the whole [E, D, F] stack out of the layer
+        # scan for the product (tests/test_tpu_compile.py -k compact_axis)
+        per_expert = jnp.broadcast_to(tokens[None], (n_experts,) + tokens.shape)
         h = jax.nn.silu(
-            jnp.einsum("td,edf->etf", tokens, _w(layer, "w_gate_e"))
-        ) * jnp.einsum("td,edf->etf", tokens, _w(layer, "w_up_e"))
+            jnp.einsum("etd,edf->etf", per_expert, _w(layer, "w_gate_e"))
+        ) * jnp.einsum("etd,edf->etf", per_expert, _w(layer, "w_up_e"))
         expert_out = jnp.einsum("etf,efd->etd", h, _w(layer, "w_down_e"))
         out = jnp.einsum("te,etd->td", weights.astype(x.dtype), expert_out)
         return out.reshape(b, s, d_).astype(x.dtype)
@@ -1592,43 +1597,79 @@ def build(config: dict) -> SimpleNamespace:
 
     # -- ragged mixed prefill+decode step (docs/ragged_attention.md) ---------
 
+    def _ragged_axes(tok_slot, rows, q_block):
+        """The two token axes of a ragged pass. Everything per token (the
+        embedding, norms, QKV, RoPE, the pool writes, O, the FFN, the logit
+        gathers) runs on the COMPACT axis: C = ``tok_slot.shape[0]`` rows,
+        the launch's tokens packed row after row, pads at the end. A
+        token-mixing kernel reads a row's tokens by the row's start, which
+        ops.ragged_layout aligns to ``q_block``: its operands live in the
+        aligned VIEW of ops.ragged_view_tokens(C, rows, q_block) rows, and
+        only they. ``tok_slot`` [C] is a compact token's place in the view
+        (pads: out of range). Returns (view rows, place, back): ``place``
+        lays a [C, ...] operand out in the view (zeros where no token
+        lives), ``back`` reads a [view, ...] result at the tokens' places
+        (zeros for pads). Both are row gathers; the view's side of the map
+        is built once a pass."""
+        from ..ops.paged_attention import ragged_view_tokens
+
+        c = tok_slot.shape[0]
+        view = ragged_view_tokens(c, rows, q_block)
+        slot_tok = jnp.full((view,), c, jnp.int32).at[tok_slot].set(
+            jnp.arange(c, dtype=jnp.int32), mode="drop"
+        )
+
+        def place(a):
+            return a.at[slot_tok].get(mode="fill", fill_value=0)
+
+        def back(a):
+            return a.at[tok_slot].get(mode="fill", fill_value=0)
+
+        return view, place, back
+
     def forward_ragged(
         params,
-        tokens,        # [T] int32 flattened ragged chunk (token-major)
-        tok_pos,       # [T] int32 absolute position of each token in its row
-        tok_row,       # [T] int32 owning batch row per token (pads -> 0)
-        tok_valid,     # [T] bool real tokens (pads never route in MoE)
-        row_last,      # [R] int32 flat index of each row's last real token
+        tokens,        # [C] int32 the launch's tokens, packed (compact axis)
+        tok_pos,       # [C] int32 absolute position of each token in its row
+        tok_row,       # [C] int32 owning batch row per token (pads -> 0)
+        tok_valid,     # [C] bool real tokens (pads never route in MoE)
+        tok_slot,      # [C] int32 each token's place in the kernel's aligned
+                       #  view (row_starts[row] + its index; pads >= view)
+        row_last,      # [R] int32 compact index of each row's last real token
         k_pools,       # [L, Hkv, N, P, D] (int8 under kv_quant)
         v_pools,
         page_table,    # [R, PP] int32
         kv_lens,       # [R] int32 tokens present AFTER this chunk's writes
-        row_starts,    # [R] int32 ragged row map (ops.ragged_layout)
+        row_starts,    # [R] int32 row map of the VIEW (ops.ragged_layout)
         row_lens,      # [R] int32 query tokens per row (0 = idle row)
-        write_page,    # [T] int32 per-token write coords (pads -> null page)
-        write_offset,  # [T] int32
+        write_page,    # [C] int32 per-token write coords (pads -> null page)
+        write_offset,  # [C] int32
         item_rows=None,   # [NI] int32 the kernel's work plan (host-built,
         item_q0=None,     #  ops.ragged_work_items; required with the kernel)
         lora_idx=None,    # [R] int32 adapter index per row (None = base)
         *,
         k_scales=None,  # [L, Hkv, N, P] f32 scale pools (kv_quant only)
         v_scales=None,
-        row_logit_idx=None,  # [R, W] int32 flat token indices to read
+        row_logit_idx=None,  # [R, W] int32 compact token indices to read
                              # logits at (None = row_last only)
-        tree_anc=None,       # [T, DMAX] int32 per-token ancestor lists for
-                             # draft-TREE verify rows (None = plain causal;
+        tree_anc=None,       # [view, DMAX] int32 per-token ancestor lists
+                             # for draft-TREE verify rows, in the VIEW's
+                             # order (None = plain causal;
                              # ops.paged_attention.tree_ancestors layout)
     ):
         """ONE forward step over a ragged mixed batch: each row is at an
         arbitrary phase — decode rows contribute one query token (plus
         reserved multi-step pad positions), spec-verify rows a known
         draft chain of q=k+1 candidate tokens, prefill rows a prompt
-        chunk — flattened into a token-major operand (PAPERS.md "Ragged
+        chunk — packed into a token-major operand (PAPERS.md "Ragged
         Paged Attention"). Every token embeds at its own absolute
         position, writes its K/V into the stacked paged pools at
         host-precomputed (page, offset) coords — decode_paged's scatter, with
         the chunk's quantized scales beside int8 pages — and attends
-        through ops.ragged_paged_attention with per-row causal bounds.
+        through ops.ragged_paged_attention with per-row causal bounds. The
+        dense layers multiply the C packed tokens; only q and the
+        attention output pass through the kernel's aligned view
+        (:func:`_ragged_axes`), whose row map the kernel takes as before.
         Returns (row logits [R, vocab] at each row's last real token,
         updated pools); when ``row_logit_idx`` [R, W] is given, the
         spec-verify gather ([R, W, vocab] logits at the W requested flat
@@ -1639,6 +1680,7 @@ def build(config: dict) -> SimpleNamespace:
         the decode path's logits, which is what the engine's
         ragged-vs-two-dispatch byte-identity rests on."""
         from ..ops.paged_attention import (
+            _RAGGED_QB,
             paged_kernel_unsupported_reason,
             ragged_paged_attention,
             ragged_paged_attention_xla,
@@ -1651,10 +1693,20 @@ def build(config: dict) -> SimpleNamespace:
         use_kernel = paged_kernel_unsupported_reason(
             head_dim, k_pools.shape[3], k_pools.dtype
         ) is None
-        t = tokens.shape[0]
-        positions = tok_pos[:, None]                               # [T, 1]
+        c = tokens.shape[0]
+        # the alignment is the kernel's constant; its XLA twin packs rows
+        # densely, so there the view is the compact axis
+        view, place, back = _ragged_axes(
+            tok_slot, row_starts.shape[0], _RAGGED_QB if use_kernel else 1
+        )
+        if tree_anc is not None and tree_anc.shape[0] != view:
+            raise ValueError(
+                "forward_ragged: tree_anc has {} rows, the aligned view "
+                "{}".format(tree_anc.shape[0], view)
+            )
+        positions = tok_pos[:, None]                               # [C, 1]
         cos, sin = _rope(positions, head_dim, theta, rope_scaling)
-        x = _embed(params, tokens)[:, None]                        # [T, 1, dim]
+        x = _embed(params, tokens)[:, None]                        # [C, 1, dim]
         tok_lora = lora_idx[tok_row] if lora_idx is not None else None
         q_prescale = query_scale * (head_dim ** 0.5)
 
@@ -1662,31 +1714,33 @@ def build(config: dict) -> SimpleNamespace:
             stash = []
 
             def attn_fn(layer_, h):
-                q, k, v = _qkv(layer_, h, cos, sin, tok_lora)  # [T,1,H,D]
+                q, k, v = _qkv(layer_, h, cos, sin, tok_lora)  # [C,1,H,D]
                 new = _paged_write(
                     pools, li, k[:, 0], v[:, 0], write_page, write_offset
                 )
                 stash.append(new)
-                q_grouped = q[:, 0].reshape(t, n_kv, group, head_dim)
+                q_flat = q[:, 0].reshape(c, n_heads * head_dim)
                 if q_prescale != 1.0:
-                    q_grouped = q_grouped * jnp.asarray(
-                        q_prescale, q_grouped.dtype
-                    )
+                    q_flat = q_flat * jnp.asarray(q_prescale, q_flat.dtype)
                 kw = dict(zip(("k_scale", "v_scale"), new[2:]),
                           tree_anc=tree_anc, layer=li)
                 with jax.named_scope("attn"):
+                    q_grouped = place(q_flat).reshape(
+                        view, n_kv, group, head_dim
+                    )
                     if use_kernel:
                         attn = ragged_paged_attention(
                             q_grouped, new[0], new[1], page_table, kv_lens,
                             row_starts, row_lens,
                             item_rows=item_rows, item_q0=item_q0, **kw,
-                        )                                          # [T,Hkv,G,D]
+                        )                                       # [view,Hkv,G,D]
                     else:
                         attn = ragged_paged_attention_xla(
                             q_grouped, new[0], new[1], page_table, kv_lens,
                             row_starts, row_lens, **kw,
                         )
-                return attn.reshape(t, 1, n_heads * head_dim).astype(x.dtype)
+                    attn = back(attn.reshape(view, n_heads * head_dim))
+                return attn.reshape(c, 1, n_heads * head_dim).astype(x.dtype)
 
             # dropless MoE: capacity dropping would make a row's tokens
             # depend on what the OTHER rows put in the launch — the ragged
@@ -1787,14 +1841,14 @@ def build(config: dict) -> SimpleNamespace:
 
     def forward_ragged_state(
         params,
-        tokens,        # [T] int32 flattened ragged chunk (token-major)
+        tokens,        # [T] int32 the launch's tokens on the aligned VIEW (below)
         tok_pos,       # [T] int32 absolute position of each token in its row
         tok_row,       # [T] int32 owning batch row per token (pads -> 0)
         tok_valid,     # [T] bool real tokens of THIS pass
         row_last,      # [R] int32 flat index of each row's last real token
         s_pool,        # [L, slots, Hkv, D, rows] float32 (slot = row)
         z_pool,        # [L, slots, Hkv, zrows, D] float32
-        row_starts,    # [R] int32 ragged row map (8-aligned starts)
+        row_starts,    # [R] int32 row map of the view (8-aligned starts)
         row_lens,      # [R] int32 tokens of this pass per row (0 = idle)
         row_reset,     # [R] bool the row's slot counts as zero before it
     ):
@@ -1806,8 +1860,11 @@ def build(config: dict) -> SimpleNamespace:
         read-out of the state before it, one state update); idle rows and
         pad positions touch no slot. A row whose chunk starts its sequence
         says so in ``row_reset``: whatever the slot's last owner left counts
-        as zero. Returns (logits [R, vocab] at each row's last real token,
-        s_pool, z_pool)."""
+        as zero. Unlike forward_ragged, the whole pass runs on ONE token
+        axis, the kernels' aligned view (ops.ragged_layout at
+        ops.power_retention.ROW_ALIGN; the engine makes its compact axis
+        that view for this cache). Returns (logits [R, vocab] at each row's
+        last real token, s_pool, z_pool)."""
         del tok_row, tok_valid       # the row map says the same, per row
         update, chunk = _retention_ops()
         t = tokens.shape[0]

@@ -820,6 +820,10 @@ def paged_kv_write(k_pool, v_pool, k_new, v_new, write_page, write_offset, *,
 #   heads a descriptor: that a row's segment starts at a multiple of 8
 #   (:func:`ragged_layout`) is what these copies need, and all they need.
 #   ``out`` is born zero (an aliased operand), so tokens no item owns read 0.
+#   This aligned view ([:func:`ragged_view_tokens`] rows) exists around the
+#   token-mixing kernel only: the model step keeps its tokens packed on a
+#   compact axis for everything that is per token, places q into the view
+#   and gathers out back (models/llama.py ``forward_ragged``).
 # - the int8 path's pre-gathered per-row scale operands pipeline per ITEM via
 #   an index map that reads the item's row, all heads a block.
 
@@ -856,6 +860,16 @@ def ragged_layout(row_lens, q_block: int = _RAGGED_QB, total: int | None = None)
             )
         t_pad = -(-int(total) // q_block) * q_block
     return starts, int(t_pad)
+
+
+def ragged_view_tokens(tokens: int, rows: int, q_block: int = _RAGGED_QB) -> int:
+    """Rows of the aligned view that holds any launch of at most ``tokens``
+    tokens in ``rows`` rows under :func:`ragged_layout`: every row may waste
+    one copy less a token. With ``q_block`` 1 the view is the compact axis
+    itself. The engine sizes its layout (and ``tree_anc``) with this and the
+    model step its view, from the same shapes."""
+    q_block = int(q_block)
+    return -(-(int(tokens) + int(rows) * (q_block - 1)) // q_block) * q_block
 
 
 def _ragged_sub_queries(g):

@@ -60,6 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _HI = jax.lax.Precision.HIGHEST
 _CHUNK = 128          # tokens per sub-chunk of the chunk kernel on the chip
+ROW_ALIGN = 8         # the chunk kernel's row_starts: whole float32 sublane tiles
 _VMEM_LIMIT = 64 << 20
 
 

@@ -3,7 +3,8 @@
 - the engine's ``health()`` names the device and which implementation each
   kernel's call sites take, with the routing functions' own reasons;
 - the TPU-only ragged layout (rows aligned to the kernel's 8-token copies,
-  the work plan, padded ``_ragged_tpad``, null-row warmup operands)
+  the work plan, the ``_ragged_tpad`` rows of the kernel's view around
+  ``_ragged_dense`` packed tokens, null-row warmup operands)
   EXECUTES in tier-1: the
   routing function is patched to answer "kernel" and the kernels run in
   the Pallas interpreter, so the branch no CPU test used to reach serves
@@ -166,8 +167,9 @@ def test_kernel_layout_serves_and_matches_the_xla_path(parts, monkeypatch):
     )
     engine = LLMEngineCore(bundle, params, **kw)
     assert engine._ragged_kernel and engine._ragged_qb == pa._RAGGED_QB
-    # budget 16 + one copy of alignment waste per row, aligned to 8
-    assert engine._ragged_tpad == 32
+    # the dense layers' axis is the budget; the kernel's view adds one copy
+    # less a token of alignment waste per row, aligned to 8
+    assert (engine._ragged_dense, engine._ragged_tpad) == (16, 32)
     # a tile holds any chunk of this model: an item a row, none further
     assert (engine._ragged_tile, engine._ragged_items) == (128, 2)
     assert engine.health()["kernels"] == {

@@ -327,6 +327,14 @@ def test_ragged_health_and_stats_blocks(parts):
         assert s["decode_tokens"] == 0
         assert s["tokens_per_launch"]["count"] == 0
         assert s["spec_acceptance"]["count"] == 0
+        # the mixed pass's two token axes: the budget on the compact one;
+        # the XLA twin needs no alignment, so the view is the same axis
+        assert (s["dense_axis"], s["layout_axis"], s["dense_rows"]) == (16, 16, 0)
+        assert (h["ragged"]["dense_axis"], h["ragged"]["layout_axis"]) == (16, 16)
+        _staggered(engine, [LONG, SHORT], n=4)
+        s = engine.lifecycle_stats()["ragged"]
+        # the dense layers multiplied the compact axis, whole, every launch
+        assert s["steps"] > 0 and s["dense_rows"] == 16 * s["steps"]
     finally:
         engine.stop()
     legacy = _engine(bundle, params)

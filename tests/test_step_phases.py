@@ -379,7 +379,8 @@ def _pass_jaxpr(which, quant, scan_layers):
         "decode_paged": ([per_row], [table] + [per_row] * 3),
         "verify_paged": ([i32(rows, 3)], [table, per_row]),
         "forward_ragged": (
-            [per_tok] * 3 + [jax.ShapeDtypeStruct((t,), jnp.bool_), per_row],
+            [per_tok] * 3 + [jax.ShapeDtypeStruct((t,), jnp.bool_), per_tok,
+                             per_row],
             [table] + [per_row] * 3 + [per_tok] * 2,
         ),
     }[which]

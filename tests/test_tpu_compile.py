@@ -81,7 +81,7 @@ def _compiled_step(one_chip, monkeypatch, quant):
              blocks, chain_coords):
         names = ("k_scales", "v_scales")
         out = bundle.forward_ragged(
-            params, tok, tok, tok, valid, row_last, pools[0], pools[1],
+            params, tok, tok, tok, valid, tok, row_last, pools[0], pools[1],
             table, per_row, per_row, per_row, per_tok, per_tok, blocks,
             blocks, **dict(zip(names, pools[2:])))
         nxt = jnp.argmax(out[0], -1).astype(jnp.int32)
@@ -131,6 +131,76 @@ def test_the_v5e_step_updates_the_stacked_pools_in_place(
     stack_shapes = set(re.findall(
         r"\w+\[{},{}\](\{{[\d,]+)".format(LAYERS, layer), hlo))
     assert stack_shapes == {"{4,3,2,1,0"}, stack_shapes
+
+
+# ------------------------------------- the ragged pass's two token axes
+
+@pytest.mark.parametrize("experts", [0, 8], ids=["mistral7b", "mixtral8x7b"])
+def test_the_dense_layers_multiply_the_compact_axis(one_chip, monkeypatch, experts):
+    """ISSUE 42: a ragged pass at the K/V cells' widths (d 4096, 32/8 heads x
+    128, FFN 14336, int8 weights; 128 packed tokens, 32 rows, so a view of 352)
+    compiled for the described v5e. Outside the attention scope no instruction
+    of the layer loop has 352 rows, and none re-lays a layer's expert weights
+    out of the scan (at 128 tokens the compiler copied both [8, 4096, 14336]
+    stacks twice a layer while the expert axis was free on the weights alone:
+    ``_ffn_moe_dropless``)."""
+    monkeypatch.setattr(pa, "paged_kernel_unsupported_reason",
+                        lambda *a, **k: None)
+    layers, rows, dense, page, hkv = 2, 32, 128, 16, 8
+    cfg = dict(vocab_size=32000, dim=4096, n_layers=layers, n_heads=32,
+               n_kv_heads=hkv, head_dim=D, ffn_dim=14336, scan_layers=True,
+               dtype="bfloat16")
+    if experts:
+        cfg.update(n_experts=experts, moe_top_k=2)
+    bundle = models.build_model("llama", cfg)
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: bundle.init(jax.random.PRNGKey(0),
+                                           weight_quant="int8")))
+    pool = on_chip((layers, hkv, 2000, page, D), jnp.bfloat16)
+    view = pa.ragged_view_tokens(dense, rows)
+    assert view == 352
+    items = pa.ragged_item_count(
+        rows, view, pa.ragged_query_tile(hkv, 4, D, jnp.bfloat16))
+
+    def step(params, k, v, tok, valid, row_last, table, per_row, item):
+        return bundle.forward_ragged(
+            params, tok, tok, tok, valid, tok, row_last, k, v, table, per_row,
+            per_row, per_row, tok, tok, item, item)
+
+    hlo = _compiled_text(jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pool, pool, on_chip((dense,)), on_chip((dense,), jnp.bool_),
+        on_chip((rows,)), on_chip((rows, 256)), on_chip((rows,)),
+        on_chip((items,))))
+    assert "ragged_paged_attention" in hlo
+    shaped = re.compile(
+        r"^\s*(?:ROOT )?%?(\S+) = \(?(\w+)\[([\d,]+)\]\S* ([\w\-]+)\(")
+    wide, moved, computation = [], [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?(\S+) \(.*\) -> .* \{", line)
+        if head:
+            computation = head.group(1)
+        m = shaped.match(line)
+        # what runs is what the loops and the entry hold; a fused
+        # computation's instructions are its fusion's own business
+        if not m or computation is None or "fused_computation" in computation:
+            continue
+        name, _, dims, op = m.groups()
+        dims = [int(n) for n in dims.split(",")]
+        if op in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+            continue
+        # (the view's side of the token map is a vector of 352, once a pass)
+        if view in dims and len(dims) > 1 and "/attn/" not in line:
+            wide.append((name, op, dims))
+        if experts and dims[-3:] in ([experts, 4096, 14336],
+                                     [experts, 14336, 4096]):
+            moved.append((name, op, dims))
+    assert not wide, wide
+    assert not moved, moved
 
 
 # ------------------------------------------- the decode kernel's work plan
