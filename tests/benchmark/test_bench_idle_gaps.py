@@ -2,7 +2,7 @@
 `breakdown.idle_gaps` (the device's idle seconds under the innermost
 ``engine.*`` annotation of the host plane): each on hand-made counters, spans
 and gaps, the join against ``host_spans.idle_by_phase`` of the same spans, and
-the manifest's entry at its end."""
+the manifest's entry, found by its name."""
 
 import json
 import re
@@ -17,6 +17,7 @@ if str(ROOT) not in sys.path:
 
 from benchmark import host_spans  # noqa: E402
 from benchmark.layer_metrics import _common, ragged_launch_share  # noqa: E402
+from tests.benchmark.test_bench_manifest import holds_entry  # noqa: E402
 from tests.benchmark.test_bench_phase_metrics import counters  # noqa: E402
 
 ALL_CELLS = ["mistral7b.chat_steady", "mistral7b.decode_batch",
@@ -48,12 +49,16 @@ def test_reads_nothing_without_the_counter_or_without_launches():
         {"before": with_steps(2, 5), "after": with_steps(2, 5)}) is None
 
 
-def test_the_manifest_holds_it_at_its_end():
-    entry = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"][-1]
-    assert entry == {
+def manifest_holds_ragged_launch_share(manifest):
+    holds_entry(manifest, {
         "name": "ragged_launch_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "scheduler (llm/engine.py loop)",
-        "moves": "tpot_p50_ms", "workloads": ALL_CELLS}
+        "moves": "tpot_p50_ms", "workloads": ALL_CELLS})
+
+
+def test_the_manifest_holds_it_by_its_name():
+    manifest_holds_ragged_launch_share(
+        json.loads((ROOT / "BENCHMARK.json").read_text()))
     doc = " ".join(ragged_launch_share.__doc__.split())
     assert doc.startswith("scheduler:") and "``better`` has no meaning" in doc
     assert re.search(r"Source: program_counter\. Moves tpot_p50_ms\.$", doc)

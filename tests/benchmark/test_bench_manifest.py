@@ -19,6 +19,8 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|head).*(size|dim)|_dim$|_rank$|"
                    r"^head_dim$|expansion|experts_per_tok")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+E2E_KEYS = {"name", "unit", "better", "bound", "source"}     # and "workloads"
+PER_LAYER_KEYS = {"name", "unit", "better", "source", "layer", "moves"}
 MANIFESTS = [ROOT / "BENCHMARK.json",
              ROOT / "tests" / "benchmark" / "tiny" / "BENCHMARK.json"]
 
@@ -29,6 +31,27 @@ def load(path):
 
 def cells_of(metric, manifest):
     return metric.get("workloads") or [w["name"] for w in manifest["workloads"]]
+
+
+def without_cells(entry):
+    return {k: v for k, v in entry.items() if k != "workloads"}
+
+
+def holds_entry(manifest, want, never=()):
+    """A pin on one ``per_layer`` entry, as benchmark/README.md ("Pinning an
+    entry in a test") has it: found by its name, once, wherever it stands;
+    every key but ``workloads`` equal to ``want``'s; ``workloads`` holds at
+    least the cells of ``want`` (those its author measured), only cells of
+    the manifest, each once, and none of ``never``."""
+    held = [m for m in manifest["per_layer"] if m["name"] == want["name"]]
+    assert len(held) == 1, (want["name"], "is held", len(held), "times")
+    entry = held[0]
+    assert without_cells(entry) == without_cells(want)
+    assert ("workloads" in entry) == ("workloads" in want)
+    listed = entry.get("workloads", [])
+    assert set(want.get("workloads", [])) <= set(listed), (want["name"], listed)
+    assert set(listed) <= {w["name"] for w in manifest["workloads"]}, listed
+    assert len(listed) == len(set(listed)) and not set(never) & set(listed)
 
 
 @pytest.fixture(params=MANIFESTS, ids=["benchmark", "tiny"])
@@ -49,8 +72,7 @@ def test_keys_and_sizes(manifest):
 
 def test_names_units_and_lines(manifest):
     names = []
-    for group, keys in (("end_to_end", {"name", "unit", "better", "bound", "source"}),
-                        ("per_layer", {"name", "unit", "better", "source", "layer", "moves"})):
+    for group, keys in (("end_to_end", E2E_KEYS), ("per_layer", PER_LAYER_KEYS)):
         for m in manifest[group]:
             assert set(m) - {"workloads"} == keys, m
             assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
@@ -99,12 +121,11 @@ def test_tails_only_where_the_population_reaches_100(manifest):
             assert all("chat" in c for c in cells_of(m, manifest))
 
 
-def test_every_cell_reports_its_tpot_tail_once():
+def manifest_reports_every_tpot_tail_once(manifest):
     """The 90th percentile of TPOT is judged (an end-to-end `*_p90_ms`) only
     where runs hold it steady, which since PR 41 is nowhere (PERF.md section
     2); everywhere else it stands beside the layers as `req_tpot_p90_ms`: no
     cell twice, none left out."""
-    manifest = load(MANIFESTS[0])
     judged = [c for m in manifest["end_to_end"] if m["name"] == "tpot_p90_ms"
               for c in cells_of(m, manifest)]
     beside = cells_of(next(m for m in manifest["per_layer"]
@@ -113,7 +134,11 @@ def test_every_cell_reports_its_tpot_tail_once():
     assert sorted(judged + beside) == sorted(w["name"] for w in manifest["workloads"])
 
 
-def test_files_behind_the_names(manifest):
+def test_every_cell_reports_its_tpot_tail_once():
+    manifest_reports_every_tpot_tail_once(load(MANIFESTS[0]))
+
+
+def files_behind_the_names(manifest, root):
     used = {w["config"] for w in manifest["workloads"]}
     files = [c["file"] for c in manifest["configs"]]
     assert len(files) == len(set(files))
@@ -121,20 +146,23 @@ def test_files_behind_the_names(manifest):
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["name"] in used and len(c["reduced"]) <= 16
         assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
-        cfg = sut.load_config(ROOT / c["file"])      # refuses unpinned knobs
+        cfg = sut.load_config(root / c["file"])      # refuses unpinned knobs
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
         assert not [k for k in c["reduced"] if WIDTH.search(k)]
-        assert (ROOT / "benchmark" / "reference" / (cfg["reference"] + ".py")).is_file()
+        assert (root / "benchmark" / "reference" / (cfg["reference"] + ".py")).is_file()
     for m in manifest["per_layer"]:
-        reader = ROOT / "benchmark" / "layer_metrics" / (m["name"] + ".py")
+        reader = root / "benchmark" / "layer_metrics" / (m["name"] + ".py")
         assert reader.is_file(), "no reader for " + m["name"]
         assert m["source"] in reader.read_text(), (m["name"], "names another source")
 
 
-def test_cells_have_their_mix_and_rate():
+def test_files_behind_the_names(manifest):
+    files_behind_the_names(manifest, ROOT)
+
+
+def manifest_cells_have_their_mix_and_rate(manifest):
     from benchmark import traffic
 
-    manifest = load(MANIFESTS[0])
     for w in manifest["workloads"]:
         mix = traffic.load_mix(w["traffic"])
         assert mix["loop"] in ("open", "closed")
@@ -142,6 +170,10 @@ def test_cells_have_their_mix_and_rate():
             assert w["config"] in mix["session_rate_per_s"]
         plan = traffic.make_plan(w["traffic"], w["config"], 1, manifest["run_seconds"])
         assert plan["window_s"] == manifest["run_seconds"]
+
+
+def test_cells_have_their_mix_and_rate():
+    manifest_cells_have_their_mix_and_rate(load(MANIFESTS[0]))
 
 
 def test_a_knob_beyond_sizing_is_refused(tmp_path):

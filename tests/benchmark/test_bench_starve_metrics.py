@@ -17,6 +17,7 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from tests.benchmark.test_bench_manifest import holds_entry  # noqa: E402
 from tests.benchmark.test_bench_phase_metrics import (  # noqa: E402
     STEP, counters as parent_counters, hist)
 from tests.benchmark.test_bench_rehearsal import TINY, run  # noqa: E402
@@ -155,25 +156,29 @@ def added_entries():
     return [m for m in per_layer if m["name"] in NAMES]
 
 
-def test_the_manifest_holds_the_eleven_behind_what_was_there():
-    per_layer = json.loads((ROOT / "BENCHMARK.json").read_text())["per_layer"]
-    names = [m["name"] for m in per_layer]
+def manifest_holds_the_eleven_in_their_order(manifest):
+    names = [m["name"] for m in manifest["per_layer"]]
     # appended: the driver's check reads an entry put before one that was
     # there as a change to that one; later entries follow them
     at = names.index("attn_decode_roofline")
     assert names[at + 1:at + 12] == NAMES
-    for m in added_entries():
-        ttft = m["name"].startswith("eng_")
-        assert m == {
-            "name": m["name"],
-            "unit": "%" if m["name"].endswith("_share") else "ms",
-            "better": "higher" if m["name"] == "idle_seen_share" else "lower",
-            "source": ("device_trace" if m["name"] == "idle_seen_share"
+    for name in NAMES:
+        ttft = name.startswith("eng_")
+        holds_entry(manifest, {
+            "name": name,
+            "unit": "%" if name.endswith("_share") else "ms",
+            "better": "higher" if name == "idle_seen_share" else "lower",
+            "source": ("device_trace" if name == "idle_seen_share"
                        else "program_span"),
-            "layer": "device" if m["name"] == "idle_seen_share" else SCHEDULER,
+            "layer": "device" if name == "idle_seen_share" else SCHEDULER,
             "moves": "ttft_p50_ms" if ttft else "tpot_p50_ms",
             "workloads": TTFT_CELLS if ttft else ALL_CELLS,
-        }
+        })
+
+
+def test_the_manifest_holds_the_eleven_behind_what_was_there():
+    manifest_holds_the_eleven_in_their_order(
+        json.loads((ROOT / "BENCHMARK.json").read_text()))
 
 
 @pytest.mark.parametrize("name", NAMES)
