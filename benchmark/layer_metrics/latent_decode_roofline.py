@@ -1,0 +1,33 @@
+"""kernels: the latent decode kernel's share of its roofline in the traced
+tail: the bytes the algorithm must read (the latent rows of every selected
+or windowed key of every row of a chained decode pass, in every layer of the
+kind, once: ``latent.decode_keys_full`` / ``decode_keys_window`` gained
+between the trace's edges x roofline_latent.row_bytes) over the HBM peak,
+against the traced seconds of the operations named latent_attention_decode.
+None where the program has no such counter, or the trace lacks the kernel
+although rows were chained; 0 where no row was chained in the tail. Source:
+device_trace. Moves tpot_p50_ms."""
+
+from benchmark.layer_metrics import _common, _latent, _retention
+
+KERNEL = "latent_attention_decode"
+
+
+def read(ctx):
+    from benchmark import roofline, roofline_latent as rl
+    from benchmark.sut import model_block
+
+    g = _latent.gains(ctx, _common.trace_edges(ctx))
+    if _common.traced(ctx) is None or g is None:
+        return None
+    keys = g["decode_keys_full"] + g["decode_keys_window"]
+    seconds = _retention.kernel_seconds(ctx, KERNEL)
+    if keys and not seconds:
+        return None
+    if not keys:
+        return 0.0
+    model = model_block(ctx["cfg"])
+    nbytes = rl.cache_bytes(model, 0, g["decode_keys_full"],
+                            g["decode_keys_window"])
+    peaks = roofline.peaks_for(ctx["device"]["kind"])
+    return 100.0 * nbytes / peaks["hbm_bytes_per_s"] / seconds

@@ -508,10 +508,113 @@ def _mixed_pass_work(row_lens, kv_lens) -> tuple:
     return int(n.size), int(kv.sum()), int(pairs.sum())
 
 
+_LATENT_COUNTERS = (
+    "rows_full", "rows_window", "index_keys_scored", "index_keys_kept",
+    "window_keys", "decode_keys_full", "decode_keys_window",
+    "mixed_keys_full", "mixed_keys_window", "decode_latent_tokens",
+)
+
+
+def _latent_pass_work(layout, mixed_visible=(), chain_first=(),
+                      chain_passes=(), row_lens=(), kv_lens=()) -> dict:
+    """What one launch over a latent page layout adds to ``latent.*`` of
+    lifecycle_stats (docs/latent_cache.md). A token with v visible keys (its
+    position + 1): a full layer's indexer scores all v and its attention
+    reads min(v, index_topk) rows, a window layer's reads min(v, window).
+    ``mixed_visible``: the visible keys of a mixed pass's valid tokens, with
+    the pass's rows (``row_lens`` queries on ``kv_lens`` tokens each);
+    the chained decode passes as :func:`_decode_pass_work` takes them: row r
+    rides ``chain_passes[r]`` passes, ``chain_first[r]`` visible keys in the
+    first and one more in each next. ``decode_*``: what the decode kernel
+    reads; ``mixed_keys_*``: the rows the mixed pass's kernel must read at
+    least, a ROW of the launch once (its last token's selection; the union
+    of its tokens' windows)."""
+    topk, window = layout.index_topk, layout.window
+    n_full, n_window = layout.n_full, layout.n_window
+    mixed = np.asarray(mixed_visible, np.int64)
+    chain = [np.zeros(0, np.int64)] + [
+        first + np.arange(int(n), dtype=np.int64)
+        for first, n in zip(np.asarray(chain_first, np.int64),
+                            np.asarray(chain_passes, np.int64)) if n > 0
+    ]
+    chain = np.concatenate(chain)
+    seen = np.concatenate([mixed, chain])
+    n = np.asarray(row_lens, np.int64)
+    kv = np.asarray(kv_lens, np.int64)[n > 0]
+    n = n[n > 0]
+    out = {
+        "rows_full": seen.size * n_full,
+        "rows_window": seen.size * n_window,
+        "index_keys_scored": int(seen.sum()) * n_full,
+        "index_keys_kept": int(np.minimum(seen, topk).sum()) * n_full,
+        "window_keys": int(np.minimum(seen, window).sum()) * n_window,
+        "decode_keys_full": int(np.minimum(chain, topk).sum()) * n_full,
+        "decode_keys_window": int(np.minimum(chain, window).sum()) * n_window,
+        "mixed_keys_full": int(np.minimum(kv, topk).sum()) * n_full,
+        "mixed_keys_window": int(
+            np.minimum(kv, window + n - 1).sum()) * n_window,
+    }
+    out["decode_latent_tokens"] = (
+        out["decode_keys_full"] + out["decode_keys_window"])
+    return out
+
+
 # ragged scheduler (docs/ragged_attention.md): stage-3 brownout shrinks the
 # per-step admission share to roughly one minimal chunk instead of the
 # legacy gate's one-segment-per-chunk budget
 _RAGGED_BROWNOUT_CHUNK = 16
+
+
+def _latent_cache_refusal(bundle, *, cache_mode, mesh,
+                          prefix_cache_host_pages, prefix_cache_host_bytes,
+                          speculation, spec_tree,
+                          lora_adapters) -> Optional[str]:
+    """Why this engine cannot be built over a model's LATENT page layout
+    (``bundle.paged_layout``: one compressed row a token for all heads, a
+    window bound on some layers, an indexer-key plane on the others), or
+    None. What the layout cannot do yet is refused by name with what it
+    would take (docs/latent_cache.md)."""
+    if cache_mode != "paged":
+        return (
+            "this model attends through a latent (one compressed row a "
+            "token, shared by all heads): serve it with engine.cache=paged "
+            "(got engine.cache={}); it keeps no per-head K/V for a dense "
+            "cache and no recurrent state".format(cache_mode)
+        )
+    if mesh is not None and mesh.size > 1:
+        return (
+            "a {}-device mesh cannot serve the latent page layout yet: the "
+            "latent kernels have no partitioning rule and the held experts "
+            "are this chip's by configuration (experts_held); the exchange "
+            "of expert inputs across chips is not built. Serve one engine "
+            "per chip".format(mesh.size)
+        )
+    if bundle.config.get("kv_quant"):
+        return (
+            "kv_quant cannot serve the latent page layout: its rows are "
+            "bfloat16 by the configuration (a quantised latent or "
+            "indexer-key plane is another configuration)"
+        )
+    if prefix_cache_host_pages or prefix_cache_host_bytes:
+        return (
+            "prefix_cache_host_pages / prefix_cache_host_mb (HostKVTier) "
+            "cannot serve the latent page layout yet: the host tier's slabs "
+            "are [L, Hkv, P, D] K/V pages, and a latent page is three "
+            "planes of other widths"
+        )
+    if speculation or spec_tree:
+        return (
+            "speculation cannot serve the latent page layout yet: verify "
+            "rows (verify_paged, draft trees) need per-position logits and "
+            "a tree mask in the latent kernels, and a rejected draft's "
+            "indexer keys rolled back"
+        )
+    if lora_adapters:
+        return (
+            "lora_adapters are not served on the latent page layout yet: "
+            "the low-rank projections have no adapter rows"
+        )
+    return None
 
 
 def _state_cache_refusal(bundle, *, mesh, prefix_cache,
@@ -1063,7 +1166,12 @@ class LLMEngineCore:
         ):
             raise ValueError(
                 "sliding_window models need engine.cache=dense (the paged "
-                "decode path does not window its attention yet)"
+                "decode path does not window its attention yet): the window "
+                "bound exists for latent layers only "
+                "(ops/latent_attention.py, a model's 'sliding_attention' "
+                "layers over the latent page layout); a window on per-head "
+                "K/V pages (Mistral-style, every layer) is still one causal "
+                "bound a work item in ops/paged_attention.py"
             )
         if cache_mode == "paged" and getattr(
             bundle, "paged_unsupported_reason", None
@@ -1119,6 +1227,20 @@ class LLMEngineCore:
             )
             if refused:
                 raise ValueError(refused)
+        # a model's own page layout (docs/latent_cache.md): the pools'
+        # planes, the kernels and the counters follow from the model
+        self._latent = getattr(bundle, "paged_layout", None)
+        if self._latent is not None:
+            refused = _latent_cache_refusal(
+                bundle, cache_mode=cache_mode, mesh=mesh,
+                prefix_cache_host_pages=prefix_cache_host_pages,
+                prefix_cache_host_bytes=prefix_cache_host_bytes,
+                speculation=speculation, spec_tree=spec_tree,
+                lora_adapters=lora_adapters,
+            )
+            if refused:
+                raise ValueError(refused)
+        self._latent_counts = dict.fromkeys(_LATENT_COUNTERS, 0)
         self.cache_mode = cache_mode
         # kernel or XLA gather for paged pools of this model's shape
         # (ops.paged_attention): the same pure function models/llama.py
@@ -1129,7 +1251,8 @@ class LLMEngineCore:
         from ..ops.paged_attention import paged_kernel_unsupported_reason
 
         paged_reason = paged_kernel_unsupported_reason(
-            bundle.head_dim, page_size,
+            bundle.head_dim if self._latent is None
+            else tuple(self._latent.row_widths), page_size,
             "int8" if bundle.config.get("kv_quant")
             else bundle.config.get("dtype", "bfloat16"),
         )
@@ -1435,6 +1558,7 @@ class LLMEngineCore:
                 max_slots=self.max_batch,
                 dtype=bundle.config.get("dtype", "bfloat16"),
                 kv_quant=str(bundle.config.get("kv_quant") or ""),
+                layout=self._latent,
             )
             if mesh is not None:
                 # shard the pools' kv-head dim over tp (pools [L,Hkv,N,P,D]) —
@@ -1455,6 +1579,12 @@ class LLMEngineCore:
                     )
             self._pages_per_seq = pages_per_slot
             self.cache = None
+            if self._latent is not None:
+                # the scrape's copy (_latent_snapshot) is an eager add: run
+                # once here, so that its one small program compiles at
+                # construction and not under the first scrape inside a
+                # serving window
+                self.paged_cache.v["counters"] + 0
             if self._paged_kernel_reason is None:
                 self._check_kernel_smem()
             self.state_cache = None
@@ -4604,9 +4734,33 @@ class LLMEngineCore:
             "ledger": self._ledger_snapshot(),
             "sharding": self._shard_snapshot(),
         }
+        if self._latent is not None:
+            out.update(self._latent_snapshot())
         if self.replica_id is not None:
             out["replica"] = self.replica_id
         return out
+
+    def _latent_snapshot(self) -> dict:
+        """The latent page layout's two blocks of lifecycle_stats
+        (docs/latent_cache.md): ``latent`` from the launches' plans (rows
+        the two layer kinds attended for, keys the indexer scored and kept)
+        and ``moe`` from the counters the model's passes keep on the device
+        beside the pools (held experts that received a token, summed over
+        the expert layers of every pass; assignments that stayed here)."""
+        with self.paged_cache.dispatch_lock:
+            # a copy taken under the lock: the next launch donates the
+            # pools' own buffer, and the read below waits for this one only
+            counters = self.paged_cache.v["counters"] + 0
+        hit, local, layers = (int(x) for x in np.asarray(counters)[:3])
+        return {
+            "latent": dict(self._latent_counts),
+            "moe": {
+                "experts_held": self._latent.experts_held,
+                "experts_hit": hit,
+                "local_assignments": local,
+                "layer_passes": layers,
+            },
+        }
 
     @property
     def logprobs_k(self) -> int:
@@ -6841,9 +6995,17 @@ class LLMEngineCore:
         if self.cache_mode == "paged":
             # a row of window n rides chained passes 1..n-1 and attends
             # pre_len + 1 + step tokens in pass ``step``
-            self._count_decode_passes(_decode_pass_work(
+            chained = (
                 plan["pre_lens"] + 2, np.maximum(plan["row_steps"] - 1, 0)
-            ))
+            )
+            work = _decode_pass_work(*chained)
+            if self._latent is not None:
+                valid = np.asarray(plan["tok_valid"], bool)
+                work += (_latent_pass_work(
+                    self._latent, np.asarray(plan["tok_pos"])[valid] + 1,
+                    *chained, plan["row_lens"], plan["kv_lens"],
+                ),)
+            self._count_decode_passes(work)
             for name, n in zip(
                 ("mixed_rows", "mixed_kv_tokens", "mixed_qk_pairs"),
                 _mixed_pass_work(plan["row_lens"], plan["kv_lens"]),
@@ -7467,6 +7629,10 @@ class LLMEngineCore:
         live = lengths0 > 0
         held = lengths0[live]
         chain_work = _decode_pass_work(held + 1, np.full(held.shape, n))
+        if self._latent is not None:
+            chain_work += (_latent_pass_work(
+                self._latent, chain_first=held + 1,
+                chain_passes=np.full(held.shape, n)),)
         write_pages = np.zeros((self.max_batch, n), np.int32)   # null page 0
         write_offsets = np.zeros((self.max_batch, n), np.int32)
         for slot in np.nonzero(active_mask)[0]:
@@ -7548,9 +7714,12 @@ class LLMEngineCore:
         return reads
 
     def _count_decode_passes(self, work: tuple) -> None:
-        """Loop thread: add a launch's :func:`_decode_pass_work`."""
+        """Loop thread: add a launch's :func:`_decode_pass_work`, and over a
+        latent page layout its :func:`_latent_pass_work` behind it."""
         self.counters["decode_chain_rows"] += work[0]
         self.counters["decode_chain_kv_tokens"] += work[1]
+        for name, n in (work[2] if len(work) > 2 else {}).items():
+            self._latent_counts[name] += n
 
     def _count_sampler_passes(self, passes: int, row_passes) -> None:
         """Loop thread: a launch sampled ``passes`` times and slot ``r`` was

@@ -647,6 +647,7 @@ class PagedKVCache:
         max_slots: int = 8,
         dtype="bfloat16",
         kv_quant: str = "",
+        layout=None,
     ):
         import jax
         import jax.numpy as jnp
@@ -655,13 +656,28 @@ class PagedKVCache:
             raise ValueError(
                 "kv_quant must be '' or 'int8' (got {!r})".format(kv_quant)
             )
+        if layout is not None and kv_quant:
+            raise ValueError(
+                "kv_quant cannot serve a {} page layout: its planes are the "
+                "model's own".format(layout.kind)
+            )
         self.kv_quant = kv_quant
+        self.layout = layout
         self.pool = PagePool(num_pages, page_size, max_slots)
         self.n_layers = n_layers
         shape = (n_layers, n_kv_heads, num_pages, page_size, head_dim)
         pool_dtype = jnp.int8 if kv_quant else jnp.dtype(dtype)
-        self.k = jnp.zeros(shape, pool_dtype)
-        self.v = jnp.zeros(shape, pool_dtype)
+        if layout is not None:
+            # a model's own LAYOUT (docs/latent_cache.md): ``k`` and ``v``
+            # are pytrees of planes [layers of a kind, 1, N, P, row width],
+            # every plane under the ONE page id the PagePool hands out, so
+            # the page table, the radix prefix cache, copy-on-write and
+            # preemption go on working on page ids; a leaf that is no plane
+            # (rank under 4: the launch's counters) rides along untouched
+            self.k, self.v = layout.init_pools(num_pages, page_size)
+        else:
+            self.k = jnp.zeros(shape, pool_dtype)
+            self.v = jnp.zeros(shape, pool_dtype)
         # int8: per-(token, head) f32 dequant scales, page-id addressed so
         # a page and its scale row share one lifecycle (module docstring)
         if kv_quant:
@@ -707,7 +723,11 @@ class PagedKVCache:
             # program launches per shared-tail slot between chunks. Pair
             # lists pad to (0, 0): writing the reserved null page onto
             # itself is a no-op by construction.
-            return pool.at[:, :, dsts].set(pool[:, :, srcs])
+            # a K/V pool is one plane; a layout's pool is a pytree of them
+            return jax.tree.map(
+                lambda plane: plane.at[:, :, dsts].set(plane[:, :, srcs])
+                if plane.ndim >= 4 else plane, pool,
+            )
 
         self._write_pages = jax.jit(_write_pages, donate_argnums=(0,))
         self._copy_page = jax.jit(_copy_page, donate_argnums=(0,))
@@ -727,11 +747,16 @@ class PagedKVCache:
         scale = 0
         if self.k_scale is not None:
             scale = int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
-        return {"kv": int(self.k.nbytes) + int(self.v.nbytes), "scale": scale}
+        import jax
+
+        planes = [x for x in jax.tree.leaves((self.k, self.v)) if x.ndim >= 4]
+        return {"kv": sum(int(x.nbytes) for x in planes), "scale": scale}
 
     @property
     def pool_dtype(self) -> str:
-        return str(self.k.dtype)
+        import jax
+
+        return str(jax.tree.leaves(self.k)[0].dtype)
 
     def max_pages_per_seq(self, max_seq_len: int) -> int:
         return self.pool.pages_needed(max_seq_len)
@@ -772,6 +797,7 @@ class PagedKVCache:
     def enable_host_tier(self, num_pages: int) -> "HostKVTier":
         """Preallocate a host-RAM page tier matching this pool's geometry.
         Returns the tier (also kept as ``self.host_tier``)."""
+        self._require_kv_pages("the host tier (HostKVTier)")
         _l, hkv, _n, p, d = self.k.shape
         self.host_tier = HostKVTier(
             num_pages, p, self.n_layers, hkv, d,
@@ -794,6 +820,7 @@ class PagedKVCache:
         needs). The victim list pads to a power of two with null-page
         entries (llm/shapes.py) so the gather compiles once per power of
         two, not once per count (tpuserve-analyze TPU601)."""
+        self._require_kv_pages('page export (KV shipment, demotion)')
         import jax.numpy as jnp
 
         n = len(pages)
@@ -939,6 +966,7 @@ class PagedKVCache:
         transport slab, which the sender's mailbox may recycle (the PR-4
         zero-copy race class) — and completion is observed at the engine's
         retire boundaries (``reap_promotions``)."""
+        self._require_kv_pages('page import (KV shipment, promotion)')
         if len(pages) != int(hk.shape[0]):
             raise ValueError(
                 "import of {} slab rows into {} device pages".format(
@@ -1027,6 +1055,14 @@ class PagedKVCache:
                 round(hidden / total, 4) if total > 0 else None
             ),
         }
+
+    def _require_kv_pages(self, what: str) -> None:
+        if self.layout is not None:
+            raise ValueError(
+                "{} moves K/V pages [L, Hkv, N, P, D]; the {} page layout "
+                "keeps planes of its own and is not taught to it yet "
+                "(docs/latent_cache.md)".format(what, self.layout.kind)
+            )
 
     def _require_scales(self, k_scales, v_scales) -> None:
         """Fail fast when the caller's scale operands disagree with the

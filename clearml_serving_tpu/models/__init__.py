@@ -40,3 +40,4 @@ from . import cnn  # noqa: E402,F401
 from . import bert  # noqa: E402,F401
 from . import llama  # noqa: E402,F401
 from . import whisper  # noqa: E402,F401
+from . import dots3_note  # noqa: E402,F401

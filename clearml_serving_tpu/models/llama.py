@@ -238,6 +238,50 @@ def _apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
     ).astype(x.dtype)
 
 
+def moe_route(router_logits, top_k: int, *, scoring: str = "softmax",
+              bias=None, scale: float = 1.0):
+    """The router's choice for [T, E] float32 logits: (gates [T, k], experts
+    [T, k]), the gates renormalised over the chosen experts. ``softmax``
+    (Mixtral): top-k of the probabilities. ``sigmoid`` (DeepSeek-V3's
+    ``noaux_tc``, one group): top-k of score + ``bias``, gated by the score
+    alone (the bias selects and does not weigh), times ``scale``."""
+    if scoring == "softmax":
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, top_k)            # [T, k]
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(router_logits)
+        _, top_e = jax.lax.top_k(probs + bias, top_k)
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+    else:
+        raise ValueError("unknown router scoring {!r}".format(scoring))
+    # mixtral renormalizes the chosen experts' probabilities
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scale != 1.0:
+        top_p = top_p * scale
+    return top_p, top_e
+
+
+def moe_dropless(tokens, top_p, top_e, w_gate_e, w_up_e, w_down_e):
+    """Every token through ALL the experts of the stacks [E, ...], combined
+    by its gates: no capacity, no cross-token interaction. ``top_e`` [T, k]
+    indexes the stacks; an index past them (an expert this chip does not
+    hold) adds nothing."""
+    n_tok, n_experts = tokens.shape[0], w_gate_e.shape[0]
+    weights = jnp.zeros((n_tok, n_experts), jnp.float32).at[
+        jnp.arange(n_tok)[:, None], top_e
+    ].add(top_p)
+    # the expert axis is a BATCH axis of every product, on both
+    # operands: left free on the weights alone ("td,edf->etf") the v5e
+    # compiler may re-lay the whole [E, D, F] stack out of the layer
+    # scan for the product (tests/test_tpu_compile.py -k compact_axis)
+    per_expert = jnp.broadcast_to(tokens[None], (n_experts,) + tokens.shape)
+    h = jax.nn.silu(
+        jnp.einsum("etd,edf->etf", per_expert, w_gate_e)
+    ) * jnp.einsum("etd,edf->etf", per_expert, w_up_e)
+    expert_out = jnp.einsum("etf,efd->etd", h, w_down_e)
+    return jnp.einsum("te,etd->td", weights.astype(tokens.dtype), expert_out)
+
+
 @register_model("llama")
 def build(config: dict) -> SimpleNamespace:
     cfg = resolve_config(config)
@@ -685,11 +729,7 @@ def build(config: dict) -> SimpleNamespace:
         router_logits = (
             tokens.astype(jnp.float32) @ _w(layer, "w_router").astype(jnp.float32)
         )                                                         # [T, E]
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, moe_top_k)            # [T, k]
-        # mixtral renormalizes the chosen experts' probabilities
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-        return top_p, top_e
+        return moe_route(router_logits, moe_top_k)
 
     def _ffn_moe(layer, x, valid=None):
         """Mixtral-style sparse MoE FFN, GShard dispatch (TPU-first: the
@@ -748,19 +788,10 @@ def build(config: dict) -> SimpleNamespace:
         b, s, d_ = x.shape
         tokens = x.reshape(b * s, d_)
         top_p, top_e = _moe_routing(layer, tokens)
-        weights = jnp.zeros((b * s, n_experts), jnp.float32).at[
-            jnp.arange(b * s)[:, None], top_e
-        ].add(top_p)
-        # the expert axis is a BATCH axis of every product, on both
-        # operands: left free on the weights alone ("td,edf->etf") the v5e
-        # compiler may re-lay the whole [E, D, F] stack out of the layer
-        # scan for the product (tests/test_tpu_compile.py -k compact_axis)
-        per_expert = jnp.broadcast_to(tokens[None], (n_experts,) + tokens.shape)
-        h = jax.nn.silu(
-            jnp.einsum("etd,edf->etf", per_expert, _w(layer, "w_gate_e"))
-        ) * jnp.einsum("etd,edf->etf", per_expert, _w(layer, "w_up_e"))
-        expert_out = jnp.einsum("etf,efd->etd", h, _w(layer, "w_down_e"))
-        out = jnp.einsum("te,etd->td", weights.astype(x.dtype), expert_out)
+        out = moe_dropless(
+            tokens, top_p, top_e, _w(layer, "w_gate_e"), _w(layer, "w_up_e"),
+            _w(layer, "w_down_e"),
+        )
         return out.reshape(b, s, d_).astype(x.dtype)
 
     @jax.named_scope("moe" if moe else "ffn")

@@ -140,10 +140,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def paged_kernel_unsupported_reason(
-    head_dim: int, page_size: int, kv_dtype, *, platform: Optional[str] = None
+    row_widths, page_size: int, kv_dtype, *, platform: Optional[str] = None
 ) -> Optional[str]:
     """Why paged pools of this shape cannot take the Mosaic kernels (decode
-    and ragged share the gates) — None if they can.
+    and ragged share the gates) — None if they can. ``row_widths``: the
+    widths of a cached row in every plane of the layout — one ``head_dim``
+    for K/V pages, or a tuple (llm/kv_cache.py, the latent layout).
 
     The one routing decision for paged attention: pure in its arguments
     (``platform`` = the backend the program compiles for, default
@@ -160,10 +162,19 @@ def paged_kernel_unsupported_reason(
     # the sublane tile would misalign the k_buf/v_buf destination offsets
     # (j*P). The sublane tile is dtype-dependent: 16 rows for bf16 pools,
     # 32 for int8.
-    if head_dim % 128:
-        return "head_dim {} is not a multiple of the 128-lane tile".format(
-            head_dim
-        )
+    if isinstance(row_widths, int):
+        if row_widths % 128:
+            return "head_dim {} is not a multiple of the 128-lane tile".format(
+                row_widths
+            )
+    else:
+        for width in row_widths:
+            if width % 128:
+                return (
+                    "row width {} of the layout's planes {} is not a "
+                    "multiple of the 128-lane tile".format(
+                        width, tuple(row_widths))
+                )
     min_sublane = 32 // jnp.dtype(kv_dtype).itemsize   # 8 f32, 16 bf16, 32 int8
     if page_size % min_sublane:
         return "page_size {} is not a multiple of the {}-row {} sublane " \

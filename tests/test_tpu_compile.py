@@ -483,3 +483,74 @@ def test_the_sampler_sorts_and_draws_only_inside_a_conditional(
     always = _outside_conditionals(hlo)
     assert len(always) > 20       # the walk found the entry's fusions
     assert not [l for l in always if sort.search(l) or draw.search(l)]
+
+
+# ------------------------------------ the latent page layout's kernels
+
+LATENT_ROWS, LATENT_TOKENS, LATENT_PAGES, LATENT_TABLE = 32, 128, 12289, 1793
+
+
+def _latent_case(one_chip, case):
+    """The latent kernels at the `dots3note.doc_sessions` cell's shapes: 32
+    rows of up to 28,672 tokens in 16-token pages, a pool of 196,608 tokens;
+    full layers 128 heads on 640-lane rows (512 read back), window layers 64
+    heads on 1152-lane rows (1024 read back), a top-2048 selection a token."""
+    from clearml_serving_tpu.ops import latent_attention as la
+
+    def on_chip(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    full = on_chip((4, 1, LATENT_PAGES, 16, 640), bf16)
+    window = on_chip((6, 1, LATENT_PAGES, 16, 1152), bf16)
+    table = on_chip((LATENT_ROWS, LATENT_TABLE))
+    rows = on_chip((LATENT_ROWS,))
+    view = pa.ragged_view_tokens(LATENT_TOKENS, LATENT_ROWS)
+    tile = pa.ragged_query_tile(1, 128, 1152, bf16)
+    items = on_chip((pa.ragged_item_count(LATENT_ROWS, view, tile),))
+
+    def selection(n):
+        return (on_chip((n, 2048)), on_chip((n, 2048)), on_chip((n,)))
+
+    if case == "decode_window":
+        return (lambda q, p, t, n: la.latent_attention_decode(
+            q, p, t, n, layer=jnp.int32(2), v_width=1024, window=513),
+            (on_chip((LATENT_ROWS, 64, 1152), bf16), window, table, rows),
+            "latent_attention_decode")
+    if case == "decode_selected":
+        return (lambda q, p, t, n, a, b, c: la.latent_attention_decode(
+            q, p, t, n, layer=jnp.int32(1), v_width=512, selected=(a, b, c)),
+            (on_chip((LATENT_ROWS, 128, 640), bf16), full, table, rows)
+            + selection(LATENT_ROWS), "latent_attention_decode")
+    if case == "ragged_window":
+        return (lambda q, p, t, kv, st, ln, ir, iq: la.latent_ragged_attention(
+            q, p, t, kv, st, ln, ir, iq, layer=jnp.int32(3), v_width=1024,
+            tile=tile, window=513),
+            (on_chip((view, 64, 1152), bf16), window, table, rows, rows, rows,
+             items, items), "latent_ragged_attention")
+    if case == "ragged_selected":
+        return (lambda q, p, a, b, c, v: la.latent_ragged_attention(
+            q, p, None, None, None, None, None, None, layer=jnp.int32(1),
+            v_width=512, tile=1, selected=(a, b, c), tok_valid=v),
+            (on_chip((LATENT_TOKENS, 128, 640), bf16), full)
+            + selection(LATENT_TOKENS) + (on_chip((LATENT_TOKENS,), jnp.bool_),),
+            "latent_ragged_attention")
+    width = {"write_full": 640, "write_window": 1152, "write_index": 128}[case]
+    pool = on_chip((4, 1, LATENT_PAGES, 16, width), bf16)
+    tokens = on_chip((LATENT_TOKENS,))
+    return (lambda p, r, a, b: la.latent_kv_write(p, r, a, b, layer=jnp.int32(1)),
+            (pool, on_chip((LATENT_TOKENS, width), bf16), tokens, tokens),
+            "latent_kv_write")
+
+
+@pytest.mark.parametrize("case", [
+    "decode_window", "decode_selected", "ragged_window", "ragged_selected",
+    "write_full", "write_window", "write_index"])
+def test_the_latent_kernels_compile_at_the_cells_shapes(one_chip, case):
+    """ISSUE 45: every latent kernel lowers for the described v5e as ONE
+    custom call under its trace name (a write kernel that read its rows from a
+    [T, W] operand was refused here: a bf16 row is half a packed sublane)."""
+    fn, operands, name = _latent_case(one_chip, case)
+    hlo = _compiled_text(jax.jit(fn).lower(*operands))
+    assert hlo.count("tpu_custom_call") == 1
+    assert name in hlo
