@@ -126,6 +126,7 @@ WARMUP_COVERED: FrozenSet[str] = frozenset({
     "_ragged_paged_jit",
     "_ragged_state_jit",
     "_gather_finish_jit",
+    "_ragged_unpack_jit",
 })
 
 _warmup_cache: Dict[str, FrozenSet[str]] = {}

@@ -35,6 +35,7 @@ import contextlib
 import heapq
 import itertools
 import logging
+import math
 import os
 import threading
 import time
@@ -419,9 +420,10 @@ class _CycleClock:
 class _WorkerStamps:
     """The dispatch worker's side of a launch's timeline: four ``_clock``
     reads, ``worker_in`` (first line of the worker's half), ``enqueue``
-    (immediately before the jitted step is called: every upload, page
-    allocation and table build lies before it), ``enqueued`` (the call
-    returned) and ``worker_out`` (before the return), handed back with the
+    (immediately before the jitted step is called: a ragged launch's one
+    upload and its unpack, a decode chunk's four uploads, page allocation
+    and table build lie before it), ``enqueued`` (the call returned) and
+    ``worker_out`` (before the return), handed back with the
     worker's result for the loop thread to account (_CycleClock.landed: the
     clock stays loop-thread only). While a profiler session is open,
     ``engine.upload`` and ``engine.enqueue`` annotations cover the first two
@@ -556,6 +558,33 @@ def _latent_pass_work(layout, mixed_visible=(), chain_first=(),
     }
     out["decode_latent_tokens"] = (
         out["decode_keys_full"] + out["decode_keys_window"])
+    return out
+
+
+def _staging_layout(entries) -> tuple:
+    """``(name, shape, is_bool)`` entries laid end to end in one int32
+    staging buffer: ``((name, offset, shape, is_bool), ...)`` and the
+    buffer's length (docs/ragged_attention.md, "The launch's operands").
+    Hashable: the unpack program takes it as a static argument."""
+    layout, offset = [], 0
+    for name, shape, is_bool in entries:
+        layout.append((name, offset, tuple(shape), is_bool))
+        offset += math.prod(shape)
+    return tuple(layout), offset
+
+
+def _unpack_ragged_operands(staged, layout):
+    """The device half of a ragged launch's ONE upload: slices the staged
+    int32 buffer back into the operands the jitted step takes, by name,
+    each at the shape and dtype its own upload used to give (booleans
+    crossed as 0/1). A program of its own, dispatched before the step: the
+    step's signature and trace know nothing of the buffer."""
+    out = {}
+    for name, offset, shape, is_bool in layout:
+        part = jax.lax.slice(
+            staged, (offset,), (offset + math.prod(shape),)
+        ).reshape(shape)
+        out[name] = part != 0 if is_bool else part
     return out
 
 
@@ -1016,7 +1045,7 @@ class LLMEngineCore:
             "_decode_paged_chunk_jit", "_sample_jit", "_first_lp_jit",
             "_set_sampling_row_jit", "_spec_chunk_jit",
             "_ragged_paged_jit", "_ragged_state_jit",
-            "_gather_finish_jit",
+            "_gather_finish_jit", "_ragged_unpack_jit",
         ),
         # prompt scoring runs only for completions echo+logprobs requests:
         # one compile per prefill bucket on first use, sentry-attributed
@@ -1680,6 +1709,10 @@ class LLMEngineCore:
             # rows the dense layers of the mixed passes multiplied: the
             # compact axis, whole, a launch
             "ragged_dense_rows": 0,
+            # host-to-device transfers the dispatch worker made before the
+            # jitted call, summed over ragged launches: over ragged_steps
+            # it reads 1 (the staged buffer)
+            "ragged_h2d_transfers": 0,
             # rows x decode passes over paged KV (the chained passes of a
             # ragged launch, the passes of a decode chunk) and the tokens
             # those rows attended there: the paged decode kernel's work
@@ -3033,6 +3066,23 @@ class LLMEngineCore:
                 return logits[rows]
 
             self._gather_finish_jit = jax.jit(_gather_finish_logits)
+            # a launch's host vectors cross in ONE staged buffer; its
+            # layout per step variant is static (docs/ragged_attention.md)
+            self._ragged_unpack_jit = jax.jit(
+                _unpack_ragged_operands, static_argnums=(1,)
+            )
+            windows = [1]
+            while windows[-1] < self._ragged_steps_cap:
+                windows.append(2 * windows[-1])
+            self._ragged_layouts = {
+                (steps, extras, spec): self._ragged_operand_layout(
+                    steps, extras, spec
+                )
+                for steps in windows
+                for extras in (False, True)
+                for spec in (False, True)
+                if not spec or (self._speculation and cache_mode == "paged")
+            }
 
         self._kernels = self._kernel_routes()
 
@@ -3532,14 +3582,11 @@ class LLMEngineCore:
             )
         return self._sampling_dev
 
-    def _batch_extras(self) -> "SamplingExtras":
-        """Device-side sampling extras. The per-slot config rows (penalties
-        / seeds / min_tokens / stop sets) are cached device constants,
-        invalidated only at commit; the produced-token counters are
-        per-dispatch data and account for chunks still in flight (a live
-        slot advances decode_steps per in-flight chunk — dead slots'
-        counters are garbage by then, but their samples are dropped at
-        retire anyway)."""
+    def _extras_constants(self) -> "SamplingExtras":
+        """Device-side sampling extras less the per-dispatch counters: the
+        per-slot config rows (penalties / seeds / min_tokens / stop sets)
+        are cached device constants, invalidated only at commit, and the
+        bias is device-chained state."""
         if self._extras_dev is None:
             seeds = np.where(
                 self._seeds < 0, -1, self._seeds & 0x7FFFFFFF
@@ -3555,6 +3602,13 @@ class LLMEngineCore:
                 min_new=jnp.asarray(self._min_tokens.copy()),
                 stop=jnp.asarray(self._stop_rows.copy()),
             )
+        return self._extras_dev._replace(bias=self._bias_dev)
+
+    def _produced_counters(self) -> np.ndarray:
+        """The produced-token counters [B] of a dispatch, on the host: they
+        account for chunks still in flight (a live slot advances
+        decode_steps per in-flight chunk — dead slots' counters are garbage
+        by then, but their samples are dropped at retire anyway)."""
         produced = np.asarray(
             [r.produced if r is not None else 0 for r in self._slot_req],
             np.int32,
@@ -3563,8 +3617,14 @@ class LLMEngineCore:
             produced = produced + (
                 entry.active_mask.astype(np.int32) * self.decode_steps
             )
-        return self._extras_dev._replace(
-            bias=self._bias_dev, counters=jnp.asarray(produced)
+        return produced
+
+    def _batch_extras(self) -> "SamplingExtras":
+        """Device-side sampling extras of a pipelined chunk: the constants
+        and the counters in an upload of their own (a ragged launch stages
+        them with its other vectors)."""
+        return self._extras_constants()._replace(
+            counters=jnp.asarray(self._produced_counters())
         )
 
     def _bias_pmask_rows(self, request: GenRequest):
@@ -4559,6 +4619,7 @@ class LLMEngineCore:
                     "decode_tokens": self.counters["ragged_decode_tokens"],
                     "dense_axis": self._ragged_dense,
                     "layout_axis": self._ragged_tpad,
+                    "h2d_transfers": self.counters["ragged_h2d_transfers"],
                 }
                 if self._ragged
                 else None
@@ -4685,6 +4746,8 @@ class LLMEngineCore:
                     "dense_axis": self._ragged_dense,
                     "layout_axis": self._ragged_tpad,
                     "dense_rows": self.counters["ragged_dense_rows"],
+                    # uploads the worker made before the jitted call
+                    "h2d_transfers": self.counters["ragged_h2d_transfers"],
                     "decode_chain_rows": self.counters["decode_chain_rows"],
                     "decode_chain_kv_tokens": (
                         self.counters["decode_chain_kv_tokens"]
@@ -6237,7 +6300,10 @@ class LLMEngineCore:
             "want_lp": want_lp,
             "use_extras": use_extras,
             "sampling": self._batch_sampling(),
-            "extras": self._batch_extras() if use_extras else None,
+            # the cached constants; the produced-token counters ride the
+            # launch's one upload (_upload_ragged_operands)
+            "extras": self._extras_constants() if use_extras else None,
+            "counters": self._produced_counters() if use_extras else None,
             "gtables": gtables,
             "gstate": (
                 jnp.asarray(self._gstate.copy())
@@ -6436,28 +6502,87 @@ class LLMEngineCore:
             item_rows, item_q0 = ragged_work_items(
                 row_lens, self._ragged_tile, total=self._ragged_items
             )
-            plan.update(
-                item_rows=jnp.asarray(item_rows),
-                item_q0=jnp.asarray(item_q0),
-            )
+            plan.update(item_rows=item_rows, item_q0=item_q0)
         if faults.active():
             # yield-point seam parity with _prepare_dispatch: snapshot
             # complete, worker not yet started
             faults.fire("engine.dispatch.prepare", requests=plan["requests"])
         return plan
 
-    @staticmethod
-    def _ragged_operands(plan: dict, *more: str) -> dict:
-        """Worker thread: a ragged launch's host vectors as device operands,
-        uploaded before the jitted call is entered (the ``enqueue`` stamp
-        lies between): what every cache kind's step takes, and ``more``."""
-        names = (
-            "tokens", "tok_pos", "tok_row", "tok_valid", "row_last",
-            "kv_lens", "row_starts", "row_lens",
-        ) + more
-        dev = {name: jnp.asarray(plan[name]) for name in names}
-        dev["decode_mask"] = jnp.asarray(plan["decode_mask"].copy())
-        return dev
+    def _ragged_operand_layout(
+        self, launch_steps: int, extras: bool, spec: bool
+    ) -> tuple:
+        """The staging buffer of one step variant (a decode window, extras
+        on or off, verify rows or none): every host vector the launch
+        hands the device, in the order the worker fills them, at sizes the
+        engine holds since its build (docs/ragged_attention.md)."""
+        b, dense, chain = self.max_batch, self._ragged_dense, launch_steps - 1
+        entries = [
+            ("tokens", (dense,), False), ("tok_pos", (dense,), False),
+            ("tok_row", (dense,), False), ("tok_valid", (dense,), True),
+            ("row_last", (b,), False), ("kv_lens", (b,), False),
+            ("row_starts", (b,), False), ("row_lens", (b,), False),
+            ("decode_mask", (b,), True),
+        ]
+        if self.cache_mode == "paged":
+            entries += [
+                ("tok_slot", (dense,), False),
+                ("page_table", (b, self._pages_per_seq), False),
+                ("write_page", (dense,), False),
+                ("write_offset", (dense,), False),
+            ]
+            if self._ragged_kernel:
+                entries += [
+                    ("item_rows", (self._ragged_items,), False),
+                    ("item_q0", (self._ragged_items,), False),
+                ]
+            if chain:
+                entries += [
+                    ("chain_mask", (chain, b), True),
+                    ("chain_wp", (chain, b), False),
+                    ("chain_wo", (chain, b), False),
+                ]
+            if spec:
+                k1 = self._spec_k + 1
+                entries += [
+                    ("spec_mask", (b,), True), ("sspec_mask", (b,), True),
+                    ("drafts", (b, k1 - 1), False),
+                    ("row_logit_idx", (b, k1), False),
+                ]
+                if self._spec_tree:
+                    entries += [
+                        ("tree_tokens", (b, k1), False),
+                        ("tree_parents", (b, k1), False),
+                        ("tree_n", (b,), False),
+                        ("tree_anc", (self._ragged_tpad, k1), False),
+                    ]
+        else:
+            entries.append(("row_reset", (b,), True))
+            if chain:
+                entries.append(("chain_mask", (chain, b), True))
+        if extras:
+            entries.append(("counters", (b,), False))
+        return _staging_layout(entries)
+
+    def _upload_ragged_operands(self, plan: dict) -> dict:
+        """Worker thread: the launch's host vectors as device operands, in
+        ONE transfer. A FRESH staging buffer takes every vector of the
+        plan's layout (after any pool-exhaustion drop edited them), crosses
+        as the unpack program's one argument (the call makes the transfer:
+        0.17 ms less on the chip's host than a ``device_put`` before it,
+        PERF.md section 6) and is never written again (on the CPU backend
+        the device array may alias it: the hazard _chain_input records);
+        the unpack program hands back the operands by name, before the
+        ``enqueue`` stamp."""
+        layout, total = self._ragged_layouts[(
+            plan["launch_steps"], plan["use_extras"],
+            plan["row_logit_idx"] is not None,
+        )]
+        staged = np.empty(total, np.int32)
+        for name, offset, shape, _ in layout:
+            staged[offset : offset + math.prod(shape)] = plan[name].reshape(-1)
+        plan["h2d_transfers"] = 1
+        return self._ragged_unpack_jit(staged, layout)
 
     def _ragged_drop_row(self, plan: dict, slot: int) -> None:
         """Worker-side removal of a row whose page extension failed: its
@@ -6520,33 +6645,6 @@ class LLMEngineCore:
         want_lp = plan["want_lp"]
         launch_steps = plan["launch_steps"]
 
-        def _spec_arrays():
-            # built AFTER any pool-exhaustion drops: _ragged_drop_row edits
-            # the host masks/indices in place and the device copies must
-            # see the post-drop state
-            if plan["row_logit_idx"] is None:
-                return None
-            return (
-                jnp.asarray(plan["spec_mask"].copy()),
-                jnp.asarray(plan["sspec_mask"].copy()),
-                jnp.asarray(plan["drafts"]),
-                jnp.asarray(plan["row_logit_idx"]),
-                plan["spec_rng"],
-            )
-
-        def _tree_arrays():
-            # tree topology operands (docs/spec_decode_trees.md), also
-            # post-drop: a dropped verify row's masks are already False
-            # and its ancestor rows reverted to plain-causal sentinels
-            if plan.get("tree_anc") is None or plan["row_logit_idx"] is None:
-                return None
-            return (
-                jnp.asarray(plan["tree_tokens"]),
-                jnp.asarray(plan["tree_parents"]),
-                jnp.asarray(plan["tree_n"]),
-                jnp.asarray(plan["tree_anc"]),
-            )
-
         if self.cache_mode == "paged":
             pool = self.paged_cache.pool
             for slot in list(plan["spans"]):
@@ -6566,16 +6664,15 @@ class LLMEngineCore:
                 for i, (page, offset) in enumerate(coords):
                     plan["write_page"][s + i] = page
                     plan["write_offset"][s + i] = offset
-            chain_arrays = None
             if launch_steps > 1:
                 # multi-step decode rows: the reserved span positions 1..
                 # become the chained steps' per-step write coordinates —
                 # the mixed pass writes only position 0 (the others go to
                 # the null page there, exactly like any pad)
-                chain_wp = np.zeros(
+                chain_wp = plan["chain_wp"] = np.zeros(
                     (launch_steps - 1, self.max_batch), np.int32
                 )
-                chain_wo = np.zeros(
+                chain_wo = plan["chain_wo"] = np.zeros(
                     (launch_steps - 1, self.max_batch), np.int32
                 )
                 for slot, (s, n) in plan["spans"].items():
@@ -6591,18 +6688,38 @@ class LLMEngineCore:
                         chain_wo[i - 1, slot] = plan["write_offset"][s + i]
                         plan["write_page"][s + i] = 0
                         plan["write_offset"][s + i] = 0
-                chain_arrays = (
-                    plan["step_rngs"],
-                    jnp.asarray(plan["chain_mask"].copy()),
-                    jnp.asarray(chain_wp),
-                    jnp.asarray(chain_wo),
-                )
             self.paged_cache.apply_pending_cow()
             plan["page_table"] = pool.page_table(self._pages_per_seq)
-            dev = self._ragged_operands(
-                plan, "tok_slot", "page_table", "write_page", "write_offset"
+            # AFTER any pool-exhaustion drop: _ragged_drop_row edits the
+            # host vectors in place (a dropped verify row's masks are False
+            # and its ancestor rows reverted to plain-causal sentinels), and
+            # the device operands must see the post-drop state
+            dev = self._upload_ragged_operands(plan)
+            extras = (
+                plan["extras"]._replace(counters=dev["counters"])
+                if use_extras
+                else None
             )
-            spec, tree = _spec_arrays(), _tree_arrays()
+            chain_arrays = (
+                (
+                    plan["step_rngs"], dev["chain_mask"],
+                    dev["chain_wp"], dev["chain_wo"],
+                )
+                if launch_steps > 1
+                else None
+            )
+            spec = tree = None
+            if "row_logit_idx" in dev:
+                spec = (
+                    dev["spec_mask"], dev["sspec_mask"], dev["drafts"],
+                    dev["row_logit_idx"], plan["spec_rng"],
+                )
+                # tree topology operands (docs/spec_decode_trees.md)
+                if "tree_anc" in dev:
+                    tree = (
+                        dev["tree_tokens"], dev["tree_parents"],
+                        dev["tree_n"], dev["tree_anc"],
+                    )
             with self.paged_cache.dispatch_lock:
                 stamps.enqueue()
                 (
@@ -6628,13 +6745,13 @@ class LLMEngineCore:
                     dev["row_lens"],
                     dev["write_page"],
                     dev["write_offset"],
-                    plan["item_rows"],
-                    plan["item_q0"],
+                    dev.get("item_rows"),
+                    dev.get("item_q0"),
                     dev["decode_mask"],
                     plan["sampling"],
                     plan["rng"],
                     plan["lora"],
-                    plan["extras"],
+                    extras,
                     self._counts_dev if use_extras else None,
                     self._pmask_dev if use_extras else None,
                     gtables,
@@ -6653,14 +6770,18 @@ class LLMEngineCore:
             # row in place of page tables and write coordinates; nothing is
             # allocated here (admission gave the row its slot) and nothing
             # can run out
-            chain_arrays = None
-            if launch_steps > 1:
-                chain_arrays = (
-                    plan["step_rngs"],
-                    jnp.asarray(plan["chain_mask"].copy()),
-                )
             cache = self.state_cache
-            dev = self._ragged_operands(plan, "row_reset")
+            dev = self._upload_ragged_operands(plan)
+            extras = (
+                plan["extras"]._replace(counters=dev["counters"])
+                if use_extras
+                else None
+            )
+            chain_arrays = (
+                (plan["step_rngs"], dev["chain_mask"])
+                if launch_steps > 1
+                else None
+            )
             with cache.dispatch_lock:
                 stamps.enqueue()
                 (
@@ -6682,7 +6803,7 @@ class LLMEngineCore:
                     dev["decode_mask"],
                     plan["sampling"],
                     plan["rng"],
-                    plan["extras"],
+                    extras,
                     self._counts_dev if use_extras else None,
                     self._pmask_dev if use_extras else None,
                     gtables,
@@ -6989,6 +7110,7 @@ class LLMEngineCore:
         )
         self.counters["ragged_passes"] += int(plan["launch_steps"])
         self.counters["ragged_dense_rows"] += len(plan["tokens"])
+        self.counters["ragged_h2d_transfers"] += plan["h2d_transfers"]
         self._count_sampler_passes(
             int(plan["launch_steps"]), plan["row_steps"]
         )

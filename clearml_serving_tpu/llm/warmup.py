@@ -65,6 +65,7 @@ WARMUP_COVERED = frozenset({
     "_ragged_paged_jit",
     "_ragged_state_jit",
     "_gather_finish_jit",
+    "_ragged_unpack_jit",
 })
 
 
@@ -191,6 +192,12 @@ def warm_ragged_variants(engine) -> int:
     def key():
         return engine._next_rng()
 
+    def unpack(steps, spec_on=False):
+        # the variant's unpack program (the launch's ONE upload sliced back
+        # into operands, engine._upload_ragged_operands), over a null buffer
+        layout, total = engine._ragged_layouts[(steps, False, spec_on)]
+        engine._ragged_unpack_jit(np.zeros(total, np.int32), layout)
+
     def spec_args(on):
         if not on:
             return None
@@ -236,6 +243,7 @@ def warm_ragged_variants(engine) -> int:
         ) if engine._ragged_kernel else (None, None)
         for steps in windows:
             for spec_on in spec_opts:
+                unpack(steps, bool(spec_on))
                 chain = None
                 if steps > 1:
                     chain = (
@@ -286,6 +294,7 @@ def warm_ragged_variants(engine) -> int:
         cache = engine.state_cache
         tpad = engine._ragged_tpad
         for steps in windows:
+            unpack(steps)
             chain = None
             if steps > 1:
                 chain = (
