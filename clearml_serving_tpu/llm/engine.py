@@ -517,6 +517,23 @@ _LATENT_COUNTERS = (
 )
 
 
+def _pass_visible(mixed_visible, chain_first, chain_passes, row_lens,
+                  kv_lens) -> tuple:
+    """A launch's visible keys as int64 arrays: of the mixed pass's valid
+    tokens, of every (row, chained pass) (row r rides ``chain_passes[r]``
+    passes, ``chain_first[r]`` visible keys in the first and one more in
+    each next), and the mixed pass's live rows' queries and keys."""
+    mixed = np.asarray(mixed_visible, np.int64)
+    chain = np.concatenate([np.zeros(0, np.int64)] + [
+        first + np.arange(int(n), dtype=np.int64)
+        for first, n in zip(np.asarray(chain_first, np.int64),
+                            np.asarray(chain_passes, np.int64)) if n > 0
+    ])
+    n = np.asarray(row_lens, np.int64)
+    kv = np.asarray(kv_lens, np.int64)[n > 0]
+    return mixed, chain, n[n > 0], kv
+
+
 def _latent_pass_work(layout, mixed_visible=(), chain_first=(),
                       chain_passes=(), row_lens=(), kv_lens=()) -> dict:
     """What one launch over a latent page layout adds to ``latent.*`` of
@@ -533,17 +550,9 @@ def _latent_pass_work(layout, mixed_visible=(), chain_first=(),
     of its tokens' windows)."""
     topk, window = layout.index_topk, layout.window
     n_full, n_window = layout.n_full, layout.n_window
-    mixed = np.asarray(mixed_visible, np.int64)
-    chain = [np.zeros(0, np.int64)] + [
-        first + np.arange(int(n), dtype=np.int64)
-        for first, n in zip(np.asarray(chain_first, np.int64),
-                            np.asarray(chain_passes, np.int64)) if n > 0
-    ]
-    chain = np.concatenate(chain)
+    mixed, chain, n, kv = _pass_visible(
+        mixed_visible, chain_first, chain_passes, row_lens, kv_lens)
     seen = np.concatenate([mixed, chain])
-    n = np.asarray(row_lens, np.int64)
-    kv = np.asarray(kv_lens, np.int64)[n > 0]
-    n = n[n > 0]
     out = {
         "rows_full": seen.size * n_full,
         "rows_window": seen.size * n_window,
@@ -559,6 +568,44 @@ def _latent_pass_work(layout, mixed_visible=(), chain_first=(),
     out["decode_latent_tokens"] = (
         out["decode_keys_full"] + out["decode_keys_window"])
     return out
+
+
+_WINDOW_COUNTERS = (
+    "rows_full", "rows_window", "decode_keys_full", "decode_keys_window",
+    "mixed_keys_full", "mixed_keys_window", "mixed_pairs_full",
+    "mixed_pairs_window", "window_keys_unbounded",
+)
+
+
+def _window_pass_work(kinds, mixed_visible=(), chain_first=(),
+                      chain_passes=(), row_lens=(), kv_lens=()) -> dict:
+    """What one launch of a model whose paged path windows adds to
+    ``window.*`` of lifecycle_stats (docs/window_attention.md), layers
+    counted. ``kinds`` = ``bundle.paged_window`` (window, n_full, n_window);
+    the operands as :func:`_latent_pass_work` takes them. A token with v
+    visible keys reads v on a full layer and min(v, window) on a window
+    layer. ``decode_keys_*``: what the decode kernel has to read in the
+    chained passes; ``mixed_keys_*``: the keys the mixed pass's kernel has
+    to read at least, a ROW of the launch once (the union of its tokens'
+    windows); ``mixed_pairs_*``: the (query, visible key) pairs of the mixed
+    pass; ``window_keys_unbounded``: what the window layers would read
+    without the bound."""
+    window, n_full, n_window = kinds.window, kinds.n_full, kinds.n_window
+    mixed, chain, n, kv = _pass_visible(
+        mixed_visible, chain_first, chain_passes, row_lens, kv_lens)
+    tokens = mixed.size + chain.size
+    return {
+        "rows_full": tokens * n_full,
+        "rows_window": tokens * n_window,
+        "decode_keys_full": int(chain.sum()) * n_full,
+        "decode_keys_window": int(np.minimum(chain, window).sum()) * n_window,
+        "mixed_keys_full": int(kv.sum()) * n_full,
+        "mixed_keys_window": int(
+            np.minimum(kv, window + n - 1).sum()) * n_window,
+        "mixed_pairs_full": int(mixed.sum()) * n_full,
+        "mixed_pairs_window": int(np.minimum(mixed, window).sum()) * n_window,
+        "window_keys_unbounded": int(chain.sum() + kv.sum()) * n_window,
+    }
 
 
 def _staging_layout(entries) -> tuple:
@@ -592,6 +639,39 @@ def _unpack_ragged_operands(staged, layout):
 # per-step admission share to roughly one minimal chunk instead of the
 # legacy gate's one-segment-per-chunk budget
 _RAGGED_BROWNOUT_CHUNK = 16
+
+
+def _window_paged_refusal(*, cache_mode, mesh, speculation, spec_tree,
+                          lora_adapters) -> Optional[str]:
+    """Why this engine cannot be built over a model that windows its paged
+    path (``bundle.paged_window``), or None: what the window bound is not
+    taught is refused by name (docs/window_attention.md)."""
+    if cache_mode != "paged":
+        return (
+            "this model's window lives in the paged kernels: serve it with "
+            "engine.cache=paged (got engine.cache={}); it has no dense-cache "
+            "path".format(cache_mode)
+        )
+    if mesh is not None and mesh.size > 1:
+        return (
+            "a {}-device mesh cannot serve this model yet: its expert "
+            "layers hold the experts the configuration names "
+            "(experts_held) and the exchange of expert inputs across chips "
+            "is not built. Serve one engine per chip".format(mesh.size)
+        )
+    if speculation or spec_tree:
+        return (
+            "speculation cannot serve a windowed paged path yet: verify "
+            "rows need per-position logits from the model and a draft "
+            "tree's ancestor mask is written against the causal bound "
+            "alone (ops.paged_attention.window_kernel_unsupported_reason)"
+        )
+    if lora_adapters:
+        return (
+            "lora adapters are not served by this model: its projections "
+            "have no adapter rows"
+        )
+    return None
 
 
 def _latent_cache_refusal(bundle, *, cache_mode, mesh,
@@ -1190,18 +1270,28 @@ class LLMEngineCore:
         self.max_seq_len = int(max_seq_len)
         self.eos_token_id = eos_token_id
         self.decode_steps = max(1, int(decode_steps))
-        if cache_mode == "paged" and int(
+        # a model whose paged path windows says so itself
+        # (``bundle.paged_window``: docs/window_attention.md)
+        self._window = getattr(bundle, "paged_window", None)
+        if cache_mode == "paged" and self._window is None and int(
             bundle.config.get("sliding_window", 0) or 0
         ):
             raise ValueError(
-                "sliding_window models need engine.cache=dense (the paged "
-                "decode path does not window its attention yet): the window "
-                "bound exists for latent layers only "
-                "(ops/latent_attention.py, a model's 'sliding_attention' "
-                "layers over the latent page layout); a window on per-head "
-                "K/V pages (Mistral-style, every layer) is still one causal "
-                "bound a work item in ops/paged_attention.py"
+                "sliding_window models of arch llama need engine.cache=dense:"
+                " the paged kernels take a window bound "
+                "(ops/paged_attention.py, window=) but models/llama.py does "
+                "not hand it to them on its paged path yet (forward_ragged / "
+                "decode_paged attend every causal key); arch afmoe does "
+                "(models/afmoe.py, docs/window_attention.md)"
             )
+        if self._window is not None:
+            refused = _window_paged_refusal(
+                cache_mode=cache_mode, mesh=mesh, speculation=speculation,
+                spec_tree=spec_tree, lora_adapters=lora_adapters,
+            )
+            if refused:
+                raise ValueError(refused)
+        self._window_counts = dict.fromkeys(_WINDOW_COUNTERS, 0)
         if cache_mode == "paged" and getattr(
             bundle, "paged_unsupported_reason", None
         ):
@@ -1270,6 +1360,16 @@ class LLMEngineCore:
             if refused:
                 raise ValueError(refused)
         self._latent_counts = dict.fromkeys(_LATENT_COUNTERS, 0)
+        # a launch's keys by layer kind: (what counts them, the model's
+        # layer kinds, where they add up), for a latent page layout or a
+        # windowed paged path; None for a model of one layer kind
+        self._by_kind = None
+        if self._latent is not None:
+            self._by_kind = (
+                _latent_pass_work, self._latent, self._latent_counts)
+        elif self._window is not None:
+            self._by_kind = (
+                _window_pass_work, self._window, self._window_counts)
         self.cache_mode = cache_mode
         # kernel or XLA gather for paged pools of this model's shape
         # (ops.paged_attention): the same pure function models/llama.py
@@ -1588,6 +1688,7 @@ class LLMEngineCore:
                 dtype=bundle.config.get("dtype", "bfloat16"),
                 kv_quant=str(bundle.config.get("kv_quant") or ""),
                 layout=self._latent,
+                counters=getattr(self._window, "counters", 0),
             )
             if mesh is not None:
                 # shard the pools' kv-head dim over tp (pools [L,Hkv,N,P,D]) —
@@ -1608,12 +1709,12 @@ class LLMEngineCore:
                     )
             self._pages_per_seq = pages_per_slot
             self.cache = None
-            if self._latent is not None:
-                # the scrape's copy (_latent_snapshot) is an eager add: run
+            if self._expert_counters() is not None:
+                # the scrape's copy (_moe_snapshot) is an eager add: run
                 # once here, so that its one small program compiles at
                 # construction and not under the first scrape inside a
                 # serving window
-                self.paged_cache.v["counters"] + 0
+                self._expert_counters() + 0
             if self._paged_kernel_reason is None:
                 self._check_kernel_smem()
             self.state_cache = None
@@ -4798,31 +4899,40 @@ class LLMEngineCore:
             "sharding": self._shard_snapshot(),
         }
         if self._latent is not None:
-            out.update(self._latent_snapshot())
+            out["latent"] = dict(self._latent_counts)
+        if self._window is not None:
+            out["window"] = dict(self._window_counts)
+        if self._expert_counters() is not None:
+            out["moe"] = self._moe_snapshot()
         if self.replica_id is not None:
             out["replica"] = self.replica_id
         return out
 
-    def _latent_snapshot(self) -> dict:
-        """The latent page layout's two blocks of lifecycle_stats
-        (docs/latent_cache.md): ``latent`` from the launches' plans (rows
-        the two layer kinds attended for, keys the indexer scored and kept)
-        and ``moe`` from the counters the model's passes keep on the device
-        beside the pools (held experts that received a token, summed over
-        the expert layers of every pass; assignments that stayed here)."""
+    def _expert_counters(self):
+        """The counters a model's expert layers keep on the device beside
+        the pools, or None: a plane-less leaf of a model's own page layout
+        (docs/latent_cache.md) or the standard pools' carried companion
+        (``PagedKVCache.v_carry``)."""
+        if self.paged_cache is None:
+            return None
+        if self._latent is not None:
+            return self.paged_cache.v["counters"]
+        return self.paged_cache.counters
+
+    def _moe_snapshot(self) -> dict:
+        """``moe`` of lifecycle_stats (docs/latent_cache.md): held experts
+        that received a token, summed over the expert layers of every pass;
+        assignments that stayed here; expert layers run."""
         with self.paged_cache.dispatch_lock:
             # a copy taken under the lock: the next launch donates the
             # pools' own buffer, and the read below waits for this one only
-            counters = self.paged_cache.v["counters"] + 0
+            counters = self._expert_counters() + 0
         hit, local, layers = (int(x) for x in np.asarray(counters)[:3])
         return {
-            "latent": dict(self._latent_counts),
-            "moe": {
-                "experts_held": self._latent.experts_held,
-                "experts_hit": hit,
-                "local_assignments": local,
-                "layer_passes": layers,
-            },
+            "experts_held": self._by_kind[1].experts_held,
+            "experts_hit": hit,
+            "local_assignments": local,
+            "layer_passes": layers,
         }
 
     @property
@@ -6724,7 +6834,7 @@ class LLMEngineCore:
                 stamps.enqueue()
                 (
                     sampled, logits,
-                    self.paged_cache.k, self.paged_cache.v,
+                    self.paged_cache.k, self.paged_cache.v_carry,
                     new_ks, new_vs, new_counts, lp, gstate_out,
                     spec_g, spec_acc,
                 ) = self._ragged_paged_jit(
@@ -6736,7 +6846,7 @@ class LLMEngineCore:
                     dev["tok_slot"],
                     dev["row_last"],
                     self.paged_cache.k,
-                    self.paged_cache.v,
+                    self.paged_cache.v_carry,
                     self.paged_cache.k_scale,
                     self.paged_cache.v_scale,
                     dev["page_table"],
@@ -7121,10 +7231,11 @@ class LLMEngineCore:
                 plan["pre_lens"] + 2, np.maximum(plan["row_steps"] - 1, 0)
             )
             work = _decode_pass_work(*chained)
-            if self._latent is not None:
+            if self._by_kind is not None:
                 valid = np.asarray(plan["tok_valid"], bool)
-                work += (_latent_pass_work(
-                    self._latent, np.asarray(plan["tok_pos"])[valid] + 1,
+                pass_work, kinds, _ = self._by_kind
+                work += (pass_work(
+                    kinds, np.asarray(plan["tok_pos"])[valid] + 1,
                     *chained, plan["row_lens"], plan["kv_lens"],
                 ),)
             self._count_decode_passes(work)
@@ -7751,9 +7862,10 @@ class LLMEngineCore:
         live = lengths0 > 0
         held = lengths0[live]
         chain_work = _decode_pass_work(held + 1, np.full(held.shape, n))
-        if self._latent is not None:
-            chain_work += (_latent_pass_work(
-                self._latent, chain_first=held + 1,
+        if self._by_kind is not None:
+            pass_work, kinds, _ = self._by_kind
+            chain_work += (pass_work(
+                kinds, chain_first=held + 1,
                 chain_passes=np.full(held.shape, n)),)
         write_pages = np.zeros((self.max_batch, n), np.int32)   # null page 0
         write_offsets = np.zeros((self.max_batch, n), np.int32)
@@ -7791,7 +7903,7 @@ class LLMEngineCore:
             (
                 chunk,
                 self.paged_cache.k,
-                self.paged_cache.v,
+                self.paged_cache.v_carry,
                 new_k_scale,
                 new_v_scale,
                 new_counts,
@@ -7801,7 +7913,7 @@ class LLMEngineCore:
                 self.params,
                 prep["tokens"],
                 self.paged_cache.k,
-                self.paged_cache.v,
+                self.paged_cache.v_carry,
                 self.paged_cache.k_scale,
                 self.paged_cache.v_scale,
                 page_table,
@@ -7836,12 +7948,13 @@ class LLMEngineCore:
         return reads
 
     def _count_decode_passes(self, work: tuple) -> None:
-        """Loop thread: add a launch's :func:`_decode_pass_work`, and over a
-        latent page layout its :func:`_latent_pass_work` behind it."""
+        """Loop thread: add a launch's :func:`_decode_pass_work`, and its
+        keys by layer kind behind it (:func:`_latent_pass_work` over a latent
+        page layout, :func:`_window_pass_work` over a windowed paged path)."""
         self.counters["decode_chain_rows"] += work[0]
         self.counters["decode_chain_kv_tokens"] += work[1]
         for name, n in (work[2] if len(work) > 2 else {}).items():
-            self._latent_counts[name] += n
+            self._by_kind[2][name] += n
 
     def _count_sampler_passes(self, passes: int, row_passes) -> None:
         """Loop thread: a launch sampled ``passes`` times and slot ``r`` was

@@ -648,6 +648,7 @@ class PagedKVCache:
         dtype="bfloat16",
         kv_quant: str = "",
         layout=None,
+        counters: int = 0,
     ):
         import jax
         import jax.numpy as jnp
@@ -678,6 +679,11 @@ class PagedKVCache:
         else:
             self.k = jnp.zeros(shape, pool_dtype)
             self.v = jnp.zeros(shape, pool_dtype)
+        # a model that counts its passes' work on the device (``counters``
+        # int32 values; models/afmoe.py) gets them carried beside the
+        # standard V pool: see :attr:`v_carry`
+        self.counters = (
+            jnp.zeros((int(counters),), jnp.int32) if counters else None)
         # int8: per-(token, head) f32 dequant scales, page-id addressed so
         # a page and its scale row share one lifecycle (module docstring)
         if kv_quant:
@@ -736,6 +742,21 @@ class PagedKVCache:
     def layer(self, li: int):
         """Per-layer head-major views for ops.paged_attention."""
         return self.k[li], self.v[li]
+
+    @property
+    def v_carry(self):
+        """What a launch takes and gives back as ``v_pools``: the V pool,
+        or ``(pool, counters)`` for a model that keeps counters on the
+        device. The pool itself stays ``self.v``, a plain stack, for
+        everything else that moves pages."""
+        return self.v if self.counters is None else (self.v, self.counters)
+
+    @v_carry.setter
+    def v_carry(self, carry):  # tpuserve: ignore[TPU301] lock held by caller
+        if self.counters is None:
+            self.v = carry
+        else:
+            self.v, self.counters = carry
 
     @property
     def has_scales(self) -> bool:

@@ -254,7 +254,7 @@ def warm_ragged_variants(engine) -> int:
                     )
                 with cache.dispatch_lock:
                     (
-                        sampled, _logits, cache.k, cache.v,
+                        sampled, _logits, cache.k, cache.v_carry,
                         new_ks, new_vs, _counts, _lp, _gs, _sg, _sa,
                     ) = engine._ragged_paged_jit(
                         engine.params,
@@ -264,7 +264,7 @@ def warm_ragged_variants(engine) -> int:
                         jnp.asarray(np.zeros(dense, bool)),
                         jnp.asarray(np.full(dense, tpad, np.int32)),
                         jnp.asarray(np.zeros(b, np.int32)),
-                        cache.k, cache.v, cache.k_scale, cache.v_scale,
+                        cache.k, cache.v_carry, cache.k_scale, cache.v_scale,
                         page_table,
                         jnp.asarray(np.zeros(b, np.int32)),
                         jnp.asarray(np.zeros(b, np.int32)),

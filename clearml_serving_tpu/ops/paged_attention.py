@@ -182,12 +182,58 @@ def paged_kernel_unsupported_reason(
     return None
 
 
-def _check_kernel_operands(name, q, k_pool, quantized, interpret):
+def window_kernel_unsupported_reason(window: int, quantized: bool = False,
+                                     tree: bool = False) -> Optional[str]:
+    """Why the Mosaic kernels cannot take this ``window`` with these
+    operands — None if they can (always at ``window`` 0). What the window
+    bound is not taught is refused by name, never computed as something
+    else (docs/window_attention.md)."""
+    if window < 0:
+        return "window {} is negative (0 = no window)".format(window)
+    if window and quantized:
+        return (
+            "a window on int8 K/V pools is not implemented: the int8 flash "
+            "update (scale rows folded into scores and probabilities) has "
+            "not been taken through the windowed walk"
+        )
+    if window and tree:
+        return (
+            "a window on draft-tree verify rows is not implemented: a "
+            "tree's ancestor mask is written against the causal bound alone"
+        )
+    return None
+
+
+def window_first_page(first_pos, window: int, page_size: int):
+    """The first page of a row that holds a key the query at ``first_pos``
+    sees under ``window`` (it sees ``first_pos - window < s <= first_pos``):
+    where the kernels' page walks start. 0 without a window. Works on
+    ints, numpy arrays and traced scalars alike."""
+    if not window:
+        return 0
+    import numpy as np
+
+    first = first_pos - (window - 1)
+    maximum = jnp.maximum if isinstance(first, jax.Array) else np.maximum
+    return maximum(first, 0) // page_size
+
+
+def _plus(x, offset):
+    """``x + offset``, and ``x`` itself where ``offset`` is the Python 0 of
+    a kernel without a window: that kernel's program gains no operation."""
+    return x if isinstance(offset, int) and offset == 0 else x + offset
+
+
+def _check_kernel_operands(name, q, k_pool, quantized, interpret,
+                           window=0, tree=False):
     """The kernel entry points run the kernel or raise — never a reference."""
     if jnp.issubdtype(k_pool.dtype, jnp.signedinteger) and not quantized:
         raise ValueError(
             "int8 KV pools need k_scale/v_scale operands (per-token dequant)"
         )
+    reason = window_kernel_unsupported_reason(window, quantized, tree)
+    if reason is not None:
+        raise ValueError("{}: {}".format(name, reason))
     if not interpret:
         reason = paged_kernel_unsupported_reason(
             q.shape[-1], k_pool.shape[-2], k_pool.dtype, platform="tpu"
@@ -261,7 +307,8 @@ def gather_pages(pool, page_table, layer=None):
 # ----------------------------------------------------------------- reference
 
 def paged_attention_xla(q, k_pool, v_pool, page_table, lengths,
-                        k_scale=None, v_scale=None, layer=None):
+                        k_scale=None, v_scale=None, layer=None,
+                        window: int = 0):
     """Reference implementation in plain XLA ops (also the CPU fallback).
 
     q: [B, Hkv, G, D]; pools: [Hkv, N, P, D], or stacked [L, Hkv, N, P, D]
@@ -271,6 +318,9 @@ def paged_attention_xla(q, k_pool, v_pool, page_table, lengths,
     per-(token, head) symmetric scales of models/llama._kv_store. Dequant
     happens in f32 and casts to the query dtype before the attention math,
     mirroring the dense path's _kv_load, so XLA fuses it into the gather.
+
+    ``window`` (static; 0 = none): the row's query, at position
+    ``lengths - 1``, sees the keys ``lengths - 1 - window < s``.
     """
     b, hkv, g, d = q.shape
     p = k_pool.shape[-2]
@@ -285,6 +335,8 @@ def paged_attention_xla(q, k_pool, v_pool, page_table, lengths,
         v = (v.astype(jnp.float32) * vs).astype(q.dtype)
     t_idx = jnp.arange(pp * p, dtype=jnp.int32)[None]
     valid = t_idx < lengths[:, None]                          # [B, T]
+    if window:
+        valid = valid & (t_idx >= lengths[:, None] - window)
     scores = jnp.einsum(
         "bkgd,kbtd->bkgt", q, k, preferred_element_type=jnp.float32
     ) * (d ** -0.5)
@@ -342,6 +394,7 @@ def _paged_attention_kernel(
     page_size: int,
     pages_per_block: int,
     quantized: bool = False,
+    window: int = 0,
 ):
     if quantized:
         (q_ref, k_hbm, v_hbm, k_scale_ref, v_scale_ref,
@@ -357,8 +410,26 @@ def _paged_attention_kernel(
     block_tokens = pb * p
     layer = layer_ref[0]
     length = lengths_ref[b]
+
+    def first_page(row):
+        """The first page of ``row`` that holds a visible key: the pages
+        before it are never copied and the blocks wholly before it never
+        walked (0, a Python int, without a window: the program of before).
+        The blocks keep their places on the row (block i = pages i * PB
+        ...), so a key meets the same block whatever the window skipped."""
+        if not window:
+            return 0
+        return window_first_page(lengths_ref[row] - 1, window, p)
+
+    def first_block(row):
+        """Of a row the walk may name past the last (``rows`` = none)."""
+        if not window:
+            return 0
+        return first_page(jnp.minimum(row, rows - 1)) // pb
+
+    b0 = first_block(b)
     # blocks that contain live tokens; DMA never touches pages past length
-    n_blocks = (length + block_tokens - 1) // block_tokens
+    n_blocks = _plus((length + block_tokens - 1) // block_tokens, -b0)
 
     def next_live(row):
         """First row after ``row`` that attends anything (``rows`` if none)."""
@@ -372,6 +443,12 @@ def _paged_attention_kernel(
     def block_pages(row, block):
         left = lengths_ref[row] - block * block_tokens
         return jnp.minimum((left + p - 1) // p, pb)
+
+    def block_first(row, block):
+        """The first page of ``block`` the window leaves to copy."""
+        if not window:
+            return 0
+        return jnp.clip(first_page(row) - block * pb, 0, pb)
 
     def page_copies(row, block, slot, j):
         """A page's K and V planes of ALL kv heads: one strided descriptor a
@@ -394,7 +471,8 @@ def _paged_attention_kernel(
                 getattr(copy, act)()
             return carry
 
-        jax.lax.fori_loop(0, block_pages(row, block), page, 0)
+        jax.lax.fori_loop(
+            block_first(row, block), block_pages(row, block), page, 0)
 
     start_block = functools.partial(block_copies, "start")
     wait_block = functools.partial(block_copies, "wait")
@@ -407,7 +485,7 @@ def _paged_attention_kernel(
 
         @pl.when(first < rows)
         def _():
-            start_block(first, 0, 0)
+            start_block(first, first_block(first), 0)
 
     @pl.when(n_blocks > 0)
     def _run():
@@ -420,16 +498,17 @@ def _paged_attention_kernel(
         def body(i, carry):
             m_prev, l_prev, acc_prev = carry
             slot = jax.lax.rem(done + i, 2)
+            blk = _plus(i, b0)      # the block's place on the row
 
             @pl.when(i + 1 < n_blocks)
             def _prefetch():
-                start_block(b, i + 1, 1 - slot)
+                start_block(b, blk + 1, 1 - slot)
 
             @pl.when(jnp.logical_and(i + 1 == n_blocks, after < rows))
             def _prefetch_next_row():
-                start_block(after, 0, 1 - slot)
+                start_block(after, first_block(after), 1 - slot)
 
-            wait_block(b, i, slot)
+            wait_block(b, blk, slot)
             # K/V feed the MXU in pool dtype (bf16) with f32 accumulation,
             # batched over the kv heads. int8 pools (quantized): the block
             # feeds the dot as raw int8 cast to the output compute dtype —
@@ -454,10 +533,14 @@ def _paged_attention_kernel(
                                                  block_tokens)]  # [Hkv, 1, PB*P]
                 scores = scores * k_s
             token_ids = (
-                i * block_tokens
+                blk * block_tokens
                 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
             )
             valid = token_ids < length
+            if window:
+                # the first block's pages behind the window were not copied,
+                # and its first copied page may begin behind it
+                valid = valid & (token_ids >= length - window)
             scores = jnp.where(valid, scores, -jnp.inf)
             # rows past length were never DMA'd: their buffer bytes are
             # arbitrary (NaN/inf poisons 0*v), so zero them before the matmul.
@@ -465,10 +548,13 @@ def _paged_attention_kernel(
             # masked rows from polluting the scaled-probs matmul below.)
             # Mask built as an i32 iota compare: Mosaic cannot insert a
             # minor dim on an i1 vector (bool[:, None] fails to compile).
-            row_ids = i * block_tokens + jax.lax.broadcasted_iota(
+            row_ids = blk * block_tokens + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_tokens, 1), 1
             )
-            v = jnp.where(row_ids < length, v, jnp.zeros_like(v))
+            live = row_ids < length
+            if window:
+                live = live & (row_ids >= first_page(b) * p)
+            v = jnp.where(live, v, jnp.zeros_like(v))
 
             block_max = jnp.maximum(
                 jnp.max(scores, axis=2, keepdims=True), -1e30
@@ -510,7 +596,8 @@ def _paged_attention_kernel(
 
 def paged_attention(
     q, k_pool, v_pool, page_table, lengths, *,
-    k_scale=None, v_scale=None, layer=None, interpret: bool = False,
+    k_scale=None, v_scale=None, layer=None, window: int = 0,
+    interpret: bool = False,
 ):
     """Pallas paged decode attention — compiled by Mosaic, or interpreted
     under ``interpret=True``. Never the XLA reference: operands the
@@ -525,9 +612,17 @@ def paged_attention(
     per-(token, head) dequant scales for int8 pools (required when the
     pools are int8); dequant fuses into the in-kernel flash update (module
     docstring).
+
+    ``window`` (static; 0 = none, the program of before): the row's query
+    sees its last ``window`` keys. The block walk starts at the first page
+    that holds one (:func:`window_first_page`): pages wholly behind the
+    window cost no DMA and no flash block, the partial first page is masked.
+    Not with int8 pools (:func:`window_kernel_unsupported_reason`).
     """
     quantized = k_scale is not None
-    _check_kernel_operands("paged_attention", q, k_pool, quantized, interpret)
+    window = int(window)
+    _check_kernel_operands("paged_attention", q, k_pool, quantized, interpret,
+                           window)
 
     b, hkv, g, d = q.shape
     page_size = k_pool.shape[-2]
@@ -540,6 +635,7 @@ def paged_attention(
         page_size=page_size,
         pages_per_block=pb,
         quantized=quantized,
+        window=window,
     )
     row_spec = pl.BlockSpec((1, hkv, g, d), lambda b, *_: (b, 0, 0, 0))
     in_specs = [
@@ -973,7 +1069,7 @@ def tree_ancestors(parents, n_nodes=None, *, width=None):
 def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
                                row_starts, row_lens,
                                k_scale=None, v_scale=None,
-                               tree_anc=None, layer=None):
+                               tree_anc=None, layer=None, window: int = 0):
     """Reference ragged paged attention in plain XLA ops (CPU fallback).
 
     Shapes per the module's ragged section (pools of one layer, or the
@@ -988,7 +1084,13 @@ def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
     tokens by row index — the per-token [T, cap] operand still
     materializes for the score/PV einsums (acceptable at the fallback's
     test/smoke scale; the Pallas kernel is the capacity-scale path), but
-    HBM gather traffic stays R*cap, not T*cap."""
+    HBM gather traffic stays R*cap, not T*cap.
+
+    ``window`` (static; 0 = none): a query at position p sees the keys
+    ``p - window < s <= p``."""
+    if window and tree_anc is not None:
+        raise ValueError("ragged_paged_attention_xla: {}".format(
+            window_kernel_unsupported_reason(window, tree=True)))
     t, hkv, g, d = q.shape
     p = k_pool.shape[-2]
     pp = page_table.shape[1]
@@ -1020,6 +1122,10 @@ def ragged_paged_attention_xla(q, k_pool, v_pool, page_table, kv_lens,
         "thgd,htcd->thgc", q, k, preferred_element_type=jnp.float32
     ) * (d ** -0.5)
     valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < bound[:, None]
+    if window:
+        valid = valid & (
+            jnp.arange(cap, dtype=jnp.int32)[None, :] >= bound[:, None] - window
+        )
     if tree_anc is not None:
         # tree-topology pruning INSIDE the causal bound
         # (docs/spec_decode_trees.md): a tree row's query attends its
@@ -1057,6 +1163,7 @@ def _ragged_attention_kernel(
     group: int,
     quantized: bool = False,
     tree_width: int = 0,
+    window: int = 0,
 ):
     # then positionally: q_hbm [Hkv, T*G, D] (ANY); k_hbm/v_hbm
     # [L,Hkv,N,P,D] (ANY); quantized only: k_scale_ref/v_scale_ref
@@ -1104,7 +1211,14 @@ def _ragged_attention_kernel(
 
     def item(j):
         """(row, first query, queries, position of the row's query 0, causal
-        bound of the last query) of item ``j``; 0 queries = nothing to do."""
+        bound of the last query, first page to copy) of item ``j``; 0
+        queries = nothing to do. The first page that holds a key the item's
+        FIRST query sees: the pages before it are never copied and the
+        blocks wholly before it never walked (0, a Python int, without a
+        window: the program of before). The blocks keep their places on the
+        row, so a query's keys meet the same blocks in the same order
+        whatever tile it rides in: its output does not depend on how the
+        prompt was cut into chunks."""
         row = jnp.maximum(item_rows_ref[j], 0)
         q0 = item_q0_ref[j]
         row_len = row_lens_ref[row]
@@ -1112,7 +1226,9 @@ def _ragged_attention_kernel(
             item_rows_ref[j] >= 0, jnp.clip(row_len - q0, 0, qt), 0
         )
         base = kv_lens_ref[row] - row_len
-        return row, q0, qn, base, jnp.where(qn > 0, base + q0 + qn, 0)
+        page0 = window_first_page(base + q0, window, p) if window else 0
+        return (row, q0, qn, base, jnp.where(qn > 0, base + q0 + qn, 0),
+                page0)
 
     def next_live(j):
         """First item after ``j`` with queries (``ni`` if none)."""
@@ -1123,12 +1239,16 @@ def _ragged_attention_kernel(
             lambda r: r + 1, j + 1,
         )
 
+    def first_block(page0):
+        return page0 // pb if window else 0
+
     def block_pages(bound, block):
         return jnp.clip((bound - block * bt + p - 1) // p, 0, pb)
 
-    def block_copies(act, row, block, slot, pages):
-        """Start, or wait for, the first ``pages`` of a block: a page's K and V
-        planes of ALL kv heads are one strided descriptor a side, onto the
+    def block_copies(act, row, block, slot, pages, page0):
+        """Start, or wait for, the pages of a block up to its ``pages``-th,
+        from the first the window leaves to copy (``page0``): a page's K and
+        V planes of ALL kv heads are one strided descriptor a side, onto the
         slot's semaphore of that side."""
         def page(j, carry):
             page_id = page_table_ref[row, block * pb + j]
@@ -1143,11 +1263,12 @@ def _ragged_attention_kernel(
                 ), act)()
             return carry
 
-        jax.lax.fori_loop(0, pages, page, 0)
+        first = jnp.clip(page0 - block * pb, 0, pb) if window else 0
+        jax.lax.fori_loop(first, pages, page, 0)
 
     def q_copies(act, j, slot):
         """Item ``j``'s queries, 8 tokens of all heads a descriptor."""
-        row, q0, qn, _, _ = item(j)
+        row, q0, qn = item(j)[:3]
         t0 = row_starts_ref[row] + q0
 
         def chunk(c, carry):
@@ -1180,21 +1301,23 @@ def _ragged_attention_kernel(
         # the only cold start of a call: the first live item's first block
         # and its queries
         first = next_live(-1)
-        row, _, _, _, bound = item(jnp.minimum(first, ni - 1))
-        pages = jnp.where(first < ni, block_pages(bound, 0), 0)
+        row, _, _, _, bound, page0 = item(jnp.minimum(first, ni - 1))
+        block0 = first_block(page0)
+        pages = jnp.where(first < ni, block_pages(bound, block0), 0)
         walk[0] = 0
         walk[1] = pages
         walk[2] = 0
         walk[3] = 0
-        block_copies("start", row, 0, 0, pages)
+        block_copies("start", row, block0, 0, pages, page0)
 
         @pl.when(first < ni)
         def _():
             q_copies("start", first, 0)
 
-    row, q0, qn, base, bound = item(i)
+    row, q0, qn, base, bound, page0 = item(i)
     row_len = row_lens_ref[row]
-    n_blocks = (bound + bt - 1) // bt
+    b0 = first_block(page0)          # blocks wholly behind the window
+    n_blocks = _plus((bound + bt - 1) // bt, -b0)
     t0 = row_starts_ref[row] + q0
 
     def flash(q, k, v, block, carry, q_first):
@@ -1217,6 +1340,11 @@ def _ragged_attention_kernel(
         qi = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // g
         q_live = (q_first + qi) < row_len                   # query exists
         valid = (token_ids < base + q_first + qi + 1) & q_live
+        if window:
+            # the first block's keys behind the first query's window (its
+            # pages before ``page0`` were not copied), and the keys the
+            # tile's later queries no longer see
+            valid = valid & (token_ids > base + q_first + qi - window)
         if tree:
             # tree-topology pruning inside the unchanged causal bound
             # (docs/spec_decode_trees.md): parent-before-child node order
@@ -1295,8 +1423,9 @@ def _ragged_attention_kernel(
         q_slot = jax.lax.rem(walk[2], 2)
         nxt = next_live(i)
         more = nxt < ni
-        n_row, _, _, _, n_bound = item(jnp.minimum(nxt, ni - 1))
-        n_pages = jnp.where(more, block_pages(n_bound, 0), 0)
+        n_row, _, _, _, n_bound, n_page0 = item(jnp.minimum(nxt, ni - 1))
+        n_block0 = first_block(n_page0)
+        n_pages = jnp.where(more, block_pages(n_bound, n_block0), 0)
 
         @pl.when(more)
         def _next_queries():
@@ -1313,20 +1442,22 @@ def _ragged_attention_kernel(
 
             jax.lax.fori_loop(0, n_sub, init, 0)
 
-        def body(block, carry):
-            slot = jax.lax.rem(done + block, 2)
+        def body(step, carry):
+            slot = jax.lax.rem(done + step, 2)
+            block = _plus(step, b0)     # the block's place on the row
 
-            @pl.when(block + 1 < n_blocks)
+            @pl.when(step + 1 < n_blocks)
             def _prefetch():
                 block_copies("start", row, block + 1, 1 - slot,
-                             block_pages(bound, block + 1))
+                             block_pages(bound, block + 1), page0)
 
-            @pl.when(block + 1 == n_blocks)
+            @pl.when(step + 1 == n_blocks)
             def _prefetch_next_item():
-                block_copies("start", n_row, 0, 1 - slot, n_pages)
+                block_copies("start", n_row, n_block0, 1 - slot, n_pages,
+                             n_page0)
 
             block_copies("wait", row, block, slot, jnp.where(
-                block == 0, pending, block_pages(bound, block)))
+                step == 0, pending, block_pages(bound, block)), page0)
 
             # K/V feed the MXU in pool dtype (bf16) with f32 accumulation;
             # int8 pools as raw int8 cast to the compute dtype (lossless),
@@ -1340,7 +1471,10 @@ def _ragged_attention_kernel(
             row_ids = block * bt + jax.lax.broadcasted_iota(
                 jnp.int32, (1, bt, 1), 1
             )
-            v = jnp.where(row_ids < bound, v, jnp.zeros_like(v))
+            live = row_ids < bound
+            if window:
+                live = live & (row_ids >= page0 * p)
+            v = jnp.where(live, v, jnp.zeros_like(v))
             if small:
                 return flash(q_buf[q_slot, :, :cr], k, v, block, carry, q0)
 
@@ -1400,7 +1534,7 @@ def _ragged_attention_kernel(
 def ragged_paged_attention(
     q, k_pool, v_pool, page_table, kv_lens, row_starts, row_lens, *,
     item_rows=None, item_q0=None,
-    k_scale=None, v_scale=None, tree_anc=None, layer=None,
+    k_scale=None, v_scale=None, tree_anc=None, layer=None, window: int = 0,
     interpret: bool = False,
 ):
     """Ragged paged attention over mixed prefill+decode rows — compiled by
@@ -1425,15 +1559,25 @@ def ragged_paged_attention(
     draft-TREE rows (docs/spec_decode_trees.md): per flat token, the
     in-row indices of its root-to-node ancestor path (self included, -1
     padded); ``tree_anc[t, 0] == -2`` keeps token t plain-causal. Only
-    the mask changes — the page DMA plan is topology-blind."""
+    the mask changes — the page DMA plan is topology-blind.
+
+    ``window`` (static; 0 = none, the program of before): a query at
+    position p sees the keys ``p - window < s <= p``. A work item's block
+    walk starts at the first page that holds a key its FIRST query sees
+    (:func:`window_first_page`); pages wholly behind it cost no DMA and no
+    flash block, and the mask takes the partial first page and the keys the
+    tile's later queries no longer see. Not with int8 pools nor with
+    ``tree_anc`` (:func:`window_kernel_unsupported_reason`)."""
     quantized = k_scale is not None
+    window = int(window)
     if item_rows is None or item_q0 is None:
         raise ValueError(
             "ragged_paged_attention needs the host-built item_rows/item_q0 "
             "work plan (ragged_work_items)"
         )
     _check_kernel_operands(
-        "ragged_paged_attention", q, k_pool, quantized, interpret
+        "ragged_paged_attention", q, k_pool, quantized, interpret, window,
+        tree_anc is not None,
     )
 
     t, hkv, g0, d = q.shape
@@ -1463,6 +1607,7 @@ def ragged_paged_attention(
         group=g,
         quantized=quantized,
         tree_width=0 if tree_anc is None else tree_anc.shape[1],
+        window=window,
     )
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [anywhere, anywhere, anywhere]   # q, K and V pools stay in HBM

@@ -496,6 +496,6 @@ def test_page_export_and_the_dense_cache_are_refused_by_name(engine):
 def test_a_windowed_kv_model_is_still_refused_on_pages_by_layer_kind():
     bundle = models.build_model("llama", {
         "preset": "llama-tiny", "dtype": "float32", "sliding_window": 8})
-    with pytest.raises(ValueError, match="latent layers only"):
+    with pytest.raises(ValueError, match="arch llama need engine.cache=dense"):
         LLMEngineCore(bundle, bundle.init(jax.random.PRNGKey(0)),
                       max_batch=2, max_seq_len=64, cache_mode="paged")

@@ -554,3 +554,74 @@ def test_the_latent_kernels_compile_at_the_cells_shapes(one_chip, case):
     hlo = _compiled_text(jax.jit(fn).lower(*operands))
     assert hlo.count("tpu_custom_call") == 1
     assert name in hlo
+
+
+# ------------------------- the window bound of the two paged kernels (PR 47)
+
+WIN_ROWS, WIN_HKV, WIN_G, WIN_LAYERS = 32, 4, 8, 8
+WIN_PAGES, WIN_PAGES_PER_SEQ, WIN_TOKENS = 12289, 17408 // 16 + 1, 352
+
+
+def _window_case(one_chip, case, window):
+    """The two paged kernels at trinity-mini-d8's shapes (32 rows, Hkv 4, G 8,
+    D 128, pages of 16, the cell's pool of 12,289 pages over 8 layers, rows of
+    17,408 tokens) with a static ``window``."""
+    def on_chip(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf16 = jnp.bfloat16
+    pool = on_chip((WIN_LAYERS, WIN_HKV, WIN_PAGES, 16, D), bf16)
+    table, rows = on_chip((WIN_ROWS, WIN_PAGES_PER_SEQ)), on_chip((WIN_ROWS,))
+    if case == "decode":
+        return (
+            lambda q, k, v, t, n, layer: pa.paged_attention(
+                q, k, v, t, n, layer=layer, window=window),
+            (on_chip((WIN_ROWS, WIN_HKV, WIN_G, D), bf16), pool, pool, table,
+             rows, on_chip(())),
+            "paged_attention_decode")
+    tile = pa.ragged_query_tile(WIN_HKV, WIN_G, D, bf16)
+    items = on_chip((pa.ragged_item_count(WIN_ROWS, WIN_TOKENS, tile),))
+    return (
+        lambda q, k, v, t, kv, st, n, ir, iq, layer: pa.ragged_paged_attention(
+            q, k, v, t, kv, st, n, item_rows=ir, item_q0=iq, layer=layer,
+            window=window),
+        (on_chip((WIN_TOKENS, WIN_HKV, WIN_G, D), bf16), pool, pool, table,
+         rows, rows, rows, items, items, on_chip(())),
+        "ragged_paged_attention")
+
+
+@pytest.mark.parametrize("case", ["decode", "ragged"])
+@pytest.mark.parametrize("window", [0, 2048], ids=["causal", "window2048"])
+def test_the_windowed_kernels_compile_at_the_published_shapes(
+        one_chip, case, window):
+    """ISSUE 47: both kernels lower for the described v5e at the published
+    shapes as ONE custom call under their trace names, with the window and
+    without it (the full layers of the same model)."""
+    fn, operands, name = _window_case(one_chip, case, window)
+    hlo = _compiled_text(jax.jit(fn).lower(*operands))
+    assert hlo.count("tpu_custom_call") == 1
+    assert name in hlo
+
+
+@pytest.mark.parametrize("case", ["decode", "ragged"])
+def test_window_zero_lowers_to_the_kernel_of_before(one_chip, case):
+    """``window=0`` adds no operation to either kernel: its kernel body is
+    the one a call without the argument traces (what Mistral's and Mixtral's
+    cells run), equation for equation, and the windowed body is longer."""
+    def body(window):
+        fn, operands, _ = _window_case(one_chip, case, window)
+        if window is None:       # the call of before: no such argument
+            if case == "decode":
+                fn = lambda q, k, v, t, n, layer: pa.paged_attention(  # noqa: E731
+                    q, k, v, t, n, layer=layer)
+            else:
+                fn = lambda q, k, v, t, kv, st, n, ir, iq, layer: (  # noqa: E731
+                    pa.ragged_paged_attention(
+                        q, k, v, t, kv, st, n, item_rows=ir, item_q0=iq,
+                        layer=layer))
+        call, = [e for e in jax.make_jaxpr(fn)(*operands).eqns
+                 if e.primitive.name == "pallas_call"]
+        return str(call.params["jaxpr"])
+
+    assert body(0) == body(None)
+    assert len(body(2048)) > len(body(0))
