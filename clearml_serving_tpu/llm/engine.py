@@ -219,6 +219,15 @@ _STOP_SLOTS = 8
 # emission with chunk N+1's device compute (docs/pipelined_decode.md)
 _DEFAULT_PIPELINE_DEPTH = 2
 
+# watchdog: a dispatch still in its worker is given this many intervals before
+# it counts as a stall (a first-use XLA compile runs inside the call)
+_DISPATCH_GRACE_INTERVALS = 10.0
+# the ragged step's loop thread stands still (blocking, GIL released) until
+# its worker's ``enqueued`` stamp, so that no handler's Python runs beside the
+# worker's upload + enqueue; bounded, so that a first-use compile inside the
+# call cannot hold the event loop (the await that follows takes over)
+_ENQUEUED_WAIT_S = 0.05
+
 # host-tier auto-sizing clamps (aux engine.prefix_cache_host_mb: "auto",
 # docs/kv_tiering.md): half of /proc/meminfo MemAvailable, bounded so a
 # tiny CI box still gets a usable tier and a 1 TiB host does not
@@ -290,32 +299,42 @@ class _CycleClock:
     """The loop thread's time through one scheduling cycle, cut into phases
     that add up (docs/pipelined_decode.md "Observability"): ``admin`` (loop
     top until the step is entered), ``plan`` (_prepare_ragged /
-    _prepare_dispatch), ``launch`` (waiting for the dispatch worker),
-    ``wait`` (retire entered until every host copy is in hand), ``emit``
-    (the rest of the retire) and ``yield`` (sanitizer + the sleep(0) that
-    hands the event loop to the HTTP handlers). A cycle is a loop iteration
-    that dispatched or retired; it runs from its loop top to the next one.
-    ``mark`` closes the running phase and opens the next on ONE clock read,
-    so the phases partition the cycle (``cycle_ms`` is their sum), and each
-    phase is an ``engine.<phase>`` annotation on the profiler's host plane
-    while a profiler session is open (a flag test otherwise).
+    _prepare_dispatch), ``launch`` (waiting for the dispatch worker: a
+    ragged step stands still until the worker's ``enqueued`` stamp, then
+    awaits its hop back), ``wait`` (the dispatch landed, or a chunk's
+    retire entered, until every host copy is in hand: a worker thread
+    waits for the device and copies, the loop thread awaits it, and the
+    HTTP handlers run meanwhile), ``emit`` (the rest of the retire) and
+    ``yield`` (sanitizer + the sleep(0) that hands the event loop to the
+    HTTP handlers; none after a ragged step that awaited its worker, whose
+    streams are served inside the next launch's ``launch`` and ``wait``).
+    A cycle is a loop iteration that dispatched or retired; it runs from
+    its loop top to the next one. ``mark`` closes the running phase and
+    opens the next on ONE clock read, so the phases partition the cycle
+    (``cycle_ms`` is their sum), and each phase is an ``engine.<phase>``
+    annotation on the profiler's host plane while a profiler session is
+    open (a flag test otherwise).
 
     One launch timeline lies over the phases. ``landed`` takes the dispatch
     worker's four reads of a launch (_WorkerStamps) between the loop's own
-    around the hop, ``ready`` the instant the retire's FIRST device-to-host
-    copy returned (the device has finished). ``parts`` cuts the way from
-    the ``launch`` mark to the loop having the result into ``hop_out`` /
-    ``upload`` / ``enqueue`` / ``tail`` / ``hop_back``; ``readback`` is
+    around the hop, ``ready`` the instant the FIRST device-to-host copy of
+    the launch's results returned (the device has finished; the read
+    worker's own read, handed over with the copies). ``parts`` cuts the
+    way from the ``launch`` mark to the loop having the result into
+    ``hop_out`` / ``upload`` / ``enqueue`` / ``tail`` / ``hop_back``;
+    ``readback`` is
     ``ready`` until ``wait`` closes; ``starve`` is ``enqueue(N) -
     ready(N-1)``, never below 0: the stretch in which the program KNOWS the
     chip had nothing queued (none for a launch with no predecessor since a
     park: an engine without work is not starved). In the serial ragged step
     the parts add up to the ``launch`` phase and a starve is readback + emit
     + yield of one cycle plus admin + plan + hop_out + upload of the next
-    (shared reads). Where launches overlap (pipelined step) the worker's
-    three parts still add up to ``dispatch_ms``, the ``launch`` phase is only
-    what the concurrent retire left of the hop, and a launch whose
-    predecessor is not back yet starves 0. Loop-thread only."""
+    (shared reads; that readback ends with the read worker's hop back, and
+    that yield holds no handler). Where launches overlap (pipelined step)
+    the worker's three parts still add up to ``dispatch_ms``, the
+    ``launch`` phase is only what the concurrent retire left of the hop,
+    and a launch whose predecessor is not back yet starves 0. Loop-thread
+    only."""
 
     PHASES = ("admin", "plan", "launch", "wait", "emit", "yield")
     PARTS = ("hop_out", "upload", "enqueue", "tail", "hop_back")
@@ -427,37 +446,51 @@ class _WorkerStamps:
     worker's result for the loop thread to account (_CycleClock.landed: the
     clock stays loop-thread only). While a profiler session is open,
     ``engine.upload`` and ``engine.enqueue`` annotations cover the first two
-    stretches inside the caller's ``engine.dispatch``. The jitted step is
+    stretches inside the caller's ``engine.dispatch``. ``launched``, where the
+    caller gives one, is set with the ``enqueued`` read (or when the worker
+    leaves without it): the ragged step's loop thread stands still until
+    then (_ENQUEUED_WAIT_S). The jitted step is
     called between ``enqueue()`` and ``enqueued()`` with plain positional
     operands uploaded before: called through a ``call(fn, *args)`` helper
     or with starred operands, the first call of each program took 1.7-3.4 s
     longer on the state cache (set-up +10-15%; PERF.md section 6, PR 40)."""
 
-    def __init__(self, seq: int):
+    def __init__(self, seq: int, launched: Optional[threading.Event] = None):
         self.seq, self.reads, self._span = seq, [_clock()], None
+        self._launched = launched
 
     def _open(self, name: str) -> None:
         # an annotation starts where it is built
         self._span = jax.profiler.TraceAnnotation(name, seq=self.seq)
         self._span.__enter__()
 
+    def _shut(self) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def _release(self) -> None:
+        if self._launched is not None:
+            self._launched.set()
+
     def __enter__(self):
         self._open("engine.upload")
         return self
 
     def __exit__(self, *exc):
-        if self._span is not None:
-            self._span.__exit__(None, None, None)
-            self._span = None
+        self._shut()
+        # a worker that raised before its jitted call releases the loop too
+        self._release()
 
     def enqueue(self) -> None:
-        self.__exit__()
+        self._shut()
         self.reads.append(_clock())
         self._open("engine.enqueue")
 
     def enqueued(self) -> None:
-        self.__exit__()
+        self._shut()
         self.reads.append(_clock())
+        self._release()
 
 
 @dataclass
@@ -1814,6 +1847,10 @@ class LLMEngineCore:
             # jitted call, summed over ragged launches: over ragged_steps
             # it reads 1 (the staged buffer)
             "ragged_h2d_transfers": 0,
+            # ragged launches whose results a worker thread waited for and
+            # copied while the event loop ran (under ragged_steps by the
+            # launches that had already run when the loop looked)
+            "ragged_waits_off_loop": 0,
             # rows x decode passes over paged KV (the chained passes of a
             # ragged launch, the passes of a decode chunk) and the tokens
             # those rows attended there: the paged decode kernel's work
@@ -4832,6 +4869,8 @@ class LLMEngineCore:
                     "effective_budget": self._effective_token_budget(),
                     "prefill_jobs": len(self._prefill_jobs),
                     "steps": self.counters["ragged_steps"],
+                    # of those, the launches a worker thread waited out
+                    "waits_off_loop": self.counters["ragged_waits_off_loop"],
                     "budget_utilization": self._hist_budget.snapshot(),
                     "step_rows": dict(self._step_rows),
                     # multi-step decode rows + spec-as-row
@@ -4980,17 +5019,22 @@ class LLMEngineCore:
                     self._last_progress = time.monotonic()
                     continue
                 disp = self._dispatching
-                if disp is not None and (
-                    time.monotonic() - disp[2] < 10.0 * interval
+                if disp is not None and disp[2] is not None and (
+                    time.monotonic() - disp[2]
+                    < _DISPATCH_GRACE_INTERVALS * interval
                 ):
                     # a dispatch call is mid-flight in its worker thread:
                     # first-use XLA compiles run inside that call and can
-                    # legitimately take many seconds (the serial loop hid
-                    # this by blocking the event loop through the compile).
-                    # The grace is BOUNDED at 10x the interval — a dispatch
-                    # wedged past that (lock deadlock, hung inline backend)
-                    # is a stall, not a compile; device hangs also surface
-                    # at the retire sync, where no grace applies.
+                    # legitimately take many seconds. The grace is BOUNDED
+                    # at 10x the interval — a dispatch wedged past that
+                    # (lock deadlock, hung inline backend) is a stall, not
+                    # a compile. A device hang surfaces at the wait for the
+                    # launch's results, where no grace applies: a decode
+                    # chunk's retire sync and a ragged step's read both
+                    # wait in a worker (the ragged step drops its dispatch
+                    # start once the launch is enqueued), so this task
+                    # keeps running and trips one interval after the last
+                    # progress.
                     continue
                 if time.monotonic() - self._last_progress > interval:
                     self._watchdog_trip(interval)
@@ -6737,7 +6781,7 @@ class LLMEngineCore:
         row's chunk plus the ONE device launch (donated pools/cache,
         rebound under the dispatch lock — same discipline as the legacy
         dispatch workers)."""
-        stamps = _WorkerStamps(plan["seq"])
+        stamps = _WorkerStamps(plan["seq"], plan.get("launched"))
         with self._sentry_scope("ragged", seq=plan["seq"]), \
                 jax.profiler.TraceAnnotation("engine.dispatch", seq=plan["seq"]), \
                 stamps:
@@ -6953,68 +6997,143 @@ class LLMEngineCore:
             "spec_acc": spec_acc,
         }
 
-    async def _ragged_step(self, active_mask: np.ndarray, epoch: int) -> None:
+    async def _ragged_step(self, active_mask: np.ndarray, epoch: int) -> bool:
         """One ragged scheduling iteration (docs/ragged_attention.md): ONE
         device launch carries every decode row (one token each) plus as
         many prefill-chunk rows as fit the step token budget — admissions
         no longer stall the decode loop, they share its launches. Serial
-        dispatch -> sync -> emit; the pipelined in-flight queue resumes
-        the moment the admission backlog drains."""
+        dispatch -> read -> emit, with both waits in worker threads: the
+        event loop's handlers (the streams the LAST emission woke) run
+        while this launch does. True once the step awaited its worker, so
+        the caller need not hand the event loop over again; the pipelined
+        in-flight queue resumes the moment the admission backlog drains."""
         # post-ragged decode must re-upload the host mirrors: the device
         # chains were built by the (drained) pipelined path
         self._cycle.mark("plan", self._dispatch_seq + 1)
         self._reset_device_chains()
         plan = self._prepare_ragged(active_mask, epoch)
         if plan is None:
-            return
-        plan["launch_at"] = self._cycle.mark("launch", plan["seq"])
-        self._dispatching = (plan["seq"], plan["decode_mask"], time.monotonic())
+            return False
+        seq = plan["seq"]
+        plan["launch_at"] = self._cycle.mark("launch", seq)
+        plan["launched"] = threading.Event()
+        self._dispatching = (seq, plan["decode_mask"], time.monotonic())
         try:
-            result = await asyncio.to_thread(self._dispatch_ragged_device, plan)
-        except asyncio.CancelledError:
-            raise
-        except BaseException as ex:
-            req = getattr(ex, "request", None)
-            job = (
-                next(
-                    (j for j in self._prefill_jobs if j.request is req), None
-                )
-                if req is not None
-                else None
+            launch = asyncio.get_running_loop().run_in_executor(
+                None, self._dispatch_ragged_device, plan
             )
-            if job is not None:
-                # per-request fault attributed to an admission row: the
-                # seam fires before any device work, so decode rows lost
-                # nothing — fail only the job; next iteration re-plans
-                self.counters["step_failures"] += 1
-                self._fail_ragged_job(job, EngineStepError(
-                    "ragged admission chunk failed for this request: "
-                    "{}".format(ex)
-                ))
-                return
-            raise
+            # no handler runs between ``plan`` and the ``enqueued`` stamp:
+            # the streams' Python beside the worker's upload + enqueue
+            # would only make the launch late (one GIL)
+            plan["launched"].wait(_ENQUEUED_WAIT_S)
+            try:
+                result = await launch
+            except asyncio.CancelledError:
+                raise
+            except BaseException as ex:
+                req = getattr(ex, "request", None)
+                job = (
+                    next(
+                        (j for j in self._prefill_jobs if j.request is req),
+                        None,
+                    )
+                    if req is not None
+                    else None
+                )
+                if job is not None:
+                    # per-request fault attributed to an admission row: the
+                    # seam fires before any device work, so decode rows lost
+                    # nothing — fail only the job; next iteration re-plans
+                    self.counters["step_failures"] += 1
+                    self._fail_ragged_job(job, EngineStepError(
+                        "ragged admission chunk failed for this request: "
+                        "{}".format(ex)
+                    ))
+                    return True
+                raise
+            plan["wait_at"] = self._cycle.mark("wait", seq)
+            self._cycle.landed(
+                seq, plan["launch_at"], result["stamps"], plan["wait_at"]
+            )
+            # enqueued: what is left is the device's run, and a hang there
+            # gets no compile grace from the watchdog. The launch still
+            # counts as dispatching (its program writes the rows' pages)
+            # until its results are in hand
+            self._dispatching = (seq, plan["decode_mask"], None)
+            sampled, rest, ready_at, awaited = await self._read_back(
+                seq,
+                result["sampled"],
+                {
+                    name: result[name]
+                    for name in ("gstate", "lp", "spec_acc", "spec_g", "logits")
+                },
+            )
+            result = dict(
+                result, sampled=sampled, ready_at=ready_at,
+                off_loop=int(awaited), **rest,
+            )
         finally:
             self._dispatching = None
         if epoch != self._recover_epoch:
-            await self._ragged_recover(plan, result)
-            return
+            await self._ragged_recover(plan)
+            return True
         self._retire_ragged(plan, result)
+        return True
 
-    async def _ragged_recover(self, plan: dict, result: dict) -> None:
+    async def _read_back(self, seq: int, first, rest) -> tuple:
+        """A launch's blocking device-to-host copies, off the loop thread: a
+        worker waits for ``first`` (its copy back says the device has
+        finished: the worker's ``ready`` read) and copies ``rest`` (a pytree
+        of device arrays and None), the loop awaits it under its open
+        ``wait`` phase, so the event loop's handlers and the watchdog run
+        while the device does. The copies are all asked for before the
+        wait (``copy_to_host_async``), so they overlap each other behind
+        the launch. Where ``first`` has already landed (the
+        device ran ahead, an inline backend) the copies are microseconds
+        and the loop thread takes them itself. Returns the host copies,
+        the ``ready`` read and whether the worker was awaited."""
+        chaos = faults.active()
+        # a stall can be matched to the requests the watchdog can fail: the
+        # rows that hold a slot
+        held = [r for r in self._slot_req if r is not None] if chaos else ()
+
+        def _sync():
+            if chaos:
+                # worker-thread stall seam: wedges THIS launch's wait
+                # without blocking the event loop, so the watchdog can
+                # observe it
+                faults.fire("engine.decode.stall", requests=held)
+            head = np.asarray(first)
+            ready_at = _clock()        # the device has finished the launch
+            with jax.profiler.TraceAnnotation("engine.readback", seq=seq):
+                # np.array (copy): asarray can alias a device buffer that
+                # the next launch is given to overwrite (the DFA chain)
+                tail = jax.tree.map(np.array, rest)
+            return head, tail, ready_at
+
+        # every copy back is asked for at once, behind the launch: the
+        # worker then finds them done, or under way together, and not one
+        # round trip after another with an idle chip
+        for leaf in jax.tree.leaves((first, rest)):
+            start = getattr(leaf, "copy_to_host_async", None)
+            if start is not None:
+                start()
+        landed = getattr(first, "is_ready", None)
+        awaited = chaos or landed is None or not landed()
+        if awaited:
+            head, tail, ready_at = await asyncio.to_thread(_sync)
+        else:
+            head, tail, ready_at = _sync()
+        self._cycle.ready(seq, ready_at)
+        return head, tail, ready_at, awaited
+
+    async def _ragged_recover(self, plan: dict) -> None:
         """The watchdog tripped while this ragged step was mid-worker: the
         decode results are stale (those requests were already failed) and
-        no commit may run. Wait out the device program off-thread, roll
-        surviving jobs' page extensions back to their pre-step lengths
-        (the next step redoes the chunk cleanly — its K/V rewrites are
-        value-identical), then run the shared recovery."""
-
-        def _wait():
-            try:
-                jax.block_until_ready(result["sampled"])
-            except Exception:
-                pass
-
-        await asyncio.to_thread(_wait)
+        no commit may run. The step's read waited the device program out
+        off-thread; roll surviving jobs' page extensions back to their
+        pre-step lengths (the next step redoes the chunk cleanly — its K/V
+        rewrites are value-identical), then run the shared recovery."""
         if self.paged_cache is not None:
             pool = self.paged_cache.pool
             for job, _take in plan["shares"]:
@@ -7031,7 +7150,8 @@ class LLMEngineCore:
         await self._finish_recovery()
 
     def _retire_ragged(self, plan: dict, result: dict) -> None:
-        """Loop-thread tail of a ragged step: decode emissions re-anchor
+        """Loop-thread tail of a ragged step, over the HOST copies of its
+        results (_read_back): decode emissions re-anchor
         the host mirrors exactly like a pipelined retire — a q=N decode
         row emits its whole window in order under the MID-WINDOW EOS MASK
         (a row finishing inside its window delivers the tokens up to the
@@ -7041,37 +7161,16 @@ class LLMEngineCore:
         finishing prefill jobs sample their first token (the legacy
         admission code path) and activate their slot."""
         seq = plan["seq"]
-        t0 = self._cycle.mark("wait", seq)
-        self._cycle.landed(seq, plan["launch_at"], result["stamps"], t0)
-        sampled = np.asarray(result["sampled"])
-        # the device has finished: what follows in ``wait`` is host time
-        ready_at = self._cycle.ready(seq)
+        self._cycle.mark("emit", seq)
+        sampled, gstate_np, lp_np = (
+            result["sampled"], result["gstate"], result["lp"]
+        )
+        spec_acc, spec_g = result["spec_acc"], result["spec_g"]
+        ready_at = result["ready_at"]
         if sampled.ndim == 1:
             sampled = sampled[None]               # step-major [S, B]
-        with jax.profiler.TraceAnnotation("engine.readback", seq=seq):
-            gstate_np = (
-                np.array(result["gstate"])
-                if result["gstate"] is not None
-                else None
-            )
-            lp_np = (
-                tuple(np.asarray(a) for a in result["lp"])
-                if result["lp"] is not None
-                else None
-            )
-            spec_acc = (
-                np.asarray(result["spec_acc"])
-                if result["spec_acc"] is not None
-                else None
-            )
         if lp_np is not None and lp_np[0].ndim == 1:
             lp_np = tuple(a[None] for a in lp_np)  # step-major [S, B, ...]
-        self._cycle.mark("emit", seq)
-        spec_g = (
-            np.asarray(result["spec_g"])
-            if result["spec_g"] is not None
-            else None
-        )
         spec_any = plan["spec_mask"] | plan["sspec_mask"]
         # the per-request retire fault on a MULTI-TOKEN row fails the
         # request with its partial window delivered (all but the last
@@ -7214,6 +7313,7 @@ class LLMEngineCore:
             if not any(j is f for f in failed)
         ]
         self.counters["ragged_steps"] += 1
+        self.counters["ragged_waits_off_loop"] += result["off_loop"]
         self.counters["ragged_decode_tokens"] += emitted_decode
         self.counters["ragged_prefill_tokens"] += sum(
             t for _, t in live_shares
@@ -7261,7 +7361,6 @@ class LLMEngineCore:
         self._hist_budget.observe(used / max(1, plan["budget"]))
         for job, err in plan["failed_jobs"]:
             self._fail_ragged_job(job, err)
-        logits_np = None
         for job, take in live_shares:
             if job not in self._prefill_jobs:  # failed since planning
                 continue
@@ -7293,14 +7392,10 @@ class LLMEngineCore:
                 request.out_queue.put_nowait(_FINISHED)
                 self._free_ragged_slot(job.slot)
                 continue
-            if logits_np is None:
-                # [F, vocab]: only the finishing rows were read back
-                logits_np = np.asarray(result["logits"])
-                finish_index = {
-                    s: i for i, s in enumerate(result["finish_rows"])
-                }
+            # [F, vocab]: only the finishing rows were read back
+            row = result["finish_rows"].index(job.slot)
             first_id, first_lp = self._first_token_from_logits(
-                request, jnp.asarray(logits_np[finish_index[job.slot]][None])
+                request, jnp.asarray(result["logits"][row][None])
             )
             if self.cache_mode == "paged" and self._prefix is not None:
                 # zero-copy store, same point as the legacy commit: the
@@ -7318,7 +7413,7 @@ class LLMEngineCore:
         self._reap_promotions()
         self._last_progress = time.monotonic()
         t1 = self._cycle.mark("yield", seq)
-        self._hist_retire.observe((t1 - t0) * 1e3)
+        self._hist_retire.observe((t1 - plan["wait_at"]) * 1e3)
 
     async def _run_loop(self) -> None:
         try:
@@ -7512,6 +7607,13 @@ class LLMEngineCore:
             # and a watchdog trip (epoch bump) discards the whole in-flight
             # queue — the loop itself survives both and keeps serving
             step_epoch = self._recover_epoch
+            # a serial iteration of the ragged phase (the step, or the
+            # retire that drains the pipeline before it) that awaited its
+            # worker has handed the event loop over already: the streams
+            # its emission wakes are served inside the NEXT launch's
+            # awaits, while the chip works, and not here, where it has
+            # nothing queued
+            handed_over = False
             try:
                 if (
                     self._prefill_jobs
@@ -7529,9 +7631,11 @@ class LLMEngineCore:
                     # on, spec rows ride these launches — the serial
                     # pipeline-draining spec scan never runs here.
                     if self._inflight:
-                        await self._retire_oldest()
+                        handed_over = await self._retire_oldest()
                     else:
-                        await self._ragged_step(active_mask, step_epoch)
+                        handed_over = await self._ragged_step(
+                            active_mask, step_epoch
+                        )
                 else:
                     await self._decode_step(active_mask, step_epoch)
             except asyncio.CancelledError:
@@ -7544,7 +7648,8 @@ class LLMEngineCore:
             # is exactly where reclamation bugs hide. A violation raises out
             # of the loop (fail loud beats serving corrupted KV).
             self._sanitize("decode-step")
-            await asyncio.sleep(0)  # let HTTP handlers interleave
+            if not handed_over:
+                await asyncio.sleep(0)  # let HTTP handlers interleave
 
 
     # -- pipelined decode: dispatch / retire ----------------------------------
@@ -7656,13 +7761,15 @@ class LLMEngineCore:
             await self._retire_oldest()
         self._reset_device_chains()
 
-    async def _retire_oldest(self) -> None:
+    async def _retire_oldest(self) -> bool:
         """Retire the oldest in-flight chunk; it leaves the queue only once
-        its emissions landed (recovery may clear the queue mid-retire)."""
+        its emissions landed (recovery may clear the queue mid-retire).
+        _retire_chunk's answer."""
         entry = self._inflight[0]
-        await self._retire_chunk(entry)
+        awaited = await self._retire_chunk(entry)
         if self._inflight and self._inflight[0] is entry:
             self._inflight.popleft()
+        return awaited
 
     def _dispatchable_mask(self, active_mask: np.ndarray) -> np.ndarray:
         """Slots worth including in the NEXT chunk: active, and not already
@@ -7994,50 +8101,21 @@ class LLMEngineCore:
             )
         return dev
 
-    async def _retire_chunk(self, entry: "_InFlightChunk") -> None:
+    async def _retire_chunk(self, entry: "_InFlightChunk") -> bool:
         """Device->host readback + token emission for the OLDEST in-flight
         chunk, running while the next chunk computes. Every anchor point of
         the old serial loop re-lands here: slot frees / EOS handling,
         prefill-gate deposits, the watchdog-epoch check, the quarantine
         release, and (via the caller) the sanitizer audit — admission
-        commits follow at the next loop top."""
-
-        def _sync():
-            if faults.active():
-                # worker-thread stall seam: wedges THIS retire without
-                # blocking the event loop, so the watchdog can observe it
-                faults.fire(
-                    "engine.decode.stall",
-                    requests=[r for r in self._slot_req if r is not None],
-                )
-            chunk_np = np.asarray(entry.chunk)
-            ready_at = _clock()        # the device has finished the chunk
-            with jax.profiler.TraceAnnotation("engine.readback", seq=entry.seq):
-                # np.array (copy): asarray would alias the immutable device
-                # buffer and commit/release paths write rows in place
-                gstate_np = (
-                    np.array(entry.gstate)
-                    if entry.gstate is not None
-                    else None
-                )
-                lp_np = (
-                    tuple(np.asarray(a) for a in entry.lp)
-                    if entry.lp is not None
-                    else None
-                )
-            return chunk_np, gstate_np, lp_np, ready_at
+        commits follow at the next loop top. True where the readback
+        awaited its worker (the event loop's handlers ran meanwhile)."""
 
         t0 = self._cycle.mark("wait", entry.seq)
-        ready = getattr(entry.chunk, "is_ready", None)
-        if not faults.active() and ready is not None and ready():
-            # chunk already landed (device ran ahead): the copies are
-            # microseconds — skip the worker-thread hop entirely
-            chunk_np, gstate_np, lp_np, ready_at = _sync()
-        else:
-            chunk_np, gstate_np, lp_np, ready_at = await asyncio.to_thread(
-                _sync
-            )
-        self._cycle.ready(entry.seq, ready_at)
+        chunk_np, (gstate_np, lp_np), _, awaited = await self._read_back(
+            entry.seq,
+            entry.chunk,
+            (entry.gstate, entry.lp),
+        )
         self._cycle.mark("emit", entry.seq)
         if entry.epoch != self._recover_epoch:
             # the watchdog failed this batch while the pipeline was in
@@ -8046,7 +8124,7 @@ class LLMEngineCore:
             # _finish_recovery defers itself while the concurrent dispatch
             # leg is mid-worker; that leg completes recovery on landing.
             await self._finish_recovery()
-            return
+            return awaited
         if faults.active():
             try:
                 # chaos seam: a retire-stage failure (host emission path)
@@ -8101,6 +8179,7 @@ class LLMEngineCore:
         self._last_progress = time.monotonic()
         t1 = self._cycle.mark("yield", entry.seq)
         self._hist_retire.observe((t1 - t0) * 1e3)
+        return awaited
 
     async def _retire_beside_dispatch(self, entry: "_InFlightChunk") -> None:
         """The gather leg of the steady pipelined step: once the retire is

@@ -22,7 +22,10 @@ Known points (ctx carried with each):
                          ``delay`` models a slow spec round wedging the
                          loop (the watchdog's view of a stuck spec scan),
                          ``raise`` fails the dispatch before it touches
-                         the pool.
+                         the pool. Also in the worker that waits for a
+                         launch's results (a chunk's retire sync, a ragged
+                         step's read): ``delay`` there is a launch that
+                         does not come back, with the event loop free.
 - ``engine.decode.retire`` — on the loop thread at chunk retirement, after
                          the device->host sync and before emission
                          (``requests``); ``match_token`` fails only the

@@ -17,7 +17,9 @@ sleeps racing compiles beyond an explicit warmup) — they run inside tier-1
 """
 
 import asyncio
+import threading
 import time
+import types
 
 import jax
 import pytest
@@ -32,6 +34,7 @@ from clearml_serving_tpu.errors import (
     UpstreamTimeoutError,
     UpstreamUnavailableError,
 )
+from clearml_serving_tpu.llm import engine as engine_mod
 from clearml_serving_tpu.llm import faults
 from clearml_serving_tpu.llm.engine import GenRequest, LLMEngineCore
 from clearml_serving_tpu.llm.kv_sanitizer import KVSanitizerError
@@ -416,6 +419,126 @@ def test_watchdog_trips_on_stalled_decode_and_recovers(parts):
 
     engine = asyncio.run(run())
     assert engine.health()["ready"]
+
+
+# -- watchdog: a ragged launch that never comes back ---------------------------
+
+_STATE_CFG = dict(vocab_size=300, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                  head_dim=16, ffn_dim=96, scan_layers=True, dtype="float32",
+                  attention="power_retention", retention_degree=2, qk_norm=True,
+                  norm_eps=1e-6, rope_theta=1e6, max_seq_len=512)
+_RAGGED_KINDS = {
+    "paged": (None, dict(cache_mode="paged", page_size=8, num_pages=64)),
+    "state": (_STATE_CFG, dict(cache_mode="state")),
+}
+
+
+@pytest.mark.parametrize("cache", list(_RAGGED_KINDS))
+def test_watchdog_trips_on_a_stalled_ragged_wait_and_recovers(
+        parts, cache, monkeypatch):
+    """The ragged step waits for its launch in a worker thread: a launch
+    that does not come back (``engine.decode.stall`` in that worker) leaves
+    the event loop free, so the watchdog trips, ``health()`` answers during
+    the stall, the decoding victim fails with EngineStuckError, and when the
+    worker returns ``_ragged_recover`` takes the surviving admission's chunk
+    back (pages truncated / state rewound), which then finishes with the
+    tokens of an undisturbed run; the engine serves on. No wall clock is
+    judged: the engine's ``time.monotonic`` stands still until the test
+    moves it, the stall is armed from inside the step that carries a decode
+    row beside a prompt chunk, and it lasts until the test has seen the
+    trip."""
+    cfg, kw = _RAGGED_KINDS[cache]
+    if cfg is None:
+        bundle, params = parts
+    else:
+        bundle = models.build_model("llama", cfg)
+        params = bundle.init(jax.random.PRNGKey(0))
+    kw = dict(kw, max_batch=2, eos_token_id=None, scheduler="ragged",
+              step_token_budget=16, decode_steps=2, watchdog_interval=1.0)
+    victim = [256, 4, 5]
+    survivor = [256] + [(5 * j) % 250 + 1 for j in range(40)]   # three chunks
+
+    async def undisturbed():
+        engine = _make_engine(bundle, params, **kw)
+        out = await _collect(
+            engine, GenRequest(prompt_ids=list(survivor), max_new_tokens=6))
+        await engine.wait_drained()
+        engine.stop()
+        return out
+
+    want = asyncio.run(undisturbed())
+
+    now = [1000.0]
+    monkeypatch.setattr(engine_mod, "time", types.SimpleNamespace(
+        monotonic=lambda: now[0], perf_counter=time.perf_counter,
+        time=time.time))
+    stalled, release, survivors = threading.Event(), threading.Event(), []
+
+    def stall(seconds):
+        stalled.set()
+        release.wait(seconds)       # the bound is a net, not a measurement
+
+    monkeypatch.setattr(faults, "time", types.SimpleNamespace(sleep=stall))
+
+    async def run():
+        engine = _make_engine(bundle, params, **kw)
+        prepare = engine._prepare_ragged
+
+        def arm(mask, epoch):
+            plan = prepare(mask, epoch)
+            if (plan is not None and plan["decode_mask"].any()
+                    and plan["shares"] and not faults.active()):
+                faults.configure([
+                    {"point": "engine.decode.stall", "action": "delay",
+                     "delay": 120.0, "times": 1},
+                ])
+            return plan
+
+        engine._prepare_ragged = arm
+        recover = engine._ragged_recover
+
+        async def recovered(plan):
+            survivors.extend(job.request for job, _ in plan["shares"])
+            await recover(plan)
+
+        engine._ragged_recover = recovered
+        a = GenRequest(prompt_ids=list(victim), max_new_tokens=100)
+        a_task = asyncio.create_task(_collect(engine, a))
+        while a.produced < 2:
+            await asyncio.sleep(0.005)
+        b = GenRequest(prompt_ids=list(survivor), max_new_tokens=6)
+        b_task = asyncio.create_task(_collect(engine, b))
+        while not stalled.is_set():
+            await asyncio.sleep(0.005)      # the loop is free while it waits
+        assert engine.health()["ready"] and not a_task.done()
+        now[0] += 10.0                      # ten intervals without progress
+        while engine.counters["watchdog_trips"] < 1:
+            await asyncio.sleep(0.005)
+        # the launch is still out: the engine answers, and says not ready
+        assert not engine.health()["ready"]
+        with pytest.raises(EngineStuckError):
+            await a_task
+        assert not b_task.done()
+        release.set()
+        assert await b_task == want
+        assert survivors == [b] and engine.is_ready
+        out = await _collect(
+            engine, GenRequest(prompt_ids=[256, 9], max_new_tokens=3))
+        assert len(out) == 3
+        await engine.wait_drained()
+        return engine
+
+    engine = asyncio.run(run())
+    assert engine.counters["watchdog_trips"] == 1
+    stats = engine.lifecycle_stats()
+    if cache == "paged":
+        pool = engine.paged_cache.pool
+        assert pool.free_pages == pool.num_pages - 1
+    else:
+        assert stats["state_pool"]["rewinds"] >= 1
+        assert stats["state_pool"]["in_use"] == 0
+    assert engine.health()["ready"]
+    engine.stop()
 
 
 # -- admission shedding -------------------------------------------------------

@@ -3,17 +3,23 @@ worker's four stamps come back with its result, the loop thread cuts the
 ``launch`` phase into five parts that add up to it, takes ``ready`` from the
 retire's first device-to-host copy, and observes per launch the stretch in
 which the chip had nothing queued (``starve_ms``); a request's ``prefill_ms``
-is cut on the same timeline into three stretches that add up to it."""
+is cut on the same timeline into three stretches that add up to it. Both of
+a ragged step's waits are a worker's (the dispatch, then the read of its
+results), so the streams an emission wakes are served inside the NEXT launch,
+after its ``enqueued`` read and before its ``ready`` read."""
 
 import asyncio
 import itertools
 import threading
 
 import jax
+import numpy as np
 import pytest
 
 from clearml_serving_tpu import models
+from clearml_serving_tpu.errors import EngineOverloadedError
 from clearml_serving_tpu.llm import engine as engine_mod
+from clearml_serving_tpu.llm import faults
 from clearml_serving_tpu.llm.engine import (
     GenRequest,
     LLMEngineCore,
@@ -119,6 +125,39 @@ def keep_last(monkeypatch):
     monkeypatch.setattr(engine_mod._MsHistogram, "observe", remember)
 
 
+def _ragged_spy(engine):
+    """The seqs of the launches that were ragged steps, as they are made."""
+    ragged, dispatch = [], engine._dispatch_ragged_device
+
+    def spy(plan):
+        ragged.append(plan["seq"])
+        return dispatch(plan)
+
+    engine._dispatch_ragged_device = spy
+    return ragged
+
+
+def _iterations(engine, monkeypatch):
+    """One list a loop iteration (opened at the clock's ``top``), which takes
+    a "sleep0" where the iteration hands the event loop over with
+    ``asyncio.sleep(0)`` and whatever the test's own spies append."""
+    iterations = []
+    real_sleep, top = asyncio.sleep, engine._cycle.top
+
+    async def sleep(delay, *args, **kw):
+        if delay == 0 and iterations:
+            iterations[-1].append("sleep0")
+        return await real_sleep(delay, *args, **kw)
+
+    def on_top(seq):
+        iterations.append([])
+        return top(seq)
+
+    monkeypatch.setattr(asyncio, "sleep", sleep)
+    engine._cycle.top = on_top
+    return iterations
+
+
 # -- the serial step: five parts = launch, seven stretches = starve -----------
 
 
@@ -137,16 +176,13 @@ def counted_clock(monkeypatch):
 
 def test_parts_add_up_to_launch_and_stretches_to_starve(kind, counted_clock):
     """Depth 1: every cycle is one serial launch (a ragged step, or on pages
-    a decode chunk once the prompts are in), so cycle k is launch k."""
+    a decode chunk once the prompts are in), so cycle k is launch k. A ragged
+    step's ``launch`` still ends at the dispatch worker's hop back (the loop
+    stood still until the ``enqueued`` stamp inside it); the read of the
+    results is a second worker's, under ``wait``, and its ``ready`` read
+    comes back with the copies."""
     engine = _engine(kind, pipeline_depth=1)
-    ragged = []
-    dispatch = engine._dispatch_ragged_device
-
-    def spy(plan):
-        ragged.append(plan["seq"])
-        return dispatch(plan)
-
-    engine._dispatch_ragged_device = spy
+    ragged = _ragged_spy(engine)
     tape = Tape(engine)
     _run(engine, PROMPTS[:3])
     launches = tape.launches
@@ -177,6 +213,186 @@ def test_parts_add_up_to_launch_and_stretches_to_starve(kind, counted_clock):
     assert starved >= 4
     if kind[2]["cache_mode"] == "state":
         assert len(ragged) == len(launches)      # the state cache has ONE step
+    engine.stop()
+
+
+# -- the streams are served inside the next launch -----------------------------
+
+
+def test_a_woken_stream_runs_inside_the_next_launch(kind, counted_clock,
+                                                    monkeypatch):
+    """One long answer decodes while three prompts take the other slot in
+    turn, so ragged steps with a decode row follow one another. A consumer
+    reads the injected clock for every token it is handed: between the first
+    copy of launch N (its emission follows) and launch N+1's ``enqueued``
+    read no consumer runs, although N woke one; it runs before N+1's
+    ``ready`` read."""
+    # a first-use compile inside the dispatch must not release the loop early
+    monkeypatch.setattr(engine_mod, "_ENQUEUED_WAIT_S", 600.0)
+    engine = _engine(kind, pipeline_depth=1)
+    ragged = _ragged_spy(engine)
+    tape = Tape(engine)
+    readies = {}
+    ready = engine._cycle.ready
+
+    def on_ready(seq, at=None):
+        readies[seq] = ready(seq, at)
+        return readies[seq]
+
+    engine._cycle.ready = on_ready
+    handed = []
+
+    async def go():
+        async def one(prompt, n):
+            req = GenRequest(prompt_ids=list(prompt), max_new_tokens=n)
+            async for _ in engine.generate(req):
+                handed.append(engine_mod._clock())
+
+        await asyncio.gather(*(one(p, n) for p, n in zip(PROMPTS, (48, 6, 6, 6))))
+        await engine.wait_drained()
+
+    asyncio.run(go())
+    stamps = {e["seq"]: e["stamps"] for e in tape.launches}
+    order = [e["seq"] for e in tape.launches]
+    served = pairs = 0
+    for before, seq in zip(order, order[1:]):
+        if before not in ragged or seq not in ragged:
+            continue
+        pairs += 1
+        enqueued = stamps[seq][2]
+        # the loop stood still from the last emission to this stamp ...
+        assert not [t for t in handed if readies[before] < t < enqueued]
+        # ... and what that emission woke ran while this launch did
+        served += bool([t for t in handed if enqueued < t < readies[seq]])
+    assert pairs >= 8 and served >= 6
+    stats = engine.lifecycle_stats()["ragged"]
+    assert 0 < stats["waits_off_loop"] <= stats["steps"] == len(ragged)
+    engine.stop()
+
+
+def test_a_step_without_a_plan_still_yields(kind, monkeypatch):
+    """An iteration whose ragged step launched nothing hands the event loop
+    over with ``sleep(0)`` (the loop cannot spin); one that awaited its
+    worker does not (the handlers ran inside its launch)."""
+    engine = _engine(kind, pipeline_depth=1)
+    iterations, prepare = _iterations(engine, monkeypatch), engine._prepare_ragged
+
+    def on_prepare(mask, epoch):
+        plan = prepare(mask, epoch)
+        iterations[-1].append("none" if plan is None else "plan")
+        return plan
+
+    engine._prepare_ragged = on_prepare
+    # the lone job is shed as it asks for budget: nothing is left to launch
+    faults.configure([{"point": "engine.admit.budget", "action": "raise",
+                       "times": 1}])
+    try:
+        with pytest.raises(EngineOverloadedError):
+            _run(engine, PROMPTS[:1])
+    finally:
+        faults.clear()
+    _run(engine, PROMPTS[1:3])
+    assert ["none", "sleep0"] in iterations
+    planned = [it for it in iterations if "plan" in it]
+    assert len(planned) >= 4 and all(it == ["plan"] for it in planned)
+    engine.stop()
+
+
+@pytest.mark.parametrize("armed", [False, True])
+def test_a_drain_that_awaited_its_worker_does_not_yield_again(armed, monkeypatch):
+    """Pages, depth 2: a prompt that arrives while chunks are in flight makes
+    the loop retire them first; such a retire is serial (nothing is queued
+    behind it), so where its readback awaited a worker the iteration ends
+    without ``sleep(0)``, and where the chunk had already landed it sleeps.
+    With any fault armed no readback takes the short cut: every drain awaits."""
+    cfg, cache = KINDS["paged"]
+    bundle = models.build_model("llama", cfg)
+    engine = LLMEngineCore(
+        bundle, bundle.init(jax.random.PRNGKey(0)), max_batch=2, max_seq_len=128,
+        eos_token_id=None, decode_steps=4, step_token_budget=16,
+        pipeline_depth=2, **cache)
+    iterations, retire = _iterations(engine, monkeypatch), engine._retire_oldest
+
+    async def on_retire():
+        drains = bool(engine._prefill_jobs)     # called from the ragged phase
+        awaited = await retire()
+        if drains:
+            iterations[-1].append(("drain", awaited))
+        return awaited
+
+    engine._retire_oldest = on_retire
+
+    async def go():
+        async def one(prompt, n):
+            req = GenRequest(prompt_ids=list(prompt), max_new_tokens=n)
+            return [t async for t in engine.generate(req)]
+
+        await asyncio.gather(*(one(p, n) for p, n in zip(PROMPTS, (64, 6, 6, 6))))
+        await engine.wait_drained()
+
+    if armed:       # a seam that fires once the engine has drained: inert here
+        faults.configure([{"point": "engine.drain", "action": "delay"}])
+    try:
+        asyncio.run(go())
+    finally:
+        faults.clear()
+    drained = [it for it in iterations if any(isinstance(e, tuple) for e in it)]
+    assert len(drained) >= 2
+    for it in drained:
+        (_, awaited), = [e for e in it if isinstance(e, tuple)]
+        assert ("sleep0" in it) == (not awaited)
+        assert awaited or not armed
+    engine.stop()
+
+
+class _Result:
+    """A stand-in for a launch's result on the device: says whether it has
+    landed and notes the thread that copies it back."""
+
+    def __init__(self, name, landed, log):
+        self.name, self.landed, self.log = name, landed, log
+
+    def is_ready(self):
+        return self.landed
+
+    def copy_to_host_async(self):
+        self.log.append(("ask " + self.name, threading.get_ident()))
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append((self.name, threading.get_ident()))
+        return np.zeros(2, np.int32)
+
+
+@pytest.mark.parametrize("landed", [False, True])
+def test_the_readback_leaves_the_loop_thread_unless_the_launch_has_landed(
+        kind, landed):
+    """One readback for both steps: a worker waits for the first result and
+    copies the rest where the launch is still out (the loop awaits it), the
+    loop thread copies itself where it has already landed; every copy is
+    asked for (``copy_to_host_async``) on the loop thread before the first is
+    waited for, so they overlap behind the launch; the pytree of the rest
+    comes back in its shape, None where nothing was asked for."""
+    engine = _engine(kind)
+    log = []
+    first = _Result("first", landed, log)
+    rest = {"lp": (_Result("a", landed, log), _Result("b", landed, log)),
+            "gstate": None}
+
+    async def go():
+        engine._cycle.top(1)
+        return await engine._read_back(1, first, rest)
+
+    head, tail, ready_at, awaited = asyncio.run(go())
+    assert awaited is (not landed)
+    assert [name for name, _ in log] == [
+        "ask first", "ask a", "ask b", "first", "a", "b"]
+    assert {thread for _, thread in log[:3]} == {threading.get_ident()}
+    threads = {thread for _, thread in log[3:]}
+    assert len(threads) == 1
+    assert (threads == {threading.get_ident()}) is landed
+    assert isinstance(head, np.ndarray) and tail["gstate"] is None
+    assert [type(x) for x in tail["lp"]] == [np.ndarray, np.ndarray]
+    assert engine._cycle._ready == (1, ready_at)
     engine.stop()
 
 

@@ -7,6 +7,7 @@ mid-ragged-dispatch."""
 import asyncio
 
 import jax
+import numpy as np
 import pytest
 
 from clearml_serving_tpu import models
@@ -501,6 +502,10 @@ def test_chaos_retire_fault_mid_multistep_window(parts, monkeypatch):
     real_retire = engine._retire_ragged
 
     def spy(plan, result):
+        # the retire is handed HOST copies: the step's read worker waited
+        # for the launch and copied its results off the loop thread
+        assert isinstance(result["sampled"], np.ndarray)
+        assert result["ready_at"] >= result["stamps"][2]
         if not seen:
             for slot, request in enumerate(engine._slot_req):
                 if request is not None and marker in request.prompt_ids:

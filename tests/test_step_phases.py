@@ -231,7 +231,9 @@ def test_phases_are_annotations_on_the_host_plane(parts, tmp_path):
         launch, dispatch = spans["engine.launch"], spans["engine.dispatch"]
         assert launch[0] <= dispatch[0] and dispatch[1] <= launch[1]
         # the worker's uploads, then its jitted call, inside its dispatch;
-        # the copies after the first inside the loop's wait
+        # the copies after the first (the read worker's for a ragged step,
+        # the retire's for a chunk) inside the loop's wait, which stays
+        # open on the loop thread across the await
         upload, enqueue = spans["engine.upload"], spans["engine.enqueue"]
         assert dispatch[0] <= upload[0] <= upload[1] <= enqueue[0]
         assert enqueue[1] <= dispatch[1]
