@@ -119,13 +119,11 @@ WARMUP_COVERED: FrozenSet[str] = frozenset({
     "_merge_rows_jit",
     "_decode_chunk_jit",
     "_decode_paged_chunk_jit",
-    "_sample_jit",
-    "_first_lp_jit",
+    "_first_token_jit",
     "_set_sampling_row_jit",
     "_spec_chunk_jit",
     "_ragged_paged_jit",
     "_ragged_state_jit",
-    "_gather_finish_jit",
     "_ragged_unpack_jit",
 })
 

@@ -117,6 +117,9 @@ class GenRequest:
     # engine-internal: combined-table DFA state after the first token
     _gstate0: int = -1
     _guided_key: Optional[str] = None
+    # engine-internal (legacy admission): the (bias, prompt mask) rows the
+    # worker staged for the first token, for the commit's device-row reset
+    _extras_rows: Optional[tuple] = None
     # filled by the engine:
     out_queue: "asyncio.Queue" = field(default_factory=asyncio.Queue)
     produced: int = 0
@@ -668,6 +671,32 @@ def _unpack_ragged_operands(staged, layout):
     return out
 
 
+# what the first-token program is told of its row, one int32 word each; the
+# last five cross as the bits of a float32
+_FIRST_TOKEN_WORDS = ("slot", "seed", "top_k", "min_new", "guided")
+_FIRST_TOKEN_FLOATS = (
+    "temperature", "top_p", "presence", "frequency", "repetition",
+)
+
+
+def _first_token_layout(vocab: int) -> tuple:
+    """The staging buffer of the first-token program
+    (docs/ragged_attention.md, "The first token"): the row's words, its
+    stop set, its bias row (float32 bits) and its prompt and grammar masks
+    (one bit a token, 32 to the word, little end first)."""
+    words = -(-vocab // 32)
+    return _staging_layout(
+        [(name, (1,), False)
+         for name in _FIRST_TOKEN_WORDS + _FIRST_TOKEN_FLOATS]
+        + [
+            ("stop", (1, _STOP_SLOTS), False),
+            ("bias", (1, vocab), False),
+            ("pmask", (1, words), False),
+            ("gmask", (1, words), False),
+        ]
+    )
+
+
 # ragged scheduler (docs/ragged_attention.md): stage-3 brownout shrinks the
 # per-step admission share to roughly one minimal chunk instead of the
 # legacy gate's one-segment-per-chunk budget
@@ -1155,10 +1184,10 @@ class LLMEngineCore:
             "_prefill_chunk_first_jit", "_prefill_chunk_jit",
             "_assemble_prefix_jit", "_insert_jit",
             "_merge_rows_jit", "_decode_chunk_jit",
-            "_decode_paged_chunk_jit", "_sample_jit", "_first_lp_jit",
+            "_decode_paged_chunk_jit", "_first_token_jit",
             "_set_sampling_row_jit", "_spec_chunk_jit",
             "_ragged_paged_jit", "_ragged_state_jit",
-            "_gather_finish_jit", "_ragged_unpack_jit",
+            "_ragged_unpack_jit",
         ),
         # prompt scoring runs only for completions echo+logprobs requests:
         # one compile per prefill bucket on first use, sentry-attributed
@@ -1851,6 +1880,8 @@ class LLMEngineCore:
             # copied while the event loop ran (under ragged_steps by the
             # launches that had already run when the loop looked)
             "ragged_waits_off_loop": 0,
+            "ragged_first_tokens": 0,
+            "ragged_first_tokens_behind_launch": 0,
             # rows x decode passes over paged KV (the chained passes of a
             # ragged launch, the passes of a decode chunk) and the tokens
             # those rows attended there: the paged decode kernel's work
@@ -2420,9 +2451,74 @@ class LLMEngineCore:
 
         self._score_prompt_jit = jax.jit(_score_prompt)
 
-        self._first_lp_jit = jax.jit(
-            lambda logits, chosen: _lp_of(logits, chosen, logits.shape[0])
+        def _first_token(logits, staged, layout, key, state, keyed):
+            """A prompt's FIRST token (docs/ragged_attention.md, "The first
+            token"), from the logits of the launch that carried its last
+            chunk (``logits`` [R, V]: the row is picked on the device) and
+            ONE staged host buffer (``_first_token_layout``): the
+            grammar's start mask, then the sampler over this one row, with
+            the request's bias / penalties / ``min_tokens`` / seed where
+            it has any (``keyed``, static: the two traces the admission
+            sampler always had), and the log-probabilities of what was
+            sampled from. ``state`` (the slots' [B, V] counts / bias /
+            prompt-mask rows, or None while no request has needed them)
+            takes the slot's reset from the sampled id as a device value."""
+            ops = _unpack_ragged_operands(staged, layout)
+            vocab = ops["bias"].shape[1]
+            slot = ops["slot"][0]
+
+            def f32(name):
+                return jax.lax.bitcast_convert_type(ops[name], jnp.float32)
+
+            def bits(name):
+                words = jax.lax.bitcast_convert_type(ops[name], jnp.uint32)
+                lanes = (
+                    words[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
+                ) & 1
+                return lanes.reshape(1, -1)[:, :vocab] > 0
+
+            row = jax.lax.dynamic_index_in_dim(logits, slot, keepdims=True)
+            logits32 = jnp.where(
+                (ops["guided"] == 0)[:, None] | bits("gmask"),
+                row.astype(jnp.float32), jnp.float32(-1e30),
+            )
+            sampling = SamplingParams(
+                f32("temperature"), ops["top_k"], f32("top_p")
+            )
+            bias, prompt = f32("bias"), bits("pmask")
+            lp_src = logits32
+            if keyed:
+                extras = SamplingExtras(
+                    presence=f32("presence"), frequency=f32("frequency"),
+                    repetition=f32("repetition"), bias=bias,
+                    seeds=ops["seed"], counters=jnp.zeros((1,), jnp.int32),
+                    min_new=ops["min_new"], stop=ops["stop"],
+                )
+                counts0 = jnp.zeros((1, vocab), jnp.int32)
+                first = sample_tokens(
+                    logits32, sampling, key, extras, counts0, prompt
+                )
+                # reported logprobs reflect bias/penalties (OpenAI
+                # semantics); XLA CSEs this against the sampler's own pass
+                lp_src = penalize_logits(logits32, extras, counts0, prompt)
+            else:
+                first = sample_tokens(logits32, sampling, key)
+            lp = _lp_of(lp_src, first, 1)
+            if state is not None:
+                counts, bias_rows, pmask_rows = state
+                # the slot's histogram restarts at the prefill-sampled
+                # token (it IS generated output for penalty purposes)
+                state = (
+                    counts.at[slot].set(0).at[slot, first[0]].set(1),
+                    bias_rows.at[slot].set(bias[0]),
+                    pmask_rows.at[slot].set(prompt[0]),
+                )
+            return first, lp, state
+
+        self._first_token_jit = jax.jit(
+            _first_token, static_argnums=(2, 5), donate_argnums=(4,)
         )
+        self._first_layout = _first_token_layout(self._vocab)
 
         # -- n-gram speculative decoding (per-slot; dense or paged cache) --
         # Fully on-device draft-and-verify: each scan round proposes spec_k
@@ -2753,8 +2849,6 @@ class LLMEngineCore:
             donate_argnums=(2, 3, 4, 5) if self._paged_quant else (2, 3),
             static_argnames=("want_lp",),
         )
-        self._sample_jit = sample_tokens
-
         # -- ragged mixed prefill+decode step (docs/ragged_attention.md) ---
         # ONE launch per loop iteration: every decode row advances one token
         # while prefill rows process budget-bounded prompt chunks, all
@@ -3195,15 +3289,6 @@ class LLMEngineCore:
                     self._ragged_items,
                 )
 
-            def _gather_finish_logits(logits, rows):
-                # only the FINISHING admission rows' logits leave the
-                # device: retire used to read back the full [R, vocab]
-                # matrix every step that completed a job (8B: R x 128k
-                # f32), when it only ever consumes the finishing rows —
-                # row lists pad to a power of two so traces stay bounded
-                return logits[rows]
-
-            self._gather_finish_jit = jax.jit(_gather_finish_logits)
             # a launch's host vectors cross in ONE staged buffer; its
             # layout per step variant is static (docs/ragged_attention.md)
             self._ragged_unpack_jit = jax.jit(
@@ -3766,41 +3851,128 @@ class LLMEngineCore:
         )
 
     def _bias_pmask_rows(self, request: GenRequest):
+        """The request's dense bias row [V] float32 and prompt mask [V]
+        bool, as the slots' device state holds them."""
         bias = np.zeros(self._vocab, np.float32)
         if request.logit_bias:
-            for tok, bv in request.logit_bias.items():
-                tok = int(tok)
-                if 0 <= tok < self._vocab:
-                    bias[tok] = float(bv)
+            toks = np.fromiter(
+                (int(t) for t in request.logit_bias), np.int64,
+                len(request.logit_bias),
+            )
+            vals = np.fromiter(
+                (float(v) for v in request.logit_bias.values()), np.float32,
+                len(request.logit_bias),
+            )
+            ok = (toks >= 0) & (toks < self._vocab)
+            bias[toks[ok]] = vals[ok]
         pmask = np.zeros(self._vocab, bool)
-        ids = [t for t in request.prompt_ids if 0 <= t < self._vocab]
-        pmask[ids] = True
+        ids = np.asarray(request.prompt_ids, np.int64)
+        pmask[ids[(ids >= 0) & (ids < self._vocab)]] = True
         return bias, pmask
 
-    def _request_extras_row(self, request: GenRequest):
-        """Single-row extras for admission (first-token) sampling."""
+    def _first_token_ops(self, request: GenRequest) -> dict:
+        """What a request's first token needs of the host beside its
+        logits, taken when the launch that ends its prompt is planned (the
+        ragged plan, on the loop thread) or when its prefill returned (the
+        legacy admission worker): its key of the shared stream, and its
+        grammar's entry with the start state's mask row."""
+        gentry = grow = None
+        if request.guided is not None:
+            # compile/register the grammar (slow part; on the legacy path
+            # we're in the admission worker thread — the ragged path
+            # compiled it there already and only refetches its entry) and
+            # constrain the FIRST token — subsequent tokens are constrained
+            # inside the decode scan
+            if request._guided_key is not None:
+                with self._guided_lock:
+                    gentry = self._grammars.get(request._guided_key)
+            if gentry is None:
+                gentry = self._ensure_grammar(request)
+            with self._guided_lock:
+                grow = self._gmask_np[gentry["start"]]
+        return {
+            "request": request, "key": self._next_rng(),
+            "gentry": gentry, "grow": grow,
+        }
+
+    def _sample_first_token(self, op: dict, slot: int, logits, state):
+        """ONE dispatch and ONE transfer for a prompt's first token: the
+        request's sampling settings, stop set, bias row and prompt /
+        grammar masks go into one fresh int32 buffer (never written
+        again: the device array may alias it), which crosses as the
+        argument of the first-token program over ``logits[slot]``
+        (``_first_token_jit``). Worker thread; nothing here waits for the
+        device. Returns the program's (ids, logprob triple, state) and the
+        request's (bias, prompt mask) rows, for a caller that resets the
+        slot's device rows itself."""
+        request = op["request"]
+        layout, total = self._first_layout
+        staged = np.zeros(total, np.int32)
+        at = {
+            name: staged[offset : offset + math.prod(shape)].reshape(shape)
+            for name, offset, shape, _ in layout
+        }
+        at["slot"][0] = slot
+        at["seed"][0] = (
+            -1 if request.seed is None else int(request.seed) & 0x7FFFFFFF
+        )
+        at["top_k"][0] = request.top_k
+        at["min_new"][0] = min(
+            max(0, int(request.min_tokens or 0)), 2**31 - 1
+        )
+        at["stop"][0] = self._request_stop_row(request)
+        for name, value in (
+            ("temperature", request.temperature),
+            ("top_p", request.top_p),
+            ("presence", request.presence_penalty),
+            ("frequency", request.frequency_penalty),
+            ("repetition", request.repetition_penalty or 1.0),
+        ):
+            at[name].view(np.float32)[0] = value
         bias, pmask = self._bias_pmask_rows(request)
-        seed = -1 if request.seed is None else int(request.seed) & 0x7FFFFFFF
-        extras = SamplingExtras(
-            presence=jnp.asarray([request.presence_penalty], jnp.float32),
-            frequency=jnp.asarray([request.frequency_penalty], jnp.float32),
-            repetition=jnp.asarray(
-                [request.repetition_penalty or 1.0], jnp.float32
-            ),
-            bias=jnp.asarray(bias[None]),
-            seeds=jnp.asarray([seed], jnp.int32),
-            counters=jnp.zeros((1,), jnp.int32),
-            min_new=jnp.asarray(
-                [min(max(0, int(request.min_tokens or 0)), 2**31 - 1)],
-                jnp.int32,
-            ),
-            stop=jnp.asarray(self._request_stop_row(request)[None]),
+        at["bias"].view(np.float32)[0] = bias
+        packed = np.packbits(pmask, bitorder="little")
+        at["pmask"].view(np.uint8)[0, : packed.size] = packed
+        if op["grow"] is not None:
+            at["guided"][0] = 1
+            at["gmask"].view(np.uint8)[0, : op["grow"].size] = op["grow"]
+        out = self._first_token_jit(
+            logits, staged, layout, op["key"], state,
+            self._request_has_extras(request),
         )
-        return (
-            extras,
-            jnp.zeros((1, self._vocab), jnp.int32),
-            jnp.asarray(pmask[None]),
-        )
+        return out, (bias, pmask)
+
+    def _first_token_commit(self, op: dict, first):
+        """Host half of a first token, over the HOST copies of the
+        program's results (``first``: the id [1], then the logprob
+        triple): the guided DFA's walk over the sampled token's bytes and
+        the first logprob entry. Returns (first_id, first_lp)."""
+        request, gentry = op["request"], op["gentry"]
+        ids, (chosen, top_id, top_lp) = first
+        first_id = int(ids[0])
+        if gentry is not None:
+            # host-side byte walk for the first token's state advance
+            if first_id == self.eos_token_id:
+                request._gstate0 = gentry["terminal"]
+            else:
+                s = gentry["start"]
+                with self._guided_lock:
+                    byte_np = self._gbyte_np
+                    tb, tl = self._gtok_np
+                for b in tb[first_id][: int(tl[first_id])]:
+                    s = int(byte_np[s, int(b)])
+                    if s < 0:
+                        break
+                request._gstate0 = s
+        first_lp = None
+        if request.logprobs is not None:
+            first_lp = {
+                "id": first_id,
+                "logprob": float(chosen[0]),
+                "top_ids": top_id[0].tolist(),
+                "top_logprobs": top_lp[0].tolist(),
+            }
+        return first_id, first_lp
 
     def check_admission(self, request: GenRequest, reserve: int = 0) -> None:
         """Load shedding: raise a structured 429/503 error instead of
@@ -4871,6 +5043,13 @@ class LLMEngineCore:
                     "steps": self.counters["ragged_steps"],
                     # of those, the launches a worker thread waited out
                     "waits_off_loop": self.counters["ragged_waits_off_loop"],
+                    # prompts whose first token a ragged retire committed,
+                    # and of those the ones whose id came back with the
+                    # launch's own copies (sampled behind it)
+                    "first_tokens": self.counters["ragged_first_tokens"],
+                    "first_tokens_behind_launch": self.counters[
+                        "ragged_first_tokens_behind_launch"
+                    ],
                     "budget_utilization": self._hist_budget.snapshot(),
                     "step_rows": dict(self._step_rows),
                     # multi-step decode rows + spec-as-row
@@ -5561,71 +5740,20 @@ class LLMEngineCore:
         return first_id, mini_cache, first_lp
 
     def _first_token_from_logits(self, request: GenRequest, last_logits):
-        """Sample a request's FIRST token from its prefill logits [1, V]:
-        grammar-constrain, apply the request's sampling extras, walk the
-        guided DFA host-side, and build the first logprob entry. Shared by
-        the legacy admission worker (_prefill_device) and the ragged
-        scheduler's finishing-chunk commit — the two paths sampling through
-        ONE function is what makes their first tokens byte-identical."""
-        sp = SamplingParams(
-            temperature=jnp.asarray([request.temperature], jnp.float32),
-            top_k=jnp.asarray([request.top_k], jnp.int32),
-            top_p=jnp.asarray([request.top_p], jnp.float32),
+        """The legacy admission worker's first token, from its prefill
+        logits [1, V]: the ragged scheduler's first-token program
+        (``_first_token_jit``) over that one row, and the same host half —
+        the two paths sampling through ONE program is what makes their
+        first tokens byte-identical. The slot's device rows are reset at
+        the commit (``_commit_admission``: the state belongs to the loop
+        thread's chunks), from the rows staged here."""
+        op = self._first_token_ops(request)
+        (ids, lp, _), request._extras_rows = self._sample_first_token(
+            op, 0, last_logits, None
         )
-        logits32 = last_logits.astype(jnp.float32)
-        gentry = None
-        if request.guided is not None:
-            # compile/register the grammar (slow part; on the legacy path
-            # we're in the admission worker thread — the ragged path
-            # compiled it there already and only refetches its entry) and
-            # constrain the FIRST token here — subsequent tokens are
-            # constrained inside the decode scan
-            if request._guided_key is not None:
-                with self._guided_lock:
-                    gentry = self._grammars.get(request._guided_key)
-            if gentry is None:
-                gentry = self._ensure_grammar(request)
-            row = self._gmask_np[gentry["start"]]
-            allowed = np.unpackbits(row, bitorder="little")[: self._vocab] > 0
-            logits32 = jnp.where(
-                jnp.asarray(allowed)[None, :], logits32, jnp.float32(-1e30)
-            )
-        lp_src = logits32
-        if self._request_has_extras(request):
-            extras, counts0, pmask0 = self._request_extras_row(request)
-            first = self._sample_jit(
-                logits32, sp, self._next_rng(), extras, counts0, pmask0
-            )
-            if request.logprobs is not None:
-                # reported logprobs reflect bias/penalties (OpenAI semantics)
-                lp_src = penalize_logits(logits32, extras, counts0, pmask0)
-        else:
-            first = self._sample_jit(logits32, sp, self._next_rng())
-        first_id = int(np.asarray(first)[0])
-        if gentry is not None:
-            # host-side byte walk for the first token's state advance
-            if first_id == self.eos_token_id:
-                request._gstate0 = gentry["terminal"]
-            else:
-                s = gentry["start"]
-                with self._guided_lock:
-                    byte_np = self._gbyte_np
-                    tb, tl = self._gtok_np
-                for b in tb[first_id][: int(tl[first_id])]:
-                    s = int(byte_np[s, int(b)])
-                    if s < 0:
-                        break
-                request._gstate0 = s
-        first_lp = None
-        if request.logprobs is not None:
-            chosen, tid, tlp = self._first_lp_jit(lp_src, first)
-            first_lp = {
-                "id": first_id,
-                "logprob": float(np.asarray(chosen)[0]),
-                "top_ids": np.asarray(tid)[0].tolist(),
-                "top_logprobs": np.asarray(tlp)[0].tolist(),
-            }
-        return first_id, first_lp
+        return self._first_token_commit(
+            op, jax.tree.map(np.asarray, (ids, lp))
+        )
 
     def _prefix_bucket(self, prefix_len: int, n_tokens: int) -> Optional[int]:
         """Mini-cache bucket covering the prefix plus the tail's segment
@@ -5738,6 +5866,25 @@ class LLMEngineCore:
             jnp.asarray(len(request.prompt_ids), jnp.int32),  # tpuserve: ignore[TPU601] a scalar operand (the row's length), never a shape
             slot,
         )
+        (bias_row, pmask_row), request._extras_rows = request._extras_rows, None
+        if self._request_has_extras(request) or self._counts_dev is not None:
+            # the [B, V] state exists as soon as anyone needs it; rows must
+            # then be reset on EVERY admission (stale bias/mask from a
+            # previous occupant would leak into this request)
+            self._ensure_extras_state()
+            (
+                self._counts_dev,
+                self._bias_dev,
+                self._pmask_dev,
+            ) = self._set_sampling_row_jit(
+                self._counts_dev,
+                self._bias_dev,
+                self._pmask_dev,
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(first_id, jnp.int32),
+                jnp.asarray(bias_row),
+                jnp.asarray(pmask_row),
+            )
         self._activate_slot(request, slot, first_id, first_lp)
 
     def _activate_slot(self, request: GenRequest, slot: int, first_id: int,
@@ -5746,7 +5893,10 @@ class LLMEngineCore:
         scheduler's finishing-chunk commit (whose KV is already in place —
         it was written slot-resident, chunk by chunk): per-slot sampling /
         extras / guided mirrors, admission bookkeeping, and the first
-        token's emission."""
+        token's emission. Host bookkeeping only: the slot's device rows
+        (counts / bias / prompt mask) were reset by whoever sampled the
+        first token's id onto them (the ragged launch's first-token
+        program, the legacy ``_commit_admission``)."""
         self._slot_req[slot] = request
         # admission-drain bookkeeping: the Retry-After hint derives from the
         # rate these commits land at
@@ -5790,27 +5940,7 @@ class LLMEngineCore:
         self._sampling_dev = None
         self._extras_dev = None
         self._slot_overrides[slot] = True
-        has_extras = self._request_has_extras(request)
-        self._slot_extra[slot] = has_extras
-        if has_extras or self._counts_dev is not None:
-            # the [B, V] state exists as soon as anyone needs it; rows must
-            # then be reset on EVERY admission (stale bias/mask from a
-            # previous occupant would leak into this request)
-            self._ensure_extras_state()
-            bias_row, pmask_row = self._bias_pmask_rows(request)
-            (
-                self._counts_dev,
-                self._bias_dev,
-                self._pmask_dev,
-            ) = self._set_sampling_row_jit(
-                self._counts_dev,
-                self._bias_dev,
-                self._pmask_dev,
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(first_id, jnp.int32),
-                jnp.asarray(bias_row),
-                jnp.asarray(pmask_row),
-            )
+        self._slot_extra[slot] = self._request_has_extras(request)
         self._emit(slot, first_id, first_lp)
 
     async def _admission_task(self, request: GenRequest, slot: int) -> None:
@@ -6657,6 +6787,21 @@ class LLMEngineCore:
                 row_lens, self._ragged_tile, total=self._ragged_items
             )
             plan.update(item_rows=item_rows, item_q0=item_q0)
+        # the finishing rows' first tokens are sampled behind this launch
+        # (_enqueue_first_token): each row's key of the shared stream, in
+        # the order of the commits (this launch's keys are taken above;
+        # the next launch's come after these)
+        plan["first_ops"] = {
+            job.slot: self._first_token_ops(job.request)
+            for job, _ in shares if job.slot in plan["finish_slots"]
+        }
+        if self._counts_dev is None and any(
+            self._request_has_extras(op["request"])
+            for op in plan["first_ops"].values()
+        ):
+            # the [B, V] state exists as soon as anyone needs it; the
+            # program then resets the rows of EVERY finishing slot
+            self._ensure_extras_state()
         if faults.active():
             # yield-point seam parity with _prepare_dispatch: snapshot
             # complete, worker not yet started
@@ -6974,28 +7119,48 @@ class LLMEngineCore:
                 cache.advance(slot, n)
         if use_extras:
             self._counts_dev = new_counts
-        # finishing-row logit gather: keep only rows whose admission
-        # completes this step (minus any the pool-exhaustion path dropped)
-        # — the [R, vocab] matrix never crosses the device boundary
+        # the rows whose admission completes this step (minus any the
+        # pool-exhaustion path dropped): their first tokens are sampled
+        # right here, behind the launch — the [R, vocab] matrix never
+        # crosses the device boundary, and neither does a row of it
         finish = [s for s in plan["finish_slots"] if s in plan["spans"]]
-        if finish:
-            pad = 1 << (len(finish) - 1).bit_length()
-            rows = np.zeros(pad, np.int32)
-            rows[: len(finish)] = finish
-            logits = self._gather_finish_jit(logits, jnp.asarray(rows))
-        else:
-            logits = None
+        first = [
+            self._enqueue_first_token(plan["first_ops"][slot], slot, logits)
+            for slot in finish
+        ]
         self._last_progress = time.monotonic()
         return {
             "stamps": self._worker_out(stamps),
             "sampled": sampled,
-            "logits": logits,
+            "first": first,
             "lp": lp,
             "gstate": gstate_out if gtables is not None else None,
             "finish_rows": finish,
             "spec_g": spec_g,
             "spec_acc": spec_acc,
         }
+
+    def _enqueue_first_token(self, op: dict, slot: int, logits):
+        """Worker thread, right behind the launch: the program that
+        samples a finishing prompt's first token from its row of the
+        launch's logits and resets the slot's counts / bias / prompt-mask
+        rows (``_sample_first_token``). The chip is still running the
+        launch, so nothing here waits for it; the id and the logprob
+        triple are read back with the launch's own copies
+        (``_read_back``). A row a launch (two or more prompts end in under
+        2% of the finishing launches) keeps the program at ONE shape: its
+        sampler holds a whole-vocabulary sort, 16 s of the v5e compiler a
+        variant."""
+        state = (
+            None if self._counts_dev is None
+            else (self._counts_dev, self._bias_dev, self._pmask_dev)
+        )
+        (ids, lp, state), _rows = self._sample_first_token(
+            op, slot, logits, state
+        )
+        if state is not None:
+            self._counts_dev, self._bias_dev, self._pmask_dev = state
+        return ids, lp
 
     async def _ragged_step(self, active_mask: np.ndarray, epoch: int) -> bool:
         """One ragged scheduling iteration (docs/ragged_attention.md): ONE
@@ -7065,7 +7230,7 @@ class LLMEngineCore:
                 result["sampled"],
                 {
                     name: result[name]
-                    for name in ("gstate", "lp", "spec_acc", "spec_g", "logits")
+                    for name in ("gstate", "lp", "spec_acc", "spec_g", "first")
                 },
             )
             result = dict(
@@ -7158,8 +7323,9 @@ class LLMEngineCore:
         stop and drops the surplus; the q=1 path simply stopped
         launching), a spec-verify row emits its accepted chain after the
         pool rolls its over-allocation back to what the verify kept, and
-        finishing prefill jobs sample their first token (the legacy
-        admission code path) and activate their slot."""
+        finishing prefill jobs commit the first token that was sampled
+        behind the launch (_enqueue_first_token) and activate their slot:
+        host bookkeeping, no device call."""
         seq = plan["seq"]
         self._cycle.mark("emit", seq)
         sampled, gstate_np, lp_np = (
@@ -7392,11 +7558,14 @@ class LLMEngineCore:
                 request.out_queue.put_nowait(_FINISHED)
                 self._free_ragged_slot(job.slot)
                 continue
-            # [F, vocab]: only the finishing rows were read back
-            row = result["finish_rows"].index(job.slot)
-            first_id, first_lp = self._first_token_from_logits(
-                request, jnp.asarray(result["logits"][row][None])
+            # sampled behind the launch; its id and logprob entry came
+            # back with the launch's copies: host bookkeeping from here
+            first_id, first_lp = self._first_token_commit(
+                plan["first_ops"][job.slot],
+                result["first"][result["finish_rows"].index(job.slot)],
             )
+            self.counters["ragged_first_tokens"] += 1
+            self.counters["ragged_first_tokens_behind_launch"] += 1
             if self.cache_mode == "paged" and self._prefix is not None:
                 # zero-copy store, same point as the legacy commit: the
                 # slot's own pages now hold the whole prompt's KV

@@ -29,12 +29,13 @@ bucket; every resume-commit final-segment length 1..block per hit bucket
 bucketed sweep is the dense engine's compile surface, a ragged engine
 (paged, state) runs the same requests through the one static shape of its
 step; power-of-two CoW
-copy buckets (and, on int8 pools, their scale-row copies); the ragged
-finish-row gathers at every power of two; a spec-decode round when
-speculation is on. Coverage assumption, stated plainly: the sweep warms
-the PLAIN-SAMPLING serve surface — sampling-extras / guided / logprob
-variants trace on first use (each is one bounded compile per variant, not
-a per-request key), and the compile sentry attributes them when armed.
+copy buckets (and, on int8 pools, their scale-row copies); a spec-decode
+round when speculation is on. Coverage assumption, stated plainly: the
+sweep warms the PLAIN-SAMPLING serve surface (the first-token program,
+``_first_token_jit``, has ONE shape, and every swept request runs it) —
+sampling-extras / guided / logprob variants trace on first use (each is
+one bounded compile per variant, not a per-request key), and the compile
+sentry attributes them when armed.
 """
 
 from __future__ import annotations
@@ -58,13 +59,11 @@ WARMUP_COVERED = frozenset({
     "_merge_rows_jit",
     "_decode_chunk_jit",
     "_decode_paged_chunk_jit",
-    "_sample_jit",
-    "_first_lp_jit",
+    "_first_token_jit",
     "_set_sampling_row_jit",
     "_spec_chunk_jit",
     "_ragged_paged_jit",
     "_ragged_state_jit",
-    "_gather_finish_jit",
     "_ragged_unpack_jit",
 })
 
@@ -418,16 +417,6 @@ async def run_warmup(
                 break
             p *= 2
         cache.reap_promotions(force=True)
-
-    # ragged finish-row gather: retire reads back only finishing admission
-    # rows, padded to a power of two — warm every pad size directly
-    if full and engine._ragged and engine._gather_finish_jit is not None:
-        logits = jnp.zeros((engine.max_batch, max(engine._vocab, 8)),
-                           jnp.float32)
-        p = 1
-        while p <= engine.max_batch:
-            engine._gather_finish_jit(logits, jnp.zeros((p,), jnp.int32))
-            p *= 2
 
     # multi-step / spec-as-row ragged launch variants
     # (docs/ragged_attention.md): the per-launch decode window buckets to a

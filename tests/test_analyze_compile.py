@@ -260,10 +260,10 @@ def test_tpu603_reads_registry_from_real_warmup_py():
     src = """
         import jax
         class E:
-            __compile_keys__ = {"serve": ("_gather_finish_jit",)}
+            __compile_keys__ = {"serve": ("_first_token_jit",)}
             __shardings__ = {"params": "llama_param_sharding"}
             def __init__(self):
-                self._gather_finish_jit = jax.jit(lambda x: x)
+                self._first_token_jit = jax.jit(lambda x: x)
     """
     assert codes(src, path=LLM_PATH) == []
 

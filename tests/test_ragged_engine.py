@@ -633,19 +633,23 @@ def test_ragged_cancel_mid_admission_reclaims(parts, monkeypatch):
 
 def test_ragged_retire_reads_back_only_finishing_rows(parts, monkeypatch):
     """ISSUE-10 satellite: the retire stage must never read back the full
-    [R, vocab] logits — the dispatch worker gathers only the FINISHING
-    admission rows device-side (None when no job finishes), and the
-    streams stay byte-identical to the two-dispatch arm."""
+    [R, vocab] logits, and the streams stay byte-identical to the
+    two-dispatch arm. Since ISSUE 49 not even the finishing rows cross:
+    the dispatch worker samples each finishing prompt's first token from
+    its row ON the device, behind the launch (nothing when no job
+    finishes), and the result carries the ids and logprob triples alone."""
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     bundle, _, params = parts
-    shapes = []
+    finished, crossed = [], []
     orig = LLMEngineCore._dispatch_ragged_device
 
     def spy(self, plan):
         result = orig(self, plan)
-        shapes.append(
-            None if result["logits"] is None
-            else tuple(result["logits"].shape)
+        assert "logits" not in result
+        assert len(result["first"]) == len(result["finish_rows"])
+        finished.append(len(result["first"]))
+        crossed.extend(
+            tuple(leaf.shape) for leaf in jax.tree.leaves(result["first"])
         )
         return result
 
@@ -655,18 +659,14 @@ def test_ragged_retire_reads_back_only_finishing_rows(parts, monkeypatch):
                       ragged_kw={"pipeline_depth": 1})
     assert a == b, "streams must stay byte-identical under the gather"
     assert stats["ragged"]["steps"] >= 2
-    assert shapes, "spy never saw a ragged step"
+    assert finished, "spy never saw a ragged step"
     vocab = bundle.config["vocab_size"]
-    # most steps finish no job: nothing is read back at all
-    assert any(s is None for s in shapes)
-    finished = [s for s in shapes if s is not None]
-    assert finished, "at least one step must complete an admission"
-    for shape in finished:
-        # padded finishing-row count, never the full R=max_batch rows of
-        # a non-finishing step — with 2 jobs in this workload the padded
-        # gather is at most 2 rows
-        assert shape[1] == vocab
-        assert shape[0] <= 2
+    # most steps finish no job: nothing is sampled or read back for them
+    assert 0 in finished
+    assert sum(finished) == 2, "both admissions must complete in a step"
+    # what a finishing launch hands back: an id [1] and the logprob triple
+    # ([1], [1, K], [1, K]) a prompt, never a row of the vocabulary's size
+    assert crossed and all(vocab not in shape for shape in crossed)
 
 
 # -- the sampler does what the launch's live rows asked for (ISSUE 34) --------

@@ -342,28 +342,59 @@ def test_writing_the_plan_after_the_dispatch_changes_no_operand(paged_parts):
 @pytest.mark.parametrize("kind", ["paged", "state"])
 def test_a_second_pass_compiles_nothing(kind, paged_parts, state_parts, monkeypatch):
     """No warm-up sweep: the first pass of a stream that meets both decode
-    windows compiles each window's step and its unpack; the same stream
-    again compiles nothing (the compile sentry's count after the fence)."""
+    windows and ends two prompts builds each window's step, its unpack
+    and the first-token program behind a finishing launch; the same stream
+    again compiles nothing (the compile sentry's count after the fence).
+    Nothing here counts compile EVENTS of the first pass: a program that an
+    earlier test of this process left in jax's caches (a function's
+    programs outlive a ``jax.jit`` wrapper), or a checkout's persisted
+    ``.jax_cache`` served, is no event. What is held instead: one static
+    layout a variant (the unpack's only compile key beside the buffer's
+    length, which follows from it), and no compile of anything after the
+    fence, with the persistent cache off so that none can hide as a load."""
     monkeypatch.setenv("TPUSERVE_COMPILE_SENTRY", "1")
     sentry = compile_sentry.get()
     sentry.reset(strict=False)
-    # sizes no other engine of this module has: jax keeps a function's
-    # compiled programs across ``jax.jit`` wrappers, per static layout
+    persisted = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
     engine = (_paged(paged_parts, max_batch=3, step_token_budget=24)
               if kind == "paged" else _state(state_parts, max_batch=2))
     seen = Uploads(engine)
+    layouts, firsts = [], []
+    unpack, first_token = engine._ragged_unpack_jit, engine._first_token_jit
+
+    def unpacking(staged, layout):
+        layouts.append((staged.shape, layout))
+        return unpack(staged, layout)
+
+    def sampling(logits, staged, layout, key, state, keyed):
+        firsts.append((tuple(logits.shape), staged.shape, layout, keyed))
+        return first_token(logits, staged, layout, key, state, keyed)
+
+    engine._ragged_unpack_jit, engine._first_token_jit = unpacking, sampling
     traffic = [(SHORT, 12, {}), (LONG, 6, {})]
     try:
         first = _serve(engine, traffic)
         assert {u["key"][0] for u in seen.seen} == {1, 4}
-        unpacks = [e for e in sentry.stats()["events"]
-                   if "unpack_ragged_operands" in e["fn"]]
-        assert len(unpacks) == 2, [e["fn"] for e in sentry.stats()["events"]]
-        assert all(e["context"]["phase"] == "ragged" for e in unpacks)
+        # two variants, two unpack programs: each launch handed the unpack
+        # its variant's ONE layout object and a buffer of that length
+        assert len(set(layouts)) == 2
+        for (shape, layout), upload in zip(layouts, seen.seen):
+            want, total = engine._ragged_layouts[upload["key"]]
+            assert layout is want and shape == (total,)
+        # both prompts ended in a launch, through one first-token program
+        assert len(firsts) == 2 and len(set(firsts)) == 1
+        assert all(e["context"]["phase"] == "ragged"
+                   for e in sentry.stats()["events"]
+                   if "unpack_ragged_operands" in e["fn"]
+                   or "first_tokens" in e["fn"])
         sentry.fence()
         again = _serve(engine, traffic)
         assert again == first
+        assert len(firsts) == 4 and len(set(firsts)) == 1
+        assert len(set(layouts)) == 2
         assert sentry.post_fence_compiles == 0, sentry.stats()["events"][-5:]
     finally:
         engine.stop()
         sentry.reset(strict=False)
+        jax.config.update("jax_enable_compilation_cache", persisted)
