@@ -1673,7 +1673,7 @@ class LLMEngineCore:
             self.weight_quant = quantize
         # weight-tree HBM footprint (global bytes; per-chip is 1/tp under a
         # mesh) — the decode roofline's dominant bytes/step term, surfaced
-        # through lifecycle_stats()/health() and bench.py --int4-ab
+        # through lifecycle_stats()["weights"] and health()
         import jax as _jax
 
         self._weight_bytes = int(sum(
@@ -3477,7 +3477,7 @@ class LLMEngineCore:
         """Compile the serve loop's XLA key space ahead of traffic: drive
         the shared warmup shape registry (llm/warmup.py) against this
         engine and set the compile sentry's warmup fence when armed.
-        Endpoint startup, ``bench.py --loadtest`` and the coverage tests
+        Endpoint startup, a replica's re-admission and the coverage tests
         all run THIS sweep — one coverage-checked list."""
         from . import warmup as _warmup
 
@@ -4743,8 +4743,8 @@ class LLMEngineCore:
         prefix resident (ship HIT — it recomputes none of the shipped KV)
         or recomputes (transport drop, eviction, receive failure). The
         hit-rate gauge is the disaggregation headline
-        (engine_kv_ship_hit_rate; benchmarks/DISAGG_AB_cpu.json asserts
-        >= 0.9 on the clean path). One-shot per request."""
+        (engine_kv_ship_hit_rate; tests/test_kv_transport.py asserts
+        1.0 on the clean path). One-shot per request."""
         if not request._shipped or self._prefix is None:
             return
         request._shipped = False

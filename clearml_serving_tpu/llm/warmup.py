@@ -10,7 +10,7 @@ over the ENGINE'S OWN configuration (prefill buckets, prefix block, page
 size, scheduler), and made a registry three consumers share:
 
 - engine startup (``LLMEngineCore.warmup()``, e.g. at endpoint load),
-- ``bench.py --loadtest`` (benchmarks/slo_loadtest.py),
+- a replica's re-admission to the ring (llm/replica.py),
 - tests (the warmup-coverage suite proves a warmed engine serves in-class
   traffic with ZERO further compiles under the strict compile sentry).
 

@@ -587,8 +587,8 @@ def build(config: dict) -> SimpleNamespace:
     # route through the Pallas fused dequant-matmul — packed nibbles stream
     # HBM->VMEM and unpack next to the MXU, so the HBM weight read is
     # structurally 4-bit instead of fusion-dependent. cfg int4_fused=False
-    # pins the XLA inline-dequant path (the A/B arm bench.py measures
-    # against); misaligned shapes, prefill-sized M, and non-TPU backends
+    # pins the XLA inline-dequant path (the arm tests/test_fused_matmul.py
+    # compares); misaligned shapes, prefill-sized M, and non-TPU backends
     # take that same path, byte-identically — the decision is
     # ops.fused_matmul.int4_kernel_unsupported_reason, which the engine's
     # health block reports for its decode shapes.

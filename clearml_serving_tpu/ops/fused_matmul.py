@@ -1,6 +1,6 @@
 """w4a16 fused dequant-matmul: Pallas TPU kernel + XLA reference.
 
-Decode is weight-read bound (ROOFLINE gap #3): every step streams the whole
+Decode is weight-read bound (PERF.md section 4): every step streams the whole
 projection stack out of HBM for a handful of activation rows. int4 group
 quantization (ops/quant.py: packed ``_q4`` uint8 [K//2, N] + per-(group,
 out-channel) ``_scale4`` f32 [K//group, N]) stores those bytes at a quarter

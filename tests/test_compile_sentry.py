@@ -342,28 +342,43 @@ def test_warmup_covers_ragged_multistep_and_spec_rows(parts, monkeypatch):
         sentry.reset(strict=False)
 
 
-def test_warmup_registry_covers_all_dispatch_paths_paged(parts, monkeypatch):
+@pytest.mark.parametrize("fleet", ["one", "disagg"])
+def test_warmup_registry_covers_all_dispatch_paths_paged(
+    parts, monkeypatch, fleet
+):
     """Full coverage certification: a paged+prefix-cache engine, the FULL
     warmup sweep, then novel random-length traffic with shared prefixes
     under the STRICT fence — zero post-fence compiles, proving
-    WARMUP_COVERED means covered."""
+    WARMUP_COVERED means covered. ``disagg``: the same behind a
+    prefill/decode pair (docs/disaggregation.md): the sweep's transport
+    block has warmed the ship export and the receive import, so shipping
+    KV between the replicas compiles nothing either."""
     import random
+
+    from clearml_serving_tpu.llm.replica import ReplicaGroup
 
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
     monkeypatch.setenv("TPUSERVE_COMPILE_SENTRY", "strict")
     sentry = compile_sentry.get()
     sentry.reset(strict=True)
     bundle, params = parts
-    engine = LLMEngineCore(
-        bundle, params, max_batch=2, max_seq_len=128,
-        prefill_buckets=[32, 64], eos_token_id=None, decode_steps=1,
-        cache_mode="paged", page_size=16, chunked_prefill_size=16,
-        prefix_cache=64, prefix_block=16, num_pages=49,
-        prefix_cache_pages=16, pipeline_depth=1,
-    )
+    engines = [
+        LLMEngineCore(
+            bundle, params, replica="r{}".format(i), max_batch=2,
+            max_seq_len=128,
+            prefill_buckets=[32, 64], eos_token_id=None, decode_steps=1,
+            cache_mode="paged", page_size=16, chunked_prefill_size=16,
+            prefix_cache=64, prefix_block=16, num_pages=49,
+            prefix_cache_pages=16, pipeline_depth=1,
+        )
+        for i in range(2 if fleet == "disagg" else 1)
+    ]
+    served = engines[0]
+    if fleet == "disagg":
+        served = ReplicaGroup(engines, roles=["prefill", "decode"])
 
     async def run():
-        stats = await engine.warmup(full=True)
+        stats = await served.warmup(full=True)
         assert stats["fenced"]
         rng = random.Random(9)
         shared = [(5 * i + 3) % 250 + 1 for i in range(48)]
@@ -372,14 +387,17 @@ def test_warmup_registry_covers_all_dispatch_paths_paged(parts, monkeypatch):
             ids = [rng.randrange(1, 251) for _ in range(n)]
             if i % 3 == 0:
                 ids = (shared + ids[:10])[:120]
-            await _collect(engine, GenRequest(
+            await _collect(served, GenRequest(
                 prompt_ids=ids, max_new_tokens=3
             ))
-        await engine.wait_drained()
+        await served.wait_drained()
         assert sentry.post_fence_compiles == 0, sentry.stats()["events"][-5:]
+        if fleet == "disagg":
+            ship = engines[1]._kv_ship_snapshot()
+            assert ship["receives"] >= 1 and ship["hits"] >= 1, ship
 
     try:
         asyncio.run(run())
     finally:
-        engine.stop()
+        served.stop()
         sentry.reset(strict=False)

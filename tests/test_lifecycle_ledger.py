@@ -272,20 +272,18 @@ def _make_engine(bundle, params, **kwargs):
     return LLMEngineCore(bundle, params, **kwargs)
 
 
-def test_engine_clean_run_is_leak_free_strict(parts, monkeypatch):
+@pytest.mark.parametrize("traffic", ["plain", "preempted"])
+def test_engine_clean_run_is_leak_free_strict(parts, monkeypatch, traffic):
     """A strict-armed paged engine serves and drains with zero leaks, and
-    lifecycle_stats()/health() expose the ledger block."""
+    lifecycle_stats()/health() expose the ledger block. ``preempted``: a
+    batch request loses its only slot to an interactive one and resumes
+    from its pinned history: the preempt -> pin -> resume -> unpin round
+    trip pairs every acquire too."""
     bundle, params = parts
     monkeypatch.setenv("TPUSERVE_LEDGER", "strict")
     monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
 
-    async def run():
-        engine = _make_engine(
-            bundle, params, cache_mode="paged", page_size=16,
-            prefix_cache=64, prefix_block=16,
-        )
-        assert engine._ledger is not None, "TPUSERVE_LEDGER did not arm"
-        engine._ledger.reset(strict=True)
+    async def plain(engine):
         for seed in (1, 2, 1):
             out = await _collect(
                 engine,
@@ -293,6 +291,30 @@ def test_engine_clean_run_is_leak_free_strict(parts, monkeypatch):
                            max_new_tokens=4),
             )
             assert out
+
+    async def preempted(engine):
+        batch = GenRequest(
+            prompt_ids=[256] + [(i * 3 + 1) % 250 for i in range(16)],
+            max_new_tokens=24, priority="batch",
+        )
+        b_task = asyncio.create_task(_collect(engine, batch))
+        while batch.produced < 4:
+            await asyncio.sleep(0.005)
+        hi = GenRequest(prompt_ids=[256, 9], max_new_tokens=2)
+        assert await asyncio.wait_for(_collect(engine, hi), timeout=60)
+        assert len(await asyncio.wait_for(b_task, timeout=60)) == 24
+        assert engine.counters["preemptions"] >= 1, "no preemption happened"
+
+    async def run():
+        kw = dict(cache_mode="paged", page_size=16, prefix_cache=64,
+                  prefix_block=16)
+        if traffic == "preempted":
+            kw.update(max_batch=1, decode_steps=2, prefill_buckets=[32, 64],
+                      eos_token_id=None)
+        engine = _make_engine(bundle, params, **kw)
+        assert engine._ledger is not None, "TPUSERVE_LEDGER did not arm"
+        engine._ledger.reset(strict=True)
+        await (preempted if traffic == "preempted" else plain)(engine)
         await engine.wait_drained()
         return engine
 
@@ -305,6 +327,7 @@ def test_engine_clean_run_is_leak_free_strict(parts, monkeypatch):
                      "prefix.resume_pin", "slot.quarantine", "guided.ref"):
         assert block["outstanding"][resource] == 0, (resource, block)
     assert engine.health()["ledger"]["leaks"] == 0
+    assert engine._sanitizer.stats()["failures"] == 0
     engine.stop()
 
 

@@ -349,6 +349,18 @@ def test_int4_llama_forward_close():
     wq = qparams["layers"][0]["wq"]
     assert wq["_q4"].dtype == jnp.uint8
     assert wq["_q4"].shape[-2] == params["layers"][0]["wq"].shape[-2] // 2
+    # and its quantised leaves (values + scales, what a decode pass reads
+    # of the projections) weigh about half of the int8 tree's
+    def quant_bytes(tree):
+        names = {"_q4", "_scale4", "_q8", "_scale"}
+        return sum(
+            leaf.nbytes
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+            if names & {getattr(key, "key", None) for key in path}
+        )
+
+    ratio = quant_bytes(qparams) / quant_bytes(quantize_llama_params(params))
+    assert 0.4 <= ratio <= 0.6, ratio
     out = bundle.apply(dequant_llama_params(qparams, jnp.float32), tokens)
     denom = float(jnp.std(ref))
     drift = float(jnp.max(jnp.abs(out - ref))) / denom

@@ -78,8 +78,8 @@ def _collect(engine, req):
 def test_scan_layers_paged_engine_matches(tiny):
     """scan_layers + paged cache produce the same greedy tokens as the plain
     unrolled dense engine; and with int8 on BOTH engines (same quantized
-    weights, list vs stacked) outputs still agree — the exact configuration
-    the 8B bench runs (BENCH_QUANTIZE=int8 BENCH_SCAN_LAYERS=1)."""
+    weights, list vs stacked) outputs still agree — the configuration an 8B
+    model is served in (int8 weights with ``scan_layers``)."""
     bundle_u, params_u = tiny
     bundle_s = models.build_model(
         "llama", {"preset": "llama-tiny", "dtype": "float32", "scan_layers": True}

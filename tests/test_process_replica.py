@@ -198,12 +198,14 @@ def test_process_fleet_streams_match_inprocess_mono():
     from clearml_serving_tpu.llm.engine import LLMEngineCore
 
     prompts = [list(range(2, 22)), [7, 8, 9, 10]]
+    seeded = dict(temperature=0.8, seed=1000)
 
     async def mono_arm():
         bundle = models.build_model("llama", {"preset": "llama-tiny"})
         params = bundle.init(jax.random.PRNGKey(0))
         engine = LLMEngineCore(bundle, params, **ENGINE)
         out = [await _collect(engine, ids) for ids in prompts]
+        out.append(await _collect(engine, prompts[0], **seeded))
         await engine.wait_drained()
         engine.stop()
         return out
@@ -212,6 +214,7 @@ def test_process_fleet_streams_match_inprocess_mono():
     group = _fleet()
     try:
         got = [asyncio.run(_collect(group, ids)) for ids in prompts]
+        got.append(asyncio.run(_collect(group, prompts[0], **seeded)))
         assert got == expected
         health = group.health()
         blocks = health["replicas"]
@@ -225,12 +228,29 @@ def test_process_fleet_streams_match_inprocess_mono():
 
 
 @pytest.mark.slow
-def test_process_fleet_disagg_ships_kv_over_sockets():
+def test_process_fleet_disagg_ships_kv_over_sockets(monkeypatch):
+    """A prefill and a decode WORKER ship KV over the socket wire. The
+    workers inherit the sentries from the environment, and the parent's
+    only view of them is each worker's health block over the RPC: a clean
+    sanitizer, no leak under the strict ledger, no implicit transfer."""
+    monkeypatch.setenv("TPUSERVE_SANITIZE", "1")
+    monkeypatch.setenv("TPUSERVE_LEDGER", "strict")
+    monkeypatch.setenv("TPUSERVE_SHARD_SENTRY", "1")
     group = _fleet(roles=["prefill", "decode"])
     try:
         toks = asyncio.run(_collect(group, list(range(2, 34))))
         assert len(toks) == 6
         assert group.ship_legs >= 1 and group.ship_leg_failures == 0
+        asyncio.run(group.wait_drained())
+        blocks = [replica.engine.health() for replica in group.replicas]
+        for block in blocks:
+            assert block["sanitizer"]["checks"] > 0
+            assert block["sanitizer"]["failures"] == 0
+            assert block["ledger"]["strict"] and block["ledger"]["leaks"] == 0
+            assert block["sharding"]["implicit_transfers"] == 0
+        ship = blocks[1]["kv_ship"]
+        assert ship["receives"] >= 1 and ship["hits"] >= 1
+        assert ship["recomputes"] == 0
     finally:
         group.stop()
 
