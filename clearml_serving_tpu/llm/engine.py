@@ -791,11 +791,23 @@ def _latent_cache_refusal(bundle, *, cache_mode, mesh,
 def _state_cache_refusal(bundle, *, mesh, prefix_cache,
                          prefix_cache_host_pages, prefix_cache_host_bytes,
                          speculation, spec_tree,
-                         lora_adapters) -> Optional[str]:
-    """Why this engine cannot be built on ``engine.cache=state``, or None.
-    Every feature that assumes K/V pages is refused by name with its reason
-    (docs/state_cache.md lists what each would need)."""
-    if getattr(bundle, "forward_ragged_state", None) is None:
+                         lora_adapters, cache_mode="state") -> Optional[str]:
+    """Why this engine cannot be built over a ROW STATE, or None: on
+    ``engine.cache=state`` (a model with no keys and values at all), or for
+    a model whose rows keep a state BESIDE their K/V pages
+    (``bundle.row_state``, served from ``engine.cache=paged``:
+    docs/hybrid_cache.md). Every feature that assumes that a sequence IS its
+    pages is refused by name with its reason (docs/state_cache.md and
+    docs/hybrid_cache.md list what each would need)."""
+    beside = getattr(bundle, "row_state", None) is not None
+    if beside and cache_mode != "paged":
+        return (
+            "this model's rows own K/V pages AND a recurrent state slot: "
+            "serve it with engine.cache=paged (got engine.cache={}); it has "
+            "no dense-cache path, and engine.cache=state is for models "
+            "with no keys and values at all".format(cache_mode)
+        )
+    if not beside and getattr(bundle, "forward_ragged_state", None) is None:
         return (
             "engine.cache=state needs a model whose layers keep a recurrent "
             "state (forward_ragged_state / decode_state / init_state "
@@ -803,36 +815,48 @@ def _state_cache_refusal(bundle, *, mesh, prefix_cache,
             "model attends over keys and values: use engine.cache=paged or "
             "dense"
         )
+    what = ("a model that keeps a recurrent state beside its pages"
+            if beside else "engine.cache=state")
     if prefix_cache:
         return (
-            "prefix_cache cannot serve engine.cache=state: a cached prefix "
+            "prefix_cache cannot serve {}: a cached prefix "
             "is shared K/V pages, and a recurrent state has none; reuse "
             "would need snapshots of the state at block boundaries "
             "(RadixPrefixCache holds pages only). Set prefix_cache to 0"
+            .format(what)
         )
     if prefix_cache_host_pages or prefix_cache_host_bytes:
         return (
             "prefix_cache_host_pages / prefix_cache_host_mb (HostKVTier) "
-            "cannot serve engine.cache=state: the host tier spills the "
+            "cannot serve {}: the host tier spills the "
             "prefix cache's K/V pages, and a state cache has neither"
+            .format(what)
         )
     if bundle.config.get("kv_quant"):
         return (
-            "kv_quant cannot serve engine.cache=state: there are no keys "
+            "kv_quant cannot serve {}: there are no keys "
             "and values to quantise, and the state is float32 by the "
             "configuration (a bfloat16 or int8 state is another model)"
+            .format(what)
+            if not beside else
+            "kv_quant cannot serve {}: the scale pools have no place in a "
+            "carry that holds the state planes, and the state is float32 "
+            "by the configuration".format(what)
         )
     if speculation or spec_tree:
         return (
-            "speculation cannot serve engine.cache=state: a rejected draft "
+            "speculation cannot serve {}: a rejected draft "
             "cannot be rolled back out of a recurrent state without a "
             "snapshot of it (verify rows overwrite K/V positions; a state "
-            "has no positions)"
+            "has no positions)".format(what)
         )
     if lora_adapters:
         return (
             "lora_adapters are not served from engine.cache=state yet (the "
             "state step takes no per-row adapter index)"
+            if not beside else
+            "lora_adapters are not served by {} yet (its projections have "
+            "no adapter rows)".format(what)
         )
     if mesh is not None and mesh.size > 1:
         return (
@@ -841,6 +865,10 @@ def _state_cache_refusal(bundle, *, mesh, prefix_cache,
             "parallel prefill (prefill_ring) passes K/V blocks round a ring, "
             "which a recurrent state does not have. Serve one engine per "
             "chip".format(mesh.size)
+            if not beside else
+            "a {}-device mesh cannot serve {} yet: the state kernels have "
+            "no partitioning rule and the slot pool no sharding. Serve one "
+            "engine per chip".format(mesh.size, what)
         )
     return None
 
@@ -1398,16 +1426,22 @@ class LLMEngineCore:
                     "dense" if self._ragged else "paged or state",
                 )
             )
-        if cache_mode == "state":
+        # a model whose rows keep a recurrent state BESIDE their pages says
+        # so itself (``bundle.row_state``: docs/hybrid_cache.md): the engine
+        # then holds a slot pool next to the page pool, slot = batch row
+        self._row_state = getattr(bundle, "row_state", None)
+        if cache_mode == "state" or self._row_state is not None:
             refused = _state_cache_refusal(
                 bundle, mesh=mesh, prefix_cache=prefix_cache,
                 prefix_cache_host_pages=prefix_cache_host_pages,
                 prefix_cache_host_bytes=prefix_cache_host_bytes,
                 speculation=speculation, spec_tree=spec_tree,
-                lora_adapters=lora_adapters,
+                lora_adapters=lora_adapters, cache_mode=cache_mode,
             )
             if refused:
                 raise ValueError(refused)
+        self._ssm_counts = dict.fromkeys(
+            ("update_rows", "chunk_rows", "chunk_tokens"), 0)
         # a model's own page layout (docs/latent_cache.md): the pools'
         # planes, the kernels and the counters follow from the model
         self._latent = getattr(bundle, "paged_layout", None)
@@ -1743,6 +1777,11 @@ class LLMEngineCore:
                 // page_size
             )
             total_pages = num_pages or (self.max_batch * pages_per_slot + 1)
+            row_state = None
+            if self._row_state is not None:
+                from .kv_cache import StateCache
+
+                row_state = StateCache(bundle.init_state, self.max_batch)
             self.paged_cache = PagedKVCache(
                 bundle.n_layers, bundle.n_kv_heads, bundle.head_dim,
                 num_pages=total_pages, page_size=page_size,
@@ -1751,6 +1790,7 @@ class LLMEngineCore:
                 kv_quant=str(bundle.config.get("kv_quant") or ""),
                 layout=self._latent,
                 counters=getattr(self._window, "counters", 0),
+                row_state=row_state,
             )
             if mesh is not None:
                 # shard the pools' kv-head dim over tp (pools [L,Hkv,N,P,D]) —
@@ -1779,7 +1819,8 @@ class LLMEngineCore:
                 self._expert_counters() + 0
             if self._paged_kernel_reason is None:
                 self._check_kernel_smem()
-            self.state_cache = None
+            # None, or the slot pool whose planes ride ``v_carry``
+            self.state_cache = row_state
         elif self.cache_mode == "state":
             from .kv_cache import StateCache
 
@@ -4533,6 +4574,14 @@ class LLMEngineCore:
                 "recurrent state would have to travel as a snapshot of the "
                 "slot (docs/state_cache.md)"
             )
+        if endpoint is not None and self._row_state is not None:
+            raise ValueError(
+                "KV transport (KVShipment) cannot serve a model that keeps "
+                "a recurrent state beside its pages: a shipment is the "
+                "prefix cache's K/V pages, and the state of the tokens they "
+                "hold would have to travel with them as a snapshot of the "
+                "slot (docs/hybrid_cache.md)"
+            )
         if endpoint is not None and (
             self.cache_mode != "paged" or self._prefix is None
         ):
@@ -4831,6 +4880,20 @@ class LLMEngineCore:
             routes["ragged"] = routes["decode"]
             if paged is not None:
                 reason["ragged"] = paged
+        if self._row_state is not None:
+            # the mixer's two kernels (ops/mamba2.py): the same pure
+            # function models/falcon_h1.py evaluates at trace time, over the
+            # token axes its two surfaces hand it
+            from ..ops.mamba2 import ssd_kernel_unsupported_reason
+
+            rs = self._row_state
+            for name, tokens in (("ssd_update", None),
+                                 ("ssd_chunk", self._ragged_dense)):
+                why = ssd_kernel_unsupported_reason(
+                    rs.n_heads, rs.n_groups, rs.head_dim, rs.d_state, tokens)
+                routes[name] = "pallas" if why is None else "xla"
+                if why is not None:
+                    reason[name] = why
         routes["int4"] = None
         if self.weight_quant == "int4":
             int4 = self._int4_kernel_reason()
@@ -5120,11 +5183,35 @@ class LLMEngineCore:
             out["latent"] = dict(self._latent_counts)
         if self._window is not None:
             out["window"] = dict(self._window_counts)
+        if self._row_state is not None:
+            out["ssm"] = self._ssm_snapshot()
         if self._expert_counters() is not None:
             out["moe"] = self._moe_snapshot()
         if self.replica_id is not None:
             out["replica"] = self.replica_id
         return out
+
+    def _ssm_snapshot(self) -> dict:
+        """``lifecycle_stats()["ssm"]`` (docs/hybrid_cache.md): the work the
+        launches gave a model's state-space mixer, a row once whatever the
+        layers: ``update_rows`` rows x passes that advanced ONE token (the
+        mixed passes' one-token rows, every chained pass and every pass of a
+        decode chunk: ``ragged.decode_chain_rows``), ``chunk_rows`` /
+        ``chunk_tokens`` the mixed passes' rows of more tokens, ``resets``
+        the launches that counted a slot as zero for a new owner,
+        ``rewinds`` the prompts a recovery started again, ``passes`` the
+        model's passes, and the slot pool's snapshot beside them."""
+        pool = self.state_cache.snapshot()
+        return dict(
+            self._ssm_counts,
+            update_rows=(self._ssm_counts["update_rows"]
+                         + self.counters["decode_chain_rows"]),
+            resets=pool["resets"], rewinds=pool["rewinds"],
+            # every pass of the model, a launch's mixed pass, its chained
+            # passes and a decode chunk's alike (the sampler runs once each)
+            passes=self._sampler_passes["passes"],
+            layers=int(self.bundle.n_layers), state_pool=pool,
+        )
 
     def _expert_counters(self):
         """The counters a model's expert layers keep on the device beside
@@ -5306,12 +5393,14 @@ class LLMEngineCore:
 
     def _release_cache_slot(self, slot: int) -> None:
         """Give a batch row's sequence state back to its cache: the slot's
-        pages to the page pool, or the state slot (zeroed when its next
-        owner's first launch names it). The dense cache keeps nothing per
-        row."""
+        pages to the page pool and the state slot (zeroed when its next
+        owner's first launch starts at position 0). The dense cache keeps
+        nothing per row."""
         if self.paged_cache is not None:
             self.paged_cache.pool.free(slot)
-        elif self.state_cache is not None:
+        if self.state_cache is not None:
+            # alone, or beside the pages: a row's pages and its state slot
+            # never outlive each other
             self.state_cache.free(slot)
 
     def _quarantine_slot(self, slot: int, barrier: int) -> None:
@@ -5339,7 +5428,7 @@ class LLMEngineCore:
                     and self._slot_req[slot] is None
                     and slot not in self._admitting
                 ):
-                    self.paged_cache.pool.free(slot)
+                    self._release_cache_slot(slot)
 
     async def _discard_pipeline(self) -> None:
         """Drop every in-flight chunk and the device-resident chains
@@ -5369,7 +5458,7 @@ class LLMEngineCore:
                 and self._slot_req[slot] is None
                 and slot not in self._admitting
             ):
-                self.paged_cache.pool.free(slot)
+                self._release_cache_slot(slot)
 
     @staticmethod
     def _wait_chunks(entries) -> None:
@@ -5450,15 +5539,10 @@ class LLMEngineCore:
                         k: jax.device_put(v, self._cache_sharding[k])
                         for k, v in self.cache.items()
                     }
-            if self.state_cache is not None and any(
-                getattr(a, "is_deleted", lambda: False)()
-                for a in (self.state_cache.s, self.state_cache.z)
-            ):
+            if self.state_cache is not None:
                 # every live sequence's state went with the donated pools:
                 # their requests were failed by the step-failure path
-                self.state_cache.s, self.state_cache.z = (
-                    self.bundle.init_state(self.max_batch)
-                )
+                self.state_cache.reinit_if_lost()
         except Exception:
             pass  # recovery is best-effort; the next dispatch surfaces it
 
@@ -7064,6 +7148,11 @@ class LLMEngineCore:
                 if self._paged_quant:
                     self.paged_cache.k_scale = new_ks
                     self.paged_cache.v_scale = new_vs
+            if self.state_cache is not None:
+                # a row state beside the pages took every token of every
+                # row's span, as below
+                for slot, (_s, n) in plan["spans"].items():
+                    self.state_cache.advance(slot, n)
         else:
             # state cache: a launch's plan carries (slot = row, reset) per
             # row in place of page tables and write coordinates; nothing is
@@ -7303,6 +7392,13 @@ class LLMEngineCore:
             pool = self.paged_cache.pool
             for job, _take in plan["shares"]:
                 if job in self._prefill_jobs:  # identity compare
+                    if self.state_cache is not None:
+                        # the row's state took the chunk and cannot give it
+                        # back: pages and state start the prompt again
+                        pool.truncate(job.slot, 0)
+                        self.state_cache.rewind(job.slot)
+                        job.pos = 0
+                        continue
                     pool.truncate(job.slot, int(plan["pre_lens"][job.slot]))
         elif self.state_cache is not None:
             # a state cannot be rolled back to before the chunk: the
@@ -7510,6 +7606,13 @@ class LLMEngineCore:
                 _mixed_pass_work(plan["row_lens"], plan["kv_lens"]),
             ):
                 self.counters[name] += n
+            if self._row_state is not None:
+                # the mixed pass's rows by what the mixer ran for them: one
+                # token through the update, more through the chunk
+                n = np.asarray(plan["row_lens"], np.int64)
+                self._ssm_counts["update_rows"] += int((n == 1).sum())
+                self._ssm_counts["chunk_rows"] += int((n > 1).sum())
+                self._ssm_counts["chunk_tokens"] += int(n[n > 1].sum())
         self._step_rows["decode"] += len(plain_slots)
         self._step_rows["spec_verify"] += len(spec_slots)
         self._step_rows["prefill"] += len(live_shares)
@@ -7789,7 +7892,7 @@ class LLMEngineCore:
                     or self._ragged_spec_wanted(active_mask)
                     # the state cache has ONE step: decode-only phases run
                     # it too (rows of one token and their chained windows)
-                    or self.state_cache is not None
+                    or self.cache_mode == "state"
                 ):
                     # ragged scheduling phase (docs/ragged_attention.md):
                     # drain the pipelined queue first (host mirrors must be

@@ -649,6 +649,7 @@ class PagedKVCache:
         kv_quant: str = "",
         layout=None,
         counters: int = 0,
+        row_state=None,
     ):
         import jax
         import jax.numpy as jnp
@@ -684,6 +685,14 @@ class PagedKVCache:
         # standard V pool: see :attr:`v_carry`
         self.counters = (
             jnp.zeros((int(counters),), jnp.int32) if counters else None)
+        # a model whose rows keep a recurrent state BESIDE their pages
+        # (docs/hybrid_cache.md): the ``StateCache`` whose planes ride the
+        # launch's carry the same way, slot = batch row
+        if row_state is not None and (counters or layout is not None):
+            raise ValueError(
+                "a row state rides beside the STANDARD pools alone: not "
+                "beside a model's own page layout or its counters")
+        self.row_state = row_state
         # int8: per-(token, head) f32 dequant scales, page-id addressed so
         # a page and its scale row share one lifecycle (module docstring)
         if kv_quant:
@@ -746,14 +755,19 @@ class PagedKVCache:
     @property
     def v_carry(self):
         """What a launch takes and gives back as ``v_pools``: the V pool,
-        or ``(pool, counters)`` for a model that keeps counters on the
-        device. The pool itself stays ``self.v``, a plain stack, for
-        everything else that moves pages."""
+        ``(pool, counters)`` for a model that keeps counters on the device,
+        or ``(pool, planes)`` for one whose rows keep a state beside their
+        pages (``row_state``). The pool itself stays ``self.v``, a plain
+        stack, for everything else that moves pages."""
+        if self.row_state is not None:
+            return (self.v, self.row_state.planes)
         return self.v if self.counters is None else (self.v, self.counters)
 
     @v_carry.setter
     def v_carry(self, carry):  # tpuserve: ignore[TPU301] lock held by caller
-        if self.counters is None:
+        if self.row_state is not None:
+            self.v, self.row_state.planes = carry  # tpuserve: ignore[TPU301] this cache's lock guards the planes beside it
+        elif self.counters is None:
             self.v = carry
         else:
             self.v, self.counters = carry
@@ -1101,12 +1115,20 @@ class PagedKVCache:
 class StateCache:
     """The second kind of sequence state (docs/state_cache.md): one SLOT of
     fixed size per sequence, whatever its length, where ``PagedKVCache``
-    holds pages that grow. For models whose layers keep a recurrent state in
-    place of keys and values (``attention="power_retention"``:
-    ops/power_retention.py): per layer and slot ``S`` [Hkv, D, rows] and ``z``
-    [Hkv, zrows, D], float32, stacked as ``s`` [L, slots, ...] and ``z``
-    [L, slots, ...] and carried through the model's layer loop like the paged
-    K/V stacks.
+    holds pages that grow. For models whose layers keep a recurrent state:
+    ``planes`` is a dict of NAMED device arrays, each ``[L, slots (+ a
+    model's spare), ...]``, whatever the model's ``init_state`` declares,
+    carried through the model's layer loop like the paged K/V stacks. Power
+    retention (``attention="power_retention"``: ops/power_retention.py)
+    declares ``s`` [L, slots, Hkv, D, rows] and ``z`` [L, slots, Hkv, zrows,
+    D]; a Mamba-2 mixer (models/falcon_h1.py, ops/mamba2.py) ``h`` and the
+    convolution's window ``conv``.
+
+    It stands ALONE (``engine.cache=state``: no keys and values anywhere) or
+    BESIDE a ``PagedKVCache`` (docs/hybrid_cache.md: a model that attends
+    over pages AND keeps a row state; the planes then ride the paged launch's
+    carry, ``PagedKVCache.v_carry``, and are rebound under THAT cache's
+    dispatch lock).
 
     The contract the engine keeps with it:
 
@@ -1116,28 +1138,66 @@ class StateCache:
       when the row's request ends, fails or is preempted;
     - a slot is ZEROED when it changes hands, lazily: the first launch that
       carries tokens of the new owner starts at position 0 (``length(slot)
-      == 0``) and names the slot in the launch plan's ``reset`` flags; the
-      kernels then count whatever the last owner left as zero. ``resets``
+      == 0``) and the kernels then count whatever the last owner left as
+      zero (alone: the launch plan's ``reset`` flags name the slot; beside
+      pages: the model reads it off the launch's own positions). ``resets``
       counts those launches;
     - ``advance(slot, n)`` after a launch took ``n`` tokens of the row into
       the state. A state cannot be rolled back: ``rewind(slot)`` forgets the
       whole sequence (the next launch recomputes it from position 0), which
-      is also how a preempted request comes back.
+      is also how a preempted request comes back. Beside pages the page
+      pool's slot length is the row's length, and ``length`` here only says
+      whether the slot has been counted as zero for its owner yet.
 
-    ``dispatch_lock`` and the rebinding of ``s`` / ``z`` under it follow
+    ``dispatch_lock`` and the rebinding of the planes under it follow
     ``PagedKVCache``: the step donates the pools and returns them."""
 
-    __guarded_by__ = {"dispatch_lock": ("s", "z")}
+    __guarded_by__ = {"dispatch_lock": ("planes", "s", "z")}
 
     def __init__(self, init_state, n_slots: int):
         self.n_slots = int(n_slots)
-        self.s, self.z = init_state(self.n_slots)
+        self._init_state = init_state
+        self.planes = self._fresh()
         self.dispatch_lock = threading.Lock()
         self._lengths = np.zeros(self.n_slots, np.int64)
         self._in_use = np.zeros(self.n_slots, bool)
         self.in_use_peak = 0
         self.resets = 0
         self.rewinds = 0
+
+    def _fresh(self) -> Dict[str, object]:
+        """The model's planes, zero; a bare ``(s, z)`` pair (power
+        retention's ``init_state``) is named."""
+        planes = self._init_state(self.n_slots)
+        return planes if isinstance(planes, dict) else dict(zip("sz", planes))
+
+    def reinit_if_lost(self) -> bool:
+        """After a failed launch that consumed (donated) the planes: fresh
+        zero planes. Every live sequence's state went with them; their
+        requests were failed by the step-failure path."""
+        if not any(getattr(a, "is_deleted", lambda: False)()
+                   for a in self.planes.values()):
+            return False
+        self.planes = self._fresh()  # tpuserve: ignore[TPU301] recovery: no launch in flight
+        return True
+
+    # power retention's two planes by name: its step takes and returns them
+    # as two operands (llm/engine.py ``_ragged_state_step``)
+    @property
+    def s(self):
+        return self.planes["s"]
+
+    @s.setter
+    def s(self, value):  # tpuserve: ignore[TPU301] lock held by caller
+        self.planes["s"] = value
+
+    @property
+    def z(self):
+        return self.planes["z"]
+
+    @z.setter
+    def z(self, value):  # tpuserve: ignore[TPU301] lock held by caller
+        self.planes["z"] = value
 
     # -- slots ---------------------------------------------------------------
 
@@ -1163,7 +1223,7 @@ class StateCache:
 
     def advance(self, slot: int, tokens: int) -> None:
         if self._lengths[slot] == 0 and tokens > 0:
-            self.resets += 1     # that launch named the slot in ``reset``
+            self.resets += 1     # that launch counted the slot as zero
         self._lengths[slot] += int(tokens)
 
     def rewind(self, slot: int) -> None:
@@ -1178,13 +1238,14 @@ class StateCache:
 
     @property
     def bytes_per_slot(self) -> int:
-        return int((self.s.nbytes + self.z.nbytes) // self.n_slots)
+        return int(sum(a.nbytes // a.shape[1] for a in self.planes.values()))
 
     def pool_bytes(self) -> int:
-        return int(self.s.nbytes + self.z.nbytes)
+        return int(sum(a.nbytes for a in self.planes.values()))
 
     def snapshot(self) -> Dict[str, object]:
-        return {
+        first = next(iter(self.planes.values()))
+        out = {
             "slots": self.n_slots,
             "in_use": self.in_use,
             "in_use_peak": int(self.in_use_peak),
@@ -1192,7 +1253,10 @@ class StateCache:
             "bytes": self.pool_bytes(),
             "resets": int(self.resets),
             "rewinds": int(self.rewinds),
-            "dtype": str(self.s.dtype),
-            "s_shape": list(self.s.shape),
-            "z_shape": list(self.z.shape),
+            "dtype": str(first.dtype),
+            "planes": {k: list(a.shape) for k, a in self.planes.items()},
         }
+        # the names PR 26's readers and dashboards know
+        out.update({k + "_shape": list(a.shape)
+                    for k, a in self.planes.items() if k in ("s", "z")})
+        return out

@@ -42,3 +42,4 @@ from . import llama  # noqa: E402,F401
 from . import whisper  # noqa: E402,F401
 from . import dots3_note  # noqa: E402,F401
 from . import afmoe  # noqa: E402,F401
+from . import falcon_h1  # noqa: E402,F401

@@ -625,3 +625,53 @@ def test_window_zero_lowers_to_the_kernel_of_before(one_chip, case):
 
     assert body(0) == body(None)
     assert len(body(2048)) > len(body(0))
+
+
+# ------------------------------------------------- the SSD kernels (PR 51)
+
+@pytest.mark.parametrize("case", ["update", "chunk"])
+def test_the_ssd_kernels_compile_at_the_published_shapes(
+        one_chip, monkeypatch, case):
+    """ops/mamba2.py at Falcon-H1-34B's widths (32 heads x 128, state 256, 2
+    groups), 64 rows and a launch of 256 tokens: Mosaic takes both kernels,
+    and the 2.2 GB pool is updated in place (no temporary of its size)."""
+    from clearml_serving_tpu.ops import mamba2
+
+    reason = mamba2.ssd_kernel_unsupported_reason
+    monkeypatch.setattr(
+        mamba2, "ssd_kernel_unsupported_reason",
+        lambda *a, **k: reason(*a, **dict(k, platform="tpu")))
+    layers, rows, heads, groups, p, n, t = 8, 64, 32, 2, 128, 256, 256
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = on_chip(mamba2.state_shape(layers, rows, heads, n, p))
+    plan = (on_chip((rows,), jnp.int32), on_chip((), jnp.int32),
+            on_chip((rows,), jnp.bool_))
+    if case == "update":
+        fn = lambda dtx, decay, bm, cm, r, c, z, h: mamba2.mamba2_ssd_update(  # noqa: E731
+            dtx, decay, bm, cm, r, c, z, h, layer=3)
+        args = (on_chip((rows, heads, p)), on_chip((rows, heads)),
+                on_chip((rows, groups, n)), on_chip((rows, groups, n)),
+                *plan, pool)
+    else:
+        fn = lambda dtx, ld, bm, cm, row, m, r, c, z, h: mamba2.mamba2_ssd_chunk(  # noqa: E731
+            dtx, ld, bm, cm, row, m, r, c, z, h, layer=3)
+        args = (on_chip((t, heads, p)), on_chip((t, heads)),
+                on_chip((t, groups, n)), on_chip((t, groups, n)),
+                on_chip((t,), jnp.int32), on_chip((t,), jnp.bool_),
+                *plan, pool)
+    lowered = jax.jit(fn, donate_argnums=(len(args) - 1,)).trace(
+        *args).lower(lowering_platforms=("tpu",))
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    memory = compiled.memory_analysis()
+    pool_bytes = 4 * layers * (rows + 1) * heads * n * p
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 16
+    assert "mamba2_ssd_" + case in compiled.as_text()
