@@ -4859,17 +4859,12 @@ class LLMEngineCore:
         return out
 
     def _kernel_routes(self) -> dict:
-        """Which implementation each device kernel's call sites take, and
-        why not the Pallas one where they do not — evaluated ONCE at
-        construction from the routing functions the model code itself
-        calls at trace time (ops.paged_attention.
-        paged_kernel_unsupported_reason, ops.fused_matmul.
-        int4_kernel_unsupported_reason), over the engine's own shapes:
-        ``{decode, ragged, int4: "pallas"|"xla"|None, reason: {...}}``.
-        None = the engine has no such launch (two-dispatch engines have no
-        ragged launch; only int4 trees have int4 matmuls). ``int4`` is
-        judged at the decode shape M = max_batch; prefill-sized matmuls
-        take XLA by design (M > MAX_FUSED_ROWS)."""
+        """Each device kernel's route and why not the Pallas one, evaluated
+        ONCE at construction by the ``*_kernel_unsupported_reason`` functions
+        the model code calls at trace time, over the engine's own shapes:
+        ``{decode, ragged, int4: "pallas"|"xla"|None, reason}`` and, where the
+        model has such layers, ``ssd_update``, ``ssd_chunk``, ``moe``. None =
+        no such launch; ``int4`` is judged at M = max_batch."""
         reason = {}
         paged = self._paged_kernel_reason
         routes = {"decode": "pallas" if paged is None else "xla"}
@@ -4894,6 +4889,11 @@ class LLMEngineCore:
                 routes[name] = "pallas" if why is None else "xla"
                 if why is not None:
                     reason[name] = why
+        if getattr(self.bundle, "expert_stack", None) is not None:
+            from ..ops.moe_experts import kernel_route  # held experts
+            routes["moe"], why = kernel_route(
+                self.bundle, self.params, (self._ragged_dense, self.max_batch))
+            reason.update({"moe": why} if why else {})
         routes["int4"] = None
         if self.weight_quant == "int4":
             int4 = self._int4_kernel_reason()

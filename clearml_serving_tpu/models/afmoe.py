@@ -47,8 +47,11 @@ import jax
 import jax.numpy as jnp
 
 from . import register_model
-from .dots3_note import FULL, WINDOW, layer_plan
-from .llama import _rms_norm, _rope, moe_dropless, moe_route
+from .dots3_note import (
+    FULL, WINDOW, first_expert_stack, held_experts, layer_plan,
+    scan_operands, scanned_layer,
+)
+from .llama import _rms_norm, _rope, moe_route
 
 # matmul weights that engine.weight_quant=int8 packs (per output channel)
 _QUANT_KEYS = (
@@ -329,21 +332,13 @@ def build(config: dict) -> SimpleNamespace:
                 logits, top_k, scoring="sigmoid",
                 bias=layer["router_bias"] if bias else 0.0, scale=route_scale,
             )
-            local = top_e - first_held
-            held = jnp.logical_and(local >= 0, local < n_held)
-            y = moe_dropless(
-                h, top_p, jnp.where(held, local, n_held),
-                _w(layer, "w_gate_e"), _w(layer, "w_up_e"),
-                _w(layer, "w_down_e"),
-            )
+            y, hit, took = held_experts(
+                h, top_p, top_e, valid, (first_held, n_held), layer, dtype)
             with jax.named_scope("moe_shared"):
                 y = y + _swiglu(layer, h)
             if counters is not None:
-                took = jnp.logical_and(held, valid[:, None])
-                hit = jnp.zeros((n_held + 1,), jnp.int32).at[
-                    jnp.where(took, local, n_held)].max(1)
                 counters = counters + jnp.stack([
-                    jnp.sum(hit[:n_held]), jnp.sum(took.astype(jnp.int32)),
+                    jnp.sum(hit), jnp.sum(took.astype(jnp.int32)),
                     jnp.int32(1),
                 ] + [jnp.int32(0)] * (counters.shape[0] - 3))
         return y.astype(h.dtype), counters
@@ -372,19 +367,21 @@ def build(config: dict) -> SimpleNamespace:
         for i in range(lead):
             x, carry = _layer(x, layers[i], kinds[i], i, carry, ctx)
         if period:
+            group, whole = scan_operands(layers, lead, x)
+
             def body(state, xs):
                 x, carry = state
                 group_, r = xs
                 for j in range(period):
                     x, carry = _layer(
-                        x, group_["p{}".format(j)], kinds[lead + j],
-                        lead + r * period + j, carry, ctx,
+                        x, scanned_layer(group_, whole, "p{}".format(j), r),
+                        kinds[lead + j], lead + r * period + j, carry, ctx,
                     )
                 return (x, carry), None
 
             (x, carry), _ = jax.lax.scan(
                 body, (x, carry),
-                (layers[lead], jnp.arange(n_rep, dtype=jnp.int32)),
+                (group, jnp.arange(n_rep, dtype=jnp.int32)),
             )
         return x, carry
 
@@ -500,6 +497,7 @@ def build(config: dict) -> SimpleNamespace:
         decode_paged=decode_paged,
         verify_paged=None,
         ffn=_ffn,
+        expert_stack=lambda params: first_expert_stack(params["layers"]),
         # what the engine reads to lift its sliding_window refusal and to
         # count a launch's keys by layer kind (llm/engine.py)
         paged_window=SimpleNamespace(

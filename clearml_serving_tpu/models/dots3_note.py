@@ -70,6 +70,81 @@ def pad128(n: int) -> int:
     return -(-int(n) // 128) * 128
 
 
+# a layer's routed stacks: what ``held_experts`` reads of its dict
+_EXPERT_KEYS = ("w_gate_e", "w_up_e", "w_down_e")
+
+
+def held_experts(h, top_p, top_e, valid, held, layer, dtype):
+    """The routed sum of a chip that HOLDS experts ``held`` = (first, count)
+    of a large routed set, for tokens h [T, dim] with the router's choice
+    (``models/llama.moe_route``): (y [T, dim], hit [count] int32: the held
+    experts a valid token chose, took [T, k]: the choices that stayed
+    here). The Mosaic kernel reads and multiplies the hit experts only
+    (ops/moe_experts.py), on the chip and at shapes it takes; its twin
+    ``moe_dropless`` sends every token through every held expert. ``layer``
+    holds the stacks, or under ``"experts"`` (stacks with a leading axis,
+    index): a scan's stacked operand, whole, and the repetition."""
+    from ..ops import moe_experts as me
+    from ..ops.quant import dequantize
+
+    first, n_held = held
+    stacks, rep = layer.get("experts") or (layer, None)
+    stacks = [stacks[name] for name in _EXPERT_KEYS]
+    local = top_e - first
+    here = jnp.logical_and(local >= 0, local < n_held)
+    took = jnp.logical_and(here, valid[:, None])
+    gates, hit = me.expert_gates(top_p, local, took, n_held)
+    if me.moe_kernel_unsupported_reason(
+            h.shape[0], h.dtype, stacks[0]) is None:
+        y = me.moe_experts(h, gates, *me.visit_order(hit), *stacks, layer=rep)
+    else:
+        y = moe_dropless(
+            h, top_p, jnp.where(here, local, n_held),
+            *(dequantize(w["_q8"], w["_scale"], dtype)
+              if isinstance(w, dict) else w for w in stacks))
+    return y, hit, took
+
+
+def first_expert_stack(layers):
+    """The first ``w_gate_e`` leaf of ``params["layers"]`` (a list of layer
+    dicts and, under scan_layers, one group of stacked ones), or None."""
+    for layer in layers:
+        if "w_gate_e" in layer:
+            return layer["w_gate_e"]
+        for part in layer.values():
+            if isinstance(part, dict) and "w_gate_e" in part:
+                return part["w_gate_e"]
+    return None
+
+
+def scan_operands(layers, lead: int, x):
+    """(what a layer scan slices of the group ``layers[lead]`` = {"p<j>":
+    layer leaves stacked over the repetitions}, {"p<j>": the expert stacks,
+    whole}). Where a pass over x [T, dim] takes the experts' kernel, the
+    stacks stay OUT of the sliced operands: the kernel takes the stacked
+    operand and a repetition index, where a slice handed to a custom call
+    would be copied out of the stack first. Else the group, and {}."""
+    from ..ops.moe_experts import moe_kernel_unsupported_reason
+
+    group, w_gate = layers[lead], first_expert_stack(layers)
+    if w_gate is None or moe_kernel_unsupported_reason(
+            x.shape[0], x.dtype, w_gate) is not None:
+        return group, {}
+    whole = {name: {k: layer[k] for k in _EXPERT_KEYS if k in layer}
+             for name, layer in group.items()}
+    sliced = {name: {k: v for k, v in layer.items() if k not in whole[name]}
+              for name, layer in group.items()}
+    return sliced, whole
+
+
+def scanned_layer(group, whole, name: str, rep):
+    """Position ``name`` of a scan body's slice, with its expert stacks
+    (``scan_operands``) and the repetition ``rep`` where they stayed whole."""
+    layer = group[name]
+    return dict(layer, experts=(whole[name], rep)) if whole.get(name) \
+        else layer
+
+
 def layer_plan(kinds, scan: bool):
     """(lead, period): the layers before the repeating tail, and the tail's
     period (0 = nothing repeats: every layer is unrolled). The shortest lead
@@ -438,21 +513,12 @@ def build(config: dict) -> SimpleNamespace:
                 logits, top_k, scoring="sigmoid", bias=layer["router_bias"],
                 scale=route_scale,
             )
-            local = top_e - first_held
-            held = jnp.logical_and(local >= 0, local < n_held)
-            y = moe_dropless(
-                h, top_p, jnp.where(held, local, n_held),
-                _w(layer, "w_gate_e"), _w(layer, "w_up_e"),
-                _w(layer, "w_down_e"),
-            )
+            y, hit, took = held_experts(
+                h, top_p, top_e, valid, (first_held, n_held), layer, dtype)
             with jax.named_scope("moe_shared"):
                 y = y + _swiglu(layer, h)
-            took = jnp.logical_and(held, valid[:, None])
-            hit = jnp.zeros((n_held + 1,), jnp.int32).at[
-                jnp.where(took, local, n_held)].max(1)
             counters = counters + jnp.stack([
-                jnp.sum(hit[:n_held]), jnp.sum(took.astype(jnp.int32)),
-                jnp.int32(1),
+                jnp.sum(hit), jnp.sum(took.astype(jnp.int32)), jnp.int32(1),
             ] + [jnp.int32(0)] * (counters.shape[0] - 3))
         return y.astype(h.dtype), counters
 
@@ -484,19 +550,21 @@ def build(config: dict) -> SimpleNamespace:
                 for j in range(period)
             ]
 
+            group, whole = scan_operands(layers, lead, x)
+
             def body(carry, xs):
                 x, pools = carry
                 group, r = xs
                 for j in range(period):
                     x, pools = _layer(
-                        x, group["p{}".format(j)], kinds[lead + j],
-                        base[j] + r * stride[j], pools, ctx,
+                        x, scanned_layer(group, whole, "p{}".format(j), r),
+                        kinds[lead + j], base[j] + r * stride[j], pools, ctx,
                     )
                 return (x, pools), None
 
             (x, pools), _ = jax.lax.scan(
                 body, (x, pools),
-                (layers[lead], jnp.arange(n_rep, dtype=jnp.int32)),
+                (group, jnp.arange(n_rep, dtype=jnp.int32)),
             )
         return x, pools
 
@@ -637,6 +705,7 @@ def build(config: dict) -> SimpleNamespace:
         decode_paged=decode_paged,
         verify_paged=None,
         ffn=_ffn,
+        expert_stack=lambda params: first_expert_stack(params["layers"]),
         paged_layout=layout,
         attention="latent",
         prepare_params=lambda params: params,

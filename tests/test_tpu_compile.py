@@ -675,3 +675,157 @@ def test_the_ssd_kernels_compile_at_the_published_shapes(
     assert memory.alias_size_in_bytes >= pool_bytes
     assert memory.temp_size_in_bytes < pool_bytes // 16
     assert "mamba2_ssd_" + case in compiled.as_text()
+
+
+# ------------------------------- the held experts' kernel (PR 53, ISSUE 53)
+
+@pytest.mark.parametrize("config, calls", [
+    ("trinity-mini-d8", 6), ("dots3-note-prev-ep8", 5)])
+def test_the_expert_pass_visits_the_stacks_in_place(
+        one_chip, monkeypatch, config, calls):
+    """The mixed pass of both expert configurations at their published
+    sizes (128 packed tokens, 32 rows, int8 weights, the cell's pool),
+    compiled for the described v5e: every expert layer is a ``moe_experts``
+    Mosaic call (six unrolled layers; one unrolled and four in dots3's
+    scanned period, on the WHOLE stacked operand), and no instruction but a
+    parameter on its way through the loop has an expert stack's shape: no
+    copy, no slice out of the scan's operand, no dequantised temporary."""
+    from benchmark import sut
+    from clearml_serving_tpu.ops import moe_experts as me
+
+    monkeypatch.setattr(pa, "paged_kernel_unsupported_reason",
+                        lambda *a, **k: None)
+    reason = me.moe_kernel_unsupported_reason
+    monkeypatch.setattr(
+        me, "moe_kernel_unsupported_reason",
+        lambda *a, **k: reason(*a, **dict(k, platform="tpu")))
+    cfg = sut.load_config("benchmark/configs/{}.json".format(config))
+    model = sut.model_block(cfg)
+    bundle = models.build_model(cfg["arch"], model)
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shapes(make):
+        return jax.tree.map(lambda a: on_chip(a.shape, a.dtype),
+                            jax.eval_shape(make))
+
+    params = shapes(lambda: bundle.init(jax.random.PRNGKey(0),
+                                        weight_quant="int8"))
+    rows, dense, page = 32, 128, 16
+    pages = cfg["engine"]["num_pages"]
+    if cfg["arch"] == "afmoe":
+        pool = on_chip((bundle.n_layers, bundle.n_kv_heads, pages, page, D),
+                       jnp.bfloat16)
+        k, v = pool, (pool, on_chip((bundle.paged_window.counters,)))
+        tile = pa.ragged_query_tile(
+            bundle.n_kv_heads, bundle.n_heads // bundle.n_kv_heads, D,
+            jnp.bfloat16)
+    else:
+        k, v = shapes(lambda: bundle.paged_layout.init_pools(pages, page))
+        tile = pa.ragged_query_tile(
+            1, bundle.paged_layout.n_heads, bundle.paged_layout.head_dim,
+            jnp.bfloat16)
+    items = pa.ragged_item_count(
+        rows, pa.ragged_view_tokens(dense, rows), tile)
+
+    def step(params, k, v, tok, valid, row_last, table, per_row, item):
+        return bundle.forward_ragged(
+            params, tok, tok, tok, valid, tok, row_last, k, v, table, per_row,
+            per_row, per_row, tok, tok, item, item)
+
+    hlo = _compiled_text(jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k, v, on_chip((dense,)), on_chip((dense,), jnp.bool_),
+        on_chip((rows,)), on_chip((rows, cfg["engine"]["max_seq_len"] // page + 1)),
+        on_chip((rows,)), on_chip((items,))))
+    assert len(re.findall(r"= \S+ custom-call\(.*moe_experts", hlo)) == calls
+    n_held = model.get("experts_held", (0, model["router_experts"]))[1]
+    dim, width = model["dim"], model["moe_intermediate_size"]
+    stack = ([n_held, dim, width], [n_held, width, dim])
+    shaped = re.compile(
+        r"^\s*(?:ROOT )?%?(\S+) = \(?(\w+)\[([\d,]+)\]\S* ([\w\-]+)\(")
+    moved = []
+    for line in hlo.splitlines():
+        m = shaped.match(line)
+        if not m:
+            continue
+        name, dtype, dims, op = m.groups()
+        dims = [int(n) for n in dims.split(",")]
+        if dims[-3:] in stack and op not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            moved.append((name, dtype, dims, op))
+    assert not moved, moved
+
+
+_LLAMA_PROGRAMS = "tests/data/llama_step_programs.json"
+
+
+def _llama_step_digests():
+    """sha256 of the lowered text of a ragged pass and a decode pass of
+    ``models/llama.py`` at tiny widths, int8 weights: Mistral's shape of
+    model (no experts) and Mixtral's (8 experts, top-2)."""
+    import hashlib
+
+    out = {}
+    for name, experts in (("mistral", 0), ("mixtral", 8)):
+        cfg = dict(vocab_size=256, dim=128, n_layers=2, n_heads=4,
+                   n_kv_heads=2, head_dim=32, ffn_dim=256, scan_layers=True,
+                   dtype="bfloat16")
+        if experts:
+            cfg.update(n_experts=experts, moe_top_k=2)
+        bundle = models.build_model("llama", cfg)
+        params = jax.eval_shape(lambda: bundle.init(
+            jax.random.PRNGKey(0), weight_quant="int8"))
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        pool = jax.ShapeDtypeStruct((2, 2, 9, 16, 32), jnp.bfloat16)
+        tok, rows = i32(16), i32(4)
+
+        def ragged(params, k, v, tok, valid, row_last, table, per_row):
+            return bundle.forward_ragged(
+                params, tok, tok, tok, valid, tok, row_last, k, v, table,
+                per_row, per_row, per_row, tok, tok)
+
+        def decode(params, k, v, nxt, table, per_row, live):
+            return bundle.decode_paged(
+                params, nxt, k, v, table, per_row, per_row, per_row,
+                active=live)
+
+        texts = (
+            jax.jit(ragged).lower(
+                params, pool, pool, tok,
+                jax.ShapeDtypeStruct((16,), jnp.bool_), rows, i32(4, 4),
+                rows).as_text(),
+            jax.jit(decode).lower(
+                params, pool, pool, rows, i32(4, 4), rows,
+                jax.ShapeDtypeStruct((4,), jnp.bool_)).as_text(),
+        )
+        for step, text in zip(("ragged", "decode"), texts):
+            out["{}.{}".format(name, step)] = hashlib.sha256(
+                text.encode()).hexdigest()
+    return out
+
+
+def test_the_llama_step_programs_are_the_recorded_ones():
+    """ISSUE 53 leaves ``models/llama.py`` alone: Mistral's and Mixtral's
+    step programs lower to the text recorded from the parent commit
+    (``python tests/test_tpu_compile.py --record`` re-records, for a PR
+    that means to change them; ``recorded_with`` names the jax it holds
+    for)."""
+    import json
+
+    with open(_LLAMA_PROGRAMS) as f:
+        recorded = json.load(f)
+    if recorded["recorded_with"] != jax.__version__:
+        pytest.skip("recorded with jax {}".format(recorded["recorded_with"]))
+    assert _llama_step_digests() == recorded["sha256"]
+
+
+if __name__ == "__main__":
+    import json
+    import sys
+
+    if sys.argv[1:] == ["--record"]:
+        with open(_LLAMA_PROGRAMS, "w") as f:
+            json.dump({"recorded_with": jax.__version__,
+                       "sha256": _llama_step_digests()}, f, indent=1)
+            f.write("\n")
