@@ -125,6 +125,7 @@ WARMUP_COVERED: FrozenSet[str] = frozenset({
     "_ragged_paged_jit",
     "_ragged_state_jit",
     "_ragged_unpack_jit",
+    "_ragged_chain_jit",
 })
 
 _warmup_cache: Dict[str, FrozenSet[str]] = {}

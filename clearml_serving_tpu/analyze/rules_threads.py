@@ -98,6 +98,9 @@ AFFINITY_REGISTRY: Dict[str, Tuple[str, Optional[Tuple[str, ...]]]] = {
     # ragged scheduler job list (docs/ragged_attention.md): the loop opens,
     # shares out, and retires jobs; dispatch workers only read plan dicts
     "_prefill_jobs": (LOOP, ("self", "engine")),
+    # the ragged step's launches in flight: appended when the dispatch
+    # lands, popped at the retire, both on the loop thread
+    "_ragged_flights": (LOOP, ("self", "engine")),
     # multi-step / spec-as-row per-launch chain state
     # (docs/ragged_attention.md): window planning and retire-side
     # acceptance land these counters/histograms on the loop thread only

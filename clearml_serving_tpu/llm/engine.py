@@ -336,8 +336,11 @@ class _CycleClock:
     that yield holds no handler). Where launches overlap (pipelined step)
     the worker's three parts still add up to ``dispatch_ms``, the
     ``launch`` phase is only what the concurrent retire left of the hop,
-    and a launch whose predecessor is not back yet starves 0. Loop-thread
-    only."""
+    and a launch whose predecessor is not back yet starves 0. A ragged step
+    that keeps a launch in flight runs plan(N+1) | launch(N+1) | wait(N) |
+    emit(N) in one cycle: the parts still add up to ``launch``, N+1 starves
+    0, and ``wait`` is what is left of N's run once the host is through
+    with N+1. Loop-thread only."""
 
     PHASES = ("admin", "plan", "launch", "wait", "emit", "yield")
     PARTS = ("hop_out", "upload", "enqueue", "tail", "hop_back")
@@ -523,6 +526,29 @@ class _InFlightChunk:
     live: Any = None
 
 
+@dataclass
+class _RaggedFlight:
+    """One ragged launch that is enqueued and not retired: its ``plan``
+    (host arrays, the rows' requests) and the worker's ``result`` (device
+    arrays, possibly still computing)."""
+
+    plan: dict
+    result: dict
+
+    @property
+    def seq(self) -> int:
+        return self.plan["seq"]
+
+    @property
+    def chunk(self):
+        # what _wait_chunks blocks on: the launch's program has written
+        # its rows' pages / state when its tokens exist
+        return self.result["sampled"]
+
+    def carries(self, slot: int) -> bool:
+        return slot in self.plan["spans"]
+
+
 def _decode_pass_work(first, passes) -> tuple:
     """(rows x passes, tokens attended) of rows that ride ``passes[r]``
     consecutive decode passes over paged KV, attending ``first[r]`` tokens
@@ -656,18 +682,32 @@ def _staging_layout(entries) -> tuple:
     return tuple(layout), offset
 
 
-def _unpack_ragged_operands(staged, layout):
-    """The device half of a ragged launch's ONE upload: slices the staged
-    int32 buffer back into the operands the jitted step takes, by name,
-    each at the shape and dtype its own upload used to give (booleans
-    crossed as 0/1). A program of its own, dispatched before the step: the
-    step's signature and trace know nothing of the buffer."""
+def _unpack_staged(staged, layout):
+    """Slices a staged int32 buffer (:func:`_staging_layout`) back into
+    its entries, by name, each at its shape and dtype (booleans crossed as
+    0/1). Traced inside the program that takes the buffer."""
     out = {}
     for name, offset, shape, is_bool in layout:
         part = jax.lax.slice(
             staged, (offset,), (offset + math.prod(shape),)
         ).reshape(shape)
         out[name] = part != 0 if is_bool else part
+    return out
+
+
+def _unpack_ragged_operands(staged, layout, chain):
+    """The device half of a ragged launch's ONE upload: the staged buffer
+    back as the operands the jitted step takes, each as its own upload used
+    to give it. A program of its own, dispatched before the step: the
+    step's signature and trace know nothing of the buffer. ``chain`` is
+    the device's [B] vector of each row's pending token (what the launch
+    before this one sampled last for the row, or a finishing prompt's
+    first token): ``chain_at[r]`` says where in ``tokens`` row ``r``'s goes,
+    and a row whose token the host wrote itself points past the end."""
+    out = _unpack_staged(staged, layout)
+    out["tokens"] = out["tokens"].at[out.pop("chain_at")].set(
+        chain, mode="drop"
+    )
     return out
 
 
@@ -1181,7 +1221,7 @@ class LLMEngineCore:
         "loop": (
             "_inflight", "_quarantine", "_dispatching", "_slot_req",
             "_admitting", "_next_token", "_gstate", "_slot_overrides",
-            "_prefill_jobs", "_tier_counters",
+            "_prefill_jobs", "_tier_counters", "_ragged_flights",
             # multi-step / spec-as-row chain observability
             # (docs/ragged_attention.md): per-launch window and acceptance
             # state is planned and retired on the loop thread only; the
@@ -1215,7 +1255,7 @@ class LLMEngineCore:
             "_decode_paged_chunk_jit", "_first_token_jit",
             "_set_sampling_row_jit", "_spec_chunk_jit",
             "_ragged_paged_jit", "_ragged_state_jit",
-            "_ragged_unpack_jit",
+            "_ragged_unpack_jit", "_ragged_chain_jit",
         ),
         # prompt scoring runs only for completions echo+logprobs requests:
         # one compile per prefill bucket on first use, sentry-attributed
@@ -1923,6 +1963,13 @@ class LLMEngineCore:
             "ragged_waits_off_loop": 0,
             "ragged_first_tokens": 0,
             "ragged_first_tokens_behind_launch": 0,
+            # ragged launches enqueued while the one before them had not
+            # been read back (the launch kept in flight), and rows such a
+            # launch carried for nothing: they had ended at the launch
+            # before for a reason only the device knew, and their step was
+            # dropped at retire
+            "ragged_launches_behind": 0,
+            "ragged_surplus_rows": 0,
             # rows x decode passes over paged KV (the chained passes of a
             # ragged launch, the passes of a decode chunk) and the tokens
             # those rows attended there: the paged decode kernel's work
@@ -2052,6 +2099,10 @@ class LLMEngineCore:
             else _env_pipeline_depth()
         )
         self._inflight: Deque[_InFlightChunk] = deque()
+        # the ragged step's launches that are enqueued and not retired,
+        # oldest first: one, or two while the next launch goes behind the
+        # one in flight (docs/ragged_attention.md, "A launch in flight")
+        self._ragged_flights: Deque[_RaggedFlight] = deque()
         self._dispatch_seq = 0
         # (seq, active_mask) of a chunk whose worker-thread dispatch is in
         # progress (not yet an _inflight entry): the slot-reuse barrier
@@ -2069,6 +2120,12 @@ class LLMEngineCore:
         self._next_token_dev = None
         self._gstate_dev = None
         self._slot_overrides = np.zeros(self.max_batch, bool)
+        # (slot's request) whose sampling rows the host mirrors hold: a
+        # launch planned behind the one that ends a prompt writes them
+        # before the commit does (_stage_slot_config)
+        self._slot_config_of: List[Optional[GenRequest]] = (
+            [None] * self.max_batch
+        )
         # cached device-side sampling constants: re-uploading temperature /
         # top_k / top_p (and the static extras rows) as fresh device arrays
         # every chunk puts 6+ tiny host->device transfers on every dispatch;
@@ -2504,7 +2561,7 @@ class LLMEngineCore:
             sampled from. ``state`` (the slots' [B, V] counts / bias /
             prompt-mask rows, or None while no request has needed them)
             takes the slot's reset from the sampled id as a device value."""
-            ops = _unpack_ragged_operands(staged, layout)
+            ops = _unpack_staged(staged, layout)
             vocab = ops["bias"].shape[1]
             slot = ops["slot"][0]
 
@@ -3335,6 +3392,17 @@ class LLMEngineCore:
             self._ragged_unpack_jit = jax.jit(
                 _unpack_ragged_operands, static_argnums=(1,)
             )
+            # the rows' pending tokens, kept on the device from one launch
+            # to the one behind it: a launch's last sampled row, and a
+            # finishing prompt's first token put at its slot (``at`` past
+            # the end: none)
+            self._ragged_chain_jit = jax.jit(
+                lambda sampled, first_id, at: (
+                    sampled if sampled.ndim == 1 else sampled[-1]
+                ).at[at].set(first_id[0], mode="drop")
+            )
+            self._chain_null = jnp.zeros(self.max_batch, jnp.int32)
+            self._first_null = jnp.zeros(1, jnp.int32)
             windows = [1]
             while windows[-1] < self._ragged_steps_cap:
                 windows.append(2 * windows[-1])
@@ -3477,7 +3545,8 @@ class LLMEngineCore:
     def _sanitize(self, where: str, drained: bool = False) -> None:
         if self._sanitizer is not None:
             self._sanitizer.check(
-                where, drained=drained, inflight=len(self._inflight)
+                where, drained=drained,
+                inflight=len(self._inflight) + len(self._ragged_flights),
             )
         if self._compile_sentry is not None:
             # strict-mode violations surface here, on the loop thread,
@@ -3486,7 +3555,10 @@ class LLMEngineCore:
         if self._ledger is not None:
             self._ledger.check(
                 where=where,
-                drained=drained and not self._inflight,
+                drained=(
+                    drained and not self._inflight
+                    and not self._ragged_flights
+                ),
                 domains=self._ledger_domains(),
             )
         if self._shard_sentry is not None:
@@ -4975,7 +5047,7 @@ class LLMEngineCore:
             "step_failures": self.counters["step_failures"],
             "pipeline": {
                 "depth": self.pipeline_depth,
-                "inflight": len(self._inflight),
+                "inflight": len(self._inflight) + len(self._ragged_flights),
                 # over pipeline.cycle_ms: the share of the chip the host
                 # wastes, as far as the program knows (_CycleClock)
                 "starve_ms": self._cycle.starve.snapshot(),
@@ -5078,7 +5150,7 @@ class LLMEngineCore:
             "step_failures": c["step_failures"],
             "pipeline": {
                 "depth": self.pipeline_depth,
-                "inflight": len(self._inflight),
+                "inflight": len(self._inflight) + len(self._ragged_flights),
                 "dispatch_ms": self._hist_dispatch.snapshot(),
                 # wait_ms + emit_ms: the device->host sync is inside it
                 "retire_ms": self._hist_retire.snapshot(),
@@ -5113,6 +5185,14 @@ class LLMEngineCore:
                     "first_tokens_behind_launch": self.counters[
                         "ragged_first_tokens_behind_launch"
                     ],
+                    # of ``steps``, the launches enqueued while the one
+                    # before them had not been read back, and the rows such
+                    # launches carried for nothing (ended at the launch
+                    # before, dropped at retire)
+                    "launches_behind": self.counters[
+                        "ragged_launches_behind"
+                    ],
+                    "surplus_rows": self.counters["ragged_surplus_rows"],
                     "budget_utilization": self._hist_budget.snapshot(),
                     "step_rows": dict(self._step_rows),
                     # multi-step decode rows + spec-as-row
@@ -5373,6 +5453,11 @@ class LLMEngineCore:
         for entry in self._inflight:
             if entry.active_mask[slot]:
                 barrier = entry.seq
+        for flight in self._ragged_flights:
+            # a ragged launch in flight writes the pages / the state of
+            # every row it carries, prompt chunks included
+            if flight.carries(slot):
+                barrier = flight.seq
         disp = self._dispatching
         if disp is not None and disp[1][slot]:
             barrier = disp[0]
@@ -5424,8 +5509,7 @@ class LLMEngineCore:
                     lifecycle_ledger.release("slot.quarantine", key=slot,
                                              domain=self, all_of_key=True)
                 if (
-                    self.paged_cache is not None
-                    and self._slot_req[slot] is None
+                    self._slot_req[slot] is None
                     and slot not in self._admitting
                 ):
                     self._release_cache_slot(slot)
@@ -5443,6 +5527,8 @@ class LLMEngineCore:
         The host mirrors become the source of truth for the next dispatch."""
         dropped = list(self._inflight)
         self._inflight.clear()
+        flights = list(self._ragged_flights)
+        self._ragged_flights.clear()
         pending = list(self._quarantine)
         self._quarantine.clear()
         if self._ledger is not None:
@@ -5450,12 +5536,14 @@ class LLMEngineCore:
                 lifecycle_ledger.release("slot.quarantine", key=slot,
                                          domain=self, all_of_key=True)
         self._reset_device_chains()
-        if self.paged_cache is not None and dropped:
-            await asyncio.to_thread(self._wait_chunks, dropped)
+        if (self.paged_cache is not None and dropped) or flights:
+            await asyncio.to_thread(self._wait_chunks, dropped + flights)
+        # prompts whose chunks rode the dropped ragged launches and that
+        # are still served go back to where the oldest of them found them
+        self._ragged_rollback([flight.plan for flight in flights])
         for slot in pending:
             if (
-                self.paged_cache is not None
-                and self._slot_req[slot] is None
+                self._slot_req[slot] is None
                 and slot not in self._admitting
             ):
                 self._release_cache_slot(slot)
@@ -5996,6 +6084,30 @@ class LLMEngineCore:
             row[: len(ids)] = ids
             row[len(ids)] = first_id
             self._tokbuf[slot] = row
+        self._stage_slot_config(request, slot)
+        self._slot_config_of[slot] = None     # the next owner stages anew
+        if request._guided_key is not None:
+            # transfer the grammar ref from the request to the slot; the
+            # first token may already have completed the match (terminal)
+            self._slot_guided_key[slot] = request._guided_key
+            request._guided_key = None
+            self._gstate[slot] = request._gstate0
+        # mark the slot so the next dispatch merges the host value into the
+        # device-chained token/DFA vectors
+        self._slot_overrides[slot] = True
+        self._emit(slot, first_id, first_lp)
+
+    def _stage_slot_config(self, request: GenRequest, slot: int) -> None:
+        """The slot's rows of the host's sampling mirrors, written from its
+        request (loop thread): by the commit, or earlier by the plan of a
+        launch that goes behind the one ending the request's prompt and
+        carries it as a decode row (the slot is the request's since its
+        admission, and no launch reads the rows of a slot it does not
+        decode). Once a request: the cached device constants are dropped
+        only when the rows changed hands."""
+        if self._slot_config_of[slot] is request:
+            return
+        self._slot_config_of[slot] = request
         self._temperature[slot] = request.temperature
         self._top_k[slot] = request.top_k
         self._top_p[slot] = request.top_p
@@ -6012,20 +6124,13 @@ class LLMEngineCore:
             max(0, int(request.min_tokens or 0)), 2**31 - 1
         )
         self._stop_rows[slot] = self._request_stop_row(request)
-        if request._guided_key is not None:
-            # transfer the grammar ref from the request to the slot; the
-            # first token may already have completed the match (terminal)
-            self._slot_guided_key[slot] = request._guided_key
-            request._guided_key = None
-            self._gstate[slot] = request._gstate0
-        # fresh per-slot config: invalidate the cached device constants and
-        # mark the slot so the next dispatch merges the host value into the
-        # device-chained token/DFA vectors
+        self._slot_extra[slot] = self._request_has_extras(request)
+        # unguided until the commit says otherwise: a launch that still
+        # carried the slot's last owner may have written its state back
+        self._gstate[slot] = -1
+        # fresh per-slot config: invalidate the cached device constants
         self._sampling_dev = None
         self._extras_dev = None
-        self._slot_overrides[slot] = True
-        self._slot_extra[slot] = self._request_has_extras(request)
-        self._emit(slot, first_id, first_lp)
 
     async def _admission_task(self, request: GenRequest, slot: int) -> None:
         """Background prefill for one request; reserves `slot` via
@@ -6446,12 +6551,13 @@ class LLMEngineCore:
         return _RaggedJob(request=request, slot=slot, pos=pos)
 
     def _free_ragged_slot(self, slot: int) -> None:
-        """Reclaim a ragged job's slot pages (no pipeline barrier applies:
-        ragged steps run with the pipeline drained and are synchronous)."""
+        """Reclaim a ragged job's slot pages and state slot."""
         # a failed/cancelled job never seals its draft-ahead stream: the
         # receiver's unsealed assembly stays unconsumable and ages out
         self._kv_draft_ahead.pop(slot, None)
-        self._release_cache_slot(slot)
+        # at once where no launch in flight carries the row, else when the
+        # newest that does retires (the slot-reuse barrier)
+        self._free_slot_pages(slot)
 
     def _fail_ragged_job(self, job: "_RaggedJob",
                          err: Optional[BaseException]) -> None:
@@ -6499,6 +6605,118 @@ class LLMEngineCore:
             )
         return self._step_token_budget
 
+    def _ragged_ahead(self, flight: dict, budget: int) -> Optional[dict]:
+        """Loop thread, launch ``flight`` enqueued and not read back: the
+        host state as it WILL stand once it retires, for the plan of the
+        launch that goes behind it, or None where the step stays serial
+        (docs/ragged_attention.md, "A launch in flight"). Nothing is
+        written here.
+
+        What the next launch needs of this one is host arithmetic on its
+        plan (a decode row advances by its window, a job by its share, a
+        finishing slot becomes a decode row; the pools' lengths already
+        moved in its worker) and, for the rows it carried, their pending
+        tokens, which stay on the device (``carried``). A row that ends at
+        this launch for a reason the host knows (``max_new_tokens``,
+        ``max_seq_len``) is not planned; one that ends for a reason only
+        the device knows rides the next launch for nothing (a surplus row,
+        dropped at that launch's retire).
+
+        Serial, because the plan cannot be written ahead: a verify row
+        (the accepted length is the device's, and drafts come from the
+        host's token history: speculation keeps the step serial while it
+        is on), a guided prompt that ends here (the host walks its DFA
+        over the first token), a row the worker dropped for want of pages,
+        a recovery epoch that moved, a KV transport (ships read the pools
+        at the commit). Serial, because a request arriving now could have
+        used the next launch: neither (a) the prompts in the engine hold at
+        least the tokens the budget leaves after the decode rows, nor (b)
+        every slot is taken and nothing waits for admission; or a request
+        of a higher class than the backlog's waits or was just admitted.
+        And the next step is not this one's to plan where a paged engine
+        has no prompt left: the pipelined chunk runs its decode phases."""
+        if (
+            flight["epoch"] != self._recover_epoch
+            or flight["exhausted"] or flight["failed_jobs"]
+            or flight["spec_mask"].any() or flight["sspec_mask"].any()
+            or self._kv_transport is not None
+            or (
+                self._speculation
+                and (self._brownout is None or self._brownout.stage < 1)
+            )
+        ):
+            return None
+        reqs: List[Optional[GenRequest]] = list(self._slot_req)
+        produced = self._produced_counters()
+        carried = np.zeros(self.max_batch, bool)
+        staged = []
+
+        def stays(request, made: int) -> bool:
+            # _emit's endings that the host can tell before the token
+            return not (
+                request.cancelled
+                or made >= self._effective_max_new(request)
+                or request.prompt_len + made >= self.max_seq_len
+            )
+
+        for slot in np.nonzero(flight["decode_mask"])[0]:
+            slot = int(slot)
+            request = reqs[slot]
+            if request is None or request is not flight["row_req"][slot]:
+                reqs[slot] = None
+                continue
+            produced[slot] += int(flight["row_steps"][slot])
+            if stays(request, int(produced[slot])):
+                carried[slot] = True
+            else:
+                reqs[slot] = None
+        pos = {}
+        backlog = 0
+        ranks = []
+        takes = {id(job): take for job, take in flight["shares"]}
+        for job in self._prefill_jobs:
+            request = job.request
+            pos[id(job)] = job.pos + takes.get(id(job), 0)
+            left = len(request.prompt_ids) - pos[id(job)]
+            if left > 0:
+                backlog += left
+                ranks.append(_CLASS_RANK.get(request.priority, 0))
+                continue
+            if request.guided is not None:
+                return None
+            if stays(request, request.produced + 1):
+                reqs[job.slot] = request
+                produced[job.slot] = request.produced + 1
+                carried[job.slot] = True
+                staged.append((job.slot, request))
+        if backlog == 0 and self.cache_mode != "state":
+            return None
+        decode_mask = np.array([r is not None for r in reqs])
+        spoken_for = backlog >= budget - int(decode_mask.sum())
+        unopened = len(self._admitting) - len(self._prefill_jobs)
+        full = (
+            self._pending.empty() and unopened <= 0
+            and not any(
+                r is None and i not in self._admitting
+                and i not in self._quarantine
+                for i, r in enumerate(self._slot_req)
+            )
+        )
+        if not (spoken_for or full):
+            return None
+        waiting = self._pending.requests() + [
+            request for request, _slot in self._ragged_ready._queue
+        ]
+        if ranks and any(
+            _CLASS_RANK.get(r.priority, 0) < max(ranks) for r in waiting
+        ):
+            return None
+        return {
+            "reqs": reqs, "decode_mask": decode_mask, "produced": produced,
+            "carried": carried, "decoded": carried & flight["decode_mask"],
+            "pos": pos, "staged": staged,
+        }
+
     def _prepare_ragged(self, active_mask: np.ndarray,
                         epoch: int) -> Optional[dict]:
         """Loop-thread half of a ragged step: sweep dead jobs, classify the
@@ -6516,8 +6734,29 @@ class LLMEngineCore:
         dispatchable."""
         self._last_progress = time.monotonic()
         self._sweep_ragged_jobs()
-        decode_mask = active_mask.copy()
         budget = self._effective_token_budget()
+        behind = bool(self._ragged_flights)
+        if behind:
+            # one launch is in flight: this one goes behind it, planned
+            # from the host state as it will stand once that one retires,
+            # where the plan can be written ahead and the launch is spoken
+            # for (_ragged_ahead); else the step reads the one in flight
+            # back first and plans at its next iteration
+            view = self._ragged_ahead(self._ragged_flights[-1].plan, budget)
+            if view is None:
+                return None
+            for slot, request in view["staged"]:
+                self._stage_slot_config(request, slot)
+        else:
+            none = np.zeros(self.max_batch, bool)
+            view = {
+                "reqs": list(self._slot_req),
+                "decode_mask": active_mask.copy(),
+                "produced": self._produced_counters(),
+                "carried": none, "decoded": none, "pos": {},
+            }
+        reqs, produced, job_pos = view["reqs"], view["produced"], view["pos"]
+        decode_mask = view["decode_mask"]
         n_decode = int(decode_mask.sum())
         k_ = self._spec_k
         # spec-as-row: eligible decode slots become q=k+1 verify rows in
@@ -6525,7 +6764,7 @@ class LLMEngineCore:
         # at retire) — the serial spec scan never runs under this scheduler
         spec_mask = np.zeros(self.max_batch, bool)
         sspec_mask = np.zeros(self.max_batch, bool)
-        if self._ragged_spec_wanted(decode_mask):
+        if not behind and self._ragged_spec_wanted(decode_mask):
             greedy, sampled_m = self._spec_eligible_mask(decode_mask)
             spec_mask, sspec_mask = greedy.copy(), sampled_m.copy()
             if faults.active() and (spec_mask.any() or sspec_mask.any()):
@@ -6566,7 +6805,8 @@ class LLMEngineCore:
         for job in list(self._prefill_jobs):
             if left <= 0:
                 break
-            remaining = len(job.request.prompt_ids) - job.pos
+            pos = job_pos.get(id(job), job.pos)
+            remaining = len(job.request.prompt_ids) - pos
             take = min(left, remaining)
             if take <= 0:
                 continue
@@ -6604,12 +6844,12 @@ class LLMEngineCore:
             )
         row_steps = np.zeros(self.max_batch, np.int32)
         for slot in plain_slots:
-            request = self._slot_req[slot]
+            request = reqs[slot]
             remaining_new = (
-                self._effective_max_new(request) - request.produced
+                self._effective_max_new(request) - int(produced[slot])
             )
             remaining_len = self.max_seq_len - (
-                request.prompt_len + request.produced
+                request.prompt_len + int(produced[slot])
             )
             row_steps[slot] = max(
                 1, min(launch_steps, remaining_new, remaining_len)
@@ -6651,17 +6891,30 @@ class LLMEngineCore:
                 tree_depths[spec_slots] = forest.depths
                 tree_n[spec_slots] = forest.n_nodes
         want_lp = any(
-            self._slot_req[s] is not None
-            and self._slot_req[s].logprobs is not None
+            reqs[s] is not None and reqs[s].logprobs is not None
             for s in np.nonzero(decode_mask)[0]
         )
         use_extras = self._extras_active(decode_mask)
         use_guided = bool(np.any(self._gstate[decode_mask] >= 0))
         gtables = self._guided_device_tables() if use_guided else None
+        gstate = None
+        if gtables is not None:
+            # behind a launch in flight the rows it DECODED chain their DFA
+            # state on the device, as a pipelined chunk's do; the host's
+            # value wins for every other row (_chain_input): a slot that
+            # changed hands still holds its last owner's state there
+            self._slot_overrides[:] = decode_mask & ~view["decoded"]
+            gstate = self._chain_input(self._gstate_dev, self._gstate)
+            self._slot_overrides[:] = False
         self._dispatch_seq += 1
         plan = {
             "seq": self._dispatch_seq,
             "epoch": epoch,
+            # enqueued while the launch before it has not been read back
+            "behind": behind,
+            # each row's request as planned: a row whose slot holds another
+            # (or none) when the launch retires rode it for nothing
+            "row_req": reqs,
             "decode_mask": decode_mask,
             "shares": shares,
             "budget": budget,
@@ -6671,20 +6924,16 @@ class LLMEngineCore:
             # the cached constants; the produced-token counters ride the
             # launch's one upload (_upload_ragged_operands)
             "extras": self._extras_constants() if use_extras else None,
-            "counters": self._produced_counters() if use_extras else None,
+            "counters": produced if use_extras else None,
             "gtables": gtables,
-            "gstate": (
-                jnp.asarray(self._gstate.copy())
-                if gtables is not None
-                else None
-            ),
+            "gstate": gstate,
             "rng": self._next_rng(),
             "lora": (
                 jnp.asarray(self._lora_slots.copy())
                 if self._lora_enabled
                 else None
             ),
-            "requests": [r for r in self._slot_req if r is not None]
+            "requests": [r for r in reqs if r is not None]
             + [j.request for j, _ in shares],
             "exhausted": [],
             "failed_jobs": [],
@@ -6693,7 +6942,8 @@ class LLMEngineCore:
             # logits device-side before readback
             "finish_slots": [
                 job.slot for job, take in shares
-                if job.pos + take >= len(job.request.prompt_ids)
+                if job_pos.get(id(job), job.pos) + take
+                >= len(job.request.prompt_ids)
             ],
             # multi-step / spec-as-row row taxonomy
             # (docs/ragged_attention.md)
@@ -6780,6 +7030,9 @@ class LLMEngineCore:
         tok_row = np.zeros(dense, np.int32)
         tok_valid = np.zeros(dense, bool)
         tok_slot = np.full(dense, tpad, np.int32)
+        # where the device puts a carried row's pending token (past the
+        # end: the host wrote the row's token itself)
+        chain_at = np.full(self.max_batch, dense, np.int32)
         row_last = np.zeros(self.max_batch, np.int32)
         kv_lens = np.zeros(self.max_batch, np.int32)
         pre_lens = np.zeros(self.max_batch, np.int32)
@@ -6798,12 +7051,13 @@ class LLMEngineCore:
             pre_lens[slot] = pre
             if slot in job_of:
                 job = job_of[slot]
-                tokens[s : s + n] = job.request.prompt_ids[
-                    job.pos : job.pos + n
-                ]
+                pos = job_pos.get(id(job), job.pos)
+                tokens[s : s + n] = job.request.prompt_ids[pos : pos + n]
             elif spec_any[slot]:
                 tokens[s] = self._next_token[slot]
                 tokens[s + 1 : s + n] = drafts[slot]
+            elif view["carried"][slot]:
+                chain_at[slot] = s
             else:
                 tokens[s] = self._next_token[slot]
             spans[slot] = (s, n)
@@ -6855,9 +7109,11 @@ class LLMEngineCore:
         plan.update(
             tokens=tokens, tok_pos=tok_pos, tok_row=tok_row,
             tok_valid=tok_valid, tok_slot=tok_slot, row_last=row_last,
-            kv_lens=kv_lens,
+            chain_at=chain_at, kv_lens=kv_lens,
             pre_lens=pre_lens, row_starts=starts, row_lens=row_lens,
             span_lens=span_lens, spans=spans,
+            # every row the launch writes pages / state for ([B] bool)
+            rows=span_lens > 0,
             # state cache: a row whose tokens start its sequence finds
             # its slot as the last owner left it — the launch zeroes it
             row_reset=(pre_lens == 0) & (row_lens > 0),
@@ -6905,7 +7161,7 @@ class LLMEngineCore:
             ("tok_row", (dense,), False), ("tok_valid", (dense,), True),
             ("row_last", (b,), False), ("kv_lens", (b,), False),
             ("row_starts", (b,), False), ("row_lens", (b,), False),
-            ("decode_mask", (b,), True),
+            ("decode_mask", (b,), True), ("chain_at", (b,), False),
         ]
         if self.cache_mode == "paged":
             entries += [
@@ -6956,7 +7212,11 @@ class LLMEngineCore:
         PERF.md section 6) and is never written again (on the CPU backend
         the device array may alias it: the hazard _chain_input records);
         the unpack program hands back the operands by name, before the
-        ``enqueue`` stamp."""
+        ``enqueue`` stamp. Behind a launch in flight the program also puts
+        the carried rows' pending tokens, which never left the device
+        (``_next_token_dev``), where ``chain_at`` says; a launch planned
+        from the host's own tokens passes a null chain and every
+        ``chain_at`` past the end."""
         layout, total = self._ragged_layouts[(
             plan["launch_steps"], plan["use_extras"],
             plan["row_logit_idx"] is not None,
@@ -6965,7 +7225,10 @@ class LLMEngineCore:
         for name, offset, shape, _ in layout:
             staged[offset : offset + math.prod(shape)] = plan[name].reshape(-1)
         plan["h2d_transfers"] = 1
-        return self._ragged_unpack_jit(staged, layout)
+        return self._ragged_unpack_jit(
+            staged, layout,
+            self._next_token_dev if plan["behind"] else self._chain_null,
+        )
 
     def _ragged_drop_row(self, plan: dict, slot: int) -> None:
         """Worker-side removal of a row whose page extension failed: its
@@ -6977,6 +7240,7 @@ class LLMEngineCore:
         plan["tok_row"][s : s + n] = 0
         plan["tok_valid"][s : s + n] = False
         plan["tok_slot"][s : s + n] = self._ragged_tpad
+        plan["chain_at"][slot] = len(plan["tokens"])
         plan["row_lens"][slot] = 0
         plan["span_lens"][slot] = 0
         plan["kv_lens"][slot] = plan["pre_lens"][slot]
@@ -7217,6 +7481,19 @@ class LLMEngineCore:
             self._enqueue_first_token(plan["first_ops"][slot], slot, logits)
             for slot in finish
         ]
+        if self.pipeline_depth > 1:
+            # what a launch behind this one takes on the device: each
+            # row's pending token (the window's last sample; a finishing
+            # prompt's first token at its slot) and the DFA states
+            chain = sampled
+            for slot, (ids, _lp) in zip(finish, first):
+                chain = self._ragged_chain_jit(chain, ids, np.int32(slot))
+            if chain.ndim > 1:
+                chain = self._ragged_chain_jit(
+                    chain, self._first_null, np.int32(self.max_batch)
+                )
+            self._next_token_dev = chain
+            self._gstate_dev = gstate_out if gtables is not None else None
         self._last_progress = time.monotonic()
         return {
             "stamps": self._worker_out(stamps),
@@ -7255,23 +7532,91 @@ class LLMEngineCore:
         """One ragged scheduling iteration (docs/ragged_attention.md): ONE
         device launch carries every decode row (one token each) plus as
         many prefill-chunk rows as fit the step token budget — admissions
-        no longer stall the decode loop, they share its launches. Serial
-        dispatch -> read -> emit, with both waits in worker threads: the
-        event loop's handlers (the streams the LAST emission woke) run
-        while this launch does. True once the step awaited its worker, so
-        the caller need not hand the event loop over again; the pipelined
-        in-flight queue resumes the moment the admission backlog drains."""
-        # post-ragged decode must re-upload the host mirrors: the device
-        # chains were built by the (drained) pipelined path
+        no longer stall the decode loop, they share its launches. The step
+        keeps ONE launch in flight (``pipeline_depth`` 2 or more; at 1 it
+        is the serial dispatch -> read -> emit): with launch N enqueued it
+        plans launch N+1 from the host state as it will stand once N
+        retires and hands it to the dispatch worker, where the plan can be
+        written ahead and the launch is spoken for (_ragged_ahead), and
+        only then reads N back, retires it and emits its tokens, while N+1
+        runs; N+1 stays out for the next iteration. All waits are worker
+        threads': the event loop's handlers (the streams the LAST emission
+        woke) run while the launches do. True once the step awaited a
+        worker, so the caller need not hand the event loop over again; the
+        pipelined in-flight queue resumes the moment the admission backlog
+        drains."""
+        flights = self._ragged_flights
+        ahead = self.pipeline_depth > 1
+        wait_at = None
+        if not flights:
+            # post-ragged decode must re-upload the host mirrors: the
+            # device chains were built by the (drained) pipelined path
+            self._reset_device_chains()
+            launched, wait_at = await self._ragged_launch(
+                active_mask, epoch, "plan" if ahead else "wait"
+            )
+            if not launched:
+                return launched is False
+        if ahead:
+            # behind the launch in flight, where it is spoken for
+            wait_at = (
+                await self._ragged_launch(active_mask, epoch, "wait")
+            )[1]
+        plan, result = flights[0].plan, flights[0].result
+        seq = plan["seq"]
+        # the read that closed the ``launch`` of a dispatch that just
+        # landed opened this ``wait``
+        plan["wait_at"] = (
+            self._cycle.mark("wait", seq) if wait_at is None else wait_at
+        )
+        # enqueued: what is left is the device's run, and a hang there
+        # gets no compile grace from the watchdog. The launch still
+        # counts as dispatching (its program writes the rows' pages)
+        # until its results are in hand
+        self._dispatching = (seq, plan["rows"], None)
+        try:
+            sampled, rest, ready_at, awaited = await self._read_back(
+                seq,
+                result["sampled"],
+                {
+                    name: result[name]
+                    for name in ("gstate", "lp", "spec_acc", "spec_g", "first")
+                },
+            )
+        finally:
+            self._dispatching = None
+        if plan["epoch"] != self._recover_epoch:
+            await self._ragged_recover(plan)
+            return True
+        self._retire_ragged(plan, dict(
+            result, sampled=sampled, ready_at=ready_at,
+            off_loop=int(awaited), **rest,
+        ))
+        flights.popleft()
+        if not flights:
+            # the pipelined chunk starts from the host mirrors
+            self._reset_device_chains()
+        return True
+
+    async def _ragged_launch(self, active_mask: np.ndarray, epoch: int,
+                             then: str) -> tuple:
+        """Plan a ragged launch and hand it to the dispatch worker: pages,
+        the staged upload, the jitted step, the first-token programs. True
+        with the launch in ``_ragged_flights``; None where nothing was
+        planned (nothing dispatchable, or a launch is in flight and the
+        next cannot go behind it); False where the worker raised for one
+        admission's request and only that job was failed. With it, the
+        clock read that closed the cycle's ``launch`` and opened ``then``
+        (``plan``: the step tries the next launch behind this one;
+        ``wait``), None where nothing landed."""
         self._cycle.mark("plan", self._dispatch_seq + 1)
-        self._reset_device_chains()
         plan = self._prepare_ragged(active_mask, epoch)
         if plan is None:
-            return False
+            return None, None
         seq = plan["seq"]
         plan["launch_at"] = self._cycle.mark("launch", seq)
         plan["launched"] = threading.Event()
-        self._dispatching = (seq, plan["decode_mask"], time.monotonic())
+        self._dispatching = (seq, plan["rows"], time.monotonic())
         try:
             launch = asyncio.get_running_loop().run_in_executor(
                 None, self._dispatch_ragged_device, plan
@@ -7303,36 +7648,20 @@ class LLMEngineCore:
                         "ragged admission chunk failed for this request: "
                         "{}".format(ex)
                     ))
-                    return True
+                    return False, None
                 raise
-            plan["wait_at"] = self._cycle.mark("wait", seq)
-            self._cycle.landed(
-                seq, plan["launch_at"], result["stamps"], plan["wait_at"]
-            )
-            # enqueued: what is left is the device's run, and a hang there
-            # gets no compile grace from the watchdog. The launch still
-            # counts as dispatching (its program writes the rows' pages)
-            # until its results are in hand
-            self._dispatching = (seq, plan["decode_mask"], None)
-            sampled, rest, ready_at, awaited = await self._read_back(
-                seq,
-                result["sampled"],
-                {
-                    name: result[name]
-                    for name in ("gstate", "lp", "spec_acc", "spec_g", "first")
-                },
-            )
-            result = dict(
-                result, sampled=sampled, ready_at=ready_at,
-                off_loop=int(awaited), **rest,
-            )
         finally:
             self._dispatching = None
-        if epoch != self._recover_epoch:
-            await self._ragged_recover(plan)
-            return True
-        self._retire_ragged(plan, result)
-        return True
+        self._ragged_flights.append(_RaggedFlight(plan, result))
+        now = self._cycle.mark(
+            then,
+            self._ragged_flights[0].seq if then == "wait"
+            else self._dispatch_seq + 1,
+        )
+        self._cycle.landed(seq, plan["launch_at"], result["stamps"], now)
+        if plan["behind"]:
+            self.counters["ragged_launches_behind"] += 1
+        return True, now
 
     async def _read_back(self, seq: int, first, rest) -> tuple:
         """A launch's blocking device-to-host copies, off the loop thread: a
@@ -7382,33 +7711,44 @@ class LLMEngineCore:
         return head, tail, ready_at, awaited
 
     async def _ragged_recover(self, plan: dict) -> None:
-        """The watchdog tripped while this ragged step was mid-worker: the
-        decode results are stale (those requests were already failed) and
-        no commit may run. The step's read waited the device program out
-        off-thread; roll surviving jobs' page extensions back to their
-        pre-step lengths (the next step redoes the chunk cleanly — its K/V
-        rewrites are value-identical), then run the shared recovery."""
-        if self.paged_cache is not None:
-            pool = self.paged_cache.pool
+        """The watchdog tripped while ragged launches were out: the decode
+        results are stale (those requests were already failed) and no
+        commit may run. ``plan`` is the OLDEST of them, whose read has
+        waited its program out; a launch enqueued behind it is waited out
+        here, off-thread. The surviving jobs' page extensions roll back to
+        what the oldest launch found (the next step redoes the chunks
+        cleanly: its K/V rewrites are value-identical), then the shared
+        recovery runs."""
+        younger = [f for f in self._ragged_flights if f.plan is not plan]
+        self._ragged_flights.clear()
+        if younger:
+            await asyncio.to_thread(self._wait_chunks, younger)
+        self._ragged_rollback([plan] + [f.plan for f in younger])
+        await self._finish_recovery()
+
+    def _ragged_rollback(self, plans: list) -> None:
+        """Loop thread, the launches of ``plans`` (oldest first) waited out
+        and given up: each job that is still served goes back to before
+        the first of them that carried a chunk of it."""
+        seen = set()
+        for plan in plans:
             for job, _take in plan["shares"]:
-                if job in self._prefill_jobs:  # identity compare
-                    if self.state_cache is not None:
-                        # the row's state took the chunk and cannot give it
-                        # back: pages and state start the prompt again
-                        pool.truncate(job.slot, 0)
-                        self.state_cache.rewind(job.slot)
-                        job.pos = 0
-                        continue
-                    pool.truncate(job.slot, int(plan["pre_lens"][job.slot]))
-        elif self.state_cache is not None:
-            # a state cannot be rolled back to before the chunk: the
-            # surviving jobs start their prompts again (recompute; the
-            # launch that carries their first chunk zeroes the slot)
-            for job, _take in plan["shares"]:
-                if job in self._prefill_jobs:
+                if id(job) in seen or job not in self._prefill_jobs:
+                    continue                     # identity compare
+                seen.add(id(job))
+                if self.state_cache is not None:
+                    # a state took the chunk and cannot give it back: the
+                    # job starts its prompt again (recompute; the launch
+                    # that carries its first chunk zeroes the slot), and
+                    # where pages lie beside the state they go too
+                    if self.paged_cache is not None:
+                        self.paged_cache.pool.truncate(job.slot, 0)
                     self.state_cache.rewind(job.slot)
                     job.pos = 0
-        await self._finish_recovery()
+                    continue
+                self.paged_cache.pool.truncate(
+                    job.slot, int(plan["pre_lens"][job.slot])
+                )
 
     def _retire_ragged(self, plan: dict, result: dict) -> None:
         """Loop-thread tail of a ragged step, over the HOST copies of its
@@ -7481,6 +7821,17 @@ class LLMEngineCore:
                 slot, MemoryError("kv page pool exhausted for this sequence")
             )
         decode_slots = [int(s) for s in np.nonzero(plan["decode_mask"])[0]]
+        # a row that ended at the launch before this one for a reason only
+        # the device knew (EOS, a stop token, a cancel or a deadline found
+        # at the emission) rode this launch, planned behind that one, for
+        # one surplus step: nothing of it is emitted, and its pages and its
+        # state slot go back below, when the slot leaves quarantine
+        surplus = {
+            s for s in decode_slots
+            if self._slot_req[s] is not plan["row_req"][s]
+        }
+        self.counters["ragged_surplus_rows"] += len(surplus)
+        decode_slots = [s for s in decode_slots if s not in surplus]
         plain_slots = [s for s in decode_slots if not spec_any[s]]
         spec_slots = [s for s in decode_slots if spec_any[s]]
         if spec_slots:
@@ -7681,6 +8032,9 @@ class LLMEngineCore:
                 # point (docs/disaggregation.md)
                 self._maybe_ship(request, job.slot)
             self._activate_slot(request, job.slot, first_id, first_lp)
+        # slots freed while this launch still carried their rows (a
+        # surplus row, a job failed after it was planned) are free now
+        self._release_quarantine(seq)
         # retire-stage promotion reap, same rule as the pipelined retire
         self._reap_promotions()
         self._last_progress = time.monotonic()
@@ -7717,15 +8071,18 @@ class LLMEngineCore:
             # ever run, so drop the queue and its deferred frees here,
             # waiting out still-executing chunks off-thread before their
             # pages recycle (skipped on hard cancellation = teardown)
-            dropped = list(self._inflight)
+            dropped = list(self._inflight) + list(self._ragged_flights)
             self._inflight.clear()
+            self._ragged_flights.clear()
             if self._ledger is not None:
                 for slot in self._quarantine:
                     lifecycle_ledger.release("slot.quarantine", key=slot,
                                              domain=self, all_of_key=True)
             self._quarantine.clear()
             self._reset_device_chains()
-            if self.paged_cache is not None and dropped:
+            if (
+                self.paged_cache is not None or self.state_cache is not None
+            ) and dropped:
                 try:
                     await asyncio.to_thread(self._wait_chunks, dropped)
                 except BaseException:
@@ -7851,6 +8208,7 @@ class LLMEngineCore:
                 not active_mask.any()
                 and not self._inflight
                 and not self._prefill_jobs
+                and not self._ragged_flights
             ):
                 if (
                     self._pending.empty()
@@ -7889,6 +8247,8 @@ class LLMEngineCore:
             try:
                 if (
                     self._prefill_jobs
+                    # a ragged launch is out: its step reads it back
+                    or self._ragged_flights
                     or self._ragged_spec_wanted(active_mask)
                     # the state cache has ONE step: decode-only phases run
                     # it too (rows of one token and their chained windows)
@@ -8423,7 +8783,11 @@ class LLMEngineCore:
         for slot in slots:
             # host mirrors re-anchor at retire (the device chain moved on
             # at dispatch); slots committed after this chunk's dispatch are
-            # not in its mask, so fresh state is never clobbered
+            # not in its mask, so fresh state is never clobbered, and a
+            # slot whose row ended in an older chunk keeps what its release
+            # wrote (its DFA state cleared: the next owner's)
+            if self._slot_req[slot] is None:
+                continue
             self._next_token[slot] = int(chunk_np[slot, -1])
             if gstate_np is not None:
                 self._gstate[slot] = int(gstate_np[slot])

@@ -65,6 +65,7 @@ WARMUP_COVERED = frozenset({
     "_ragged_paged_jit",
     "_ragged_state_jit",
     "_ragged_unpack_jit",
+    "_ragged_chain_jit",
 })
 
 
@@ -195,7 +196,18 @@ def warm_ragged_variants(engine) -> int:
         # the variant's unpack program (the launch's ONE upload sliced back
         # into operands, engine._upload_ragged_operands), over a null buffer
         layout, total = engine._ragged_layouts[(steps, False, spec_on)]
-        engine._ragged_unpack_jit(np.zeros(total, np.int32), layout)
+        engine._ragged_unpack_jit(
+            np.zeros(total, np.int32), layout, engine._chain_null
+        )
+
+    def chain_of(sampled):
+        # the rows' pending tokens for a launch behind this one
+        # (engine._dispatch_ragged_device_inner): the window's last row,
+        # a finishing prompt's first token put in
+        if engine.pipeline_depth > 1:
+            engine._ragged_chain_jit(
+                sampled, engine._first_null, np.int32(b)
+            )
 
     def spec_args(on):
         if not on:
@@ -282,6 +294,7 @@ def warm_ragged_variants(engine) -> int:
                     if engine._paged_quant:
                         cache.k_scale = new_ks
                         cache.v_scale = new_vs
+                chain_of(sampled)
                 jax.block_until_ready(sampled)
                 ran += 1
     else:
@@ -321,6 +334,7 @@ def warm_ragged_variants(engine) -> int:
                     want_lp=False,
                     chain=chain,
                 )
+            chain_of(sampled)
             jax.block_until_ready(sampled)
             ran += 1
     return ran

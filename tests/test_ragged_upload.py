@@ -96,8 +96,19 @@ class Uploads:
                    plan["row_logit_idx"] is not None)
             layout, _total = engine._ragged_layouts[key]
             host = {name: np.array(plan[name]) for name, *_ in layout}
+            sizes = {name: v.size for name, v in host.items()}
+            # the unpack program consumes ``chain_at``: a row the launch in
+            # flight carried takes its pending token from the device's
+            # chain there, every other row points past the end
+            at = host.pop("chain_at")
+            carried = at < host["tokens"].size
+            assert plan["behind"] or not carried.any()
+            if carried.any():
+                chain = np.asarray(engine._next_token_dev)
+                host["tokens"][at[carried]] = chain[carried]
             dev = upload(plan)
             self.seen.append({"key": key, "host": host, "dev": dev, "plan": plan,
+                              "sizes": sizes,
                               "dropped": bool(plan["exhausted"] or plan["failed_jobs"])})
             return dev
 
@@ -223,9 +234,9 @@ def test_unpacked_operands_are_the_per_array_uploads(case, paged_parts, state_pa
         # one layout a variant, no wider than its operands, never rebuilt
         for upload in uploads:
             layout, total = engine._ragged_layouts[upload["key"]]
-            assert total == sum(v.size for v in upload["host"].values())
+            assert total == sum(upload["sizes"].values())
             assert [e[1] for e in layout] == list(np.cumsum(
-                [0] + [upload["host"][e[0]].size for e in layout])[:-1])
+                [0] + [upload["sizes"][e[0]] for e in layout])[:-1])
     finally:
         engine.stop()
 
@@ -277,10 +288,10 @@ def test_the_worker_uploads_nothing_else_before_the_call(paged_parts, monkeypatc
             return fn(*args, **kw)
         return call
 
-    def unpacking(staged, layout):
+    def unpacking(staged, layout, chain):
         assert inside and type(staged) is np.ndarray
         calls.append("unpack")
-        return unpack(staged, layout)
+        return unpack(staged, layout, chain)
 
     engine._dispatch_ragged_device, engine._ragged_paged_jit = worker, called
     engine._ragged_unpack_jit = unpacking
@@ -363,9 +374,9 @@ def test_a_second_pass_compiles_nothing(kind, paged_parts, state_parts, monkeypa
     layouts, firsts = [], []
     unpack, first_token = engine._ragged_unpack_jit, engine._first_token_jit
 
-    def unpacking(staged, layout):
+    def unpacking(staged, layout, chain):
         layouts.append((staged.shape, layout))
-        return unpack(staged, layout)
+        return unpack(staged, layout, chain)
 
     def sampling(logits, staged, layout, key, state, keyed):
         firsts.append((tuple(logits.shape), staged.shape, layout, keyed))
